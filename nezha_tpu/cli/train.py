@@ -858,9 +858,8 @@ def _run_traced(args) -> Dict[str, float]:
         from nezha_tpu.graph import programs
         mode, mesh = graph_mode, None
         if mode in ("dp", "zero1") and len(jax.devices()) == 1:
-            print(f"WARNING: --engine graph --parallel {mode} with 1 "
-                  f"visible device; running single-device", file=sys.stderr)
-            mode = "single"
+            raise SystemExit(f"--engine graph --parallel {mode} needs more "
+                             f"than the 1 visible device")
         if mode in ("dp", "zero1"):
             mesh_axes = _parse_mesh(args.mesh) or _parse_mesh("dp=-1")
             if list(mesh_axes) != ["dp"]:
@@ -970,8 +969,16 @@ def _run_traced(args) -> Dict[str, float]:
             _req_size *= _v  # any -1 ("all devices") counts as multi
         if (mode != "single" and len(jax.devices()) == 1
                 and _req_size != 1):
-            # Degrade, but never silently: a mis-launched multi-host job
-            # would otherwise "succeed" at 1/Nth scale.
+            if args.parallel != "config" or args.mesh:
+                # An explicit multi-device request that one device cannot
+                # meet is an error: a mis-launched job must not "succeed"
+                # at 1/Nth scale.
+                raise SystemExit(
+                    f"--parallel {mode}"
+                    + (f" --mesh {args.mesh}" if args.mesh else "")
+                    + " needs more than the 1 visible device")
+            # Only a config's DEFAULT mode degrades (one chip is a normal
+            # place to run gpt2_124m), and never silently.
             print(f"WARNING: config {args.config!r} requests parallel mode "
                   f"{mode!r} but only 1 device is visible; running "
                   f"single-device (check your mesh/launch if this is a "
@@ -1236,6 +1243,18 @@ def _run_traced(args) -> Dict[str, float]:
                          f"(single/dp/sp); got mode {mode!r}, engine "
                          f"{args.engine!r} — use --on-failure stop with a "
                          f"supervisor relaunch")
+    # Where the arrays REALLY live, reported with the final metrics: a
+    # mis-launch that quietly ran on one device shows 1 here whatever the
+    # mesh said (chip_smoke.py asserts on these).
+    placement = {"batch_devices": 1}
+    if shard is not None:
+        place_batch = shard
+
+        def shard(b):
+            out = place_batch(b)
+            placement["batch_devices"] = max(device_span(out), 1)
+            return out
+
     trainer = Trainer(
         model, optimizer, cfg.loss_fn,
         checkpoint_dir=args.ckpt_dir,
@@ -1318,6 +1337,10 @@ def _run_traced(args) -> Dict[str, float]:
         trainer._save(start_step + args.steps)
         if async_ckpt is not None:
             async_ckpt.wait()
+    last.update(placement,
+                state_devices=max(device_span(trainer.state), 1),
+                state_split_devices=device_span(trainer.state,
+                                                 split_only=True))
     if args.eval or args.eval_every:
         results = _run_eval(args, cfg, batch_size, mode, model, trainer,
                             pspec if mode == "pp" else None,
@@ -1326,6 +1349,22 @@ def _run_traced(args) -> Dict[str, float]:
             print(json.dumps({"eval": results}), file=sys.stderr)
             last.update({f"eval_{k}": v for k, v in results.items()})
     return last
+
+
+def device_span(tree, split_only: bool = False) -> int:
+    """Most devices any jax.Array leaf of ``tree`` occupies; with
+    ``split_only``, counting only leaves whose sharding partitions them
+    (replicas excluded). 0 when no leaf qualifies."""
+    import jax
+
+    span = 0
+    for x in jax.tree_util.tree_leaves(tree):
+        sharding = getattr(x, "sharding", None)
+        if sharding is None or (split_only
+                                and sharding.is_fully_replicated):
+            continue
+        span = max(span, len(sharding.device_set))
+    return span
 
 
 def _run_eval(args, cfg, batch_size, mode, model, trainer, pspec,

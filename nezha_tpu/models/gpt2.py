@@ -141,16 +141,11 @@ def _tp_sharded_flash(q, k, v, mesh, causal: bool = True,
 def _tp_flash_mesh(num_heads: int):
     """The enclosing gspmd mesh when the nested-shard_map flash path is
     usable for ``num_heads`` (TPU backend, a ``tp`` axis that divides the
-    heads); None otherwise. ``NEZHA_NO_NESTED_KERNELS=1`` disables it —
-    the day-1 escape hatch if Mosaic-inside-shard_map misbehaves on real
-    hardware (parity is virtual-mesh-proven; real-ICI compile is not)."""
-    import os
-
+    heads); None otherwise. (Mosaic inside shard_map compiled and ran on
+    a four-chip v5e host in PR 21 — ``chip_smoke.py --chips 4``.)"""
     import jax
 
     from nezha_tpu.parallel.gspmd import auto_partitioner_mesh
-    if os.environ.get("NEZHA_NO_NESTED_KERNELS"):
-        return None
     mesh = auto_partitioner_mesh()
     if (mesh is not None and "tp" in mesh.axis_names
             and num_heads % mesh.shape["tp"] == 0
@@ -199,7 +194,7 @@ def _decode_flash_shmap_mesh(cfg):
     engine's path, ops/pallas/decode_attention.py
     ``flash_decode_attention_sharded``); None otherwise. Same gates as
     the prefill ``flash_shmap`` idiom — TPU backend, a ``tp`` axis
-    dividing the heads, ``NEZHA_NO_NESTED_KERNELS`` honored — plus the
+    dividing the heads — plus the
     decode kernel's own switches (``decode_impl``, the shared
     ``attn_impl`` resolution, ``NEZHA_NO_DECODE_KERNEL``).
     ``decode_impl="kernel"`` honors the force on ANY backend (interpret
@@ -209,8 +204,7 @@ def _decode_flash_shmap_mesh(cfg):
     auto-partitions under the mesh."""
     import os
 
-    if os.environ.get("NEZHA_NO_DECODE_KERNEL") \
-            or os.environ.get("NEZHA_NO_NESTED_KERNELS"):
+    if os.environ.get("NEZHA_NO_DECODE_KERNEL"):
         return None
     if cfg.decode_impl == "xla":
         return None
@@ -261,8 +255,7 @@ def _prefill_flash_shmap_mesh(cfg):
     kernel)."""
     import os
 
-    if os.environ.get("NEZHA_NO_PREFILL_KERNEL") \
-            or os.environ.get("NEZHA_NO_NESTED_KERNELS"):
+    if os.environ.get("NEZHA_NO_PREFILL_KERNEL"):
         return None
     if cfg.prefill_impl == "xla":
         return None
@@ -695,8 +688,8 @@ class Attention(Module):
                 # The nested shard_map owns BOTH the pool write and
                 # the chunk attention; the kernel-vs-composed choice
                 # mirrors prefill_impl exactly (the shmap-mesh
-                # resolver honors NEZHA_NO_PREFILL_KERNEL and
-                # NEZHA_NO_NESTED_KERNELS, and is backend-aware).
+                # resolver honors NEZHA_NO_PREFILL_KERNEL and is
+                # backend-aware).
                 starts = jnp.broadcast_to(
                     jnp.asarray(pos, jnp.int32), (b,))
                 use_k = _prefill_flash_shmap_mesh(cfg) is not None

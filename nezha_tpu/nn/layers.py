@@ -16,7 +16,6 @@ workloads (SURVEY.md §2: matmul, conv, norms, embedding, dropout, pooling).
 
 from __future__ import annotations
 
-import os
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
@@ -165,6 +164,13 @@ class BatchNorm(Module):
         return self.policy.cast_output(y), new_state
 
 
+def _ln_kernel_backend() -> bool:
+    """Whether ``impl="pallas"`` layer norms run the fused kernel: TPU
+    backends only (tests patch this to drive the kernel through the
+    Pallas interpreter on CPU)."""
+    return jax.default_backend() == "tpu"
+
+
 class LayerNorm(Module):
     """Layer norm over the last axis; statistics in fp32.
 
@@ -201,9 +207,7 @@ class LayerNorm(Module):
     def apply(self, variables: Variables, x, training: bool = False, rng=None):
         del training, rng
         p = variables["params"]
-        force = os.environ.get("NEZHA_LN_INTERPRET")  # CPU test hook
-        if self.impl == "pallas" and (jax.default_backend() == "tpu"
-                                      or force):
+        if self.impl == "pallas" and _ln_kernel_backend():
             from nezha_tpu.parallel.gspmd import (auto_partitioner_mesh,
                                                   under_auto_partitioner)
             if not under_auto_partitioner():
@@ -214,8 +218,6 @@ class LayerNorm(Module):
                     jnp.asarray(p["bias"], jnp.float32), eps=self.eps)
                 return self.policy.cast_output(y), {}
             mesh = auto_partitioner_mesh()
-            if os.environ.get("NEZHA_NO_NESTED_KERNELS"):
-                mesh = None  # day-1 escape hatch; see gpt2._tp_flash_mesh
             if mesh is not None and "dp" in mesh.axis_names and x.ndim >= 2:
                 # Under the GSPMD auto-partitioner (which cannot partition
                 # a Mosaic call) the kernel still runs device-locally via

@@ -13,24 +13,33 @@ so every existing kernel test pins the refactor.
 
 Also hosts the package-wide scalar helpers: the finite ``NEG_BIG``
 "-inf" (fully-masked rows must stay NaN-free), the ``LANES`` lane
-width small per-row operands broadcast to, the CompilerParams rename
-shim and the block-divisor picker.
+width of the per-row scratch, the int8 block-scale operand layout and
+the block-divisor picker.
 """
 
 from __future__ import annotations
 
+from typing import Optional
+
+import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.pallas import tpu as pltpu
 
 NEG_BIG = -1e30
-LANES = 128  # per-row scalars ride lane-broadcast: [B, 128]
+LANES = 128  # per-row softmax statistics ride lane-broadcast: [rows, 128]
 
-# jax renamed pltpu.TPUCompilerParams -> CompilerParams; resolve whichever
-# this install ships so the compiled-TPU path works on either side of the
-# rename (the interpret path never touches it).
-compiler_params = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """THE ``interpret=None`` rule of every kernel in this package:
+    compiled on a TPU backend, the Pallas interpreter elsewhere (so CPU
+    tests run the same kernel code). On a TPU backend the interpreter is
+    refused outright — a kernel the compiler rejects must fail there,
+    not quietly run interpreted."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret and on_tpu:
+        raise ValueError("interpret=True on a TPU backend: kernels run "
+                         "compiled there")
+    return not on_tpu if interpret is None else interpret
 
 
 def pick_block(size: int, target: int) -> int:
@@ -40,6 +49,27 @@ def pick_block(size: int, target: int) -> int:
     while size % b:
         b -= 1
     return b
+
+
+def gather_row_scales(scales, block_tables):
+    """Int8-pool scales ``[N, H]`` gathered through ``block_tables
+    [B, M]`` into the kernel operand layout ``[B, H, 1, M]``: one lane
+    vector of per-block scales per (row, head). The TPU lowering refuses
+    a ``(1, 1)`` block over ``[N, H]`` (the last two block dims must be
+    (8, 128)-divisible or span the array's), so the per-block scalar is
+    picked in-kernel by :func:`block_scale` from a block that spans the
+    trailing ``(1, M)`` dims."""
+    rows = jnp.asarray(scales, jnp.float32)[block_tables]    # [B, M, H]
+    return rows.transpose(0, 2, 1)[:, :, None, :]
+
+
+def block_scale(rows_ref, t):
+    """Entry ``t`` of a :func:`gather_row_scales` block as a ``[1, 1]``
+    tile (a masked lane reduction — Mosaic has no dynamic lane index
+    into VMEM). Exact: one selected element plus zeros."""
+    row = rows_ref[0, 0]                                     # [1, M]
+    lane = lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(jnp.where(lane == t, row, 0.0), axis=-1, keepdims=True)
 
 
 def scratch_init(m_scr, l_scr, acc_scr):

@@ -13,15 +13,18 @@ kernel, not a generic lowering):
   ("arbitrary" semantics) — the split-K layout: each program folds one
   KV block into VMEM running ``(max, sum, acc)`` scratch via online
   softmax, merged at the final block (no score matrix, no mask tensor);
-- a per-row ``lengths`` operand: a program whose block starts at or past
-  its row's length SKIPS the block entirely (``@pl.when``), so short rows
-  and inactive rows (``length == 0``) cost block-bookkeeping only — work
-  is proportional to ``sum(lengths)``, not ``B * L_max``;
+- per-row ``lengths`` ride as a SCALAR-PREFETCH operand (SMEM — the TPU
+  lowering refuses a ``(1, 128)`` VMEM block over ``[B, 128]``): a
+  program whose block starts at or past its row's length SKIPS the block
+  entirely (``@pl.when``), so short rows and inactive rows
+  (``length == 0``) cost block-bookkeeping only — compute is
+  proportional to ``sum(lengths)``, not ``B * L_max``;
 - Q·Kᵀ and P·V accumulate fp32 over the caches' native dtype (bf16 pool
   dots run at the doubled MXU rate; the softmax statistics and the
   accumulator stay fp32 throughout);
 - ``interpret=None`` auto-selects the Pallas interpreter off-TPU, so CPU
-  tests exercise the same kernel code that compiles on hardware.
+  tests exercise the same kernel code that compiles on hardware
+  (tests/test_tpu_compile.py compiles every variant for a v5e).
 
 Decode is inference-only, so there is no VJP; ``models/gpt2.py`` routes
 its single-token cache branch here behind the ``attn_impl="auto"``
@@ -46,12 +49,17 @@ from jax.experimental.pallas import tpu as pltpu
 # inline version.
 from nezha_tpu.ops.pallas.common import (
     LANES as _LANES,
+    block_scale,
     block_step as _block_step,
-    compiler_params as _compiler_params,
+    gather_row_scales,
     pick_block as _pick_block,
+    resolve_interpret,
     scratch_init as _scratch_init,
     softmax_finalize,
 )
+
+_DECODE_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _finalize(o_ref, l_scr, acc_scr):
@@ -60,7 +68,7 @@ def _finalize(o_ref, l_scr, acc_scr):
     softmax_finalize(o_ref, None, l_scr, acc_scr)
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr,
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
                    acc_scr, *, scale: float, block_k: int):
     ki = pl.program_id(2)
 
@@ -68,7 +76,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr,
     def _init():
         _scratch_init(m_scr, l_scr, acc_scr)
 
-    length = len_ref[0, 0]
+    length = len_ref[pl.program_id(0)]
     # The block-skip that the dense masked path cannot see: blocks at or
     # past this row's length never load K/V or touch the MXU. A row with
     # length == 0 (inactive slot) runs no block at all and finalizes to
@@ -86,7 +94,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr,
         _finalize(o_ref, l_scr, acc_scr)
 
 
-def _paged_decode_kernel(tab_ref, q_ref, k_ref, v_ref, len_ref, o_ref,
+def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                          m_scr, l_scr, acc_scr, *, scale: float,
                          block_k: int):
     # Identical math to the dense kernel: the block table only changed
@@ -94,27 +102,27 @@ def _paged_decode_kernel(tab_ref, q_ref, k_ref, v_ref, len_ref, o_ref,
     # what it means — per-row lengths still skip blocks at/past the
     # row's depth, so work tracks sum(lengths) over the block
     # indirection exactly as it did over the dense pool.
-    _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr,
+    _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
                    acc_scr, scale=scale, block_k=block_k)
 
 
-def _paged_quant_decode_kernel(tab_ref, q_ref, k_ref, v_ref, ks_ref,
-                               vs_ref, len_ref, o_ref, m_scr, l_scr,
+def _paged_quant_decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref,
+                               ks_ref, vs_ref, o_ref, m_scr, l_scr,
                                acc_scr, *, scale: float, block_k: int):
-    """Paged kernel over an INT8 block pool: the per-(block, head) fp32
-    scale rides its own gathered (1, 1) operand and the dequant happens
-    right here in the block loop — int8 blocks never round-trip through
-    a dense bf16 cache. Dequantized tiles are cast to the query's dtype
-    (bf16 pools dot at the doubled MXU rate); softmax statistics and
-    the accumulator stay fp32, and the per-row length skip means a
-    skipped block never even DMAs its scale."""
+    """Paged kernel over an INT8 block pool: the row's per-block fp32
+    scales ride as one ``[1, M]`` lane vector per (row, head) (see
+    :func:`gather_row_scales`) and the dequant happens right here in
+    the block loop — int8 blocks never round-trip through a dense bf16
+    cache. Dequantized tiles are cast to the query's dtype (bf16 pools
+    dot at the doubled MXU rate); softmax statistics and the
+    accumulator stay fp32."""
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
         _scratch_init(m_scr, l_scr, acc_scr)
 
-    length = len_ref[0, 0]
+    length = len_ref[pl.program_id(0)]
     run = ki * block_k < length
 
     @pl.when(run)
@@ -125,9 +133,9 @@ def _paged_quant_decode_kernel(tab_ref, q_ref, k_ref, v_ref, ks_ref,
         # the compute dtype — the XLA gather fallback applies the same
         # expression, so kernel and fallback see identical tiles.
         k = (k_ref[0, 0].astype(jnp.float32)
-             * ks_ref[0, 0]).astype(q.dtype)                 # [bk, d]
+             * block_scale(ks_ref, ki)).astype(q.dtype)      # [bk, d]
         v = (v_ref[0, 0].astype(jnp.float32)
-             * vs_ref[0, 0]).astype(q.dtype)                 # [bk, d]
+             * block_scale(vs_ref, ki)).astype(q.dtype)      # [bk, d]
         _block_step(q, k, v, length, ki, m_scr, l_scr, acc_scr,
                     scale=scale, block_k=block_k)
 
@@ -140,51 +148,38 @@ def _paged_call(q, k, v, lengths, block_tables, scale, interpret,
                 block_scales=None):
     """Paged layout: k/v are BLOCK POOLS ``[N, H, bs, D]`` and
     ``block_tables [B, M]`` maps row b's KV block ki to pool block
-    ``block_tables[b, ki]``. The table rides as a SCALAR-PREFETCH
-    operand (pltpu.PrefetchScalarGridSpec) so the grid's KV dimension
+    ``block_tables[b, ki]``. Table and lengths ride as SCALAR-PREFETCH
+    operands (pltpu.PrefetchScalarGridSpec) so the grid's KV dimension
     gathers blocks through the table in its index map — the kernel body
     is unchanged, per-row length skipping included. With
-    ``block_scales`` (int8 pools) the per-(block, head) fp32 scales are
-    gathered through the SAME index map as (1, 1) operands and the
-    kernel dequantizes each tile in the block loop."""
+    ``block_scales`` (int8 pools) the row's per-block fp32 scales are
+    pre-gathered through the same table (:func:`gather_row_scales`) and
+    the kernel dequantizes each tile in the block loop."""
     b, h, _, d = q.shape
-    n_blocks, _, bs, _ = k.shape
+    bs = k.shape[2]
     m = block_tables.shape[1]
     quant = block_scales is not None
     kernel = functools.partial(
         _paged_quant_decode_kernel if quant else _paged_decode_kernel,
         scale=scale, block_k=bs)
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = _compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    len2d = jnp.broadcast_to(
-        jnp.clip(jnp.asarray(lengths, jnp.int32), 0, m * bs)[:, None],
-        (b, _LANES))
-    kv_spec = pl.BlockSpec((1, 1, bs, d),
-                           lambda b_, h_, ki, tab: (tab[b_, ki], h_, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, 1, d), lambda b_, h_, ki, tab: (b_, h_, 0, 0)),
-        kv_spec,
-        kv_spec,
-    ]
+    tab = jnp.asarray(block_tables, jnp.int32)
+    lens = jnp.clip(jnp.asarray(lengths, jnp.int32), 0, m * bs)
+    qo_spec = pl.BlockSpec((1, 1, 1, d),
+                           lambda b_, h_, ki, tab, lens: (b_, h_, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, bs, d), lambda b_, h_, ki, tab, lens: (tab[b_, ki], h_, 0, 0))
+    in_specs = [qo_spec, kv_spec, kv_spec]
     operands = [q, k, v]
     if quant:
-        scale_spec = pl.BlockSpec(
-            (1, 1), lambda b_, h_, ki, tab: (tab[b_, ki], h_))
-        in_specs += [scale_spec, scale_spec]
-        ks, vs = block_scales
-        operands += [jnp.asarray(ks, jnp.float32),
-                     jnp.asarray(vs, jnp.float32)]
-    in_specs.append(
-        pl.BlockSpec((1, _LANES), lambda b_, h_, ki, tab: (b_, 0)))
-    operands.append(len2d)
+        in_specs += [pl.BlockSpec((1, 1, 1, m),
+                                  lambda b_, h_, ki, tab, lens:
+                                  (b_, h_, 0, 0))] * 2
+        operands += [gather_row_scales(sc, tab) for sc in block_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, h, m),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, 1, d),
-                               lambda b_, h_, ki, tab: (b_, h_, 0, 0)),
+        out_specs=qo_spec,
         scratch_shapes=[pltpu.VMEM((1, _LANES), jnp.float32),
                         pltpu.VMEM((1, _LANES), jnp.float32),
                         pltpu.VMEM((1, d), jnp.float32)],
@@ -193,9 +188,9 @@ def _paged_call(q, k, v, lengths, block_tables, scale, interpret,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=_DECODE_PARAMS,
         interpret=interpret,
-        **kwargs,
-    )(jnp.asarray(block_tables, jnp.int32), *operands)
+    )(tab, lens, *operands)
 
 
 def flash_decode_attention(q, k, v, lengths,
@@ -219,9 +214,8 @@ def flash_decode_attention(q, k, v, lengths,
     ``[ki*block_size, (ki+1)*block_size)`` live in pool block
     ``block_tables[b, ki]``, and the kernel gathers KV blocks through
     the table via a scalar-prefetch index map. The per-row length skip
-    is preserved verbatim — a row only DMAs the table entries below its
-    own depth. ``block_k`` is ignored (the pool's block_size IS the KV
-    block).
+    is preserved verbatim. ``block_k`` is ignored (the pool's block_size
+    IS the KV block).
 
     With ``block_scales`` (paged only — a ``(k_scales, v_scales)`` pair
     of ``[num_blocks, H]`` fp32 arrays) the pools are INT8 and each
@@ -230,9 +224,8 @@ def flash_decode_attention(q, k, v, lengths,
     ``ops.quant.dequantize_kv_block``, so the composed XLA fallback
     dequantizes identically): dots run in the query's dtype over
     dequantized tiles, softmax statistics and the accumulator stay
-    fp32, and skipped blocks never load data OR scales. (On real TPU
-    hardware int8 tiles want ``block_size * D`` at or above the int8
-    native tile — tiny test shapes run in interpret mode.)
+    fp32. (A ``(16, 64)`` int8 block — below the int8 native tile —
+    compiles and runs on a v5e: ``chip_smoke.py``, PR 21.)
 
     ``block_k`` defaults to the largest divisor of ``L`` that is <= 256
     (KV pools are padded to power-of-two-ish capacities, so real shapes
@@ -244,8 +237,7 @@ def flash_decode_attention(q, k, v, lengths,
         raise ValueError(
             f"flash_decode_attention is the single-token kernel; got "
             f"s_q={s_q} (use flash_attention for prefill/training)")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     if block_scales is not None and block_tables is None:
         raise ValueError("block_scales requires block_tables (int8 is "
                          "a paged-pool format)")
@@ -275,28 +267,27 @@ def flash_decode_attention(q, k, v, lengths,
     bk = _pick_block(L, block_k or min(L, 256))
 
     kernel = functools.partial(_decode_kernel, scale=scale, block_k=bk)
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = _compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    len2d = jnp.broadcast_to(
-        jnp.clip(jnp.asarray(lengths, jnp.int32), 0, L)[:, None],
-        (b, _LANES))
-    q_spec = pl.BlockSpec((1, 1, 1, d), lambda b_, h_, ki: (b_, h_, 0, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, d), lambda b_, h_, ki: (b_, h_, ki, 0))
-    len_spec = pl.BlockSpec((1, _LANES), lambda b_, h_, ki: (b_, 0))
-    return pl.pallas_call(
-        kernel,
+    lens = jnp.clip(jnp.asarray(lengths, jnp.int32), 0, L)
+    q_spec = pl.BlockSpec((1, 1, 1, d),
+                          lambda b_, h_, ki, lens: (b_, h_, 0, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, d),
+                           lambda b_, h_, ki, lens: (b_, h_, ki, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, h, L // bk),
-        in_specs=[q_spec, kv_spec, kv_spec, len_spec],
+        in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((1, _LANES), jnp.float32),
                         pltpu.VMEM((1, _LANES), jnp.float32),
                         pltpu.VMEM((1, d), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=_DECODE_PARAMS,
         interpret=interpret,
-        **kwargs,
-    )(q, k, v, len2d)
+    )(lens, q, k, v)
 
 
 def flash_decode_attention_sharded(q, k, v, lengths, mesh, *,

@@ -45,17 +45,13 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
-# The online-softmax scratch math and the package scalar helpers moved
-# to ops/pallas/common.py (shared with the decode and prefill kernels);
-# the aliases preserve this module's historical import surface
-# (decode_attention once imported _compiler_params/_pick_block from
-# here) and keep the kernel bodies bit-identical to the pre-factoring
-# inline version.
+# The online-softmax scratch math and the package scalar helpers live
+# in ops/pallas/common.py (shared with the decode and prefill kernels).
 from nezha_tpu.ops.pallas.common import (
     LANES as _LANES,
     NEG_BIG as _NEG_BIG,
-    compiler_params as _compiler_params,
     pick_block as _pick_block,
+    resolve_interpret,
     scratch_init as _scratch_init,
     softmax_block_update as _softmax_block_update,
     softmax_finalize as _softmax_finalize,
@@ -80,19 +76,25 @@ def _causal_mask(s, qi, ki, block_q, block_k):
     return jnp.where(kpos <= qpos, s, _NEG_BIG)
 
 
+_GRID_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+
+
 def _length_mask(s, ki, block_k, kv_len):
     """Mask key columns at positions >= kv_len (right-padding support).
-    ``kv_len`` is a traced scalar read from the per-batch lengths input."""
+    ``kv_len`` is this batch row's scalar from the scalar-prefetched
+    lengths operand."""
     kpos = ki * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
     return jnp.where(kpos < kv_len, s, _NEG_BIG)
 
 
 # ---------------------------------------------------------------- forward
-def _fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, m_scr, l_scr,
+def _fwd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                 acc_scr, *, scale: float, causal: bool, block_q: int,
                 block_k: int):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
+    kv_len = None if len_ref is None else len_ref[pl.program_id(0)]
 
     @pl.when(ki == 0)
     def _init():
@@ -113,8 +115,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, m_scr, l_scr,
                             preferred_element_type=jnp.float32) * scale
         if causal:
             s = _causal_mask(s, qi, ki, block_q, block_k)
-        if len_ref is not None:
-            s = _length_mask(s, ki, block_k, len_ref[0, 0])
+        if kv_len is not None:
+            s = _length_mask(s, ki, block_k, kv_len)
         _softmax_block_update(s, v, m_scr, l_scr, acc_scr)
 
     @pl.when(ki == pl.num_programs(3) - 1)
@@ -135,82 +137,64 @@ def _flash_call(q, k, v, causal, scale, block_q, block_k, interpret,
     auto_q, auto_k = _auto_blocks(s_q, s_k)
     bq = _pick_block(s_q, block_q or auto_q)
     bk = _pick_block(s_k, block_k or auto_k)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
 
     has_len = kv_lengths is not None
-    grid = (b, h, s_q // bq, s_k // bk)
     full = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                              block_q=bq, block_k=bk)
-    # The kernel's (len_ref, lse_ref) slots are optional: wrappers splice
-    # None into whichever positional slots this call doesn't wire.
-    if has_len and return_lse:
-        kernel = full
-    elif has_len:
-        def kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr,
-                   acc_scr):
-            full(q_ref, k_ref, v_ref, len_ref, o_ref, None, m_scr, l_scr,
-                 acc_scr)
-    elif return_lse:
-        def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                   acc_scr):
-            full(q_ref, k_ref, v_ref, None, o_ref, lse_ref, m_scr, l_scr,
-                 acc_scr)
-    else:
-        def kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr):
-            full(q_ref, k_ref, v_ref, None, o_ref, None, m_scr, l_scr,
-                 acc_scr)
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = _compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
-    scratch = [pltpu.VMEM((bq, _LANES), jnp.float32),
-               pltpu.VMEM((bq, _LANES), jnp.float32),
-               pltpu.VMEM((bq, d), jnp.float32)]
-    qo_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, d), lambda b_, h_, qi, ki: (b_, h_, ki, 0))
-    in_specs = [qo_spec, kv_spec, kv_spec]
-    operands = [q, k, v]
-    if has_len:
-        # Lengths ride as a [B, LANES] int32 lane-broadcast (the TPU-native
-        # small-operand layout); each program reads its batch row's scalar.
-        len2d = jnp.broadcast_to(
-            jnp.asarray(kv_lengths, jnp.int32)[:, None], (b, _LANES))
-        in_specs.append(pl.BlockSpec((1, _LANES),
-                                     lambda b_, h_, qi, ki: (b_, 0)))
-        operands.append(len2d)
+
+    def kernel(*refs):
+        # The kernel's (len_ref, lse_ref) slots are optional: splice None
+        # into whichever this call doesn't wire.
+        refs = list(refs)
+        len_ref = refs.pop(0) if has_len else None
+        lse_ref = refs.pop(4) if return_lse else None
+        full(len_ref, *refs[:4], lse_ref, *refs[4:])
+
+    qo_spec = pl.BlockSpec((1, 1, bq, d),
+                           lambda b_, h_, qi, ki, *_: (b_, h_, qi, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, d),
+                           lambda b_, h_, qi, ki, *_: (b_, h_, ki, 0))
+    # Lengths ride as a scalar-prefetch operand (SMEM); each program
+    # reads its batch row's scalar.
+    prefetch = [jnp.asarray(kv_lengths, jnp.int32)] if has_len else []
     out_specs = qo_spec
     out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
     if return_lse:
         lse_spec = pl.BlockSpec((1, 1, bq, _LANES),
-                                lambda b_, h_, qi, ki: (b_, h_, qi, 0))
+                                lambda b_, h_, qi, ki, *_: (b_, h_, qi, 0))
         out_specs = [qo_spec, lse_spec]
         out_shape = [out_shape,
                      jax.ShapeDtypeStruct((b, h, s_q, _LANES), jnp.float32)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(b, h, s_q // bq, s_k // bk),
+        in_specs=[qo_spec, kv_spec, kv_spec],
+        out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM((bq, _LANES), jnp.float32),
+                        pltpu.VMEM((bq, _LANES), jnp.float32),
+                        pltpu.VMEM((bq, d), jnp.float32)],
+    )
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
+        grid_spec=grid_spec,
         out_shape=out_shape,
-        scratch_shapes=scratch,
+        compiler_params=_GRID_PARAMS,
         interpret=interpret,
-        **kwargs,
-    )(*operands)
+    )(*prefetch, q, k, v)
 
 
 # --------------------------------------------------------------- backward
 def _recompute_p(q_ref, k_ref, lse_ref, qi, ki, scale, causal, bq, bk,
-                 len_ref=None):
+                 kv_len=None):
     q = q_ref[0, 0]                                          # [bq, d]
     k = k_ref[0, 0]                                          # [bk, d]
     s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * scale
     if causal:
         s = _causal_mask(s, qi, ki, bq, bk)
-    if len_ref is not None:
-        s = _length_mask(s, ki, bk, len_ref[0, 0])
+    if kv_len is not None:
+        s = _length_mask(s, ki, bk, kv_len)
     return jnp.exp(s - lse_ref[0, 0][:, :1])                 # [bq, bk]
 
 
@@ -225,11 +209,12 @@ def _ds_block(p, do, o, v, scale):
     return p * (dp - delta) * scale
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, len_ref,
+def _bwd_dq_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                    dq_ref, dq_scr, delta_scr, *, scale, causal, block_q,
                    block_k):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
+    kv_len = None if len_ref is None else len_ref[pl.program_id(0)]
 
     @pl.when(ki == 0)
     def _init():
@@ -246,7 +231,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, len_ref,
     @pl.when(run)
     def _block():
         p = _recompute_p(q_ref, k_ref, lse_ref, qi, ki, scale, causal,
-                         block_q, block_k, len_ref)
+                         block_q, block_k, kv_len)
         do = do_ref[0, 0]
         v = v_ref[0, 0]
         k = k_ref[0, 0]
@@ -262,11 +247,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, len_ref,
         dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, len_ref,
+def _bwd_dkv_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal,
                     block_q, block_k):
     ki = pl.program_id(2)
     qi = pl.program_id(3)
+    kv_len = None if len_ref is None else len_ref[pl.program_id(0)]
 
     @pl.when(qi == 0)
     def _init():
@@ -279,7 +265,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, len_ref,
     @pl.when(run)
     def _block():
         p = _recompute_p(q_ref, k_ref, lse_ref, qi, ki, scale, causal,
-                         block_q, block_k, len_ref)
+                         block_q, block_k, kv_len)
         do = do_ref[0, 0]
         o = o_ref[0, 0]
         v = v_ref[0, 0]
@@ -309,24 +295,18 @@ def _flash_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
     auto_q, auto_k = _auto_blocks(s_q, s_k)
     bq = _pick_block(s_q, block_q or auto_q)
     bk = _pick_block(s_k, block_k or auto_k)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
 
-    qo_spec = lambda grid_q: pl.BlockSpec(
-        (1, 1, bq, d), (lambda b_, h_, i, j: (b_, h_, i, 0)) if grid_q
-        else (lambda b_, h_, i, j: (b_, h_, j, 0)))
-    kv_spec = lambda grid_q: pl.BlockSpec(
-        (1, 1, bk, d), (lambda b_, h_, i, j: (b_, h_, j, 0)) if grid_q
-        else (lambda b_, h_, i, j: (b_, h_, i, 0)))
-    lse_spec = lambda grid_q: pl.BlockSpec(
-        (1, 1, bq, _LANES), (lambda b_, h_, i, j: (b_, h_, i, 0)) if grid_q
-        else (lambda b_, h_, i, j: (b_, h_, j, 0)))
-
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = _compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
+    def spec(rows, outer, minor=d):
+        """``rows``-tall sequence block indexed by the grid's OUTER
+        sequence dim (2) or its inner, sequential one (3): the dq grid is
+        (.., q-blocks, k-blocks), the dk/dv grid (.., k-blocks,
+        q-blocks)."""
+        if outer:
+            return pl.BlockSpec((1, 1, rows, minor),
+                                lambda b_, h_, i, j, *_: (b_, h_, i, 0))
+        return pl.BlockSpec((1, 1, rows, minor),
+                            lambda b_, h_, i, j, *_: (b_, h_, j, 0))
 
     # The lse residual is saved compactly as [B, H, S]; re-broadcast to the
     # TPU lane layout only transiently for the kernel calls (a per-layer
@@ -334,53 +314,48 @@ def _flash_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
     lse = jnp.broadcast_to(lse[..., None], lse.shape + (_LANES,))
 
     has_len = kv_lengths is not None
-    operands = [q, k, v, o, do, lse]
-    len_specs = []
-    if has_len:
-        len2d = jnp.broadcast_to(
-            jnp.asarray(kv_lengths, jnp.int32)[:, None], (b, _LANES))
-        operands.append(len2d)
-        len_specs = [pl.BlockSpec((1, _LANES), lambda b_, h_, i, j: (b_, 0))]
+    prefetch = [jnp.asarray(kv_lengths, jnp.int32)] if has_len else []
+    operands = [*prefetch, q, k, v, o, do, lse]
 
-    dq_kernel = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
+    def with_len(kernel_fn):
+        bound = functools.partial(kernel_fn, scale=scale, causal=causal,
                                   block_q=bq, block_k=bk)
-    dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
-                                   causal=causal, block_q=bq, block_k=bk)
-    if not has_len:  # splice None into the kernels' len_ref slot
-        dq_full, dkv_full = dq_kernel, dkv_kernel
+        if has_len:
+            return bound
+        return lambda *refs: bound(None, *refs)  # empty len_ref slot
 
-        def dq_kernel(q_, k_, v_, o_, do_, lse_, dq_, s1, s2):
-            dq_full(q_, k_, v_, o_, do_, lse_, None, dq_, s1, s2)
-
-        def dkv_kernel(q_, k_, v_, o_, do_, lse_, dk_, dv_, s1, s2):
-            dkv_full(q_, k_, v_, o_, do_, lse_, None, dk_, dv_, s1, s2)
+    def in_specs(q_major):
+        return [spec(bq, q_major), spec(bk, not q_major),
+                spec(bk, not q_major), spec(bq, q_major),
+                spec(bq, q_major), spec(bq, q_major, _LANES)]
 
     dq = pl.pallas_call(
-        dq_kernel,
-        grid=(b, h, s_q // bq, s_k // bk),
-        in_specs=[qo_spec(True), kv_spec(True), kv_spec(True), qo_spec(True),
-                  qo_spec(True), lse_spec(True)] + len_specs,
-        out_specs=qo_spec(True),
+        with_len(_bwd_dq_kernel),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(b, h, s_q // bq, s_k // bk),
+            in_specs=in_specs(True),
+            out_specs=spec(bq, True),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
+                            pltpu.VMEM((bq, _LANES), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
-                        pltpu.VMEM((bq, _LANES), jnp.float32)],
+        compiler_params=_GRID_PARAMS,
         interpret=interpret,
-        **kwargs,
     )(*operands)
 
     dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(b, h, s_k // bk, s_q // bq),
-        in_specs=[qo_spec(False), kv_spec(False), kv_spec(False),
-                  qo_spec(False), qo_spec(False), lse_spec(False)]
-        + len_specs,
-        out_specs=[kv_spec(False), kv_spec(False)],
+        with_len(_bwd_dkv_kernel),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(b, h, s_k // bk, s_q // bq),
+            in_specs=in_specs(False),
+            out_specs=[spec(bk, True), spec(bk, True)],
+            scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                            pltpu.VMEM((bk, d), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
+        compiler_params=_GRID_PARAMS,
         interpret=interpret,
-        **kwargs,
     )(*operands)
     return dq, dk, dv
 

@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU lowering)
+
+from nezha_tpu.ops.pallas.common import pick_block, resolve_interpret
 
 
 def _ln_kernel(x_ref, scale_ref, bias_ref, o_ref, *, eps: float):
@@ -45,26 +46,13 @@ def _ln_bwd_kernel(x_ref, scale_ref, dy_ref, dx_ref, dscale_ref, dbias_ref,
     m2 = jnp.sum(g * xhat, axis=-1, keepdims=True) / d
     dx = r * (g - m1 - xhat * m2)
     dx_ref[:] = dx.astype(dx_ref.dtype)
-    dscale_ref[:] = jnp.sum(dy * xhat, axis=0, keepdims=True)
-    dbias_ref[:] = jnp.sum(dy, axis=0, keepdims=True)
-
-
-def _pick_block(size: int, target: int) -> int:
-    b = min(size, target)
-    while size % b:
-        b -= 1
-    return b
-
-
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+    dscale_ref[0] = jnp.sum(dy * xhat, axis=0, keepdims=True)
+    dbias_ref[0] = jnp.sum(dy, axis=0, keepdims=True)
 
 
 def _ln_fwd_raw(x2, scale, bias, eps: float, interpret: bool):
     rows, d = x2.shape
-    bn = _pick_block(rows, 256)
+    bn = pick_block(rows, 256)
     return pl.pallas_call(
         functools.partial(_ln_kernel, eps=eps),
         grid=(rows // bn,),
@@ -81,7 +69,7 @@ def _ln_fwd_raw(x2, scale, bias, eps: float, interpret: bool):
 
 def _ln_bwd_raw(x2, scale, dy2, eps: float, interpret: bool):
     rows, d = x2.shape
-    bn = _pick_block(rows, 256)
+    bn = pick_block(rows, 256)
     n_blocks = rows // bn
     dx, dscale_p, dbias_p = pl.pallas_call(
         functools.partial(_ln_bwd_kernel, eps=eps),
@@ -91,19 +79,22 @@ def _ln_bwd_raw(x2, scale, dy2, eps: float, interpret: bool):
             pl.BlockSpec((1, d), lambda i: (0, 0)),
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
         ],
+        # Partial sums are [n_blocks, 1, D] so each program's (1, 1, D)
+        # block spans the trailing dims (a (1, D) block over
+        # [n_blocks, D] is refused by the TPU lowering).
         out_specs=[
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, d), x2.dtype),
-            jax.ShapeDtypeStruct((n_blocks, d), jnp.float32),
-            jax.ShapeDtypeStruct((n_blocks, d), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, 1, d), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, 1, d), jnp.float32),
         ],
         interpret=interpret,
     )(x2, scale.reshape(1, d), dy2)
-    return dx, dscale_p.sum(axis=0), dbias_p.sum(axis=0)
+    return dx, dscale_p.sum(axis=(0, 1)), dbias_p.sum(axis=(0, 1))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -135,5 +126,5 @@ def fused_layer_norm(x, scale, bias, eps: float = 1e-5,
     for dim in orig_shape[:-1]:
         rows *= dim
     x2 = x.reshape(rows, d)
-    out = _fused_ln(x2, scale, bias, eps, _resolve_interpret(interpret))
+    out = _fused_ln(x2, scale, bias, eps, resolve_interpret(interpret))
     return out.reshape(orig_shape)

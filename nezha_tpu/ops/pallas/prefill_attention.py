@@ -72,9 +72,11 @@ from jax.experimental.pallas import tpu as pltpu
 from nezha_tpu.ops.pallas.common import (
     LANES,
     NEG_BIG,
+    block_scale,
     block_step,
-    compiler_params,
+    gather_row_scales,
     pick_block,
+    resolve_interpret,
     scratch_init,
     softmax_block_update,
     softmax_finalize,
@@ -83,6 +85,15 @@ from nezha_tpu.ops.quant import QMAX, SATURATE_MAX
 
 _Q_TILE_TARGET = 256   # q rows per tile (divisor-clamped to the chunk)
 _KC_TILE_TARGET = 256  # chunk-KV rows per self-attention tile
+
+_PREFILL_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"))
+
+
+def _tile_max(x):
+    """Max over a 2-D tile as ``[1, 1]`` (lanes, then sublanes): Mosaic
+    keeps reductions as vectors — a scalar cannot be stored to VMEM."""
+    return jnp.max(jnp.max(x, axis=1, keepdims=True), axis=0, keepdims=True)
 
 
 def _chunk_self_attention(qi, q_ref, kc_ref, vc_ref, m_scr, l_scr,
@@ -204,22 +215,22 @@ def _prefill_qoff_kernel(tab_ref, start_ref, qoff_ref, q_ref, kc_ref,
 
 
 def _quant_merge_write(wpos, start, s_chunk, old_deq, stage, ci,
-                       pool_out, scale_out, bs):
+                       pool_out, bs):
     """Merge one touched block (old prefix / fresh chunk / stale-zero),
     requantize with a fresh absmax scale — ``ops.quant.quantize_kv_block``
-    verbatim — and write block + scale. Returns the max-abs dequant
+    verbatim — and write the block. Returns ``(scale, err)`` as
+    ``[1, 1]`` tiles: the block's new scale and the max-abs dequant
     error over the written span (``serve.kv.quant_error``'s sample)."""
-    fresh = pl.load(stage, (pl.dslice(ci, bs), slice(None)))
+    fresh = stage[pl.ds(ci, bs), :]
     merged = jnp.where(wpos < start, old_deq, fresh)         # [bs, d]
     merged = jnp.nan_to_num(merged, nan=0.0, posinf=SATURATE_MAX,
                             neginf=-SATURATE_MAX)
-    amax = jnp.max(jnp.abs(merged))
+    amax = _tile_max(jnp.abs(merged))
     sc = jnp.where(amax > 0, amax / QMAX, 1.0).astype(jnp.float32)
     q = jnp.clip(jnp.round(merged / sc), -QMAX, QMAX)
     pool_out[0, 0] = q.astype(pool_out.dtype)
-    scale_out[0, 0] = sc
     err = jnp.abs(merged - q * sc)
-    return jnp.max(jnp.where(wpos < start + s_chunk, err, 0.0))
+    return sc, _tile_max(jnp.where(wpos < start + s_chunk, err, 0.0))
 
 
 def _quant_prefill_kernel(tab_ref, start_ref, q_ref, kc_ref, vc_ref,
@@ -243,8 +254,12 @@ def _quant_prefill_kernel(tab_ref, start_ref, q_ref, kc_ref, vc_ref,
         scratch_init(m_scr, l_scr, acc_scr)
 
     @pl.when((qi == 0) & (t == 0))
-    def _err_init():
+    def _row_init():
         qerr_scr[:] = jnp.zeros_like(qerr_scr)
+        # The new-scale rows start as the old ones; write steps replace
+        # the touched lanes (the block stays resident for all of (b, h)).
+        ks_out[0, 0] = ks_ref[0, 0]
+        vs_out[0, 0] = vs_ref[0, 0]
 
     @pl.when(last_q & (t == 0))
     def _stage():
@@ -262,9 +277,9 @@ def _quant_prefill_kernel(tab_ref, start_ref, q_ref, kc_ref, vc_ref,
         # THE dequant both attention paths share (see
         # ops/quant.dequantize_kv_block).
         k = (kp_ref[0, 0].astype(jnp.float32)
-             * ks_ref[0, 0]).astype(q.dtype)
+             * block_scale(ks_ref, t)).astype(q.dtype)
         v = (vp_ref[0, 0].astype(jnp.float32)
-             * vs_ref[0, 0]).astype(q.dtype)
+             * block_scale(vs_ref, t)).astype(q.dtype)
         block_step(q, k, v, start, t, m_scr, l_scr, acc_scr,
                    scale=scale, block_k=bs)
 
@@ -276,23 +291,24 @@ def _quant_prefill_kernel(tab_ref, start_ref, q_ref, kc_ref, vc_ref,
     def _write():
         wpos = t * bs + lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
         ci = t * bs - start + bs                 # stage offset, >= 0
-        old_k = kp_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
-        old_v = vp_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
-        ek = _quant_merge_write(wpos, start, s_chunk, old_k, k_stage,
-                                ci, kp_out, ks_out, bs)
-        ev = _quant_merge_write(wpos, start, s_chunk, old_v, v_stage,
-                                ci, vp_out, vs_out, bs)
+        old_k = kp_ref[0, 0].astype(jnp.float32) * block_scale(ks_ref, t)
+        old_v = vp_ref[0, 0].astype(jnp.float32) * block_scale(vs_ref, t)
+        ksc, ek = _quant_merge_write(wpos, start, s_chunk, old_k, k_stage,
+                                     ci, kp_out, bs)
+        vsc, ev = _quant_merge_write(wpos, start, s_chunk, old_v, v_stage,
+                                     ci, vp_out, bs)
+        lane = lax.broadcasted_iota(jnp.int32, ks_out[0, 0].shape, 1)
+        ks_out[0, 0] = jnp.where(lane == t, ksc, ks_out[0, 0])
+        vs_out[0, 0] = jnp.where(lane == t, vsc, vs_out[0, 0])
         qerr_scr[:] = jnp.maximum(qerr_scr[:], jnp.maximum(ek, ev))
 
     @pl.when(~writing)
     def _scratch_route():
         # Non-writing steps land on the scratch block (the output index
-        # map routed them there): zero content, unit scale — exactly
-        # what _quant_prefill_write's over-cover rows scatter.
+        # map routed them there) with zero content — what
+        # _quant_prefill_write's over-cover rows scatter.
         kp_out[0, 0] = jnp.zeros_like(kp_out[0, 0])
         vp_out[0, 0] = jnp.zeros_like(vp_out[0, 0])
-        ks_out[0, 0] = jnp.float32(1.0)
-        vs_out[0, 0] = jnp.float32(1.0)
 
     @pl.when(t == m)
     def _chunk():
@@ -303,7 +319,7 @@ def _quant_prefill_kernel(tab_ref, start_ref, q_ref, kc_ref, vc_ref,
         softmax_finalize(o_ref, m_scr, l_scr, acc_scr)
         # The qerr output's index never moves within (b, h): the last
         # write before the flush — the final q sweep's — wins.
-        qerr_ref[0, 0] = qerr_scr[0, 0]
+        qerr_ref[0, 0] = qerr_scr[:]
 
 
 def _prefill_qoff_call(q, k_chunk, v_chunk, k_pool, v_pool,
@@ -337,11 +353,6 @@ def _prefill_qoff_call(q, k_chunk, v_chunk, k_pool, v_pool,
                               lambda b_, h_, qi, t, tab, starts, qoffs:
                               (b_, h_, 0, 0))
     pool_spec = pl.BlockSpec((1, 1, bs, d), _gather_idx)
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary",
-                                 "arbitrary"))
     scratch = [pltpu.VMEM((nq_block, LANES), jnp.float32),
                pltpu.VMEM((nq_block, LANES), jnp.float32),
                pltpu.VMEM((nq_block, d), jnp.float32)]
@@ -360,8 +371,8 @@ def _prefill_qoff_call(q, k_chunk, v_chunk, k_pool, v_pool,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=_PREFILL_PARAMS,
         interpret=interpret,
-        **kwargs,
     )(tab, starts32, qoffs32, q, k_chunk, v_chunk, k_pool, v_pool)
 
 
@@ -381,9 +392,6 @@ def _prefill_call(q, k_chunk, v_chunk, k_pool, v_pool, block_tables,
     def _gather_idx(b_, h_, qi, t, tab, starts):
         return (tab[b_, jnp.minimum(t, m - 1)], h_, 0, 0)
 
-    def _gather_scale_idx(b_, h_, qi, t, tab, starts):
-        return (tab[b_, jnp.minimum(t, m - 1)], h_)
-
     def _write_blk(b_, qi, t, tab, starts):
         start = starts[b_]
         wb0 = start // bs
@@ -398,11 +406,6 @@ def _prefill_call(q, k_chunk, v_chunk, k_pool, v_pool, block_tables,
                               lambda b_, h_, qi, t, tab, starts:
                               (b_, h_, 0, 0))
     pool_spec = pl.BlockSpec((1, 1, bs, d), _gather_idx)
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary",
-                                 "arbitrary"))
     scratch = [pltpu.VMEM((nq_block, LANES), jnp.float32),
                pltpu.VMEM((nq_block, LANES), jnp.float32),
                pltpu.VMEM((nq_block, d), jnp.float32)]
@@ -425,57 +428,77 @@ def _prefill_call(q, k_chunk, v_chunk, k_pool, v_pool, block_tables,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            compiler_params=_PREFILL_PARAMS,
             interpret=interpret,
-            **kwargs,
         )(tab, starts32, q, k_chunk, v_chunk, k_pool, v_pool)
 
-    ks, vs = block_scales
+    ks, vs = (jnp.asarray(sc, jnp.float32) for sc in block_scales)
     kernel = functools.partial(
         _quant_prefill_kernel, scale=scale, s_chunk=s_chunk,
         block_q=nq_block, block_kc=nkc_block, bs=bs, m=m)
-    scale_spec = pl.BlockSpec((1, 1), _gather_scale_idx)
+    # Per-(row, head) lane vectors: the row's M block scales in, the
+    # row's M (possibly rewritten) block scales out, and the qerr sample.
+    row_spec = pl.BlockSpec((1, 1, 1, m),
+                            lambda b_, h_, qi, t, tab, starts:
+                            (b_, h_, 0, 0))
+    qerr_spec = pl.BlockSpec((1, 1, 1, LANES),
+                             lambda b_, h_, qi, t, tab, starts:
+                             (b_, h_, 0, 0))
     pool_out_spec = pl.BlockSpec(
         (1, 1, bs, d),
         lambda b_, h_, qi, t, tab, starts:
         (_write_blk(b_, qi, t, tab, starts), h_, 0, 0))
-    scale_out_spec = pl.BlockSpec(
-        (1, 1),
-        lambda b_, h_, qi, t, tab, starts:
-        (_write_blk(b_, qi, t, tab, starts), h_))
-    qerr_spec = pl.BlockSpec((1, 1),
-                             lambda b_, h_, qi, t, tab, starts: (b_, h_))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[q_spec, chunk_spec, chunk_spec, pool_spec, pool_spec,
-                  scale_spec, scale_spec],
-        out_specs=[q_spec, pool_out_spec, pool_out_spec,
-                   scale_out_spec, scale_out_spec, qerr_spec],
+                  row_spec, row_spec],
+        out_specs=[q_spec, pool_out_spec, pool_out_spec, row_spec,
+                   row_spec, qerr_spec],
         scratch_shapes=scratch + [
             pltpu.VMEM((s_chunk + 2 * bs, d), jnp.float32),
             pltpu.VMEM((s_chunk + 2 * bs, d), jnp.float32),
             pltpu.VMEM((1, LANES), jnp.float32)],
     )
-    out, kp_new, vp_new, ks_new, vs_new, qerr = pl.pallas_call(
+    rows_shape = jax.ShapeDtypeStruct((b, h, 1, m), jnp.float32)
+    out, kp_new, vp_new, ks_rows, vs_rows, qerr = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
             jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
-            jax.ShapeDtypeStruct(ks.shape, jnp.float32),
-            jax.ShapeDtypeStruct(vs.shape, jnp.float32),
-            jax.ShapeDtypeStruct((b, h), jnp.float32),
+            rows_shape, rows_shape,
+            jax.ShapeDtypeStruct((b, h, 1, LANES), jnp.float32),
         ],
-        # Operand order: tab(0) starts(1) q(2) kc(3) vc(4) kp(5) vp(6)
-        # ks(7) vs(8) — the pools and scales alias their outputs so the
-        # fused write is in place (untouched blocks keep their data).
-        input_output_aliases={5: 1, 6: 2, 7: 3, 8: 4},
+        # Operand order: tab(0) starts(1) q(2) kc(3) vc(4) kp(5) vp(6):
+        # the pools alias their outputs so the fused write is in place
+        # (untouched blocks keep their data).
+        input_output_aliases={5: 1, 6: 2},
+        compiler_params=_PREFILL_PARAMS,
         interpret=interpret,
-        **kwargs,
     )(tab, starts32, q, k_chunk, v_chunk, k_pool, v_pool,
-      jnp.asarray(ks, jnp.float32), jnp.asarray(vs, jnp.float32))
-    return out, kp_new, vp_new, ks_new, vs_new, jnp.max(qerr)
+      gather_row_scales(ks, tab), gather_row_scales(vs, tab))
+
+    # Scatter the touched blocks' new scales into the [N, H] buffers: a
+    # STATIC window of table entries from the first written block — the
+    # ceil(S/bs)+1 a chunk can touch plus one that never is. Untouched
+    # entries route to the scratch block, which the kernel zeroed on
+    # every non-writing step and which therefore always takes unit scale.
+    n_win = min((s_chunk - 1) // bs + 2, m) + 1
+    tbi = (starts32 // bs)[:, None] + jnp.arange(n_win)[None, :]  # [B, T]
+    touched = tbi <= ((starts32 + s_chunk - 1) // bs)[:, None]
+    tbi = jnp.clip(tbi, 0, m - 1)
+    blks = jnp.where(touched, jnp.take_along_axis(tab, tbi, axis=1), 0)
+
+    def scatter(scales, rows):
+        new = jnp.take_along_axis(rows[:, :, 0, :], tbi[:, None, :],
+                                  axis=2)                    # [B, H, T]
+        new = jnp.where(touched[:, None, :], new, 1.0)
+        return scales.at[blks].set(new.transpose(0, 2, 1))
+
+    return (out, kp_new, vp_new, scatter(ks, ks_rows),
+            scatter(vs, vs_rows), jnp.max(qerr))
 
 
 def flash_prefill_attention(q, k_chunk, v_chunk, k_pool, v_pool,
@@ -556,8 +579,7 @@ def flash_prefill_attention(q, k_chunk, v_chunk, k_pool, v_pool,
             raise ValueError(
                 f"block_scales {ks.shape}/{vs.shape} must be "
                 f"[num_blocks, H] = {want}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     if q_offsets is not None:
         return _prefill_qoff_call(q, k_chunk, v_chunk, k_pool, v_pool,

@@ -1,47 +1,18 @@
-"""shard_map / axis_size shims across JAX versions.
+"""shard_map with the varying-axes check off, and the static axis size.
 
-Newer JAX enforces static "varying-over-mesh-axes" (vma) inference; outputs
+JAX enforces static "varying-over-mesh-axes" (vma) inference; outputs
 produced by all_gather are mathematically replicated but the checker can't
-prove it, so we disable the check here (kwarg name differs across versions).
-
-``lax.axis_size`` only exists on newer JAX; older versions (0.4.x) spell
-the same static lookup ``lax.psum(1, axis_name)`` — under shard_map a
-constant-int psum folds to a plain Python int at trace time, so call
-sites may still use the result in shape arithmetic and ``range()``.
+prove it, so every shard_map in this repo goes through the wrapper here,
+which disables the check.
 """
 
-import inspect
-
-from jax import lax
-
-try:  # jax >= 0.6-ish exposes it at top level
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-_kwargs = {}
-_sig_params = inspect.signature(_shard_map).parameters
-if "check_vma" in _sig_params:
-    _kwargs = {"check_vma": False}
-elif "check_rep" in _sig_params:  # pragma: no cover
-    _kwargs = {"check_rep": False}
+import jax
+from jax.lax import axis_size
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **_kwargs)
-
-
-if hasattr(lax, "axis_size"):
-    def axis_size(axis_name):
-        """Number of devices along ``axis_name`` (static int)."""
-        return lax.axis_size(axis_name)
-else:  # pragma: no cover — exercised on jax < 0.6 installs
-    def axis_size(axis_name):
-        """Number of devices along ``axis_name``. ``psum`` of a constant
-        int folds to a plain Python int at trace time, so this is the
-        same static value newer JAX's ``lax.axis_size`` returns."""
-        return lax.psum(1, axis_name)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 __all__ = ["shard_map", "axis_size"]

@@ -539,14 +539,10 @@ class Engine:
         # lets dashboards and `nezha-telemetry` label the prefill line
         # with the active impl without scraping model config, and it
         # selects the kernel span / fused-write accounting in
-        # :meth:`prefill`. Guarded: a model without the prefill knobs
-        # (non-GPT2) simply reports the XLA path.
-        try:
-            from nezha_tpu.models.gpt2 import _prefill_flash_ok
-            self.prefill_kernel_active = bool(
-                self.paged and _prefill_flash_ok(model.cfg))
-        except Exception:
-            self.prefill_kernel_active = False
+        # :meth:`prefill`.
+        from nezha_tpu.models.gpt2 import _prefill_flash_ok
+        self.prefill_kernel_active = bool(
+            self.paged and _prefill_flash_ok(model.cfg))
         obs.gauge("serve.prefill.kernel_active").set(
             1.0 if self.prefill_kernel_active else 0.0)
         if self.paged:

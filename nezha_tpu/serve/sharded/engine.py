@@ -165,15 +165,6 @@ class ShardedEngine(Engine):
         # warmup.
         obs.gauge("serve.prefill.seq_shards").set(
             float(m) if self._seq_active else 0.0)
-        # The base engine resolved prefill-kernel activeness for the
-        # raw-Mosaic path; under the partitioner the kernel runs as a
-        # nested shard_map instead, so the nested-kernel escape hatch
-        # ALSO kills it here — re-pin the gauge when it does.
-        import os
-        if self.prefill_kernel_active \
-                and os.environ.get("NEZHA_NO_NESTED_KERNELS"):
-            self.prefill_kernel_active = False
-            obs.gauge("serve.prefill.kernel_active").set(0.0)
         # Trace-shape estimate of the cross-shard collective payload
         # per TOKEN through the target model: the SPMD partitioner
         # inserts one activation reduce after each row-parallel proj

@@ -24,18 +24,17 @@ def enable_persistent_compile_cache(min_compile_secs: float = 0.0) -> str:
     """Point jax at the repo's persistent compile cache; returns the dir.
 
     Call any time before the programs of interest compile (the cache is
-    consulted per-compile, not at backend init). Safe no-op on jax
-    versions without the knobs.
+    consulted per-compile, not at backend init). The directory is
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``
+    — never a temporary or per-process name: the path is part of the
+    cache key, so a directory that moves never hits.
     """
     import jax
 
     cache = os.environ.get(
         "JAX_COMPILATION_CACHE_DIR",
         os.path.join(_REPO_ROOT, ".jax_cache"))
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          min_compile_secs)
-    except Exception:
-        pass
+    jax.config.update("jax_compilation_cache_dir", cache)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      min_compile_secs)
     return cache

@@ -519,9 +519,11 @@ def test_bench_records_rule_fixture(tmp_path):
     found = _rule_findings(index, "bench-records")
     assert len(found) == 1 and "CRASH RECORD" in found[0].message
     assert found[0].file == "BENCH_crash.json"
-    # Superseding the crash clears the finding.
+    # No exemption: notes cannot excuse it, only dropping the file does.
     (tmp_path / "BENCH_NOTES.md").write_text(
         "## Superseded records\n- BENCH_crash.json — crash\n")
+    assert len(_rule_findings(index, "bench-records")) == 1
+    (tmp_path / "BENCH_crash.json").unlink()
     assert _rule_findings(index, "bench-records") == []
 
 

@@ -445,6 +445,42 @@ def test_cli_degrade_warning_is_loud(monkeypatch, capsys):
     assert "WARNING" in err and "only 1 device" in err
 
 
+def test_cli_explicit_multi_device_request_on_one_device_is_an_error(
+        monkeypatch):
+    """Only a config's DEFAULT mode degrades (with the loud warning
+    above). An explicit ``--parallel``/``--mesh`` that one visible device
+    cannot meet exits instead of quietly training at 1/Nth scale — in
+    both engines."""
+    import jax
+    import pytest
+    one = jax.devices()[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: one)
+    base = ["--config", "gpt2_124m", "--model-preset", "tiny",
+            "--steps", "1", "--batch-size", "8"]
+    for extra in (["--parallel", "dp", "--mesh", "dp=4"],
+                  ["--parallel", "zero1"],
+                  ["--mesh", "dp=4"],
+                  ["--parallel", "dp", "--engine", "graph"]):
+        with pytest.raises(SystemExit, match="1 visible device"):
+            _run(base + extra)
+
+
+def test_cli_reports_where_the_arrays_live(devices8):
+    """The final metrics carry placement facts read off the arrays
+    themselves (chip_smoke.py asserts on them): dp shards the batch and
+    replicates the state; ZeRO-1 also splits the optimizer state."""
+    base = ["--config", "gpt2_124m", "--model-preset", "tiny",
+            "--steps", "1", "--batch-size", "8", "--log-every", "1"]
+    dp = _run(base + ["--parallel", "dp", "--mesh", "dp=4"])
+    assert (dp["batch_devices"], dp["state_devices"],
+            dp["state_split_devices"]) == (4, 4, 0)
+    zero1 = _run(base + ["--parallel", "zero1", "--mesh", "dp=4"])
+    assert (zero1["batch_devices"], zero1["state_split_devices"]) == (4, 4)
+    single = _run(base + ["--parallel", "single"])
+    assert (single["batch_devices"], single["state_devices"],
+            single["state_split_devices"]) == (1, 1, 0)
+
+
 def test_cli_trains_rn50_from_image_records(devices8, tmp_path):
     """E2E: write NZR1 records, train ResNet-50 DP through the CLI from
     them (the real-data input path of benchmark config 2)."""

@@ -1,0 +1,180 @@
+"""The main path's Pallas kernels COMPILE for a TPU v5e — no chip needed.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+chip that is described, not attached
+(``/opt/skills/guides/on-chip-measurement`` section 2.3). Interpret-mode
+parity tests prove a kernel's arithmetic and nothing about the compiler:
+every decode variant passed them for eighteen PRs while the TPU lowering
+refused its block shapes. Each case here lowers one kernel with
+``interpret=False`` at GPT-2 124M head shapes (H=12, D=64, bf16) and the
+block/bucket sizes ``chip_smoke.py`` serves with, and compiles it for one
+v5e device. A compile that passes is not a chip run; it says nothing about
+results or times.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from nezha_tpu.ops.pallas import (
+    flash_attention,
+    flash_decode_attention,
+    flash_decode_attention_sharded,
+    flash_prefill_attention,
+    flash_prefill_attention_sharded,
+    fused_layer_norm,
+)
+
+H, D = 12, 64
+BF16 = jnp.bfloat16
+BLOCK, POOL, MAX_LEN, BATCH = 16, 512, 1024, 8   # chip_smoke.SERVE_SHAPE
+TABLE = MAX_LEN // BLOCK
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    """The four devices of a described v5e 2x2 host, with the persistent
+    compilation cache off around the module (such a compile is written to
+    the cache but cannot be read back without a chip, so the next one
+    would warn)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_devices):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_devices[0])
+
+
+def _grad_sum(fn):
+    return jax.grad(lambda *a: fn(*a).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2))
+
+
+def _flash(causal, seq, with_lengths=False):
+    args = [((BATCH, H, seq, D), BF16)] * 3
+    if with_lengths:
+        args.append(((BATCH,), jnp.int32))
+    return _grad_sum(lambda q, k, v, lens=None: flash_attention(
+        q, k, v, causal=causal, interpret=False, kv_lengths=lens)), args
+
+
+def _decode(pool_dtype):
+    pool = ((POOL, H, BLOCK, D), pool_dtype)
+    args = [((BATCH, H, 1, D), BF16), pool, pool, ((BATCH,), jnp.int32),
+            ((BATCH, TABLE), jnp.int32)]
+    if pool_dtype == jnp.int8:
+        args += [((POOL, H), jnp.float32)] * 2
+    return (lambda q, k, v, lens, tab, *sc: flash_decode_attention(
+        q, k, v, lens, block_tables=tab, interpret=False,
+        block_scales=sc or None)), args
+
+
+def _prefill(pool_dtype, chunk):
+    q = ((1, H, chunk, D), BF16)
+    pool = ((POOL, H, BLOCK, D), pool_dtype)
+    args = [q, q, q, pool, pool, ((1, TABLE), jnp.int32),
+            ((1,), jnp.int32)]
+    if pool_dtype == jnp.int8:
+        args += [((POOL, H), jnp.float32)] * 2
+    return (lambda q, kc, vc, kp, vp, tab, st, *sc: flash_prefill_attention(
+        q, kc, vc, kp, vp, tab, st, interpret=False,
+        block_scales=sc or None)), args
+
+
+CASES = {
+    # training: the trainer's default batch 8 x seq 1024, fwd + bwd
+    "flash-causal-fwd-bwd-s1024": lambda: _flash(True, 1024),
+    # BERT: non-causal S=512, without and with right-padding lengths
+    "flash-noncausal-fwd-bwd-s512": lambda: _flash(False, 512),
+    "flash-noncausal-kvlen-fwd-bwd-s512": lambda: _flash(
+        False, 512, with_lengths=True),
+    # serving decode: dense slots, paged bf16 pool, paged int8 pool
+    "decode-dense": lambda: (
+        lambda q, k, v, lens: flash_decode_attention(q, k, v, lens,
+                                                     interpret=False),
+        [((BATCH, H, 1, D), BF16)] + [((BATCH, H, MAX_LEN, D), BF16)] * 2
+        + [((BATCH,), jnp.int32)]),
+    "decode-paged-bf16": lambda: _decode(BF16),
+    "decode-paged-int8": lambda: _decode(jnp.int8),
+    # serving prefill at both of the smoke's buckets; int8 fuses the write
+    "prefill-float-c64": lambda: _prefill(BF16, 64),
+    "prefill-float-c256": lambda: _prefill(BF16, 256),
+    "prefill-int8-fused-write-c64": lambda: _prefill(jnp.int8, 64),
+    "prefill-int8-fused-write-c256": lambda: _prefill(jnp.int8, 256),
+    # the opt-in fused layer norm (ln_impl="pallas"), fwd + bwd
+    "layer-norm-fwd-bwd": lambda: (
+        _grad_sum(lambda x, s, b: fused_layer_norm(x, s, b,
+                                                   interpret=False)),
+        [((BATCH * 1024, 768), BF16), ((768,), jnp.float32),
+         ((768,), jnp.float32)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(v5e, case):
+    fn, shapes = CASES[case]()
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+            for shape, dtype in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# The sharded serve engine's path: the same kernels PER HEAD SHARD under a
+# nested shard_map over a 1x4 "tp" mesh (12 heads / 4), block tables and
+# lengths replicated — Mosaic inside shard_map, partitioned by the TPU
+# compiler for the four-chip host.
+MESH_CASES = {
+    "decode-paged-bf16": (flash_decode_attention_sharded, _decode, BF16),
+    "decode-paged-int8": (flash_decode_attention_sharded, _decode,
+                          jnp.int8),
+    "prefill-float-c256": (flash_prefill_attention_sharded,
+                           lambda dt: _prefill(dt, 256), BF16),
+    "prefill-int8-fused-write-c256": (flash_prefill_attention_sharded,
+                                      lambda dt: _prefill(dt, 256),
+                                      jnp.int8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MESH_CASES))
+def test_nested_shard_map_kernel_compiles_for_v5e_mesh4(v5e_devices, case):
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    sharded, shapes_of, pool_dtype = MESH_CASES[case]
+    _, shapes = shapes_of(pool_dtype)
+    mesh = Mesh(np.array(v5e_devices).reshape(4), ("tp",))
+    decode = sharded is flash_decode_attention_sharded
+
+    # int32 operands (lengths / tables / starts) replicate; q, chunks,
+    # pools and scales shard on the head axis.
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(
+                mesh, P() if dtype == jnp.int32 else P(None, "tp")))
+            for shape, dtype in shapes]
+    if decode:
+        def fn(q, k, v, lens, tab, *sc):
+            return sharded(q, k, v, lens, mesh, block_tables=tab,
+                           block_scales=sc or None, interpret=False)
+    else:
+        def fn(q, kc, vc, kp, vp, tab, st, *sc):
+            return sharded(q, kc, vc, kp, vp, tab, st, mesh,
+                           block_scales=sc or None, interpret=False)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

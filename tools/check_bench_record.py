@@ -2,14 +2,13 @@
 """Sanity-check committed BENCH_*.json perf records — shim over
 ``nezha_tpu.analysis``.
 
-The validation core lives in ``nezha_tpu/analysis/bench_records.py``
-(whose docstring tells the BENCH_r03–r05 crash-record story), shared
-between this standalone checker and the ``bench-records`` lint rule:
-every committed record must be valid JSON, a real measurement, and
-platform-labeled — or explicitly superseded in BENCH_NOTES.md.
+The validation core lives in ``nezha_tpu/analysis/bench_records.py``,
+shared between this standalone checker and the ``bench-records`` lint
+rule: every committed record must be valid JSON, a real measurement,
+and platform-labeled.
 
 This file keeps the standalone entry point and the API tier-1 tests
-import (``check_dir`` / ``check_record`` / ``superseded_records``)::
+import (``check_dir`` / ``check_record``)::
 
     python tools/check_bench_record.py            # repo root
     python tools/check_bench_record.py /some/dir
@@ -35,7 +34,7 @@ except Exception:
     sys.modules["nezha_tpu"] = _pkg
 
 from nezha_tpu.analysis.bench_records import (  # noqa: E402,F401
-    check_dir, check_record, superseded_records)
+    check_dir, check_record)
 
 
 def main(argv=None) -> int:
@@ -48,10 +47,8 @@ def main(argv=None) -> int:
         print(f"FAIL: {len(errors)} bench-record violation(s)",
               file=sys.stderr)
         return 1
-    skip = sorted(superseded_records(root))
-    note = f" ({len(skip)} superseded, skipped)" if skip else ""
-    print(f"OK: committed bench records are platform-labeled and "
-          f"schema-valid{note}")
+    print("OK: committed bench records are platform-labeled and "
+          "schema-valid")
     return 0
 
 
