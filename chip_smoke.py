@@ -335,9 +335,9 @@ def one_chip(work: str) -> None:
 
 
 def four_chips(work: str) -> None:
-    from nezha_tpu.cli.train import device_span
     from nezha_tpu.models import gpt2
     from nezha_tpu.parallel.gspmd import auto_partitioner_scope
+    from nezha_tpu.train.loop import device_span
 
     ckpt = os.path.join(work, "ckpt_dp4")
     one = train(work, "train_1dev", "--parallel", "single")

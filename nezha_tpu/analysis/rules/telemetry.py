@@ -51,10 +51,12 @@ def check(index: SourceIndex) -> List[Finding]:
             if not cn.startswith("obs."):
                 continue
             kind = cn[len("obs."):]
-            # The retroactive (emit_span) and trace-gated (traced_span)
-            # forms record into the same span stream — their literal
+            # The retroactive (emit_span), trace-gated (traced_span)
+            # and profiler-clock (annotate, annotate_step) forms
+            # record into the same span stream — their literal
             # names face the identical pinned-registry contract.
-            if kind in ("emit_span", "traced_span"):
+            if kind in ("emit_span", "traced_span", "annotate",
+                        "annotate_step"):
                 kind = "span"
             name = str_arg(node)
             if name is None:

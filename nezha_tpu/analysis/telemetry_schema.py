@@ -223,7 +223,7 @@ _OBS_GAUGES = {"slo.burn_rate_max"}
 # deliberately.
 _PINNED_SPAN_PREFIXES = ("serve.", "checkpoint.", "dist.", "router.")
 _PINNED_SPANS = {
-    "serve.prefill", "serve.decode_attention", "serve.drain",
+    "serve.prefill", "serve.decode_step", "serve.drain",
     "checkpoint.save", "checkpoint.verify",
     "dist.join", "dist.barrier", "dist.failure", "dist.leave",
     "router.drain",
@@ -271,6 +271,12 @@ _PINNED_SPANS = {
     # (attrs carry the victim's request_id, priority, and emitted
     # token count). Absent entirely with preemption off.
     "serve.preempt_s",
+    # Layer boundaries on the profiler's clock (PR 24; obs.annotate):
+    # always a TraceAnnotation, a registry span only under --run-dir.
+    # One scheduler pass and its admission passes and token hand-over;
+    # the engine's prefill, step dispatch and the blocking fetch.
+    "serve.sched.pass", "serve.sched.admit", "serve.sched.emit",
+    "serve.engine.prefill", "serve.engine.dispatch", "serve.engine.wait",
 }
 
 # Namespaces whose METRIC names (counter/gauge/histogram) the source

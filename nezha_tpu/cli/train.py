@@ -652,7 +652,12 @@ def _run_traced(args) -> Dict[str, float]:
     from nezha_tpu.runtime import Prefetcher
     from nezha_tpu.train import checkpoint as ckpt
     from nezha_tpu.train import sharded_checkpoint as sckpt
-    from nezha_tpu.train.loop import Trainer, init_train_state, make_train_step
+    from nezha_tpu.train.loop import (
+        Trainer,
+        device_span,
+        init_train_state,
+        make_train_step,
+    )
 
     cfg = _configs()[args.config]
     if args.model_preset == "tiny":
@@ -1349,22 +1354,6 @@ def _run_traced(args) -> Dict[str, float]:
             print(json.dumps({"eval": results}), file=sys.stderr)
             last.update({f"eval_{k}": v for k, v in results.items()})
     return last
-
-
-def device_span(tree, split_only: bool = False) -> int:
-    """Most devices any jax.Array leaf of ``tree`` occupies; with
-    ``split_only``, counting only leaves whose sharding partitions them
-    (replicas excluded). 0 when no leaf qualifies."""
-    import jax
-
-    span = 0
-    for x in jax.tree_util.tree_leaves(tree):
-        sharding = getattr(x, "sharding", None)
-        if sharding is None or (split_only
-                                and sharding.is_fully_replicated):
-            continue
-        span = max(span, len(sharding.device_set))
-    return span
 
 
 def _run_eval(args, cfg, batch_size, mode, model, trainer, pspec,

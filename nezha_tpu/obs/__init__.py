@@ -85,7 +85,12 @@ from nezha_tpu.obs.timeseries import (
     uninstall_windows,
     windows_payload,
 )
-from nezha_tpu.obs.trace import Tracer, annotate, profile_trace
+from nezha_tpu.obs.trace import (
+    Tracer,
+    annotate,
+    annotate_step,
+    profile_trace,
+)
 from nezha_tpu.obs.watchdog import Watchdog, WatchdogConfig, WatchdogThread
 
 __all__ = [
@@ -98,7 +103,7 @@ __all__ = [
     "RunSink", "start_run", "end_run", "current_sink",
     "METRICS_FILE", "SPANS_FILE", "EVENTS_FILE", "SUMMARY_FILE",
     "MetricsLogger", "StepTimer", "read_metrics",
-    "Tracer", "annotate", "profile_trace",
+    "Tracer", "annotate", "annotate_step", "profile_trace",
     "record_event", "windows",
     "LogSketch", "WindowStore", "WINDOW_DURATIONS",
     "install_windows", "uninstall_windows", "current_windows",

@@ -23,15 +23,20 @@ from __future__ import annotations
 
 import contextlib
 import os
+import time
 from typing import Iterator, Optional
 
 import jax
 
 from nezha_tpu.obs.registry import (  # noqa: F401 — re-exported API
+    NULL_SPAN,
+    REGISTRY,
+    Span,
     current_trace,
     emit_span,
     mint_trace_id,
     new_span_id,
+    enabled,
     set_trace_sample,
     trace_context,
     trace_sample,
@@ -56,14 +61,66 @@ def profile_trace(log_dir: str,
         jax.profiler.stop_trace()
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named region in the trace timeline (host and device rows).
+class _Annotation:
+    """One layer boundary, open on two clocks: a profiler annotation
+    (always) and, while a run dir has the registry enabled, the registry
+    ``Span`` of the same name and attrs (``NULL_SPAN`` otherwise).
+    ``set(**attrs)`` adds what is only known inside the block (a count
+    of rows admitted, tokens emitted) to both; ``dur_s`` is the block's
+    host time on ``time.monotonic`` once it has closed."""
 
-    Usable inside jit: becomes an XLA op annotation via TraceAnnotation.
+    __slots__ = ("_trace", "_span", "_t0", "dur_s")
+
+    def __init__(self, trace, span):
+        self._trace, self._span = trace, span
+        self.dur_s = 0.0
+
+    def __enter__(self) -> "_Annotation":
+        self._t0 = time.monotonic()
+        self._trace.__enter__()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._span.__exit__(*exc)
+        self._trace.__exit__(*exc)
+        self.dur_s = time.monotonic() - self._t0
+        return False
+
+    def set(self, **attrs) -> "_Annotation":
+        self._trace.set_metadata(**attrs)
+        self._span.set(**attrs)
+        return self
+
+
+def _layer_span(name: str, attrs: dict):
+    """The registry half of an annotation. A layer boundary belongs to a
+    pass, not to a request: it never joins (or re-parents) the ambient
+    request trace, so per-request timelines stitch as before."""
+    return Span(name, REGISTRY, attrs) if enabled() else NULL_SPAN
+
+
+def annotate(name: str, **attrs) -> _Annotation:
+    """THE primitive for a layer boundary (``serve.sched.pass``,
+    ``train.dispatch``, ...): a host span on the profiler's own
+    timeline, so what the program knows shares a clock with the device
+    ops of an ``.xplane.pb``. Outside a profiler session the
+    ``TraceAnnotation`` is one atomic check in C++; the registry span
+    exists only under ``--run-dir``, so ``spans.jsonl`` and the trace
+    carry one vocabulary. ``attrs`` are cheap ints: they arrive as the
+    host event's stats. Usable inside jit too (an XLA op annotation).
     """
-    with jax.profiler.TraceAnnotation(name):
-        yield
+    return _Annotation(jax.profiler.TraceAnnotation(name, **attrs),
+                       _layer_span(name, attrs))
+
+
+def annotate_step(name: str, step_num: int, **attrs) -> _Annotation:
+    """:func:`annotate` for one iteration of a training loop: a
+    ``StepTraceAnnotation``, which XProf's step view groups device ops
+    by."""
+    return _Annotation(
+        jax.profiler.StepTraceAnnotation(name, step_num=step_num, **attrs),
+        _layer_span(name, {"step": step_num, **attrs}))
 
 
 class Tracer:

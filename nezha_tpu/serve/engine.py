@@ -585,6 +585,10 @@ class Engine:
         # decode_horizon tokens for every row) — tests assert the
         # dispatch-per-token amortization against this.
         self.step_calls = 0
+        # Host seconds spent inside prefill() so far (the sum of its
+        # serve.engine.prefill spans): the scheduler subtracts what
+        # this grew by from serve.host_gap_s.
+        self.prefill_host_s = 0.0
         # Tokens the most recent prefill's compiled chunks pushed
         # through the target model (set per prefill call), and how many
         # chunk dispatches it took (the sequence-sharded engine's
@@ -825,6 +829,20 @@ class Engine:
         validated here — admission (``Scheduler.submit``) is the
         validation boundary. The first generated token comes from the
         next :meth:`step`."""
+        ann = obs.annotate("serve.engine.prefill", tokens=len(tokens))
+        try:
+            with ann:
+                self._prefill(ann, slot, tokens, seed, temperature, top_k,
+                              top_p, eos_id, max_new_tokens)
+        finally:
+            self.prefill_host_s += ann.dur_s
+
+    def _prefill(self, ann, slot: int, tokens: Sequence[int], seed: int,
+                 temperature: float, top_k: Optional[int],
+                 top_p: Optional[float], eos_id: Optional[int],
+                 max_new_tokens: Optional[int]) -> None:
+        """The body of :meth:`prefill`, inside its
+        ``serve.engine.prefill`` span ``ann``."""
         faults.point("serve.prefill")
         n = len(tokens)
         if not 1 <= n < self.cfg.max_len:
@@ -890,6 +908,7 @@ class Engine:
         # after the call — prefill_span() would overcount hits.
         self.last_prefill_tokens = sum(w for _, _, w in chunks)
         self.last_prefill_chunks = len(chunks)
+        ann.set(cached=start, chunks=len(chunks))
         qerrs: List[Any] = []
         for off, ln, width in chunks:
             obs.histogram("serve.prefill.bucket_len").observe(width)
@@ -1042,32 +1061,32 @@ class Engine:
         self.step_calls += 1
         if self.spec is not None:
             return self._spec_step(active)
-        if self.paged:
-            self._bind_decode_windows(active, self.cfg.decode_horizon,
-                                      (self.pool,))
-            out = self.executor.run(
-                self._step_fn, self.variables, self.pool.caches,
-                jnp.asarray(self.pool.tables_host),
-                self.last_logits, self.positions,
-                jnp.asarray(active, bool), self.keys,
-                self.temps, self.top_ks, self.top_ps,
-                self.eos_ids, self.budgets)
-        else:
-            out = self.executor.run(
-                self._step_fn, self.variables, self.pool.caches,
-                self.last_logits, self.positions,
-                jnp.asarray(active, bool), self.keys,
-                self.temps, self.top_ks, self.top_ps,
-                self.eos_ids, self.budgets)
-        tok, emitted, ok, caches, last, pos, keys, budgets = out
-        # Start the block's device->host transfers NOW, before any host
-        # bookkeeping (state rebinds here, retire/admit/stream in the
-        # scheduler): the np.asarray reads below then find bytes already
-        # in flight instead of paying the full sync serially.
-        for arr in (tok, emitted, ok):
-            copy_async = getattr(arr, "copy_to_host_async", None)
-            if copy_async is not None:
-                copy_async()
+        with obs.annotate("serve.engine.dispatch",
+                          rows=int(np.count_nonzero(active))):
+            if self.paged:
+                self._bind_decode_windows(
+                    active, self.cfg.decode_horizon, (self.pool,))
+                out = self.executor.run(
+                    self._step_fn, self.variables, self.pool.caches,
+                    jnp.asarray(self.pool.tables_host),
+                    self.last_logits, self.positions,
+                    jnp.asarray(active, bool), self.keys,
+                    self.temps, self.top_ks, self.top_ps,
+                    self.eos_ids, self.budgets)
+            else:
+                out = self.executor.run(
+                    self._step_fn, self.variables, self.pool.caches,
+                    self.last_logits, self.positions,
+                    jnp.asarray(active, bool), self.keys,
+                    self.temps, self.top_ks, self.top_ps,
+                    self.eos_ids, self.budgets)
+            tok, emitted, ok, caches, last, pos, keys, budgets = out
+            # Start the block's device->host transfers NOW, before any
+            # host bookkeeping (state rebinds here, retire/admit/stream
+            # in the scheduler): the fetches below then find bytes
+            # already in flight instead of paying the full sync
+            # serially.
+            _start_host_copies(tok, emitted, ok)
         self.pool.caches = caches
         if faults.enabled():
             last = faults.corrupt(
@@ -1075,8 +1094,10 @@ class Engine:
                 rows=lambda: np.flatnonzero(active))
         self.last_logits, self.positions, self.keys = last, pos, keys
         self.budgets = budgets
-        self.step_ok = np.asarray(ok)
-        tok_h, emitted_h = np.asarray(tok), np.asarray(emitted)
+        with obs.annotate("serve.engine.wait"):
+            # The host blocked on the device: the block's fetches.
+            self.step_ok = np.asarray(ok)
+            tok_h, emitted_h = np.asarray(tok), np.asarray(emitted)
         if self.paged:
             # Advance the host position/budget mirrors by the block's
             # emitted counts (positions advance and budgets decay on
@@ -1098,37 +1119,36 @@ class Engine:
         consumption path is unchanged."""
         k = self.spec.draft_k
         cap = self.cfg.decode_horizon * (k + 1)
-        if self.paged:
-            # Both pools bind the same window: verify/draft writes past
-            # it are garbage by construction and route to the scratch
-            # block through the unbound table tail.
-            self._bind_decode_windows(active, cap,
-                                      (self.pool, self.draft_pool))
-            out = self.executor.run(
-                self._step_fn, self.variables,
-                (self.pool.caches, self.draft_pool.caches),
-                self.draft_variables,
-                jnp.asarray(self.pool.tables_host),
-                jnp.asarray(self.draft_pool.tables_host),
-                self.last_logits, self.positions,
-                jnp.asarray(active, bool), self.keys,
-                self.temps, self.top_ks, self.top_ps,
-                self.eos_ids, self.budgets, self.residual)
-        else:
-            out = self.executor.run(
-                self._step_fn, self.variables,
-                (self.pool.caches, self.draft_pool.caches),
-                self.draft_variables,
-                self.last_logits, self.positions,
-                jnp.asarray(active, bool), self.keys,
-                self.temps, self.top_ks, self.top_ps,
-                self.eos_ids, self.budgets, self.residual)
-        (tok, emitted, ok, win_emitted, caches_all, last, pos, keys,
-         budgets, residual) = out
-        for arr in (tok, emitted, ok, win_emitted):
-            copy_async = getattr(arr, "copy_to_host_async", None)
-            if copy_async is not None:
-                copy_async()
+        with obs.annotate("serve.engine.dispatch",
+                          rows=int(np.count_nonzero(active))):
+            if self.paged:
+                # Both pools bind the same window: verify/draft writes
+                # past it are garbage by construction and route to the
+                # scratch block through the unbound table tail.
+                self._bind_decode_windows(active, cap,
+                                          (self.pool, self.draft_pool))
+                out = self.executor.run(
+                    self._step_fn, self.variables,
+                    (self.pool.caches, self.draft_pool.caches),
+                    self.draft_variables,
+                    jnp.asarray(self.pool.tables_host),
+                    jnp.asarray(self.draft_pool.tables_host),
+                    self.last_logits, self.positions,
+                    jnp.asarray(active, bool), self.keys,
+                    self.temps, self.top_ks, self.top_ps,
+                    self.eos_ids, self.budgets, self.residual)
+            else:
+                out = self.executor.run(
+                    self._step_fn, self.variables,
+                    (self.pool.caches, self.draft_pool.caches),
+                    self.draft_variables,
+                    self.last_logits, self.positions,
+                    jnp.asarray(active, bool), self.keys,
+                    self.temps, self.top_ks, self.top_ps,
+                    self.eos_ids, self.budgets, self.residual)
+            (tok, emitted, ok, win_emitted, caches_all, last, pos, keys,
+             budgets, residual) = out
+            _start_host_copies(tok, emitted, ok, win_emitted)
         self.pool.caches, self.draft_pool.caches = caches_all
         if faults.enabled():
             # The pinned verify-step fault point: a nan/inf rule
@@ -1142,9 +1162,10 @@ class Engine:
                 rows=lambda: np.flatnonzero(active))
         self.last_logits, self.positions, self.keys = last, pos, keys
         self.budgets, self.residual = budgets, residual
-        self.step_ok = np.asarray(ok)
-        tok_h, emitted_h = np.asarray(tok), np.asarray(emitted)
-        win_h = np.asarray(win_emitted)
+        with obs.annotate("serve.engine.wait"):
+            self.step_ok = np.asarray(ok)
+            tok_h, emitted_h = np.asarray(tok), np.asarray(emitted)
+            win_h = np.asarray(win_emitted)
         # Speculation ledger: every window that emitted >= 1 token ran
         # one verify forward; its accepted-prefix length is (e_w - 1)
         # draft tokens (the t0 column is the classic carried-logits
@@ -1199,6 +1220,13 @@ class Engine:
         on its own."""
         return (self.draft_executor.stats()
                 if self.draft_executor is not None else None)
+
+
+def _start_host_copies(*arrays) -> None:
+    for arr in arrays:
+        copy_async = getattr(arr, "copy_to_host_async", None)
+        if copy_async is not None:
+            copy_async()
 
 
 def _build_prefill(model, width: int, paged: bool = False,

@@ -25,8 +25,9 @@ metrics of record: ``serve.ttft_s`` (submit -> first token, placed at
 the row's position WITHIN its first block) and ``serve.tpot_s``
 (``block_dt / tokens_emitted`` observed once per emitted token, so
 percentiles stay comparable across horizon settings) histograms,
-``serve.host_gap_s`` (host time between consecutive step dispatches —
-the gap the decode horizon amortizes) and ``serve.decode.horizon``
+``serve.host_gap_s`` (host time between one block's fetch and the
+next dispatch, minus the time inside ``Engine.prefill`` during it — the
+gap the decode horizon amortizes) and ``serve.decode.horizon``
 (tokens-per-dispatch ceiling in effect) histograms,
 ``serve.prefill.bucket_len`` (static pad width per prefill chunk — the
 bucket-occupancy view), ``serve.queue_depth`` and
@@ -34,7 +35,8 @@ bucket-occupancy view), ``serve.queue_depth`` and
 ``serve.{admitted,rejected,expired,retired,tokens}_total``,
 ``serve.{errors,step_retries}_total`` and ``serve.prefill.chunks_total``
 counters, ``faults.injected_total`` (the chaos ledger), and a
-``serve.decode_attention`` span around every batched decode block —
+``serve.decode_step`` span around every batched decode block's
+dispatch and fetch —
 the names tools/check_telemetry_schema.py pins. With no run active
 every call site is the registry's branch-only no-op.
 
@@ -331,6 +333,7 @@ class Scheduler:
                      "preemptions": "_lock", "resumes": "_lock",
                      "_live": "_lock",
                      "results": "_lock", "_host_gap_t": "_lock",
+                     "_gap_prefill_s": "_lock",
                      "_parked": "_lock", "_digest_cache": "_lock"}
 
     def __init__(self, engine: Engine,
@@ -385,6 +388,9 @@ class Scheduler:
         # loop was idle in between — serve.host_gap_s only measures the
         # host gap WITHIN continuous decoding, never idle waits.
         self._host_gap_t: Optional[float] = None
+        # engine.prefill_host_s as it read at that timestamp: what it
+        # has grown by at the next dispatch is prefill time, not gap.
+        self._gap_prefill_s = 0.0
         register_serve_instruments()
         pool = engine.pool
         obs.gauge("serve.kv.quant_bits").set(
@@ -492,7 +498,9 @@ class Scheduler:
     def step(self) -> int:
         """One serving iteration. Returns the number of tokens decoded
         (0 when fully idle)."""
-        with self._lock:
+        with self._lock, obs.annotate("serve.sched.pass",
+                                      live=len(self._live),
+                                      queued=self._queued_n):
             self._expire_queued()
             self._expire_parked()
             self._expire_preempted()
@@ -825,14 +833,20 @@ class Scheduler:
 
     def _admit(self) -> None:
         """[holds: _lock] — step() calls this inside the lock. One
-        admission pass: grant free slots to the WFQ pick among queued
+        admission pass under its ``serve.sched.admit`` span."""
+        with obs.annotate("serve.sched.admit") as ann:
+            ann.set(admitted=self._admit_pass())
+
+    def _admit_pass(self) -> int:
+        """[holds: _lock] Grant free slots to the WFQ pick among queued
         requests and resumable preempted ones (a preempted request
         outranks a queued pick of equal or lower priority — it is
         older, already-admitted work whose KV may still be cached),
         preempting a strictly-lower-priority live decode when the pick
-        cannot get a slot or its blocks any other way."""
+        cannot get a slot or its blocks any other way. Returns the
+        number of slots granted (each one a prefill)."""
         pool = self.engine.pool
-        preempts = 0
+        preempts = granted = 0
         while True:
             cand = self._peek_next()
             pre = self._peek_preempted()
@@ -841,12 +855,12 @@ class Scheduler:
                 <= _PRIORITY_RANK[cand.req.priority])
             target = pre if use_pre else cand
             if target is None:
-                break
+                return granted
             if not pool.num_free:
                 # Slot pressure: make room by suspending a lower-
                 # priority live decode — or wait for retirement.
                 if not self._maybe_preempt(target, preempts):
-                    break
+                    return granted
                 preempts += 1
                 continue
             if self.engine.paged:
@@ -895,7 +909,8 @@ class Scheduler:
                                   f"in use (kv_eviction="
                                   f"{pool.eviction!r})")
                         continue
-                    break
+                    return granted
+            granted += 1
             if use_pre:
                 self._resume_one(target)
             else:
@@ -970,7 +985,6 @@ class Scheduler:
 
     def _decode(self) -> int:
         """[holds: _lock] — step() calls this inside the lock."""
-        horizon = self.engine.cfg.decode_horizon
         active = np.zeros((self.engine.cfg.max_batch_size,), bool)
         for slot in self._live:
             active[slot] = True
@@ -989,11 +1003,16 @@ class Scheduler:
         t0_wall = time.time() if traced_batch else None
         t0 = time.monotonic()
         if self._host_gap_t is not None:
-            # Host time since the previous block came back: the
-            # retire/admit/stream pass plus any interleaved prefill —
-            # the per-dispatch cost a horizon > 1 spreads over H tokens.
+            # Host time since the previous block came back, MINUS the
+            # time spent inside Engine.prefill since then (its
+            # serve.engine.prefill span): the retire/admit/stream pass
+            # alone — the per-dispatch cost a horizon > 1 spreads over
+            # H tokens. A prefill dispatch can block on the device
+            # (donated pool buffers still in use by the block in
+            # flight), so counting it made the gap read device time.
             obs.histogram("serve.host_gap_s").observe(
-                t0 - self._host_gap_t)
+                t0 - self._host_gap_t
+                - (self.engine.prefill_host_s - self._gap_prefill_s))
         def _dispatch():
             # KV block exhaustion (genuine, or an injected serve.kv.bind
             # fault) is TYPED BACKPRESSURE, not an engine failure: retire
@@ -1017,7 +1036,7 @@ class Scheduler:
                     if not self._live:
                         return None
 
-        with obs.span("serve.decode_attention", rows=len(self._live)):
+        with obs.span("serve.decode_step", rows=len(self._live)):
             try:
                 out = _dispatch()
             except Exception:
@@ -1036,9 +1055,34 @@ class Scheduler:
                 return 0
             tokens, block_emitted = out
         now = time.monotonic()
-        dt = now - t0
         now_wall = time.time() if traced_batch else None
         self._host_gap_t = now
+        self._gap_prefill_s = self.engine.prefill_host_s
+        with obs.annotate("serve.sched.emit") as ann:
+            emitted = self._emit_block(tokens, block_emitted, t0, now,
+                                       t0_wall, now_wall)
+            ann.set(emitted=emitted)
+        if not self._live:
+            # The block retired the whole batch: the next decode only
+            # happens after new admissions, which may be arbitrarily
+            # later (open-loop callers gate step() on has_work(), so
+            # the idle reset in step() never runs for them) — a gap
+            # measured across that wait would be idle time, not host
+            # overhead.
+            self._host_gap_t = None
+        return emitted
+
+    def _emit_block(self, tokens, block_emitted, t0: float, now: float,
+                    t0_wall: Optional[float],
+                    now_wall: Optional[float]) -> int:
+        """[holds: _lock] Hand one decoded block to its requests: per
+        row the appends, the first-token and per-token latency
+        observations, ``on_token``, and retirement (EOS / length /
+        deadline / non-finite logits). ``t0``..``now`` is the dispatch
+        window on the monotonic clock, ``*_wall`` its epoch twin when a
+        traced request rode the block. Returns the tokens emitted."""
+        horizon = self.engine.cfg.decode_horizon
+        dt = now - t0
         obs.histogram("serve.decode.horizon").observe(
             self.engine.tokens_per_dispatch)
         speculative = self.engine.spec is not None
@@ -1142,14 +1186,6 @@ class Scheduler:
                 self._finish(live, FinishReason.ERROR,
                              error="non-finite logits")
         obs.counter("serve.tokens_total").inc(emitted)
-        if not self._live:
-            # The block retired the whole batch: the next decode only
-            # happens after new admissions, which may be arbitrarily
-            # later (open-loop callers gate step() on has_work(), so
-            # the idle reset in step() never runs for them) — a gap
-            # measured across that wait would be idle time, not host
-            # overhead.
-            self._host_gap_t = None
         return emitted
 
     def _finish(self, live: _Live, reason: str,

@@ -172,6 +172,7 @@ class Trainer:
         # can land mid-window), so the timer runs in explicit-lap mode.
         self._timer = StepTimer(window=max(log_every, 1))
         self._first_step = True  # next dispatch pays trace+compile
+        self._n_chips = 1  # set from the first batch's placement
         self.state: Optional[TrainState] = None
         self.global_step = 0
 
@@ -262,85 +263,125 @@ class Trainer:
         if self.state is None:
             self.initialize()
         last_metrics: Dict[str, float] = {}
-        n_chips = max(jax.device_count(), 1)
         self._timer.start()
         window_steps = 0  # actual steps this logging window (a resume can
         # land mid-window, so log_every would overstate the first rate)
         for _ in range(steps):
-            batch = next(batches)
-            if self.shard_fn is not None:
-                batch = self.shard_fn(batch)
-            if self._first_step:
-                # The first dispatch carries trace+compile; as a span it
-                # is the run's compile-time record (jit compiles
-                # synchronously, so the call returns after the build).
-                self._first_step = False
-                if (not self.tokens_per_step and isinstance(batch, dict)
-                        and hasattr(batch.get("tokens"), "size")):
-                    # LM batches: global tokens consumed per step, for the
-                    # tokens/sec-per-chip metric (shape is static, so one
-                    # read here covers the run).
-                    self.tokens_per_step = int(batch["tokens"].size)
-                with obs.span("train.first_step",
-                              step=self.global_step + 1):
-                    self.state, metrics = self.step_fn(self.state, batch)
-            else:
-                self.state, metrics = self.step_fn(self.state, batch)
-            self.global_step += 1
-            window_steps += 1
+            # One iteration = one train.step on the profiler's timeline,
+            # its host phases nested inside: who owns an idle gap of the
+            # device is read off these (PERF.md §3).
+            with obs.annotate_step("train.step", self.global_step + 1):
+                with obs.annotate("train.data"):
+                    batch = next(batches)
+                    if self.shard_fn is not None:
+                        batch = self.shard_fn(batch)
+                metrics = self._dispatch(batch)
+                self.global_step += 1
+                window_steps += 1
+                if self._peers_rejoined():
+                    # Rate windows must not count the heal wait.
+                    self._timer.start()
+                    window_steps = 0
+                    continue
+                if self.log_every and self.global_step % self.log_every == 0:
+                    last_metrics = self._log_window(metrics, window_steps)
+                    window_steps = 0
+                if (self.checkpoint_every and self.checkpoint_dir
+                        and self.global_step % self.checkpoint_every == 0):
+                    self._save(self.global_step)
+            # Between two steps, never inside one: an annotation that
+            # began before the profile window opened is not recorded, so
+            # a window opened here has every one of its steps on record.
             if self.tracer is not None:
                 self.tracer.maybe_trace(self.global_step)
-            if (self.failure_check_every and self.process_group is not None
-                    and self.global_step % self.failure_check_every == 0):
-                failed = self.process_group.failed_ranks()
-                if failed:
-                    if self.checkpoint_dir:  # preserve progress first
-                        self._save(self.global_step)
-                        if self._save_wait is not None:
-                            self._save_wait()  # commit before raising
-                    if self.failure_mode == "rejoin":  # ckpt_dir guaranteed
-                        with obs.span("train.rejoin", failed=failed):
-                            self._rejoin_and_reload(failed)
-                        # Rate windows must not count the heal wait.
-                        self._timer.start()
-                        window_steps = 0
-                        continue
-                    if self.on_failure is not None:
-                        self.on_failure(failed)
-                    else:
-                        raise RuntimeError(
-                            f"peer rank(s) {failed} failed at step "
-                            f"{self.global_step}")
-            if self.log_every and self.global_step % self.log_every == 0:
-                # The float() fetches are the window's device barrier (the
-                # StepTimer contract): every dispatched step has finished
-                # before the lap closes.
-                last_metrics = {k: float(v) for k, v in metrics.items()}
-                rate = self._timer.lap(last_metrics.get("loss", 0.0),
-                                       window_steps)
-                last_metrics["steps_per_sec"] = rate if rate is not None \
-                    else 0.0
-                if self.examples_per_step:
-                    eps = last_metrics["steps_per_sec"] \
-                        * self.examples_per_step
-                    last_metrics["examples_per_sec"] = eps
-                    last_metrics["examples_per_sec_per_chip"] = \
-                        eps / n_chips
-                if self.tokens_per_step:
-                    tps = last_metrics["steps_per_sec"] \
-                        * self.tokens_per_step
-                    last_metrics["tokens_per_sec"] = tps
-                    last_metrics["tokens_per_sec_per_chip"] = tps / n_chips
-                last_metrics["step"] = self.global_step
-                obs.counter("train.steps").inc(window_steps)
-                obs.record_metrics(self.global_step, last_metrics)
-                window_steps = 0
-                if self.metric_logger:
-                    self.metric_logger(self.global_step, last_metrics)
-            if (self.checkpoint_every and self.checkpoint_dir
-                    and self.global_step % self.checkpoint_every == 0):
-                self._save(self.global_step)
         if not last_metrics and steps:
             last_metrics = {k: float(v) for k, v in metrics.items()}
             last_metrics["step"] = self.global_step
         return last_metrics
+
+    def _dispatch(self, batch) -> Dict[str, Any]:
+        """Enqueue one optimizer step; returns its (device) metrics."""
+        first = obs.NULL_SPAN
+        if self._first_step:
+            self._first_step = False
+            if (not self.tokens_per_step and isinstance(batch, dict)
+                    and hasattr(batch.get("tokens"), "size")):
+                # LM batches: global tokens consumed per step, for the
+                # tokens/sec-per-chip metric (shape is static, so one
+                # read here covers the run).
+                self.tokens_per_step = int(batch["tokens"].size)
+            # The chips the *_per_chip rates divide by: the devices the
+            # batch is placed on (1 in single-device mode, whatever else
+            # the host holds).
+            self._n_chips = max(device_span(batch), 1)
+            # The first dispatch carries trace+compile; as a span it is
+            # the run's compile-time record (jit compiles synchronously,
+            # so the call returns after the build).
+            first = obs.span("train.first_step", step=self.global_step + 1)
+        with obs.annotate("train.dispatch"), first:
+            self.state, metrics = self.step_fn(self.state, batch)
+        return metrics
+
+    def _peers_rejoined(self) -> bool:
+        """The periodic peer-failure check. False when it is not due or
+        every peer is alive; True when the world healed and the state
+        was reloaded (the step just taken is void); raises (or calls
+        ``on_failure``) otherwise."""
+        if not (self.failure_check_every and self.process_group is not None
+                and self.global_step % self.failure_check_every == 0):
+            return False
+        failed = self.process_group.failed_ranks()
+        if not failed:
+            return False
+        if self.checkpoint_dir:  # preserve progress first
+            self._save(self.global_step)
+            if self._save_wait is not None:
+                self._save_wait()  # commit before raising
+        if self.failure_mode == "rejoin":  # ckpt_dir guaranteed
+            with obs.span("train.rejoin", failed=failed):
+                self._rejoin_and_reload(failed)
+            return True
+        if self.on_failure is None:
+            raise RuntimeError(f"peer rank(s) {failed} failed at step "
+                               f"{self.global_step}")
+        self.on_failure(failed)
+        return False
+
+    def _log_window(self, metrics, window_steps: int) -> Dict[str, float]:
+        """Close one logging window: fetch the step's metrics, lap the
+        timer, derive the rates, record and log."""
+        with obs.annotate("train.fetch"):
+            # The float() fetches are the window's device barrier (the
+            # StepTimer contract): every dispatched step has finished
+            # before the lap closes.
+            out = {k: float(v) for k, v in metrics.items()}
+        rate = self._timer.lap(out.get("loss", 0.0), window_steps)
+        out["steps_per_sec"] = rate if rate is not None else 0.0
+        if self.examples_per_step:
+            eps = out["steps_per_sec"] * self.examples_per_step
+            out["examples_per_sec"] = eps
+            out["examples_per_sec_per_chip"] = eps / self._n_chips
+        if self.tokens_per_step:
+            tps = out["steps_per_sec"] * self.tokens_per_step
+            out["tokens_per_sec"] = tps
+            out["tokens_per_sec_per_chip"] = tps / self._n_chips
+        out["step"] = self.global_step
+        obs.counter("train.steps").inc(window_steps)
+        obs.record_metrics(self.global_step, out)
+        if self.metric_logger:
+            self.metric_logger(self.global_step, out)
+        return out
+
+
+def device_span(tree, split_only: bool = False) -> int:
+    """Most devices any jax.Array leaf of ``tree`` occupies; with
+    ``split_only``, counting only leaves whose sharding partitions them
+    (replicas excluded). 0 when no leaf qualifies."""
+    span = 0
+    for x in jax.tree_util.tree_leaves(tree):
+        sharding = getattr(x, "sharding", None)
+        if sharding is None or (split_only
+                                and sharding.is_fully_replicated):
+            continue
+        span = max(span, len(sharding.device_set))
+    return span

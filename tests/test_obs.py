@@ -145,6 +145,37 @@ def test_disabled_mode_is_noop_without_allocation():
     assert obs.REGISTRY.spans == []
     # Instruments exist (get-or-create) but recorded nothing.
     assert all(v == 0 for v in obs.REGISTRY.snapshot()["counters"].values())
+    # The layer-boundary primitive: with the registry disabled and no
+    # profiler session, obs.annotate holds the shared NULL_SPAN (no
+    # registry object allocated), records nothing, and set() chains.
+    ann = obs.annotate("serve.sched.pass", live=1)
+    assert ann._span is obs.NULL_SPAN
+    with ann as entered:
+        assert entered is ann and ann.set(queued=2) is ann
+    with obs.annotate_step("train.step", 7) as step:
+        assert step._span is obs.NULL_SPAN
+    assert obs.REGISTRY.spans == []
+    assert obs.REGISTRY.snapshot()["num_spans"] == 0
+
+
+def test_annotate_records_the_registry_span_of_the_same_name_when_enabled():
+    """Under a run (registry enabled) obs.annotate also records the
+    registry span of the same name, attrs from open and from set() both,
+    so spans.jsonl and the profiler's trace carry one vocabulary."""
+    obs.enable()
+    try:
+        with obs.annotate("serve.engine.dispatch", rows=3) as ann:
+            ann.set(extra=1)
+        with obs.annotate_step("train.step", 7):
+            with obs.annotate("train.fetch"):
+                pass
+    finally:
+        obs.disable()
+    recs = {r["name"]: r for r in obs.REGISTRY.spans}
+    assert recs["serve.engine.dispatch"]["attrs"] == {"rows": 3, "extra": 1}
+    assert recs["train.step"]["attrs"] == {"step": 7}
+    assert recs["train.fetch"]["dur_s"] <= recs["train.step"]["dur_s"]
+    assert ann.dur_s >= 0.0
 
 
 # ------------------------------------------------------- trace context

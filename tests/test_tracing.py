@@ -173,7 +173,7 @@ def test_telemetry_disabled_serving_adds_zero_spans(tiny_model):
 
 def test_trace_sampled_out_adds_zero_trace_spans(tiny_model, tmp_path):
     """--trace-sample 0: the run still captures the classic spans
-    (serve.prefill, serve.decode_attention) but NOT ONE per-request
+    (serve.prefill, serve.decode_step) but NOT ONE per-request
     trace fragment — tracing cost scales with the sample knob."""
     run_dir = str(tmp_path / "run")
     obs.set_trace_sample(0.0)
@@ -187,7 +187,7 @@ def test_trace_sampled_out_adds_zero_trace_spans(tiny_model, tmp_path):
     with open(os.path.join(run_dir, "spans.jsonl")) as f:
         spans = [json.loads(ln) for ln in f if ln.strip()]
     names = {s["name"] for s in spans}
-    assert "serve.prefill" in names and "serve.decode_attention" in names
+    assert "serve.prefill" in names and "serve.decode_step" in names
     assert not any(s.get("trace_id") for s in spans)
     assert not names & {"serve.queue_wait", "serve.decode",
                         "serve.decode_window", "serve.prefill.chunk"}
@@ -684,3 +684,204 @@ def test_bench_trace_gate_floor():
                                  "decode_wait": 0.0004})},
                 base, "cpu", 0.30)["serving"]
     assert bad["trace.prefill_compute_p50@h1"]["ok"] is False
+
+
+# ------------------------------------- layer spans on the profiler's clock
+# PR 24: obs.annotate puts the host side of each layer on the profiler's
+# own timeline (the clock the device ops of an .xplane.pb share), under
+# fixed names the benchmark's metric files refer to.
+SERVE_LAYER_SPANS = ("serve.sched.pass", "serve.sched.admit",
+                     "serve.sched.emit", "serve.engine.prefill",
+                     "serve.engine.dispatch", "serve.engine.wait")
+TRAIN_LAYER_SPANS = ("train.step", "train.data", "train.dispatch",
+                     "train.fetch")
+
+
+def _profiler_options():
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0    # the annotations only, not every call
+    opts.host_tracer_level = 2
+    return opts
+
+
+def _host_events(trace_dir, names):
+    """[(name, start_ns, end_ns, stats)] of the host events named in
+    ``names``, by start time, read back with nothing but jax."""
+    import glob
+    files = sorted(glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb")))
+    assert files, "the profiler wrote no .xplane.pb"
+    data = jax.profiler.ProfileData.from_file(files[-1])
+    out = []
+    for plane in data.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in names:
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return sorted(out, key=lambda r: r[1])
+
+
+def _inside(events, inner, outer):
+    """Every ``inner`` event lies within some ``outer`` event."""
+    outers = [(a, b) for n, a, b, _ in events if n == outer]
+    inners = [(a, b) for n, a, b, _ in events if n == inner]
+    return bool(inners) and all(
+        any(oa <= a and b <= ob for oa, ob in outers) for a, b in inners)
+
+
+def test_annotate_puts_bare_name_and_attrs_on_the_profiler_timeline(
+        tmp_path):
+    """The primitive itself: the host event's name is the bare span name
+    (the benchmark's reducer keeps a host event only by exact name), the
+    attrs given at open and by set() arrive as its stats, and an
+    annotation that began before the session is not recorded."""
+    early = obs.annotate("probe.early")
+    early.__enter__()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=_profiler_options())
+    try:
+        with obs.annotate("probe.outer", live=3, queued=7) as ann:
+            with obs.annotate("probe.inner"):
+                time.sleep(0.001)
+            assert ann.set(emitted=5) is ann
+        with obs.annotate_step("probe.step", 12):
+            pass
+        early.__exit__(None, None, None)
+    finally:
+        jax.profiler.stop_trace()
+    assert ann.dur_s >= 0.001
+    ev = _host_events(tmp_path, {"probe.early", "probe.outer",
+                                 "probe.inner", "probe.step"})
+    by_name = {n: s for n, _, _, s in ev}
+    assert set(by_name) == {"probe.outer", "probe.inner", "probe.step"}
+    assert by_name["probe.outer"] == {"live": 3, "queued": 7, "emitted": 5}
+    assert by_name["probe.step"]["step_num"] == 12
+    assert _inside(ev, "probe.inner", "probe.outer")
+
+
+def test_serve_layer_spans_reach_the_profiler_timeline(tiny_model,
+                                                       tmp_path):
+    """A tiny Scheduler run under a real profiler session, registry
+    DISABLED (as in the benchmark): all six serve spans are host events,
+    dispatch and wait nest inside the pass, the attrs are there."""
+    sched = Scheduler(_engine(tiny_model))
+    sched.submit(Request(prompt=_prompt(5), max_new_tokens=2))
+    sched.run_until_idle()                      # programs built
+    assert not obs.enabled()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=_profiler_options())
+    try:
+        rids = [sched.submit(Request(prompt=_prompt(9, salt=i),
+                                     max_new_tokens=4)) for i in range(3)]
+        sched.run_until_idle()
+    finally:
+        jax.profiler.stop_trace()
+    assert all(sched.results[r].finish_reason == "length" for r in rids)
+    assert obs.REGISTRY.spans == []
+    ev = _host_events(tmp_path, set(SERVE_LAYER_SPANS))
+    assert {n for n, *_ in ev} == set(SERVE_LAYER_SPANS)
+    for inner in SERVE_LAYER_SPANS[1:]:
+        assert _inside(ev, inner, "serve.sched.pass"), inner
+    stats = {}
+    for n, _, _, s in ev:
+        stats.setdefault(n, []).append(s)
+    assert all({"live", "queued"} <= set(s) for s in stats["serve.sched.pass"])
+    # three requests over two slots: the first pass admits two, a later
+    # one the third; every admitted request went through one prefill
+    assert sum(s["admitted"] for s in stats["serve.sched.admit"]) == 3
+    assert len(stats["serve.engine.prefill"]) == 3
+    assert all(s["tokens"] == 9 and s["chunks"] >= 1 and "cached" in s
+               for s in stats["serve.engine.prefill"])
+    assert sum(s["emitted"] for s in stats["serve.sched.emit"]) == 12
+    assert all(1 <= s["rows"] <= 2 for s in stats["serve.engine.dispatch"])
+    # one wait per dispatch, each after its dispatch closed
+    assert len(stats["serve.engine.wait"]) == len(
+        stats["serve.engine.dispatch"])
+
+
+def test_train_layer_spans_reach_the_profiler_timeline(tmp_path):
+    """A three-step Trainer.fit whose Tracer opens the window after step
+    1: steps 2 and 3 — every step after the one that opened the window
+    — are train.step events with their data / dispatch / fetch phases
+    inside."""
+    from nezha_tpu import data, optim
+    from nezha_tpu.models import MLP
+    from nezha_tpu.obs import Tracer
+    from nezha_tpu.train.loop import Trainer
+
+    def loss_fn(logits, batch):
+        from nezha_tpu import ops
+        return ops.softmax_cross_entropy_with_integer_labels(
+            logits, batch["label"])
+
+    tracer = Tracer(str(tmp_path), start_step=1, num_steps=2)
+    trainer = Trainer(MLP(hidden=(16,)), optim.momentum(0.1), loss_fn,
+                      rng=jax.random.PRNGKey(0), log_every=1, tracer=tracer)
+    trainer.fit(data.mnist_batches(8, seed=0), steps=3)
+    tracer.stop()
+    assert tracer._done
+    ev = _host_events(tmp_path, set(TRAIN_LAYER_SPANS))
+    assert {n for n, *_ in ev} == set(TRAIN_LAYER_SPANS)
+    steps = [s["step_num"] for n, _, _, s in ev if n == "train.step"]
+    assert steps == [2, 3]
+    for inner in TRAIN_LAYER_SPANS[1:]:
+        assert _inside(ev, inner, "train.step"), inner
+        assert sum(1 for n, *_ in ev if n == inner) == 2
+
+
+def test_layer_spans_mirror_into_spans_jsonl_under_a_run_dir(tiny_model,
+                                                             tmp_path):
+    """With the registry enabled the same names land in spans.jsonl with
+    the same attrs — one vocabulary on two clocks — and the capture
+    passes the pinned-span schema."""
+    run_dir = str(tmp_path / "run")
+    obs.start_run(run_dir)
+    sched = Scheduler(_engine(tiny_model))
+    sched.submit(Request(prompt=_prompt(9), max_new_tokens=3))
+    sched.run_until_idle()
+    obs.end_run()
+    with open(os.path.join(run_dir, "spans.jsonl")) as f:
+        spans = [json.loads(ln) for ln in f if ln.strip()]
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s["attrs"])
+    assert set(SERVE_LAYER_SPANS) <= set(by_name)
+    assert by_name["serve.engine.prefill"][0] == {
+        "tokens": 9, "cached": 0, "chunks": 1}
+    assert sum(a["emitted"] for a in by_name["serve.sched.emit"]) == 3
+    assert check_run_dir(run_dir) == []
+
+
+def test_host_gap_leaves_out_time_inside_engine_prefill(tiny_model,
+                                                        monkeypatch):
+    """serve.host_gap_s is the host pass between one block's fetch and
+    the next dispatch MINUS the time inside Engine.prefill during it: a
+    prefill that takes 0.3 s between two decode blocks must not show."""
+    eng = _engine(tiny_model)
+    sched = Scheduler(eng)
+    sched.submit(Request(prompt=_prompt(5), max_new_tokens=2))
+    sched.submit(Request(prompt=_prompt(5, salt=1), max_new_tokens=2))
+    sched.run_until_idle()                      # programs built
+    body = eng._prefill
+
+    def slow(*a, **kw):
+        time.sleep(0.3)
+        return body(*a, **kw)
+
+    monkeypatch.setattr(eng, "_prefill", slow)
+    obs.enable()
+    try:
+        sched.submit(Request(prompt=_prompt(9), max_new_tokens=6))
+        sched.step()
+        sched.step()
+        # admitted by the pass that follows a decode block: its prefill
+        # sits between that block's fetch and the next dispatch
+        sched.submit(Request(prompt=_prompt(9, salt=2), max_new_tokens=2))
+        sched.run_until_idle()
+        gap = obs.histogram("serve.host_gap_s").summary()
+    finally:
+        obs.disable()
+    assert eng.prefill_host_s >= 0.6
+    assert gap["count"] >= 3
+    assert 0.0 <= gap["max"] < 0.3
