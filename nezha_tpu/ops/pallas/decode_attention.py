@@ -190,6 +190,8 @@ def _paged_call(q, k, v, lengths, block_tables, scale, interpret,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=_DECODE_PARAMS,
         interpret=interpret,
+        name=("nezha_decode_attention_paged_int8" if quant
+              else "nezha_decode_attention_paged"),
     )(tab, lens, *operands)
 
 
@@ -287,6 +289,7 @@ def flash_decode_attention(q, k, v, lengths,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=_DECODE_PARAMS,
         interpret=interpret,
+        name="nezha_decode_attention_dense",
     )(lens, q, k, v)
 
 

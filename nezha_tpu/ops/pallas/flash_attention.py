@@ -181,6 +181,7 @@ def _flash_call(q, k, v, causal, scale, block_q, block_k, interpret,
         out_shape=out_shape,
         compiler_params=_GRID_PARAMS,
         interpret=interpret,
+        name="nezha_flash_fwd",
     )(*prefetch, q, k, v)
 
 
@@ -341,6 +342,7 @@ def _flash_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=_GRID_PARAMS,
         interpret=interpret,
+        name="nezha_flash_bwd_dq",
     )(*operands)
 
     dk, dv = pl.pallas_call(
@@ -356,6 +358,7 @@ def _flash_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         compiler_params=_GRID_PARAMS,
         interpret=interpret,
+        name="nezha_flash_bwd_dkv",
     )(*operands)
     return dq, dk, dv
 

@@ -64,6 +64,7 @@ def _ln_fwd_raw(x2, scale, bias, eps: float, interpret: bool):
         out_specs=pl.BlockSpec((bn, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x2.dtype),
         interpret=interpret,
+        name="nezha_layer_norm_fwd",
     )(x2, scale.reshape(1, d), bias.reshape(1, d))
 
 
@@ -93,6 +94,7 @@ def _ln_bwd_raw(x2, scale, dy2, eps: float, interpret: bool):
             jax.ShapeDtypeStruct((n_blocks, 1, d), jnp.float32),
         ],
         interpret=interpret,
+        name="nezha_layer_norm_bwd",
     )(x2, scale.reshape(1, d), dy2)
     return dx, dscale_p.sum(axis=(0, 1)), dbias_p.sum(axis=(0, 1))
 

@@ -373,6 +373,7 @@ def _prefill_qoff_call(q, k_chunk, v_chunk, k_pool, v_pool,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=_PREFILL_PARAMS,
         interpret=interpret,
+        name="nezha_prefill_attention_qoff",
     )(tab, starts32, qoffs32, q, k_chunk, v_chunk, k_pool, v_pool)
 
 
@@ -430,6 +431,7 @@ def _prefill_call(q, k_chunk, v_chunk, k_pool, v_pool, block_tables,
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             compiler_params=_PREFILL_PARAMS,
             interpret=interpret,
+            name="nezha_prefill_attention_paged",
         )(tab, starts32, q, k_chunk, v_chunk, k_pool, v_pool)
 
     ks, vs = (jnp.asarray(sc, jnp.float32) for sc in block_scales)
@@ -477,6 +479,7 @@ def _prefill_call(q, k_chunk, v_chunk, k_pool, v_pool, block_tables,
         input_output_aliases={5: 1, 6: 2},
         compiler_params=_PREFILL_PARAMS,
         interpret=interpret,
+        name="nezha_prefill_attention_paged_int8",
     )(tab, starts32, q, k_chunk, v_chunk, k_pool, v_pool,
       gather_row_scales(ks, tab), gather_row_scales(vs, tab))
 

@@ -13,6 +13,7 @@ results or times.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
 
@@ -128,13 +129,61 @@ CASES = {
 }
 
 
+# The pallas_call(name=...) of each kernel a case must find in its HLO.
+# (The layer norm's forward kernel is dead code under a sum's gradient.)
+FLASH_NAMES = ("nezha_flash_fwd", "nezha_flash_bwd_dq", "nezha_flash_bwd_dkv")
+KERNEL_NAMES = {
+    "flash-causal-fwd-bwd-s1024": FLASH_NAMES,
+    "flash-noncausal-fwd-bwd-s512": FLASH_NAMES,
+    "flash-noncausal-kvlen-fwd-bwd-s512": FLASH_NAMES,
+    "decode-dense": ("nezha_decode_attention_dense",),
+    "decode-paged-bf16": ("nezha_decode_attention_paged",),
+    "decode-paged-int8": ("nezha_decode_attention_paged_int8",),
+    "prefill-float-c64": ("nezha_prefill_attention_paged",),
+    "prefill-float-c256": ("nezha_prefill_attention_paged",),
+    "prefill-int8-fused-write-c64": ("nezha_prefill_attention_paged_int8",),
+    "prefill-int8-fused-write-c256": ("nezha_prefill_attention_paged_int8",),
+    "layer-norm-fwd-bwd": ("nezha_layer_norm_bwd",),
+}
+
+
+@pytest.fixture(scope="module")
+def hlo_of(v5e):
+    """case -> the HLO text of its kernel compiled for one v5e device
+    (each case compiles once for the tests that read it)."""
+    texts = {}
+
+    def compile_case(case):
+        if case not in texts:
+            fn, shapes = CASES[case]()
+            args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+                    for shape, dtype in shapes]
+            texts[case] = jax.jit(fn).lower(*args).compile().as_text()
+        return texts[case]
+
+    return compile_case
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_kernel_compiles_for_v5e(v5e, case):
-    fn, shapes = CASES[case]()
-    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-            for shape, dtype in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def test_kernel_compiles_for_v5e(hlo_of, case):
+    assert "tpu_custom_call" in hlo_of(case)
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_keeps_its_name_in_the_v5e_hlo(hlo_of, case):
+    """Every Pallas call is a custom-call instruction whose OWN name
+    carries the kernel's ``nezha_<kernel>_<variant>`` name (under jvp_ /
+    transpose_jvp_ for a differentiated one): what a v5e trace prints
+    before `` = `` and what the benchmark's patterns can anchor on. No
+    Pallas call of a main-path kernel is anonymous."""
+    own_names = [line.split(" = ", 1)[0].split()[-1].lstrip("%")
+                 for line in hlo_of(case).splitlines()
+                 if "tpu_custom_call" in line and " = " in line]
+    assert own_names
+    assert all("nezha_" in n for n in own_names), own_names
+    kernels = {re.sub(r"^(transpose_)?(jvp_)?_*|_*(\.\d+)?$", "", n)
+               for n in own_names}
+    assert kernels == set(KERNEL_NAMES[case]), own_names
 
 
 # The sharded serve engine's path: the same kernels PER HEAD SHARD under a
