@@ -36,7 +36,11 @@ from chipbench.stats import percentile
 clock = time.perf_counter
 
 ANNOTATIONS = ("submit", "scheduler.step", "engine.step", "engine.prefill",
-               "generator.sleep")
+               "generator.sleep",
+               "serve.sched.pass", "serve.sched.admit", "serve.sched.emit",
+               "serve.engine.prefill", "serve.engine.dispatch",
+               "serve.engine.wait", "train.step", "train.data",
+               "train.dispatch", "train.fetch")
 CHECK_REQUESTS = 8
 CHECK_STEPS = 3          # decode steps whose logits are compared
 

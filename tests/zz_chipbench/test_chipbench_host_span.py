@@ -1,0 +1,119 @@
+"""The ``host_span`` reader and the three metrics that use it (PR 24): the
+program's own layer spans, read off the profiler's clock. Hand-made events
+whose answers are known here; a slice recorded on the chip below."""
+
+import json
+import os
+
+import pytest
+
+from chipbench import manifest
+from chipbench.obs import Obs
+from chipbench.trace import reduce
+from chipbench.trace.reduce import Event, Trace
+
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+MS = 1e6
+NEW_METRICS = ("sched.host_ms_per_pass", "engine.dispatch_ms_p50",
+               "trainer.host_ms_per_step")
+
+
+def ev(name, start_ms, dur_ms):
+    return Event(name, start_ms * MS, dur_ms * MS)
+
+
+def _obs(host):
+    obs = Obs()
+    obs.trace = Trace({}, sorted(host, key=lambda e: e.start_ns), {})
+    return obs
+
+
+def _metric_files(names=NEW_METRICS):
+    out = []
+    for name in names:
+        with open(os.path.join(manifest.ROOT, "metrics", f"{name}.json")) as f:
+            out.append(json.load(f))
+    return out
+
+
+@pytest.fixture(scope="module")
+def read():
+    return manifest.load_reader("host_span")
+
+
+def test_a_span_minus_what_lies_inside_it(read):
+    """Two passes with unequal waits: each pass counts for its own length
+    less the waits INSIDE it; a wait outside any pass, or one that only
+    overlaps a pass's edge, subtracts nothing."""
+    obs = _obs([
+        ev("serve.sched.pass", 0, 100), ev("serve.engine.wait", 10, 90),
+        ev("serve.sched.pass", 200, 50), ev("serve.engine.wait", 210, 20),
+        ev("serve.engine.wait", 215, 5),      # a second fetch, same pass
+        ev("serve.engine.wait", 160, 30),     # between the passes
+        ev("serve.engine.wait", 245, 10),     # hangs over the pass's end
+        ev("serve.engine.dispatch", 1, 4)])   # not in `minus`
+    params = {"span": "serve.sched.pass", "minus": ["serve.engine.wait"],
+              "scale": 1e-6}
+    # pass 1: 100 - 90 = 10 ms; pass 2: 50 - 20 - 5 = 25 ms
+    assert read(obs, {**params, "q": 0}) == pytest.approx(10.0)
+    assert read(obs, {**params, "q": 100}) == pytest.approx(25.0)
+    assert read(obs, {**params, "q": 50}) == pytest.approx(17.5)
+    # without `minus` the span's own duration; scale defaults to 1 (ns)
+    assert read(obs, {"span": "serve.sched.pass", "q": 100}) == \
+        pytest.approx(100 * MS)
+
+
+def test_no_event_of_that_name_reads_as_nothing(read):
+    """The parent of PR 24 has no such span, a CPU rehearsal prints no
+    span metric, and an untraced run has no trace at all: None each time,
+    so the metric is left out of the line and nothing raises."""
+    params = {"span": "train.step", "minus": ["train.fetch"], "q": 50}
+    assert read(Obs(), params) is None                      # not traced
+    assert read(_obs([]), params) is None
+    assert read(_obs([ev("scheduler.step", 0, 5),
+                      ev("train.fetch", 1, 2)]), params) is None
+    metrics = manifest.read_metrics(_metric_files(), _obs(
+        [ev("scheduler.step", 0, 5), ev("engine.step", 1, 3)]))
+    assert metrics == {}
+
+
+def test_the_three_metric_files_read_their_spans():
+    serve = _obs([
+        ev("scheduler.step", 0, 750), ev("serve.sched.pass", 0.1, 749),
+        ev("serve.sched.admit", 0.2, 0.1), ev("engine.step", 0.5, 742),
+        ev("serve.engine.dispatch", 0.6, 2.5),
+        ev("serve.engine.wait", 3.2, 739), ev("serve.sched.emit", 743, 5.5),
+        ev("serve.sched.admit", 748.6, 0.2)])
+    got = manifest.read_metrics(_metric_files(), serve)
+    assert set(got) == {"sched.host_ms_per_pass", "engine.dispatch_ms_p50"}
+    assert got["sched.host_ms_per_pass"] == {
+        "value": pytest.approx(10.0), "unit": "ms"}
+    assert got["engine.dispatch_ms_p50"]["value"] == pytest.approx(2.5)
+    train = _obs(
+        [ev("train.step", 150 * i, 2.0) for i in range(4)]
+        + [ev("train.step", 600, 148), ev("train.data", 600.1, 0.4),
+           ev("train.dispatch", 600.6, 1.2), ev("train.fetch", 602, 145.5)])
+    got = manifest.read_metrics(_metric_files(), train)
+    # four steps of 2 ms and the log-boundary step: 148 - 145.5 = 2.5 ms
+    assert got == {"trainer.host_ms_per_step": {
+        "value": pytest.approx(2.0), "unit": "ms"}}
+
+
+def test_gaps_go_to_the_programs_spans_nested_inside_the_benchmarks():
+    """With the ten names in a driver's ANNOTATIONS an idle gap's owner is
+    the innermost span open at its middle: the program's own, nested
+    inside the benchmark's outer annotation."""
+    from chipbench.drivers import serve as serve_driver, train as train_driver
+
+    for name in ("serve.sched.pass", "serve.engine.wait", "train.step",
+                 "train.fetch"):
+        assert name in serve_driver.ANNOTATIONS
+        assert name in train_driver.ANNOTATIONS
+    ops = [Event("%fusion.1 = f32[8]{0} fusion()", 0, 10 * MS),
+           Event("%fusion.2 = f32[8]{0} fusion()", 13 * MS, 7 * MS),
+           Event("%fusion.3 = f32[8]{0} fusion()", 24 * MS, 1 * MS)]
+    host = [ev("train.step", 9, 6), ev("train.data", 10.5, 1.5),
+            ev("train.step", 16, 9), ev("train.fetch", 18, 6.5)]
+    gaps = dict(reduce.gaps_by_annotation(Trace({0: ops}, host, {})))
+    assert gaps == {"train.data": pytest.approx(0.003),
+                    "train.fetch": pytest.approx(0.004)}
