@@ -521,8 +521,8 @@ def _parse_profile_steps(spec: str):
     a typo can't strand multi-host peers past the rendezvous)."""
     m = re.match(r"^(\d+):(\d+)$", spec)
     if not m or int(m.group(1)) < 1 or int(m.group(2)) < 1:
-        # START >= 1: the window opens after step START completes, so 0
-        # cannot capture step 1 and would silently shift the window.
+        # START >= 1: the window opens before step START is dispatched,
+        # and the first step is 1.
         raise SystemExit(f"--profile-steps takes START:COUNT with START "
                          f">= 1 and COUNT >= 1 (e.g. 10:3), got {spec!r}")
     return int(m.group(1)), int(m.group(2))
@@ -1565,8 +1565,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "unless --profile-steps bounds it)")
     p.add_argument("--profile-steps", default=None, metavar="START:COUNT",
                    help="bounded trace into --profile-dir: capture begins "
-                        "once step START has completed and covers the next "
-                        "COUNT steps (e.g. 10:3 traces steps 11-13 — the "
+                        "between two steps, on a drained device before "
+                        "step START is dispatched, and covers COUNT whole "
+                        "steps (e.g. 10:3 traces steps 10-12 — the "
                         "standard steady-state window)")
     p.add_argument("--coordinator", default=None, metavar="HOST:PORT",
                    help="rendezvous address for multi-process launch")

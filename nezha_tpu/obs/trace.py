@@ -34,9 +34,9 @@ from nezha_tpu.obs.registry import (  # noqa: F401 — re-exported API
     Span,
     current_trace,
     emit_span,
+    enabled,
     mint_trace_id,
     new_span_id,
-    enabled,
     set_trace_sample,
     trace_context,
     trace_sample,
@@ -126,9 +126,10 @@ def annotate_step(name: str, step_num: int, **attrs) -> _Annotation:
 class Tracer:
     """Start/stop trace control for long-running loops.
 
-    A Trainer can hold one and call ``maybe_trace(step)``: the trace turns
-    on at ``start_step`` and off after ``num_steps`` — the standard
-    "profile steps 10..13" workflow without restructuring the loop.
+    A Trainer holds one and calls ``maybe_trace(step)`` between two steps,
+    before it dispatches ``step``: the trace turns on before ``start_step``
+    and off ``num_steps`` later — the standard "profile steps 10..12"
+    workflow without restructuring the loop.
     """
 
     def __init__(self, log_dir: Optional[str] = None, start_step: int = 10,
@@ -144,7 +145,11 @@ class Tracer:
     def enabled(self) -> bool:
         return self.log_dir is not None
 
-    def maybe_trace(self, step: int) -> None:
+    def maybe_trace(self, step: int, sync=None) -> None:
+        """``sync`` (optional) runs right before the window opens and
+        right before it closes: a loop passes its device barrier, so the
+        window holds whole steps only — nothing in flight at either end,
+        where the profiler would catch device ops with no host span."""
         if not self.enabled:
             return
         # A resumed run's counter may start anywhere past start_step (e.g.
@@ -154,9 +159,13 @@ class Tracer:
         if not self._active and not self._done and step >= self.start_step:
             self.stop_step = step + self.num_steps
             os.makedirs(self.log_dir, exist_ok=True)
+            if sync is not None:
+                sync()
             jax.profiler.start_trace(self.log_dir)
             self._active = True
         elif self._active and step >= self.stop_step:
+            if sync is not None:
+                sync()
             self.stop()
 
     def stop(self) -> None:

@@ -1007,9 +1007,9 @@ class Scheduler:
             # time spent inside Engine.prefill since then (its
             # serve.engine.prefill span): the retire/admit/stream pass
             # alone — the per-dispatch cost a horizon > 1 spreads over
-            # H tokens. A prefill dispatch can block on the device
-            # (donated pool buffers still in use by the block in
-            # flight), so counting it made the gap read device time.
+            # H tokens. An admission's prefill is work of its own (4-10
+            # ms of host time a prompt on a v5e, PERF.md PR 24, more
+            # where its dispatch waits for the device), not gap.
             obs.histogram("serve.host_gap_s").observe(
                 t0 - self._host_gap_t
                 - (self.engine.prefill_host_s - self._gap_prefill_s))

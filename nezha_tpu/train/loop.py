@@ -267,6 +267,15 @@ class Trainer:
         window_steps = 0  # actual steps this logging window (a resume can
         # land mid-window, so log_every would overstate the first rate)
         for _ in range(steps):
+            # A profile window opens and closes here, between two steps
+            # and before the step it names, on a drained device: an
+            # annotation that began before the window opened is not
+            # recorded, and a device op caught in flight has no host
+            # span over it, so the window holds whole steps only, every
+            # one on record.
+            if self.tracer is not None:
+                self.tracer.maybe_trace(self.global_step + 1,
+                                        sync=self._drain)
             # One iteration = one train.step on the profiler's timeline,
             # its host phases nested inside: who owns an idle gap of the
             # device is read off these (PERF.md §3).
@@ -289,15 +298,17 @@ class Trainer:
                 if (self.checkpoint_every and self.checkpoint_dir
                         and self.global_step % self.checkpoint_every == 0):
                     self._save(self.global_step)
-            # Between two steps, never inside one: an annotation that
-            # began before the profile window opened is not recorded, so
-            # a window opened here has every one of its steps on record.
-            if self.tracer is not None:
-                self.tracer.maybe_trace(self.global_step)
         if not last_metrics and steps:
             last_metrics = {k: float(v) for k, v in metrics.items()}
             last_metrics["step"] = self.global_step
         return last_metrics
+
+    def _drain(self) -> None:
+        """Block until the device has run every dispatched step (the
+        barrier at either end of a profile window). On the timeline it
+        is a ``train.fetch``: the host blocked on the device."""
+        with obs.annotate("train.fetch"):
+            jax.block_until_ready(self.state)
 
     def _dispatch(self, batch) -> Dict[str, Any]:
         """Enqueue one optimizer step; returns its (device) metrics."""

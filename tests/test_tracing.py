@@ -740,7 +740,8 @@ def test_annotate_puts_bare_name_and_attrs_on_the_profiler_timeline(
     annotation that began before the session is not recorded."""
     early = obs.annotate("probe.early")
     early.__enter__()
-    jax.profiler.start_trace(str(tmp_path), profiler_options=_profiler_options())
+    jax.profiler.start_trace(str(tmp_path),
+                             profiler_options=_profiler_options())
     try:
         with obs.annotate("probe.outer", live=3, queued=7) as ann:
             with obs.annotate("probe.inner"):
@@ -770,7 +771,8 @@ def test_serve_layer_spans_reach_the_profiler_timeline(tiny_model,
     sched.submit(Request(prompt=_prompt(5), max_new_tokens=2))
     sched.run_until_idle()                      # programs built
     assert not obs.enabled()
-    jax.profiler.start_trace(str(tmp_path), profiler_options=_profiler_options())
+    jax.profiler.start_trace(str(tmp_path),
+                             profiler_options=_profiler_options())
     try:
         rids = [sched.submit(Request(prompt=_prompt(9, salt=i),
                                      max_new_tokens=4)) for i in range(3)]
@@ -801,10 +803,12 @@ def test_serve_layer_spans_reach_the_profiler_timeline(tiny_model,
 
 
 def test_train_layer_spans_reach_the_profiler_timeline(tmp_path):
-    """A three-step Trainer.fit whose Tracer opens the window after step
-    1: steps 2 and 3 — every step after the one that opened the window
+    """A four-step Trainer.fit whose Tracer opens the window between
+    steps 1 and 2, before step 2 is dispatched, and closes it before
+    step 4: steps 2 and 3 — every step of the window, its first included
     — are train.step events with their data / dispatch / fetch phases
-    inside."""
+    inside, and the barrier that drained the device before the window
+    closed is one more train.fetch after the last of them."""
     from nezha_tpu import data, optim
     from nezha_tpu.models import MLP
     from nezha_tpu.obs import Tracer
@@ -815,16 +819,19 @@ def test_train_layer_spans_reach_the_profiler_timeline(tmp_path):
         return ops.softmax_cross_entropy_with_integer_labels(
             logits, batch["label"])
 
-    tracer = Tracer(str(tmp_path), start_step=1, num_steps=2)
+    tracer = Tracer(str(tmp_path), start_step=2, num_steps=2)
     trainer = Trainer(MLP(hidden=(16,)), optim.momentum(0.1), loss_fn,
                       rng=jax.random.PRNGKey(0), log_every=1, tracer=tracer)
-    trainer.fit(data.mnist_batches(8, seed=0), steps=3)
-    tracer.stop()
-    assert tracer._done
+    trainer.fit(data.mnist_batches(8, seed=0), steps=4)
+    assert tracer._done and not tracer._active
     ev = _host_events(tmp_path, set(TRAIN_LAYER_SPANS))
     assert {n for n, *_ in ev} == set(TRAIN_LAYER_SPANS)
     steps = [s["step_num"] for n, _, _, s in ev if n == "train.step"]
     assert steps == [2, 3]
+    last_step_end = max(b for n, _, b, _ in ev if n == "train.step")
+    *in_steps, drain = [e for e in ev if e[0] == "train.fetch"]
+    assert drain[1] >= last_step_end
+    ev.remove(drain)
     for inner in TRAIN_LAYER_SPANS[1:]:
         assert _inside(ev, inner, "train.step"), inner
         assert sum(1 for n, *_ in ev if n == inner) == 2

@@ -117,3 +117,75 @@ def test_gaps_go_to_the_programs_spans_nested_inside_the_benchmarks():
     gaps = dict(reduce.gaps_by_annotation(Trace({0: ops}, host, {})))
     assert gaps == {"train.data": pytest.approx(0.003),
                     "train.fetch": pytest.approx(0.004)}
+
+
+# ------------------------------------------------ recorded on the chip
+SLICES = "v5e_pr24_layer_span_slices"
+
+
+def _recorded(name):
+    with open(os.path.join(DATA, f"{SLICES}.json")) as f:
+        trace = Trace.from_json(json.load(f)["slices"][name])
+    with open(os.path.join(DATA, f"{SLICES}.expect.json")) as f:
+        return trace, json.load(f)["slices"][name]
+
+
+@pytest.mark.parametrize("name", ["batch_gen", "train", "train_drained",
+                                  "bert_x4"])
+def test_recorded_chip_trace_carries_the_programs_spans(name):
+    """Slices of the first traced runs with the program's own spans (my
+    chip runs, PR 24): every kept host event of the traced window, and the
+    device ops around one turnaround (a decode step's end and the pass
+    that follows it; a log boundary of the train loop — from the first
+    runs and, ``train_drained`` / ``bert_x4``, from the final tree's,
+    whose profile window opens and closes on a drained device). The new
+    metrics read the recorded numbers, each idle gap has a program span
+    for an owner, and the kernels carry their names while the patterns
+    PR 22 wrote still find them."""
+    trace, want = _recorded(name)
+    chips = want["chips"]
+    assert sorted(trace.device_ops) == list(range(chips))
+    assert sum(map(len, trace.device_ops.values())) == want["events"]
+    obs = Obs()
+    obs.trace = trace
+    obs.set("chips", chips)
+    got = manifest.read_metrics(_metric_files(), obs)
+    assert {k: v["value"] for k, v in got.items()} == pytest.approx(
+        want["metrics"], rel=1e-9)
+    assert all(0 < v < want["step_ms"] for v in want["metrics"].values())
+    counts = {}
+    for e in trace.host:
+        counts[e.name] = counts.get(e.name, 0) + 1
+    assert counts == want["host_events"]
+    s = reduce.summary(trace, chips)
+    assert s["busy_s"] == pytest.approx(want["busy_s"], rel=1e-9)
+    assert s["window_s"] == pytest.approx(want["window_s"], rel=1e-9)
+    gaps = reduce.gaps_by_annotation(trace)
+    assert gaps[0][0] == want["heaviest_gap_owner"]
+    assert gaps[0][1] == pytest.approx(want["heaviest_gap_s"], rel=1e-9)
+    assert "(no annotation)" not in dict(gaps)
+    kinds = [n for n, _ in reduce.op_seconds(trace)]
+    for kernel in want["kernels"]:
+        assert any(k.startswith(kernel + " ") for k in kinds), kernel
+    metrics = os.path.join(manifest.ROOT, "metrics")
+    for metric, (seconds, events) in want["patterns"].items():
+        with open(os.path.join(metrics, f"{metric}.json")) as f:
+            patterns = json.load(f)["params"]["patterns"]
+        got_s, got_n = reduce.matching_seconds(trace, patterns, chips)
+        assert got_n == events > 0, metric
+        assert got_s == pytest.approx(seconds, rel=1e-9), metric
+
+
+def test_recorded_pass_is_mostly_the_wait_and_the_rest_is_the_hosts():
+    """The arithmetic on one recorded pass, by hand: a 1,117 ms pass whose
+    fetch blocked for 1,106 ms leaves 11.05 ms of host time — dispatch,
+    the emit loop and an admission with its prefill."""
+    trace, _ = _recorded("batch_gen")
+    passes = [e for e in trace.host if e.name == "serve.sched.pass"]
+    waits = [e for e in trace.host if e.name == "serve.engine.wait"]
+    assert len(passes) == len(waits) == 5
+    rest = sorted((p.dur_ns - w.dur_ns) / MS for p, w in zip(passes, waits))
+    assert all(p.start_ns <= w.start_ns and w.end_ns <= p.end_ns
+               for p, w in zip(passes, waits))
+    assert rest[2] == pytest.approx(11.049098)
+    assert 4.0 < rest[0] < rest[-1] < 20.0
