@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import contextlib
 import os
-import time
 from typing import Iterator, Optional
 
 import jax
@@ -66,17 +65,14 @@ class _Annotation:
     (always) and, while a run dir has the registry enabled, the registry
     ``Span`` of the same name and attrs (``NULL_SPAN`` otherwise).
     ``set(**attrs)`` adds what is only known inside the block (a count
-    of rows admitted, tokens emitted) to both; ``dur_s`` is the block's
-    host time on ``time.monotonic`` once it has closed."""
+    of rows admitted, tokens emitted) to both."""
 
-    __slots__ = ("_trace", "_span", "_t0", "dur_s")
+    __slots__ = ("_trace", "_span")
 
     def __init__(self, trace, span):
         self._trace, self._span = trace, span
-        self.dur_s = 0.0
 
     def __enter__(self) -> "_Annotation":
-        self._t0 = time.monotonic()
         self._trace.__enter__()
         self._span.__enter__()
         return self
@@ -84,7 +80,6 @@ class _Annotation:
     def __exit__(self, *exc) -> bool:
         self._span.__exit__(*exc)
         self._trace.__exit__(*exc)
-        self.dur_s = time.monotonic() - self._t0
         return False
 
     def set(self, **attrs) -> "_Annotation":
@@ -93,14 +88,15 @@ class _Annotation:
         return self
 
 
-def _layer_span(name: str, attrs: dict):
+def _layer_span(name: str, attrs: dict, record: bool = True):
     """The registry half of an annotation. A layer boundary belongs to a
     pass, not to a request: it never joins (or re-parents) the ambient
     request trace, so per-request timelines stitch as before."""
-    return Span(name, REGISTRY, attrs) if enabled() else NULL_SPAN
+    return Span(name, REGISTRY, attrs) if record and enabled() \
+        else NULL_SPAN
 
 
-def annotate(name: str, **attrs) -> _Annotation:
+def annotate(name: str, *, record: bool = True, **attrs) -> _Annotation:
     """THE primitive for a layer boundary (``serve.sched.pass``,
     ``train.dispatch``, ...): a host span on the profiler's own
     timeline, so what the program knows shares a clock with the device
@@ -108,10 +104,14 @@ def annotate(name: str, **attrs) -> _Annotation:
     ``TraceAnnotation`` is one atomic check in C++; the registry span
     exists only under ``--run-dir``, so ``spans.jsonl`` and the trace
     carry one vocabulary. ``attrs`` are cheap ints: they arrive as the
-    host event's stats. Usable inside jit too (an XLA op annotation).
+    host event's stats. ``record=False`` leaves the registry half out:
+    a loop that polls while idle passes whether this turn has work, so
+    an idle server writes no span record (each one is a flushed line of
+    ``spans.jsonl`` and a slot of the registry's bounded span list).
+    Usable inside jit too (an XLA op annotation).
     """
     return _Annotation(jax.profiler.TraceAnnotation(name, **attrs),
-                       _layer_span(name, attrs))
+                       _layer_span(name, attrs, record))
 
 
 def annotate_step(name: str, step_num: int, **attrs) -> _Annotation:

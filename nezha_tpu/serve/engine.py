@@ -585,10 +585,6 @@ class Engine:
         # decode_horizon tokens for every row) — tests assert the
         # dispatch-per-token amortization against this.
         self.step_calls = 0
-        # Host seconds spent inside prefill() so far (the sum of its
-        # serve.engine.prefill spans): the scheduler subtracts what
-        # this grew by from serve.host_gap_s.
-        self.prefill_host_s = 0.0
         # Tokens the most recent prefill's compiled chunks pushed
         # through the target model (set per prefill call), and how many
         # chunk dispatches it took (the sequence-sharded engine's
@@ -829,13 +825,10 @@ class Engine:
         validated here — admission (``Scheduler.submit``) is the
         validation boundary. The first generated token comes from the
         next :meth:`step`."""
-        ann = obs.annotate("serve.engine.prefill", tokens=len(tokens))
-        try:
-            with ann:
-                self._prefill(ann, slot, tokens, seed, temperature, top_k,
-                              top_p, eos_id, max_new_tokens)
-        finally:
-            self.prefill_host_s += ann.dur_s
+        with obs.annotate("serve.engine.prefill",
+                          tokens=len(tokens)) as ann:
+            self._prefill(ann, slot, tokens, seed, temperature, top_k,
+                          top_p, eos_id, max_new_tokens)
 
     def _prefill(self, ann, slot: int, tokens: Sequence[int], seed: int,
                  temperature: float, top_k: Optional[int],

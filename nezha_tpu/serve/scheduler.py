@@ -388,8 +388,8 @@ class Scheduler:
         # loop was idle in between — serve.host_gap_s only measures the
         # host gap WITHIN continuous decoding, never idle waits.
         self._host_gap_t: Optional[float] = None
-        # engine.prefill_host_s as it read at that timestamp: what it
-        # has grown by at the next dispatch is prefill time, not gap.
+        # Host seconds inside Engine.prefill since that timestamp
+        # (_prefill sums them): prefill time, not gap.
         self._gap_prefill_s = 0.0
         register_serve_instruments()
         pool = engine.pool
@@ -498,9 +498,14 @@ class Scheduler:
     def step(self) -> int:
         """One serving iteration. Returns the number of tokens decoded
         (0 when fully idle)."""
-        with self._lock, obs.annotate("serve.sched.pass",
-                                      live=len(self._live),
-                                      queued=self._queued_n):
+        # An idle poll (the serving loops call step() every 2 ms with
+        # nothing to do) leaves no span record behind: only a pass with
+        # work mirrors its span into the registry.
+        with self._lock, obs.annotate(
+                "serve.sched.pass",
+                record=bool(self._live or self._queued_n
+                            or self._preempted),
+                live=len(self._live), queued=self._queued_n):
             self._expire_queued()
             self._expire_parked()
             self._expire_preempted()
@@ -810,7 +815,7 @@ class Scheduler:
                 with obs.span("serve.prefill",
                               request_id=live.request_id,
                               prompt_len=len(context), resumed=True):
-                    self.engine.prefill(
+                    self._prefill(
                         slot, context, seed=req.seed,
                         temperature=req.temperature, top_k=req.top_k,
                         top_p=req.top_p, eos_id=req.eos_id,
@@ -831,10 +836,22 @@ class Scheduler:
             live.decode_t0_wall = time.time()
         self._live[slot] = live
 
+    def _prefill(self, slot: int, tokens, **kwargs) -> None:
+        """[holds: _lock] ``Engine.prefill``, its host time summed into
+        ``_gap_prefill_s`` (what ``serve.host_gap_s`` leaves out), a
+        failed one included."""
+        t0 = time.monotonic()
+        try:
+            self.engine.prefill(slot, tokens, **kwargs)
+        finally:
+            self._gap_prefill_s += time.monotonic() - t0
+
     def _admit(self) -> None:
         """[holds: _lock] — step() calls this inside the lock. One
         admission pass under its ``serve.sched.admit`` span."""
-        with obs.annotate("serve.sched.admit") as ann:
+        with obs.annotate("serve.sched.admit",
+                          record=bool(self._queued_n
+                                      or self._preempted)) as ann:
             ann.set(admitted=self._admit_pass())
 
     def _admit_pass(self) -> int:
@@ -940,7 +957,7 @@ class Scheduler:
                 with obs.span("serve.prefill",
                               request_id=live.request_id,
                               prompt_len=len(req.prompt)):
-                    self.engine.prefill(
+                    self._prefill(
                         slot, req.prompt, seed=req.seed,
                         temperature=req.temperature, top_k=req.top_k,
                         top_p=req.top_p, eos_id=req.eos_id,
@@ -1011,8 +1028,7 @@ class Scheduler:
             # ms of host time a prompt on a v5e, PERF.md PR 24, more
             # where its dispatch waits for the device), not gap.
             obs.histogram("serve.host_gap_s").observe(
-                t0 - self._host_gap_t
-                - (self.engine.prefill_host_s - self._gap_prefill_s))
+                t0 - self._host_gap_t - self._gap_prefill_s)
         def _dispatch():
             # KV block exhaustion (genuine, or an injected serve.kv.bind
             # fault) is TYPED BACKPRESSURE, not an engine failure: retire
@@ -1057,7 +1073,7 @@ class Scheduler:
         now = time.monotonic()
         now_wall = time.time() if traced_batch else None
         self._host_gap_t = now
-        self._gap_prefill_s = self.engine.prefill_host_s
+        self._gap_prefill_s = 0.0
         with obs.annotate("serve.sched.emit") as ann:
             emitted = self._emit_block(tokens, block_emitted, t0, now,
                                        t0_wall, now_wall)

@@ -175,7 +175,14 @@ def test_annotate_records_the_registry_span_of_the_same_name_when_enabled():
     assert recs["serve.engine.dispatch"]["attrs"] == {"rows": 3, "extra": 1}
     assert recs["train.step"]["attrs"] == {"step": 7}
     assert recs["train.fetch"]["dur_s"] <= recs["train.step"]["dur_s"]
-    assert ann.dur_s >= 0.0
+    # record=False (an idle poll) keeps the registry half out.
+    obs.enable()
+    try:
+        with obs.annotate("serve.sched.pass", record=False, live=0) as idle:
+            assert idle._span is obs.NULL_SPAN
+    finally:
+        obs.disable()
+    assert "serve.sched.pass" not in {r["name"] for r in obs.REGISTRY.spans}
 
 
 # ------------------------------------------------------- trace context
