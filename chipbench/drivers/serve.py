@@ -36,11 +36,7 @@ from chipbench.stats import percentile
 clock = time.perf_counter
 
 ANNOTATIONS = ("submit", "scheduler.step", "engine.step", "engine.prefill",
-               "generator.sleep",
-               "serve.sched.pass", "serve.sched.admit", "serve.sched.emit",
-               "serve.engine.prefill", "serve.engine.dispatch",
-               "serve.engine.wait", "train.step", "train.data",
-               "train.dispatch", "train.fetch")
+               "generator.sleep")
 CHECK_REQUESTS = 8
 CHECK_STEPS = 3          # decode steps whose logits are compared
 

@@ -37,10 +37,7 @@ clock = time.perf_counter
 # its profile window inside ``fit``, and an annotation that began before
 # the window is not recorded. Idle gaps there have no owner until the
 # program annotates its steps (PERF.md, open questions).
-ANNOTATIONS = ("serve.sched.pass", "serve.sched.admit", "serve.sched.emit",
-               "serve.engine.prefill", "serve.engine.dispatch",
-               "serve.engine.wait", "train.step", "train.data",
-               "train.dispatch", "train.fetch")
+ANNOTATIONS = ()
 
 
 class Tail(threading.Thread):
