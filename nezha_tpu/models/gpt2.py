@@ -775,8 +775,10 @@ class Attention(Module):
             out = out_pf
         elif use_decode_kernel or shmap_mesh is not None:
             # The kernel takes the POOLS + table directly (block-table
-            # gather operand): rows only DMA table entries below their
-            # own length, inactive rows skip every block. Int8 pools
+            # gather operand): its grid is rows x table entries / c
+            # whatever the rows hold, but it DMAs and folds only the
+            # entries below a row's own length (all heads of an entry
+            # at once), and an inactive row folds nothing. Int8 pools
             # add the [N, H] scale operands and the kernel dequantizes
             # inside its block loop — the int8 cache never round-trips
             # through a dense bf16 view.

@@ -9,16 +9,23 @@ kernel is built for the actual access pattern (the "Harnessing HPC
 Kernels" argument from PAPERS.md: shape-specialized hot loops deserve a
 kernel, not a generic lowering):
 
-- grid ``(B, H, KV-blocks)`` with the KV dimension sequential
-  ("arbitrary" semantics) — the split-K layout: each program folds one
-  KV block into VMEM running ``(max, sum, acc)`` scratch via online
-  softmax, merged at the final block (no score matrix, no mask tensor);
+- the KV dimension of the grid is sequential ("arbitrary" semantics) —
+  the split-K layout: each program folds one KV block into VMEM running
+  ``(max, sum, acc)`` scratch via online softmax, merged at the final
+  block (no score matrix, no mask tensor). The dense layout runs
+  ``(B, H, L / block_k)``; the paged layout runs ``(B, M / c)`` — all
+  heads of ``c`` table entries a step (a pool block is contiguous over
+  its heads), see :func:`_paged_call`;
 - per-row ``lengths`` ride as a SCALAR-PREFETCH operand (SMEM — the TPU
   lowering refuses a ``(1, 128)`` VMEM block over ``[B, 128]``): a
   program whose block starts at or past its row's length SKIPS the block
-  entirely (``@pl.when``), so short rows and inactive rows
-  (``length == 0``) cost block-bookkeeping only — compute is
-  proportional to ``sum(lengths)``, not ``B * L_max``;
+  (``@pl.when``). The grid does not shrink with the rows: the paged
+  kernel takes ``B * M / c`` steps whatever they hold, but DMA and
+  arithmetic happen only for a row's live entries (entries past its
+  length repeat a block index already held, which the pipeline does not
+  fetch again), so short rows and inactive rows (``length == 0``) cost
+  the scalar core's step bookkeeping only (about 0.7 us a step of 8
+  entries on a v5e: PERF.md section 6, PR 25);
 - Q·Kᵀ and P·V accumulate fp32 over the caches' native dtype (bf16 pool
   dots run at the doubled MXU rate; the softmax statistics and the
   accumulator stay fp32 throughout);
@@ -39,6 +46,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -49,7 +57,7 @@ from jax.experimental.pallas import tpu as pltpu
 # inline version.
 from nezha_tpu.ops.pallas.common import (
     LANES as _LANES,
-    block_scale,
+    NEG_BIG,
     block_step as _block_step,
     gather_row_scales,
     pick_block as _pick_block,
@@ -94,54 +102,115 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         _finalize(o_ref, l_scr, acc_scr)
 
 
-def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *, scale: float,
-                         block_k: int):
-    # Identical math to the dense kernel: the block table only changed
-    # WHERE block ki lives (the BlockSpec index map gathered it), not
-    # what it means — per-row lengths still skip blocks at/past the
-    # row's depth, so work tracks sum(lengths) over the block
-    # indirection exactly as it did over the dense pool.
-    _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                   acc_scr, scale=scale, block_k=block_k)
+# Table entries folded into one grid step of the paged kernels: the
+# pool rides as this many K and V operands, and their tiles are one
+# ``c * block_size``-position block of the online softmax. Chosen on the
+# chip from {4, 8} by gpt2-124m.batch-gen's out_tok_s (8: 642.7 twice,
+# 4: 636.1 tokens/s on one seed; PERF.md section 6, PR 25); a table
+# whose length it does not divide takes the largest divisor below it.
+_ENTRIES_PER_STEP = 8
 
 
-def _paged_quant_decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref,
-                               ks_ref, vs_ref, o_ref, m_scr, l_scr,
-                               acc_scr, *, scale: float, block_k: int):
-    """Paged kernel over an INT8 block pool: the row's per-block fp32
-    scales ride as one ``[1, M]`` lane vector per (row, head) (see
-    :func:`gather_row_scales`) and the dequant happens right here in
-    the block loop — int8 blocks never round-trip through a dense bf16
-    cache. Dequantized tiles are cast to the query's dtype (bf16 pools
-    dot at the doubled MXU rate); softmax statistics and the
+def _paged_decode_kernel(tab_ref, len_ref, q_ref, *refs, scale: float,
+                         block_size: int, entries: int, quant: bool):
+    """One grid step = ``entries`` consecutive table entries of one row,
+    ALL heads: ``q_ref``/``o_ref`` are ``(1, H, 1, D)``, each of the
+    ``entries`` K and V refs one pool block ``(1, H, bs, D)`` (the index
+    maps of :func:`_paged_call` gathered them through the table). The
+    tiles are folded as one ``entries * bs``-position block of the
+    online softmax: :func:`common.block_step` with a leading head axis
+    (one batched contraction over H; an unrolled loop over heads with
+    per-head scratch read 1.3x slower on a v5e, PERF.md section 6, PR 25).
+    On an INT8 pool the row's per-block fp32 scales ride as
+    ``(1, H, 1, M)`` (see :func:`gather_row_scales`) and each tile is
+    dequantized right here — int8 blocks never round-trip through a
+    dense bf16 cache; dequantized tiles are cast to the query's dtype
+    (bf16 dots at the doubled MXU rate), softmax statistics and the
     accumulator stay fp32."""
-    ki = pl.program_id(2)
+    c = entries
+    k_refs, v_refs, refs = refs[:c], refs[c:2 * c], refs[2 * c:]
+    ks_ref = vs_ref = None
+    if quant:
+        ks_ref, vs_ref, *refs = refs
+    o_ref, m_scr, l_scr, acc_scr = refs
+    ki = pl.program_id(1)
+    span = c * block_size
 
     @pl.when(ki == 0)
     def _init():
         _scratch_init(m_scr, l_scr, acc_scr)
 
     length = len_ref[pl.program_id(0)]
-    run = ki * block_k < length
 
-    @pl.when(run)
+    def block(pool_refs, scale_ref):
+        """The step's ``[H, span, d]`` block in the query's dtype."""
+        tiles = [r[0] for r in pool_refs]
+        if quant:
+            # THE dequant both attention paths share (see
+            # ops/quant.dequantize_kv_block): int8 * fp32 scale, cast to
+            # the compute dtype — the XLA gather fallback applies the
+            # same expression, so kernel and fallback see identical
+            # tiles. Entry t's scale is lane t of the row's scale
+            # block: a masked lane reduction (Mosaic has no dynamic
+            # lane index into VMEM), one selected element plus zeros.
+            rows = scale_ref[0]                              # [H, 1, M]
+            lane = lax.broadcasted_iota(jnp.int32, rows.shape, 2)
+            tiles = [(t.astype(jnp.float32) * jnp.sum(
+                jnp.where(lane == ki * c + j, rows, 0.0), axis=-1,
+                keepdims=True)).astype(q_ref.dtype)
+                     for j, t in enumerate(tiles)]
+        return jnp.concatenate(tiles, axis=1)
+
+    # Steps at or past the row's length do nothing: their index maps
+    # repeat the blocks of the row's last live step, so the pipeline
+    # issues no DMA for them either. A row with length == 0 (inactive
+    # slot) runs no step at all and finalizes to an all-zero output.
+    @pl.when(ki * span < length)
     def _block():
-        q = q_ref[0, 0]                                      # [1, d]
-        # THE dequant both attention paths share (see
-        # ops/quant.dequantize_kv_block): int8 * fp32 scale, cast to
-        # the compute dtype — the XLA gather fallback applies the same
-        # expression, so kernel and fallback see identical tiles.
-        k = (k_ref[0, 0].astype(jnp.float32)
-             * block_scale(ks_ref, ki)).astype(q.dtype)      # [bk, d]
-        v = (v_ref[0, 0].astype(jnp.float32)
-             * block_scale(vs_ref, ki)).astype(q.dtype)      # [bk, d]
-        _block_step(q, k, v, length, ki, m_scr, l_scr, acc_scr,
-                    scale=scale, block_k=block_k)
+        q = q_ref[0]                                         # [H, 1, d]
+        k = block(k_refs, ks_ref)
+        v = block(v_refs, vs_ref)
+        s = lax.dot_general(q.astype(k.dtype), k,
+                            (((2,), (2,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32) * scale
+        kpos = ki * span + lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(kpos < length, s, NEG_BIG)             # [H, 1, span]
+        m_prev = m_scr[:, :, :1]
+        l_prev = l_scr[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = corr * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * corr + lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(ki == pl.num_programs(1) - 1)
     def _final():
-        _finalize(o_ref, l_scr, acc_scr)
+        # common.softmax_finalize over every head's row (no lse:
+        # inference only); a row that folded nothing stays exactly zero.
+        denom = jnp.maximum(l_scr[:, :, :1], 1e-30)
+        o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
+
+
+def _visited_entries(tab, lens, block_size: int, entries: int):
+    """``block_tables [B, M]`` as the paged kernel walks it: entry ``e``
+    of a row is operand ``e % entries`` of grid step ``e // entries``.
+    Up to the row's last live entry the table stands as it is; every
+    entry past it names a block the row's last live step already holds
+    in the same operand (or the last live block itself), so a skipped
+    step repeats the block indices of the step before it — the pipeline
+    issues no DMA — and a row with live entries names no block it does
+    not own. A row with ``length == 0`` names its entry 0 throughout
+    (fetched once, never folded)."""
+    m = tab.shape[1]
+    last = jnp.maximum((lens + block_size - 1) // block_size - 1, 0)[:, None]
+    e = jnp.arange(m, dtype=jnp.int32)[None, :]
+    step = jnp.minimum(e // entries, last // entries)
+    ent = jnp.minimum(step * entries + e % entries, last)
+    return jnp.take_along_axis(tab, ent, axis=1)
 
 
 def _paged_call(q, k, v, lengths, block_tables, scale, interpret,
@@ -149,46 +218,61 @@ def _paged_call(q, k, v, lengths, block_tables, scale, interpret,
     """Paged layout: k/v are BLOCK POOLS ``[N, H, bs, D]`` and
     ``block_tables [B, M]`` maps row b's KV block ki to pool block
     ``block_tables[b, ki]``. Table and lengths ride as SCALAR-PREFETCH
-    operands (pltpu.PrefetchScalarGridSpec) so the grid's KV dimension
-    gathers blocks through the table in its index map — the kernel body
-    is unchanged, per-row length skipping included. With
-    ``block_scales`` (int8 pools) the row's per-block fp32 scales are
-    pre-gathered through the same table (:func:`gather_row_scales`) and
-    the kernel dequantizes each tile in the block loop."""
+    operands (pltpu.PrefetchScalarGridSpec); the grid is ``(B, M / c)``
+    and each pool is passed ``c`` times, operand ``j`` gathering table
+    entry ``ki * c + j`` in its index map (XLA feeds all of them from
+    one buffer). A pool block is contiguous over its heads, so one DMA
+    brings all ``H`` heads of an entry. The table is first rewritten by
+    :func:`_visited_entries` (one index load is then all an index map
+    costs the scalar core, which is what a skipped step's time is made
+    of). With ``block_scales`` (int8 pools) the row's per-block fp32
+    scales are pre-gathered through the same rewritten table
+    (:func:`gather_row_scales`) and the kernel dequantizes each tile in
+    the block loop.
+
+    There is no manual-DMA loop over a row's own entries (pools in
+    ``pl.ANY``, ``make_async_copy`` per live entry): the TPU holds the
+    row-major pool as ``[N, H, bs, 128]`` and refuses a 64-lane slice
+    of it as a DMA source ("must be aligned to tiling (128)") until the
+    pool's minor dimension is lane-dense (ROADMAP S-a)."""
     b, h, _, d = q.shape
     bs = k.shape[2]
     m = block_tables.shape[1]
+    c = _pick_block(m, _ENTRIES_PER_STEP)
     quant = block_scales is not None
-    kernel = functools.partial(
-        _paged_quant_decode_kernel if quant else _paged_decode_kernel,
-        scale=scale, block_k=bs)
-    tab = jnp.asarray(block_tables, jnp.int32)
+    kernel = functools.partial(_paged_decode_kernel, scale=scale,
+                               block_size=bs, entries=c, quant=quant)
     lens = jnp.clip(jnp.asarray(lengths, jnp.int32), 0, m * bs)
-    qo_spec = pl.BlockSpec((1, 1, 1, d),
-                           lambda b_, h_, ki, tab, lens: (b_, h_, 0, 0))
-    kv_spec = pl.BlockSpec(
-        (1, 1, bs, d), lambda b_, h_, ki, tab, lens: (tab[b_, ki], h_, 0, 0))
-    in_specs = [qo_spec, kv_spec, kv_spec]
-    operands = [q, k, v]
+    tab = _visited_entries(jnp.asarray(block_tables, jnp.int32), lens,
+                           bs, c)
+    row_spec = pl.BlockSpec((1, h, 1, d),
+                            lambda b_, ki, tab, lens: (b_, 0, 0, 0))
+    kv_specs = [pl.BlockSpec((1, h, bs, d),
+                             lambda b_, ki, tab, lens, j=j:
+                             (tab[b_, ki * c + j], 0, 0, 0))
+                for j in range(c)]
+    in_specs = [row_spec] + kv_specs * 2
+    operands = [q] + [k] * c + [v] * c
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, 1, m),
-                                  lambda b_, h_, ki, tab, lens:
-                                  (b_, h_, 0, 0))] * 2
+        in_specs += [pl.BlockSpec((1, h, 1, m),
+                                  lambda b_, ki, tab, lens:
+                                  (b_, 0, 0, 0))] * 2
         operands += [gather_row_scales(sc, tab) for sc in block_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, m),
+        grid=(b, m // c),
         in_specs=in_specs,
-        out_specs=qo_spec,
-        scratch_shapes=[pltpu.VMEM((1, _LANES), jnp.float32),
-                        pltpu.VMEM((1, _LANES), jnp.float32),
-                        pltpu.VMEM((1, d), jnp.float32)],
+        out_specs=row_spec,
+        scratch_shapes=[pltpu.VMEM((h, 1, _LANES), jnp.float32),
+                        pltpu.VMEM((h, 1, _LANES), jnp.float32),
+                        pltpu.VMEM((h, 1, d), jnp.float32)],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_DECODE_PARAMS,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=("nezha_decode_attention_paged_int8" if quant
               else "nezha_decode_attention_paged"),
@@ -216,8 +300,8 @@ def flash_decode_attention(q, k, v, lengths,
     ``[ki*block_size, (ki+1)*block_size)`` live in pool block
     ``block_tables[b, ki]``, and the kernel gathers KV blocks through
     the table via a scalar-prefetch index map. The per-row length skip
-    is preserved verbatim. ``block_k`` is ignored (the pool's block_size
-    IS the KV block).
+    is preserved verbatim. ``block_k`` is ignored (a grid step folds a
+    fixed number of whole pool blocks, all heads at once).
 
     With ``block_scales`` (paged only — a ``(k_scales, v_scales)`` pair
     of ``[num_blocks, H]`` fp32 arrays) the pools are INT8 and each

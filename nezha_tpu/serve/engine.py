@@ -1038,6 +1038,19 @@ class Engine:
             except faults.InjectedFault as e:
                 raise KVBlocksExhausted(str(e), slot=int(slot)) from e
 
+    def _dispatch_attrs(self, active: np.ndarray) -> dict:
+        """What the ``serve.engine.dispatch`` span says of a step: the
+        active ``rows`` and, on a paged pool, the table entries they
+        hold going in (``blocks``). ``blocks / (rows * M)`` is the share
+        of the block table the paged decode kernel visits: it skips,
+        without a DMA, every entry past a row's length."""
+        active = np.asarray(active, bool)
+        attrs = {"rows": int(np.count_nonzero(active))}
+        if self.paged:
+            attrs["blocks"] = int(np.sum(
+                self.host_positions[active] // self.cfg.kv_block_size + 1))
+        return attrs
+
     def step(self, active: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """Decode one BLOCK of up to ``decode_horizon`` tokens for every
         row; ``active`` is a ``[B_max]`` bool mask. Returns
@@ -1055,7 +1068,7 @@ class Engine:
         if self.spec is not None:
             return self._spec_step(active)
         with obs.annotate("serve.engine.dispatch",
-                          rows=int(np.count_nonzero(active))):
+                          **self._dispatch_attrs(active)):
             if self.paged:
                 self._bind_decode_windows(
                     active, self.cfg.decode_horizon, (self.pool,))
@@ -1113,7 +1126,7 @@ class Engine:
         k = self.spec.draft_k
         cap = self.cfg.decode_horizon * (k + 1)
         with obs.annotate("serve.engine.dispatch",
-                          rows=int(np.count_nonzero(active))):
+                          **self._dispatch_attrs(active)):
             if self.paged:
                 # Both pools bind the same window: verify/draft writes
                 # past it are garbage by construction and route to the

@@ -167,6 +167,98 @@ def test_flash_decode_block_table_operand_parity():
         np.asarray(dense), atol=1e-5)
 
 
+def _paged_case(kind, m, seed=0, b=8, h=3, d=16, bs=8):
+    """A batch over a PERMUTED table (no two entries share a block, no
+    row's blocks are in order) whose lengths sit on every edge of the
+    kernel's iteration space: empty, one position, one block, one block
+    and one, one grid step (c entries), one step and one, the full
+    table less one, the full table. Returns the kernel's operands and
+    the composed path's answer (gather, dequantize, masked dense
+    attention in float32 over the same stored values)."""
+    from nezha_tpu import ops
+    from nezha_tpu.ops.pallas import decode_attention as da
+    from nezha_tpu.ops.pallas.common import pick_block
+    from nezha_tpu.ops.quant import dequantize_kv_block, quantize_kv_block
+
+    c = pick_block(m, da._ENTRIES_PER_STEP)
+    lengths = np.minimum([0, 1, bs, bs + 1, c * bs, c * bs + 1,
+                          m * bs - 1, m * bs], m * bs).astype(np.int32)
+    assert len(lengths) == b
+    rng = np.random.default_rng(seed)
+    n = b * m + 1                                   # block 0: scratch
+    tables = (rng.permutation(n - 1) + 1).reshape(b, m).astype(np.int32)
+    dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
+    q = jnp.asarray(rng.normal(size=(b, h, 1, d)), dtype)
+    kp = jnp.asarray(rng.normal(size=(n, h, bs, d)), dtype)
+    vp = jnp.asarray(rng.normal(size=(n, h, bs, d)), dtype)
+    scales = None
+    if kind == "int8":
+        (kp, ksc), (vp, vsc) = quantize_kv_block(kp), quantize_kv_block(vp)
+        scales = (ksc, vsc)
+        k_all = dequantize_kv_block(kp[tables], ksc[tables])
+        v_all = dequantize_kv_block(vp[tables], vsc[tables])
+    else:
+        k_all = kp[tables].astype(jnp.float32)
+        v_all = vp[tables].astype(jnp.float32)
+    k_all = k_all.transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
+    v_all = v_all.transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
+    mask = jnp.where(jnp.arange(m * bs)[None, :] < lengths[:, None],
+                     0.0, -jnp.inf).astype(jnp.float32)
+    ref = np.array(ops.dot_product_attention(
+        q.astype(jnp.float32), k_all, v_all, mask=mask[:, None, None, :]))
+    ref[lengths == 0] = 0.0          # the kernel's answer for no position
+    owned = np.zeros(n, bool)
+    for row, length in zip(tables, lengths):
+        owned[row[:-(-int(length) // bs)]] = True
+    return (q, kp, vp, jnp.asarray(lengths), jnp.asarray(tables), scales,
+            ref, owned)
+
+
+# bf16 tiles dot in bf16 (scores and P both rounded to 8 bits of
+# mantissa); float32 and dequantized-int8-in-float32 tiles stay exact.
+_PAGED_TOL = {"f32": 2e-6, "int8": 2e-6, "bf16": 3e-2}
+
+
+@pytest.mark.parametrize("m", [16, 12, 3],
+                         ids=["m16", "m12-not-a-multiple", "m3-one-step"])
+@pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+def test_paged_decode_kernel_matches_composed_on_every_edge(kind, m):
+    """The paged kernel (interpret mode) against the composed path, all
+    edge lengths mixed in one batch: M a multiple of the module's
+    entries-per-step constant, M that is not (the largest divisor
+    steps it: 12 -> 6 or 4), and a table shorter than one step."""
+    from nezha_tpu.ops.pallas import flash_decode_attention
+
+    q, kp, vp, lengths, tables, scales, ref, _ = _paged_case(kind, m)
+    out = np.asarray(flash_decode_attention(
+        q, kp, vp, lengths, block_tables=tables, block_scales=scales,
+        interpret=True), np.float32)
+    assert np.abs(out - ref).max() <= _PAGED_TOL[kind]
+    assert (out[0] == 0).all()                    # length 0: exact zeros
+
+
+@pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+def test_paged_decode_kernel_reads_no_block_a_row_does_not_own(kind):
+    """Every block no row owns (scratch block 0, and each row's table
+    entries at or past ceil(length / bs)) is NaN — on an int8 pool its
+    scale is. The outputs stay finite and equal the clean pool's: an
+    entry past a row's length is never folded, whatever it names."""
+    from nezha_tpu.ops.pallas import flash_decode_attention
+
+    q, kp, vp, lengths, tables, scales, ref, owned = _paged_case(kind, 12)
+    if kind == "int8":
+        scales = tuple(jnp.where(owned[:, None], sc, jnp.nan)
+                       for sc in scales)
+    else:
+        kp, vp = (jnp.where(owned[:, None, None, None], pool, jnp.nan)
+                  for pool in (kp, vp))
+    out = np.asarray(flash_decode_attention(
+        q, kp, vp, lengths, block_tables=tables, block_scales=scales,
+        interpret=True), np.float32)
+    assert np.isfinite(out).all()
+    assert np.abs(out - ref).max() <= _PAGED_TOL[kind]
+
+
 # --------------------------------------------------------- engine parity
 def test_paged_engine_matches_dense_and_generate(model_and_vars):
     """Greedy, sampled, and chunked-prompt requests decode identically

@@ -77,12 +77,17 @@ def _flash(causal, seq, with_lengths=False):
         q, k, v, causal=causal, interpret=False, kv_lengths=lens)), args
 
 
-def _decode(pool_dtype):
-    pool = ((POOL, H, BLOCK, D), pool_dtype)
-    args = [((BATCH, H, 1, D), BF16), pool, pool, ((BATCH,), jnp.int32),
-            ((BATCH, TABLE), jnp.int32)]
+# gpt2-124m.batch-gen's deployment (chipbench/configs/gpt2-124m.json): 256
+# slots over the default pool of 1 scratch + 256 x 64 blocks.
+CELL_BATCH, CELL_POOL = 256, 16385
+
+
+def _decode(pool_dtype, batch=BATCH, blocks=POOL):
+    pool = ((blocks, H, BLOCK, D), pool_dtype)
+    args = [((batch, H, 1, D), BF16), pool, pool, ((batch,), jnp.int32),
+            ((batch, TABLE), jnp.int32)]
     if pool_dtype == jnp.int8:
-        args += [((POOL, H), jnp.float32)] * 2
+        args += [((blocks, H), jnp.float32)] * 2
     return (lambda q, k, v, lens, tab, *sc: flash_decode_attention(
         q, k, v, lens, block_tables=tab, interpret=False,
         block_scales=sc or None)), args
@@ -115,6 +120,10 @@ CASES = {
         + [((BATCH,), jnp.int32)]),
     "decode-paged-bf16": lambda: _decode(BF16),
     "decode-paged-int8": lambda: _decode(jnp.int8),
+    "decode-paged-bf16-256slots": lambda: _decode(BF16, CELL_BATCH,
+                                                  CELL_POOL),
+    "decode-paged-int8-256slots": lambda: _decode(jnp.int8, CELL_BATCH,
+                                                  CELL_POOL),
     # serving prefill at both of the smoke's buckets; int8 fuses the write
     "prefill-float-c64": lambda: _prefill(BF16, 64),
     "prefill-float-c256": lambda: _prefill(BF16, 256),
@@ -139,6 +148,8 @@ KERNEL_NAMES = {
     "decode-dense": ("nezha_decode_attention_dense",),
     "decode-paged-bf16": ("nezha_decode_attention_paged",),
     "decode-paged-int8": ("nezha_decode_attention_paged_int8",),
+    "decode-paged-bf16-256slots": ("nezha_decode_attention_paged",),
+    "decode-paged-int8-256slots": ("nezha_decode_attention_paged_int8",),
     "prefill-float-c64": ("nezha_prefill_attention_paged",),
     "prefill-float-c256": ("nezha_prefill_attention_paged",),
     "prefill-int8-fused-write-c64": ("nezha_prefill_attention_paged_int8",),
@@ -184,6 +195,25 @@ def test_kernel_keeps_its_name_in_the_v5e_hlo(hlo_of, case):
     kernels = {re.sub(r"^(transpose_)?(jvp_)?_*|_*(\.\d+)?$", "", n)
                for n in own_names}
     assert kernels == set(KERNEL_NAMES[case]), own_names
+
+
+@pytest.mark.parametrize("case", ["decode-paged-bf16-256slots",
+                                  "decode-paged-int8-256slots"])
+def test_paged_decode_is_one_call_with_the_shape_the_benchmark_reads(
+        hlo_of, case):
+    """At the serving cell's own shape a layer's decode attention is
+    exactly ONE custom call, named ``nezha_decode_attention_paged*``,
+    whose result is ``bf16[256,12,1,64]``: ``kernel.decode_roofline``
+    divides the matched seconds by the matched events (a kernel split
+    in two calls would read double), and the ``kernel.decode_*``
+    patterns anchor on that result shape (another shape matches
+    nothing)."""
+    calls = [line.strip() for line in hlo_of(case).splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    assert len(calls) == 1, calls
+    assert re.match(
+        r"(ROOT )?%?nezha_decode_attention_paged\S* = "
+        r"bf16\[256,12,1,64\]\S* custom-call\(", calls[0]), calls[0]
 
 
 # The sharded serve engine's path: the same kernels PER HEAD SHARD under a

@@ -796,6 +796,11 @@ def test_serve_layer_spans_reach_the_profiler_timeline(tiny_model,
                for s in stats["serve.engine.prefill"])
     assert sum(s["emitted"] for s in stats["serve.sched.emit"]) == 12
     assert all(1 <= s["rows"] <= 2 for s in stats["serve.engine.dispatch"])
+    # 9-token prompts decoding positions 9..12 over blocks of 8: every
+    # active row holds two table entries (of the table's eight) at each
+    # step, which is all the paged decode kernel visits for it
+    assert all(s["blocks"] == 2 * s["rows"]
+               for s in stats["serve.engine.dispatch"])
     # one wait per dispatch, each after its dispatch closed
     assert len(stats["serve.engine.wait"]) == len(
         stats["serve.engine.dispatch"])
