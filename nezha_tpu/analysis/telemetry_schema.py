@@ -61,6 +61,12 @@ _SERVE_COUNTERS = {"serve.admitted_total", "serve.rejected_total",
                    # dense-layout run reports 0s, never omits them.
                    "serve.kv.prefix_hits_total",
                    "serve.kv.cow_copies_total",
+                   # Serving-side expert layer (PR 26): token-expert
+                   # pairs the router chose over all experts, and those
+                   # this chip's held experts computed. Model-invariant:
+                   # a dense model reports 0s, never omits them.
+                   "serve.moe.pairs_total",
+                   "serve.moe.held_pairs_total",
                    # Cross-replica KV migration (PR 11, disaggregated
                    # prefill/decode tiers): committed installs and
                    # their int8-wire bytes. Topology-invariant: a
@@ -144,7 +150,11 @@ _SERVE_GAUGES = {"serve.queue_depth", "serve.batch_occupancy",
                  "serve.prefill.seq_shards",
                  # Multi-tenant scheduling (PR 19): requests currently
                  # suspended awaiting resume (0 with preemption off).
-                 "serve.preempted_live"}
+                 "serve.preempted_live",
+                 # Serving-side expert layer (PR 26): the busiest held
+                 # expert's pairs over the mean, mean over the layers,
+                 # of the latest decode step (0 on a dense model).
+                 "serve.moe.load_max_over_mean"}
 _SERVE_HISTOGRAMS = {"serve.ttft_s", "serve.tpot_s",
                      "serve.prefill.bucket_len",
                      # Decode-horizon instruments (PR 5): host time

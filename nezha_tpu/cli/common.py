@@ -179,6 +179,26 @@ def load_gpt2_for_inference(args):
     return model, variables
 
 
+def load_model_for_inference(args):
+    """(model, variables) for ``nezha-serve --model``: GPT-2 from any of
+    its three weight sources, or Mistral-Small-4 with random weights
+    (the one source it has: no checkpoint converter exists for it)."""
+    if getattr(args, "model", "gpt2") == "gpt2":
+        return load_gpt2_for_inference(args)
+    if not getattr(args, "random_init", False):
+        raise SystemExit(
+            f"--model {args.model} takes --random-init only (no "
+            f"checkpoint or Hugging Face converter exists for it)")
+    import jax
+
+    from nezha_tpu.models.mistral4 import mistral_small4
+
+    model = mistral_small4(args.model_preset)
+    # model.init builds the tree leaf by leaf in the policy's parameter
+    # dtype (bf16 at the full preset: 2 bytes a parameter on the device).
+    return model, model.init(jax.random.PRNGKey(args.seed))
+
+
 def resolve_eos_id(explicit, tokenizer, vocab: int, flag: str = "--eos-id"):
     """ONE EOS policy for the inference CLIs (generate + serve): an
     explicit flag wins and is validated hard (out-of-vocab = user

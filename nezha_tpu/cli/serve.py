@@ -79,6 +79,13 @@ def build_parser() -> argparse.ArgumentParser:
                      help="Hugging Face GPT2LMHeadModel directory")
     src.add_argument("--random-init", action="store_true",
                      help="fresh random weights (smoke/benchmark runs)")
+    p.add_argument("--model", choices=["gpt2", "mistral_small4"],
+                   default="gpt2",
+                   help="the architecture served: gpt2, or "
+                        "mistral_small4 (Mistral-Small-4: latent "
+                        "attention, dropless experts; --random-init "
+                        "only, --model-preset full = one chip's share "
+                        "at the published widths, bf16 parameters)")
     p.add_argument("--model-preset", choices=["full", "tiny"],
                    default="full")
     p.add_argument("--tokenizer", default=None,
@@ -394,11 +401,34 @@ def _build_stack(args):
     """(scheduler, tokenizer, eos_id) from parsed args."""
     import jax.numpy as jnp
 
-    from nezha_tpu.cli.common import load_gpt2_for_inference
+    from nezha_tpu.cli.common import (load_gpt2_for_inference,
+                                      load_model_for_inference)
     from nezha_tpu.cli.generate import _load_tokenizer
     from nezha_tpu.serve import Engine, ServeConfig, Scheduler
 
     mesh_m = int(getattr(args, "mesh", 1) or 1)
+    if getattr(args, "model", "gpt2") != "gpt2":
+        # What is written for per-head K/V only refuses at start-up,
+        # typed, for a model that caches something else (the engine and
+        # the pool refuse the same from a library call).
+        unsupported = [flag for flag, on in (
+            ("--mesh", mesh_m > 1),
+            ("--kv-dtype int8", args.kv_dtype == "int8"),
+            ("--kv-host-blocks", bool(args.kv_host_blocks)),
+            ("--kv-layout dense", args.kv_layout != "paged"),
+            ("--speculative", bool(getattr(args, "speculative", False))),
+            ("--role prefill/decode (KV migration)",
+             getattr(args, "role", "both") != "both"),
+            ("--prefill-replicas/--decode-replicas (KV migration)",
+             bool(getattr(args, "prefill_replicas", 0)
+                  or getattr(args, "decode_replicas", 0))),
+            ("--affinity-routing on (peer KV pulls)",
+             getattr(args, "affinity_routing", "off") == "on")) if on]
+        if unsupported:
+            raise SystemExit(
+                f"--model {args.model}: not supported with "
+                f"{', '.join(unsupported)} (its cache is a latent row a "
+                f"token, not per-head K/V)")
     if mesh_m > 1 and getattr(args, "ckpt_dir", None):
         # The implicit nezha-reshard: build the serve mesh first, then
         # stream the training checkpoint straight into the head-sharded
@@ -435,10 +465,12 @@ def _build_stack(args):
         print(f"resharded step {step} from {args.ckpt_dir} onto a "
               f"1x{mesh_m} serve mesh", file=sys.stderr)
     else:
-        model, variables = load_gpt2_for_inference(args)
+        model, variables = load_model_for_inference(args)
     tokenizer = _load_tokenizer(args)
     from nezha_tpu.cli.common import resolve_eos_id
-    eos_id = resolve_eos_id(args.eos_id, tokenizer, model.cfg.vocab_size)
+    eos_id = resolve_eos_id(
+        args.eos_id, tokenizer,
+        getattr(model.cfg, "vocab_held", model.cfg.vocab_size))
     max_len = min(args.max_len, model.cfg.max_positions)
     buckets = ()
     if args.prefill_buckets:
@@ -538,8 +570,11 @@ def _build_stack(args):
             # reach here without the pre-reshard check above.
             raise SystemExit(f"--mesh {mesh_m}: {e}")
     else:
-        engine = Engine(model, variables, cfg, draft_model=draft_model,
-                        draft_variables=draft_variables)
+        try:
+            engine = Engine(model, variables, cfg, draft_model=draft_model,
+                            draft_variables=draft_variables)
+        except ValueError as e:
+            raise SystemExit(f"serve engine: {e}")
     scheduler = Scheduler(engine)
     if getattr(args, "slo", None):
         # The first serve.ttft_s SLO spec doubles as the scheduler's
@@ -1270,7 +1305,8 @@ def _worker_argv(args, rid: int, port: int, role: Optional[str] = None
         argv += ["--ckpt-dir", args.ckpt_dir]
     elif args.hf_dir:
         argv += ["--hf-dir", args.hf_dir]
-    argv += ["--model-preset", args.model_preset,
+    argv += ["--model", getattr(args, "model", "gpt2"),
+             "--model-preset", args.model_preset,
              "--max-batch-size", str(args.max_batch_size),
              "--max-len", str(args.max_len),
              "--max-prefill-len", str(args.max_prefill_len),

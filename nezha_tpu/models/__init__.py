@@ -10,6 +10,8 @@ _LAZY = {
     "ResNet": "resnet", "resnet50": "resnet", "wide_resnet101": "resnet",
     "GPT2": "gpt2", "GPT2Config": "gpt2", "gpt2_124m": "gpt2",
     "Bert": "bert", "BertConfig": "bert", "bert_base": "bert",
+    "Mistral4": "mistral4", "Mistral4Config": "mistral4",
+    "mistral_small4": "mistral4",
     "generate": "generate", "init_cache": "generate",
     "gpt2_from_hf": "convert", "bert_from_hf": "convert",
     "gpt2_params_from_hf": "convert", "gpt2_params_to_hf": "convert",

@@ -38,8 +38,7 @@ def init_cache(model: GPT2, batch_size: int, max_len: int,
 
 
 def _caches_from_states(model: GPT2, states: dict, prev: list) -> list:
-    return [states.get(f"h{i}", {}).get("attn", {}).get("cache", prev[i])
-            for i in range(model.cfg.num_layers)]
+    return model.caches_from_states(states, prev)
 
 
 def _sample(logits, rng, temperature: float, top_k: Optional[int],

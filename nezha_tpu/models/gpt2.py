@@ -1110,6 +1110,33 @@ class GPT2(Module):
         return logits, states
 
 
+    # ------------------------------------- what serve.Engine asks a model
+    def cache_leaves(self, block_size: int, dtype, quantized: bool = False
+                     ) -> dict:
+        """The per-layer cache leaves of one pool block: name -> (trailing
+        shape, dtype); ``PagedSlotPool`` allocates ``[num_blocks, ...]`` of
+        each. Per-head K and V, and with ``quantized`` int8 blocks plus one
+        float32 absmax scale per (block, head)."""
+        cfg = self.cfg
+        kv = (cfg.num_heads, block_size, cfg.hidden_size // cfg.num_heads)
+        if quantized:
+            return {"k": (kv, jnp.int8), "v": (kv, jnp.int8),
+                    "k_scale": ((cfg.num_heads,), jnp.float32),
+                    "v_scale": ((cfg.num_heads,), jnp.float32)}
+        return {"k": (kv, dtype), "v": (kv, dtype)}
+
+    def caches_from_states(self, states: dict, prev: list) -> list:
+        return [states.get(f"h{i}", {}).get("attn", {}).get("cache", prev[i])
+                for i in range(self.cfg.num_layers)]
+
+    def expert_load(self, states: dict):
+        """No serving-side expert layer: nothing to count."""
+        return None
+
+    def paged_prefill_uses_kernel(self) -> bool:
+        return _prefill_flash_ok(self.cfg)
+
+
 def gpt2_124m(policy: Policy | None = None, **overrides) -> GPT2:
     cfg = GPT2Config(**overrides)
     return GPT2(cfg, policy=policy or bf16_policy())

@@ -14,6 +14,7 @@ from nezha_tpu.nn.layers import (
     Conv2d,
     BatchNorm,
     LayerNorm,
+    RMSNorm,
     Embedding,
     Dropout,
     max_pool,
@@ -24,7 +25,7 @@ from nezha_tpu.nn import initializers
 
 __all__ = [
     "Module", "Sequential", "Variables", "make_variables", "child_vars",
-    "child_rng", "run_child", "Linear", "Conv2d", "BatchNorm", "LayerNorm",
+    "child_rng", "run_child", "Linear", "Conv2d", "BatchNorm", "LayerNorm", "RMSNorm",
     "Embedding",
     "Dropout", "max_pool", "avg_pool", "global_avg_pool", "initializers",
 ]

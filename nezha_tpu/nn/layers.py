@@ -248,6 +248,30 @@ class LayerNorm(Module):
         return self.policy.cast_output(y), {}
 
 
+class RMSNorm(Module):
+    """Root-mean-square norm over the last axis (no mean subtraction, no
+    bias): ``x * rsqrt(mean(x^2) + eps) * scale``; statistics in fp32."""
+
+    def __init__(self, dim: int, eps: float = 1e-6,
+                 policy: Policy = DEFAULT_POLICY):
+        self.dim = dim
+        self.eps = eps
+        self.policy = policy
+
+    def init(self, rng: jax.Array) -> Variables:
+        del rng
+        return make_variables(
+            {"scale": jnp.ones((self.dim,), self.policy.param_dtype)})
+
+    def apply(self, variables: Variables, x, training: bool = False, rng=None):
+        del training, rng
+        xf = jnp.asarray(x, jnp.float32)
+        y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                           + self.eps)
+        y = y * jnp.asarray(variables["params"]["scale"], jnp.float32)
+        return self.policy.cast_output(y), {}
+
+
 class Embedding(Module):
     """Token embedding table; lookup stays a gather (fast path on TPU)."""
 

@@ -17,6 +17,10 @@ formulation rather than gather/scatter token shuffling:
   TPU-native equivalent of NCCL all-to-all in GPU MoE stacks).
 - Tokens over capacity are *dropped* (standard Switch behavior) and the
   load-balance auxiliary loss keeps the router near-uniform.
+
+:class:`DroplessMoE` is the serving-side layer: no capacity, no dropped
+token, SiLU-gated experts, and it is told which of the routed experts it
+holds (the chip's share of an expert-parallel host).
 """
 
 from __future__ import annotations
@@ -139,6 +143,116 @@ class MoE(Module):
         y = jnp.einsum("tec,ecd->td", combine.astype(compute_dtype), out)
         y = y.reshape(b, s, d).astype(x.dtype)
         return y, {"aux_loss": aux}
+
+
+@dataclasses.dataclass(frozen=True)
+class DroplessMoEConfig:
+    """A dropless expert layer that is told which experts it holds.
+
+    ``num_experts`` is the router's width (every routed expert of the
+    model, wherever it lives); ``experts_held = (first, count)`` names
+    the contiguous ids whose weights are on this chip."""
+    d_model: int
+    d_ff: int
+    num_experts: int
+    top_k: int
+    experts_held: Tuple[int, int]
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+
+
+def route_top_k(router_logits: jax.Array, top_k: int, norm_topk_prob: bool,
+                scaling: float) -> Tuple[jax.Array, jax.Array]:
+    """Softmax over ALL experts, then the ``top_k`` largest:
+    -> (ids [T, k] int32, weights [T, k] float32). With
+    ``norm_topk_prob`` the weights are renormalised over the k chosen
+    (held here or not), then scaled."""
+    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    weights, ids = jax.lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return ids.astype(jnp.int32), weights * scaling
+
+
+class DroplessMoE(Module):
+    """Top-k routed SiLU-gated experts, no capacity and no dropped token.
+
+    The router scores all ``num_experts``; the layer computes the part
+    of ``sum_e w_e E_e(x)`` that the experts it HOLDS give, and leaves
+    out what the absent ones would add (on an expert-parallel host their
+    chips compute it; alone, that partial sum is the result). Token-
+    expert pairs are sorted by expert, pairs of absent experts last, and
+    three grouped matmuls (``jax.lax.ragged_dot``: one weight matrix per
+    group of rows) run over the held groups only; shapes are static (T x
+    top_k pair rows), so one program serves a prefill chunk (hundreds of
+    rows an expert) and a decode step (a few). ``apply`` returns
+    ``(y, {"load": [count] int32})``: the pairs computed per held expert,
+    rows with ``active`` False not counted."""
+
+    def __init__(self, cfg: DroplessMoEConfig, policy: Policy = DEFAULT_POLICY):
+        self.cfg = cfg
+        self.policy = policy
+        first, count = cfg.experts_held
+        if not (0 <= first and count >= 1
+                and first + count <= cfg.num_experts):
+            raise ValueError(
+                f"experts_held {cfg.experts_held} outside the "
+                f"{cfg.num_experts} routed experts")
+
+    def init(self, rng: jax.Array) -> Variables:
+        cfg, dtype = self.cfg, self.policy.param_dtype
+        held = cfg.experts_held[1]
+        r_router, r_gate, r_up, r_down = jax.random.split(rng, 4)
+        normal = init_lib.normal(0.02)
+        return make_variables({
+            "router": {"w": normal(r_router, (cfg.d_model, cfg.num_experts),
+                                   dtype)},
+            "w_gate": normal(r_gate, (held, cfg.d_model, cfg.d_ff), dtype),
+            "w_up": normal(r_up, (held, cfg.d_model, cfg.d_ff), dtype),
+            "w_down": normal(r_down, (held, cfg.d_ff, cfg.d_model), dtype),
+        })
+
+    def apply(self, variables: Variables, x, training: bool = False, rng=None,
+              active=None):
+        """``x`` [T, d]; ``active`` [T] bool or None."""
+        del training, rng
+        cfg, p = self.cfg, variables["params"]
+        first, held = cfg.experts_held
+        cdt = self.policy.compute_dtype
+        x = x.astype(cdt)
+        t, k = x.shape[0], cfg.top_k
+        # Router logits in float32 from compute-dtype operands: the
+        # top-k boundary is a discontinuity, so nothing rounds the
+        # logits on their way to it.
+        logits = jnp.dot(x, p["router"]["w"].astype(cdt),
+                         preferred_element_type=jnp.float32)
+        ids, weights = route_top_k(logits, k, cfg.norm_topk_prob,
+                                   cfg.routed_scaling_factor)
+        with jax.named_scope("nezha_moe_experts"):
+            local = ids - first
+            is_held = (local >= 0) & (local < held)
+            key = jnp.where(is_held, local, held).reshape(-1)    # [T*k]
+            order = jnp.argsort(key)            # stable: by expert, absent last
+            token_of = order // k
+            sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+            load = sizes if active is None else jnp.bincount(
+                jnp.where(jnp.repeat(active, k), key, held),
+                length=held + 1)[:held].astype(jnp.int32)
+            xs = x[token_of]                                     # [T*k, d]
+            f32 = dict(preferred_element_type=jnp.float32)
+            gate = jax.lax.ragged_dot(xs, p["w_gate"].astype(cdt), sizes, **f32)
+            up = jax.lax.ragged_dot(xs, p["w_up"].astype(cdt), sizes, **f32)
+            h = (jax.nn.silu(gate) * up).astype(cdt)
+            out = jax.lax.ragged_dot(h, p["w_down"].astype(cdt), sizes, **f32)
+            # Back to token order by the inverse permutation (a gather,
+            # not a scatter-add); rows of absent experts weigh nothing.
+            w_sorted = jnp.where(key[order] < held,
+                                 weights.reshape(-1)[order], 0.0)
+            out = jnp.where(w_sorted[:, None] > 0, out * w_sorted[:, None], 0.0)
+            inverse = jnp.zeros((t * k,), jnp.int32).at[order].set(
+                jnp.arange(t * k, dtype=jnp.int32))
+            y = out[inverse].reshape(t, k, cfg.d_model).sum(axis=1)
+        return y, {"load": load}        # y is float32
 
 
 def moe_ep_rules(ep_axis: str = "ep"):

@@ -91,7 +91,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from nezha_tpu import faults, obs
-from nezha_tpu.models.generate import _caches_from_states
 from nezha_tpu.runtime.executor import Executor
 from nezha_tpu.serve.sampling import (accept_mask, categorical_rows,
                                       filter_logits, filtered_probs,
@@ -521,6 +520,11 @@ class Engine:
                 and cfg.prefill_impl != getattr(model.cfg, "prefill_impl",
                                                 None)):
             impl_overrides["prefill_impl"] = cfg.prefill_impl
+        if impl_overrides and not hasattr(model.cfg, "decode_impl"):
+            raise ValueError(
+                f"decode_impl / prefill_impl {impl_overrides} given, but "
+                f"{type(model).__name__} has one attention implementation "
+                f"per path and no such knob")
         if impl_overrides:
             model = type(model)(
                 dataclasses.replace(model.cfg, **impl_overrides),
@@ -528,21 +532,43 @@ class Engine:
         self.model = model
         self.variables = variables
         self.cfg = cfg
-        self.vocab = model.cfg.vocab_size
+        # The logits' width: the vocabulary rows this model holds (a
+        # model cut to a chip's share holds a slice).
+        self.vocab = getattr(model.cfg, "vocab_held", model.cfg.vocab_size)
         self.k_max = min(cfg.k_max, self.vocab)
         self.paged = cfg.kv_layout == "paged"
         self.kv_quant = cfg.kv_dtype == "int8"
+        # What the model caches a layer: per-head K/V (GPT-2), or another
+        # leaf set (a latent row). Everything that is written for K/V
+        # only refuses here, typed, instead of taking a wrong path: the
+        # model's own declaration refuses an int8 pool; the dense slot
+        # layout, the draft pool of speculative decoding and the mesh's
+        # head sharding derive their shapes from K/V heads.
+        leaves = sorted(model.cache_leaves(
+            cfg.kv_block_size, cfg.cache_dtype, self.kv_quant))
+        self.kv_heads_cache = {"k", "v"} <= set(leaves)
+        if not self.kv_heads_cache:
+            unsupported = [what for what, on in (
+                ("kv_layout='dense'", not self.paged),
+                ("speculative decoding", cfg.speculative is not None),
+                # only the mesh-sharded engine sets this
+                ("a device mesh (--mesh)", self._seq_prefill_capable))
+                if on]
+            if unsupported:
+                raise ValueError(
+                    f"{type(model).__name__} caches {leaves}, not "
+                    f"per-head K/V: {', '.join(unsupported)} not supported "
+                    f"with it")
         # Resolve ONCE whether paged prefill chunks dispatch through the
-        # flash-prefill kernel. models.gpt2 re-resolves at trace time
+        # flash-prefill kernel. The model re-resolves at trace time
         # from the same knobs (config + env) — this mirror only drives
         # telemetry: the pinned ``serve.prefill.kernel_active`` gauge
         # lets dashboards and `nezha-telemetry` label the prefill line
         # with the active impl without scraping model config, and it
         # selects the kernel span / fused-write accounting in
         # :meth:`prefill`.
-        from nezha_tpu.models.gpt2 import _prefill_flash_ok
         self.prefill_kernel_active = bool(
-            self.paged and _prefill_flash_ok(model.cfg))
+            self.paged and model.paged_prefill_uses_kernel())
         obs.gauge("serve.prefill.kernel_active").set(
             1.0 if self.prefill_kernel_active else 0.0)
         if self.paged:
@@ -585,6 +611,11 @@ class Engine:
         # decode_horizon tokens for every row) — tests assert the
         # dispatch-per-token amortization against this.
         self.step_calls = 0
+        # [layers, experts held] int32 from the latest step, on the host:
+        # the token-expert pairs each held expert computed (models with
+        # a serving-side expert layer; None otherwise). It rides the
+        # step's existing ok / tok / emitted fetch.
+        self.last_expert_load: Optional[np.ndarray] = None
         # Tokens the most recent prefill's compiled chunks pushed
         # through the target model (set per prefill call), and how many
         # chunk dispatches it took (the sequence-sharded engine's
@@ -1047,8 +1078,10 @@ class Engine:
         active = np.asarray(active, bool)
         attrs = {"rows": int(np.count_nonzero(active))}
         if self.paged:
-            attrs["blocks"] = int(np.sum(
+            blocks = int(np.sum(
                 self.host_positions[active] // self.cfg.kv_block_size + 1))
+            attrs["blocks" if self.kv_heads_cache else "latent_blocks"] = \
+                blocks
         return attrs
 
     def step(self, active: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
@@ -1086,13 +1119,14 @@ class Engine:
                     jnp.asarray(active, bool), self.keys,
                     self.temps, self.top_ks, self.top_ps,
                     self.eos_ids, self.budgets)
-            tok, emitted, ok, caches, last, pos, keys, budgets = out
+            (tok, emitted, ok, caches, last, pos, keys, budgets,
+             *load) = out
             # Start the block's device->host transfers NOW, before any
             # host bookkeeping (state rebinds here, retire/admit/stream
             # in the scheduler): the fetches below then find bytes
             # already in flight instead of paying the full sync
             # serially.
-            _start_host_copies(tok, emitted, ok)
+            _start_host_copies(tok, emitted, ok, *load)
         self.pool.caches = caches
         if faults.enabled():
             last = faults.corrupt(
@@ -1104,6 +1138,10 @@ class Engine:
             # The host blocked on the device: the block's fetches.
             self.step_ok = np.asarray(ok)
             tok_h, emitted_h = np.asarray(tok), np.asarray(emitted)
+            if load:
+                self.last_expert_load = np.asarray(load[0])
+        if load:
+            self._record_expert_load(int(np.count_nonzero(active)))
         if self.paged:
             # Advance the host position/budget mirrors by the block's
             # emitted counts (positions advance and budgets decay on
@@ -1113,6 +1151,21 @@ class Engine:
             self.host_positions += emitted_h.astype(np.int64)
             self.host_budgets -= emitted_h.astype(np.int64)
         return tok_h, emitted_h
+
+    def _record_expert_load(self, rows: int) -> None:
+        """The expert layer's counters for one step (registry
+        instruments: no-ops without a run dir). ``pairs`` is what the
+        router chose over all experts (rows x top-k a layer); ``held``
+        what this chip's experts computed of it."""
+        load = self.last_expert_load
+        layers, held = load.shape
+        top_k = self.model.cfg.num_experts_per_tok
+        obs.counter("serve.moe.pairs_total").inc(rows * top_k * layers)
+        obs.counter("serve.moe.held_pairs_total").inc(int(load.sum()))
+        mean = load.mean(axis=1)
+        if (mean > 0).all():
+            obs.gauge("serve.moe.load_max_over_mean").set(
+                float((load.max(axis=1) / mean).mean()))
 
     def _spec_step(self, active: np.ndarray
                    ) -> Tuple[np.ndarray, np.ndarray]:
@@ -1269,7 +1322,7 @@ def _build_prefill(model, width: int, paged: bool = False,
                     for pool in caches]
         logits, states = model.apply(variables, tokens, training=False,
                                      cache=rows, pos=pos)
-        new_rows = _caches_from_states(model, states, rows)
+        new_rows = model.caches_from_states(states, rows)
         if paged:
             keys_kept = tuple(caches[0].keys())
             new_caches = [{kk: r[kk] for kk in keys_kept}
@@ -1378,7 +1431,11 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int,
         logits, states = model.apply(variables, tok[:, None],
                                      training=False, cache=rows,
                                      pos=positions, active=emit)
-        new_rows = _caches_from_states(model, states, rows)
+        new_rows = model.caches_from_states(states, rows)
+        # [layers, experts held] pairs computed per held expert, or None
+        # (a model with no serving-side expert layer: nothing is added
+        # to its program).
+        load = model.expert_load(states)
         if paged:
             keys_kept = tuple(caches[0].keys())
             new_caches = [{kk: r[kk] for kk in keys_kept}
@@ -1396,7 +1453,7 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int,
                 jnp.where(act, row_logits, last_logits),
                 jnp.where(emit, positions + 1, positions),
                 jnp.where(act, next_keys, keys),
-                done, ok, emitted), tok
+                done, ok, emitted), (tok, load)
 
     def core(variables, caches, tables, last_logits, positions, active,
              keys, temps, top_ks, top_ps, eos_ids, budgets):
@@ -1413,14 +1470,17 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int,
         if horizon == 1:
             # Inline, not a length-1 scan: the default must stay
             # bit-identical to the classic single-token step program.
-            carry, tok = scan_body(init, None)
+            carry, (tok, load) = scan_body(init, None)
             tok_block = tok[:, None]
         else:
-            carry, toks = lax.scan(scan_body, init, None, length=horizon)
+            carry, (toks, loads) = lax.scan(scan_body, init, None,
+                                            length=horizon)
             tok_block = jnp.transpose(toks, (1, 0))        # [H,B]->[B,H]
+            load = None if loads is None else loads.sum(axis=0)
         caches, last_logits, positions, keys, done, ok, emitted = carry
-        return (tok_block, emitted, ok, caches, last_logits, positions,
-                keys, jnp.maximum(budgets - emitted, 0))
+        out = (tok_block, emitted, ok, caches, last_logits, positions,
+               keys, jnp.maximum(budgets - emitted, 0))
+        return out if load is None else out + (load,)
 
     if paged:
         def step(variables, caches, tables, *rest):
@@ -1453,7 +1513,7 @@ def _build_draft_prefill(model, width: int, paged: bool = False):
                     for pool in caches]
         _, states = model.apply(variables, tokens, training=False,
                                 cache=rows, pos=pos)
-        new_rows = _caches_from_states(model, states, rows)
+        new_rows = model.caches_from_states(states, rows)
         if paged:
             kept = tuple(caches[0].keys())
             return [{kk: r[kk] for kk in kept} for r in new_rows]
@@ -1538,7 +1598,7 @@ def _build_spec_step(model, draft_model, k_max: int, pad_id: int,
             dlog, dstates = draft_model.apply(
                 dvariables, tok_in[:, None], training=False,
                 cache=rows, pos=positions + j, active=emit0)
-            new_rows = _caches_from_states(draft_model, dstates, rows)
+            new_rows = draft_model.caches_from_states(dstates, rows)
             if paged:
                 kept = tuple(dc[0].keys())
                 dc2 = [{kk: r[kk] for kk in kept} for r in new_rows]
@@ -1572,7 +1632,7 @@ def _build_spec_step(model, draft_model, k_max: int, pad_id: int,
         vlog, vstates = model.apply(variables, win, training=False,
                                     cache=vrows, pos=positions,
                                     active=emit0)
-        new_rows = _caches_from_states(model, vstates, vrows)
+        new_rows = model.caches_from_states(vstates, vrows)
         if paged:
             kept = tuple(caches[0].keys())
             new_caches = [{kk: r[kk] for kk in kept} for r in new_rows]

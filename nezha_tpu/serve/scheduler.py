@@ -223,6 +223,11 @@ def register_serve_instruments() -> None:
     # instead of re-prefilling, and copy-on-write block copies.
     obs.counter("serve.kv.prefix_hits_total")
     obs.counter("serve.kv.cow_copies_total")
+    # The serving-side expert layer's load (a model with routed experts
+    # only; the engine records them a step). Model-invariant 0s.
+    obs.counter("serve.moe.pairs_total")
+    obs.counter("serve.moe.held_pairs_total")
+    obs.gauge("serve.moe.load_max_over_mean")
     # Cross-replica migration (disaggregated prefill/decode tiers,
     # serve/migrate.py): committed installs and their wire bytes —
     # migration GB/s is bytes / the router.migrate span durations.

@@ -552,36 +552,34 @@ class PagedSlotPool:
         self.prefix_cache_enabled = prefix_cache
         self.eviction = eviction
         self.quantized = quantized
-        cfg = model.cfg
-        d = cfg.hidden_size // cfg.num_heads
-        shape = (num_blocks, cfg.num_heads, block_size, d)
-        if quantized:
-            # ``ServeConfig.kv_dtype="int8"``: K/V blocks store int8
-            # plus one fp32 absmax scale per (block, head) — the
-            # ``[num_blocks, H]`` scale buffers ride IN the caches
-            # pytree, so everything that moves a block (program
-            # donation, COW copy, checkpoint of the tree structure)
-            # moves its scale row with it by construction. Zero-init:
-            # q = 0 with scale 0 dequantizes to exact zeros, same as
-            # the bf16 pool's zero init.
-            sshape = (num_blocks, cfg.num_heads)
-            self.caches = [{"k": jnp.zeros(shape, jnp.int8),
-                            "v": jnp.zeros(shape, jnp.int8),
-                            "k_scale": jnp.zeros(sshape, jnp.float32),
-                            "v_scale": jnp.zeros(sshape, jnp.float32)}
-                           for _ in range(cfg.num_layers)]
-        else:
-            self.caches = [{"k": jnp.zeros(shape, dtype),
-                            "v": jnp.zeros(shape, dtype)}
-                           for _ in range(cfg.num_layers)]
-        kv_bytes = (cfg.num_heads * block_size * d
-                    * (1 if quantized else jnp.dtype(dtype).itemsize))
-        scale_bytes = cfg.num_heads * 4 if quantized else 0
-        # Per-block device footprint (k + v + scales, all layers) — the
+        # The model DECLARES its per-layer cache leaves (name -> trailing
+        # shape and dtype of one block); the pool allocates
+        # ``[num_blocks, ...]`` of each and does its byte accounting from
+        # the same declaration. GPT-2: per-head ``k`` / ``v``
+        # ``[N, H, bs, D]`` and, with ``ServeConfig.kv_dtype="int8"``,
+        # int8 blocks plus one fp32 absmax scale per (block, head) — the
+        # ``[num_blocks, H]`` scale buffers ride IN the caches pytree, so
+        # everything that moves a block (program donation, COW copy,
+        # checkpoint of the tree structure) moves its scale row with it
+        # by construction. A latent-attention model: one ``latent``
+        # ``[N, bs, width]`` leaf, no head axis. Zero-init: q = 0 with
+        # scale 0 dequantizes to exact zeros, same as a float pool's zero
+        # init. Block lifecycle, ref counts, COW and the trie below are
+        # one implementation over any leaf set.
+        leaves = model.cache_leaves(block_size, dtype, quantized)
+        self.caches = [{name: jnp.zeros((num_blocks,) + tuple(shape), dt)
+                        for name, (shape, dt) in leaves.items()}
+                       for _ in range(model.cfg.num_layers)]
+        # Per-block device footprint (every leaf, all layers) — the
         # serve.kv.bytes_resident gauge's unit and the equal-memory
         # bench's conversion rate between int8 and bf16 block budgets.
-        self.bytes_per_block = 2 * cfg.num_layers * (kv_bytes
-                                                     + scale_bytes)
+        self.bytes_per_block = model.cfg.num_layers * sum(
+            math.prod(shape) * jnp.dtype(dt).itemsize
+            for shape, dt in leaves.values())
+        # Migration, peer pulls and the host tier speak one wire format:
+        # int8 K/V blocks + per-(block, head) scales. A pool of other
+        # leaves has none.
+        self.kv_wire = {"k", "v"} <= set(leaves)
         self.tables_host = np.zeros((capacity, self.blocks_per_slot),
                                     np.int32)
         self._free_slots: List[int] = list(range(capacity - 1, -1, -1))
@@ -1094,6 +1092,7 @@ class PagedSlotPool:
         (serve/sharded) the host conversion IS the gather: the wire
         payload always carries full heads, whatever mesh the source
         ran (gather-on-export)."""
+        self._require_kv_wire()
         if not 1 <= nblocks <= int(self._bound[slot]):
             raise ValueError(
                 f"cannot export {nblocks} block(s) from slot {slot}: "
@@ -1107,6 +1106,13 @@ class PagedSlotPool:
                 for layer in layers]
         nbytes = sum(a.nbytes for layer in host for a in layer.values())
         return host, nbytes
+
+    def _require_kv_wire(self) -> None:
+        if not self.kv_wire:
+            raise ValueError(
+                f"this pool's cache leaves ({sorted(self.caches[0])}) have "
+                f"no migration wire format: block export/install moves "
+                f"int8 K/V blocks with per-head scales")
 
     # ------------------------------------------------------ fleet cache
     def digest_entries(self):
@@ -1134,6 +1140,7 @@ class PagedSlotPool:
         digests are advisory, a stale one costs one wasted probe).
         Read-only like :meth:`export_block_payload`: refs, trie and
         host tier are untouched; the source gives up nothing."""
+        self._require_kv_wire()
         toks = [int(t) for t in tokens]
         bs = self.block_size
         blocks: List[int] = []
@@ -1197,6 +1204,7 @@ class PagedSlotPool:
         blocks so their first reuse is counted as a fleet "peer" hit;
         ``"migrate"`` (the PR 11 two-phase handoff) leaves the tier
         accounting untouched."""
+        self._require_kv_wire()
         nblocks = int(layers[0]["k"].shape[0]) if layers else 0
         if nblocks == 0 or not self.prefix_cache_enabled:
             return 0

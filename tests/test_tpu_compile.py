@@ -257,3 +257,73 @@ def test_nested_shard_map_kernel_compiles_for_v5e_mesh4(v5e_devices, case):
                            block_scales=sc or None, interpret=False)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---- Mistral-Small-4's serve programs (PR 26): no Pallas kernel of their
+# own yet (composed latent attention, the compiler's grouped matmul for
+# the experts), so what is compiled is the ENGINE's step and 1,024-token
+# prefill programs at the published widths, one layer deep, with the
+# serving cell's slots, table and pool.
+M4_SLOTS, M4_MAX_LEN, M4_BLOCK, M4_CHUNK = 128, 4096, 64, 1024
+
+
+@pytest.fixture(scope="module")
+def mistral4_programs(v5e):
+    """{"step" | "prefill": HLO text}: compiled once for the tests below."""
+    from nezha_tpu.models.mistral4 import mistral_small4
+    from nezha_tpu.serve.engine import _build_prefill, _build_step
+
+    model = mistral_small4("full", num_hidden_layers=1)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=v5e)
+
+    variables = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    table = M4_MAX_LEN // M4_BLOCK
+    caches = [{name: spec((1 + M4_SLOTS * table,) + tuple(shape), dt)
+               for name, (shape, dt) in
+               model.cache_leaves(M4_BLOCK, BF16).items()}]
+    tables = spec((M4_SLOTS, table), jnp.int32)
+    b = M4_SLOTS
+    state = (spec((b, model.cfg.vocab_held), jnp.float32),
+             spec((b,), jnp.int32), spec((b, 2), jnp.uint32),
+             spec((b,), jnp.float32), spec((b,), jnp.int32),
+             spec((b,), jnp.float32), spec((b,), jnp.int32),
+             spec((b,), jnp.int32))
+    last, pos, keys, temps, top_ks, top_ps, eos, budgets = state
+    step = jax.jit(_build_step(model, 64, 0, 1, paged=True),
+                   donate_argnums=(1,)).lower(
+        variables, caches, tables, last, pos, spec((b,), jnp.bool_), keys,
+        temps, top_ks, top_ps, eos, budgets).compile()
+    i32, f32 = spec((), jnp.int32), spec((), jnp.float32)
+    prefill = jax.jit(_build_prefill(model, M4_CHUNK, paged=True),
+                      donate_argnums=(1,)).lower(
+        variables, caches, tables, spec((1, M4_CHUNK), jnp.int32), i32, i32, i32,
+        i32, f32, i32, f32, i32, i32, *state).compile()
+    return {"step": step.as_text(), "prefill": prefill.as_text()}
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_mistral4_serve_programs_compile_for_v5e(mistral4_programs, program):
+    text = mistral4_programs[program]
+    # the experts run through the compiler's own grouped matmul
+    assert len(re.findall(r"%ragged-dot\S* = ", text)) >= 3
+    pool = re.escape(f"bf16[{1 + M4_SLOTS * (M4_MAX_LEN // M4_BLOCK)},"
+                     f"{M4_BLOCK},384]")
+    assert re.search(pool, text)            # the latent pool, 384-lane rows
+
+
+def test_mistral4_step_program_has_no_pool_shaped_copy(mistral4_programs):
+    """The latent pool's minor dimension is whole 128-lane tiles, so the
+    device's own layout is the row-major one every consumer takes: no
+    ``copy`` of the pool's shape in the step program (a 320-wide row made
+    the compiler put the BLOCK axis minor and copy the pool twice a
+    layer: 12 copies of 0.34 GB a step at six layers)."""
+    text = mistral4_programs["step"]
+    pool = re.escape(f"bf16[{1 + M4_SLOTS * (M4_MAX_LEN // M4_BLOCK)},"
+                     f"{M4_BLOCK},384]")
+    assert not re.findall(r" = " + pool + r"\S* copy\(", text)
+    # and the program's fetch carries the expert-load counter
+    assert re.search(r"s32\[1,32\]", text.split("ENTRY", 1)[1])
