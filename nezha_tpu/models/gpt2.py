@@ -283,6 +283,35 @@ def _flash_auto_ok() -> bool:
     return jax.default_backend() == "tpu" and not under_auto_partitioner()
 
 
+def _pool_rows(x):
+    """Fresh projections ``[b, H, s, D]`` -> pool rows ``[b, s, H*D]``:
+    one row a position, all heads side by side in lanes (the layout of
+    a paged K/V pool, ``[N, bs, H*D]``)."""
+    b, h, s, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+def _pool_view(blocks, heads):
+    """Gathered pool blocks ``[b, M, bs, H*D]`` (``pool[tab]``) -> the
+    dense per-head view ``[b, H, M*bs, D]`` the composed attention
+    takes."""
+    b, m, bs, hd = blocks.shape
+    return blocks.reshape(b, m * bs, heads, hd // heads).transpose(0, 2, 1, 3)
+
+
+def _float_chunk_write(pool, tab, pos, new):
+    """One prefill chunk's K (or V) ``new [b, H, s, D]`` into a float
+    pool at traced offset ``pos``: ONE row scatter through the block
+    table (pads beyond the prompt land in the row's own bound blocks
+    and are overwritten by decode before any mask attends them)."""
+    bs = pool.shape[1]
+    m = tab.shape[1]
+    ppos = jnp.minimum(pos + jnp.arange(new.shape[2]), m * bs - 1)
+    blk = tab[:, jnp.clip(ppos // bs, 0, m - 1)]               # [b, s]
+    off = (ppos % bs)[None, :]                                 # [1, s]
+    return pool.at[blk, off, :].set(_pool_rows(new).astype(pool.dtype))
+
+
 def _quant_decode_write(pool, scales, blk, off, row):
     """One decode token's K (or V) into an INT8 block pool at BLOCK
     granularity: gather each row's target block, dequantize, zero the
@@ -294,18 +323,18 @@ def _quant_decode_write(pool, scales, blk, off, row):
     earlier tokens: they re-round only if the block absmax moved
     (unchanged scale round-trips int8 exactly), which is the bounded
     re-quantization error the ``serve.kv.quant_error`` histogram
-    samples. ``pool [N,H,bs,D] int8``, ``scales [N,H] f32``,
+    samples. ``pool [N,bs,H*D] int8``, ``scales [N,H] f32``,
     ``blk``/``off [B]``, ``row [B,H,D]``."""
     from nezha_tpu.ops import quant
-    bs = pool.shape[2]
-    qblk = pool[blk]                                     # [B, H, bs, D]
-    deq = qblk.astype(jnp.float32) * scales[blk][:, :, None, None]
+    bs = pool.shape[1]
+    heads = scales.shape[1]
+    deq = quant.dequantize_kv_rows(pool[blk], scales[blk])   # [B, bs, H*D]
     idx = jnp.arange(bs)
-    keep = (idx[None, :] < off[:, None])[:, None, :, None]
-    sel = (idx[None, :] == off[:, None])[:, None, :, None]
-    deq = jnp.where(sel, row.astype(jnp.float32)[:, :, None, :],
-                    jnp.where(keep, deq, 0.0))
-    qn, sn = quant.quantize_kv_block(deq)
+    keep = (idx[None, :] < off[:, None])[:, :, None]
+    sel = (idx[None, :] == off[:, None])[:, :, None]
+    new = row.astype(jnp.float32).reshape(row.shape[0], 1, -1)
+    deq = jnp.where(sel, new, jnp.where(keep, deq, 0.0))
+    qn, sn = quant.quantize_kv_rows(deq, heads)
     return pool.at[blk].set(qn), scales.at[blk].set(sn)
 
 
@@ -325,7 +354,8 @@ def _quant_prefill_write(pool, scales, tab, pos, new, s):
     ``err`` the max-abs dequant error over the written span — the
     ``serve.kv.quant_error`` sample."""
     from nezha_tpu.ops import quant
-    bs = pool.shape[2]
+    bs = pool.shape[1]
+    heads = scales.shape[1]
     m = tab.shape[1]
     t = min((s - 1) // bs + 2, m)
     fb = pos // bs
@@ -333,21 +363,18 @@ def _quant_prefill_write(pool, scales, tab, pos, new, s):
     touched = tbi_raw <= (pos + s - 1) // bs
     blks = jnp.where(touched[None, :],
                      tab[:, jnp.clip(tbi_raw, 0, m - 1)], 0)   # [b, T]
-    deq = (pool[blks].astype(jnp.float32)
-           * scales[blks][..., None, None])              # [b,T,H,bs,D]
+    deq = quant.dequantize_kv_rows(pool[blks], scales[blks])  # [b,T,bs,HD]
     wpos = tbi_raw[:, None] * bs + jnp.arange(bs)[None, :]     # [T, bs]
     keep = (wpos < pos) & touched[:, None]
     in_chunk = (wpos >= pos) & (wpos < pos + s) & touched[:, None]
-    neww = new.astype(jnp.float32)[
-        :, :, jnp.clip(wpos - pos, 0, s - 1), :]         # [b,H,T,bs,D]
-    neww = jnp.transpose(neww, (0, 2, 1, 3, 4))          # [b,T,H,bs,D]
-    deq = jnp.where(in_chunk[None, :, None, :, None], neww,
-                    jnp.where(keep[None, :, None, :, None], deq, 0.0))
-    qn, sn = quant.quantize_kv_block(deq)
+    neww = _pool_rows(new).astype(jnp.float32)[
+        :, jnp.clip(wpos - pos, 0, s - 1), :]            # [b,T,bs,HD]
+    deq = jnp.where(in_chunk[None, :, :, None], neww,
+                    jnp.where(keep[None, :, :, None], deq, 0.0))
+    qn, sn = quant.quantize_kv_rows(deq, heads)
     err = jnp.max(jnp.abs(jnp.where(
-        (keep | in_chunk)[None, :, None, :, None],
-        quant.sanitize(deq) - qn.astype(jnp.float32)
-        * sn[..., None, None], 0.0)))
+        (keep | in_chunk)[None, :, :, None],
+        quant.sanitize(deq) - quant.dequantize_kv_rows(qn, sn), 0.0)))
     return pool.at[blks].set(qn), scales.at[blks].set(sn), err
 
 
@@ -374,7 +401,8 @@ class Attention(Module):
 
         if cache is not None and "tables" in cache:
             # PAGED cache (the serve engine's block-paged pool): k/v are
-            # block POOLS [N, H, bs, D] and cache["tables"] [B, M] maps
+            # block POOLS [N, bs, H*D] (one row a position, all heads in
+            # lanes) and cache["tables"] [B, M] maps
             # this row's position p to pool block tables[p // bs] at
             # offset p % bs. Writes are a scatter through the table;
             # attention either runs the flash-decode kernel directly on
@@ -567,19 +595,18 @@ class Attention(Module):
     def _apply_paged(self, variables, x, q, k, v, cache, pos, prefill,
                      active, states, *, training):
         """The block-paged cache path (see ``apply``). ``cache`` is
-        ``{"k": [N, H, bs, D], "v": [N, H, bs, D], "tables": [B, M]}``;
+        ``{"k": [N, bs, H*D], "v": [N, bs, H*D], "tables": [B, M]}``;
         the engine guarantees every position this call writes sits in a
         block the row owns exclusively (ref count 1 — prepare_write
         COWed/bound it), and every position it attends below a row's
         length was genuinely written (prefill order / prefix refs)."""
         cfg = self.cfg
         b, s, h = x.shape
-        d = h // cfg.num_heads
         kp, vp, tab = cache["k"], cache["v"], cache["tables"]
         quant = "k_scale" in cache   # int8 pool: scales ride the cache
         ks_pool = cache.get("k_scale")
         vs_pool = cache.get("v_scale")
-        bs_kv = kp.shape[2]
+        bs_kv = kp.shape[1]
         m = tab.shape[1]
         L = m * bs_kv
         per_row = getattr(pos, "ndim", 0) == 1
@@ -618,10 +645,10 @@ class Attention(Module):
                         v_pool, vs_pool, blk[:, j], off[:, j],
                         v[:, :, j, :])
             else:
-                k_pool = kp.at[blk, :, off, :].set(
-                    k.transpose(0, 2, 1, 3).astype(kp.dtype))
-                v_pool = vp.at[blk, :, off, :].set(
-                    v.transpose(0, 2, 1, 3).astype(vp.dtype))
+                k_pool = kp.at[blk, off, :].set(
+                    _pool_rows(k).astype(kp.dtype))
+                v_pool = vp.at[blk, off, :].set(
+                    _pool_rows(v).astype(vp.dtype))
         elif per_row:
             # Decode: one token per row at its own depth. Clamp matches
             # the dense layout's update-slice clamp (a capacity-filled
@@ -643,10 +670,10 @@ class Attention(Module):
                 v_pool, vs_pool = _quant_decode_write(
                     vp, vs_pool, blk, off, v[:, :, 0, :])
             else:
-                k_pool = kp.at[blk, :, off, :].set(
-                    k[:, :, 0, :].astype(kp.dtype))
-                v_pool = vp.at[blk, :, off, :].set(
-                    v[:, :, 0, :].astype(vp.dtype))
+                k_pool = kp.at[blk, off, :].set(
+                    k[:, :, 0, :].reshape(b, h).astype(kp.dtype))
+                v_pool = vp.at[blk, off, :].set(
+                    v[:, :, 0, :].reshape(b, h).astype(vp.dtype))
         else:
             # Prefill chunk at a traced scalar offset. The flash-
             # prefill kernel (prefill_impl resolution, mirroring
@@ -724,14 +751,8 @@ class Attention(Module):
                     # is already a single cheap XLA op); the kernel
                     # reads only prefix positions plus the fresh
                     # operands, so write and attention commute.
-                    ppos = jnp.minimum(pos + jnp.arange(s), L - 1)
-                    bi = jnp.clip(ppos // bs_kv, 0, m - 1)
-                    blk = tab[:, bi]                           # [b, s]
-                    off = (ppos % bs_kv)[None, :]              # [1, s]
-                    k_pool = kp.at[blk, :, off, :].set(
-                        k.transpose(0, 2, 1, 3).astype(kp.dtype))
-                    v_pool = vp.at[blk, :, off, :].set(
-                        v.transpose(0, 2, 1, 3).astype(vp.dtype))
+                    k_pool = _float_chunk_write(kp, tab, pos, k)
+                    v_pool = _float_chunk_write(vp, tab, pos, v)
                     if pf_mesh is not None:
                         out_pf = flash_prefill_attention_sharded(
                             q, k, v, kp, vp, tab, starts, pf_mesh)
@@ -745,14 +766,8 @@ class Attention(Module):
                     vp, vs_pool, tab, pos, v, s)
                 qerr = jnp.maximum(ek, ev)
             else:
-                ppos = jnp.minimum(pos + jnp.arange(s), L - 1)
-                bi = jnp.clip(ppos // bs_kv, 0, m - 1)
-                blk = tab[:, bi]                               # [b, s]
-                off = (ppos % bs_kv)[None, :]                  # [1, s]
-                k_pool = kp.at[blk, :, off, :].set(
-                    k.transpose(0, 2, 1, 3).astype(kp.dtype))
-                v_pool = vp.at[blk, :, off, :].set(
-                    v.transpose(0, 2, 1, 3).astype(vp.dtype))
+                k_pool = _float_chunk_write(kp, tab, pos, k)
+                v_pool = _float_chunk_write(vp, tab, pos, v)
         use_decode_kernel = (not prefill and s == 1 and per_row
                              and _decode_flash_ok(cfg))
         shmap_mesh = None
@@ -800,13 +815,14 @@ class Attention(Module):
                     block_scales=((ks_pool, vs_pool) if quant
                                   else None))
         else:
-            # Composed path: gather the rows' blocks into the dense
-            # [b, H, L, D] view and run the same masked attention the
-            # dense layout uses (unbound table entries gather scratch —
-            # always masked, since they sit at/past the row's length).
+            # Composed path: gather the rows' blocks ([b, M, bs, H*D])
+            # into the dense [b, H, L, D] view and run the same masked
+            # attention the dense layout uses (unbound table entries
+            # gather scratch — always masked, since they sit at/past the
+            # row's length).
             # Int8 pools dequantize the gathered blocks with the SAME
             # expression as the kernel's in-loop dequant
-            # (ops.quant.dequantize_kv_block), so decode_impl="xla"
+            # (ops.quant.dequantize_kv_rows), so decode_impl="xla"
             # stays a faithful escape hatch for the quantized cache.
             # Prefill cost note: the serve engine's chunks always reach
             # here (a traced pos can never take the static-pos-0 flash
@@ -817,17 +833,15 @@ class Attention(Module):
             # kernel (the engine docstring's "obvious next kernel")
             # would lift both layouts at once.
             if quant:
-                from nezha_tpu.ops.quant import dequantize_kv_block
-                k_all = dequantize_kv_block(k_pool[tab], ks_pool[tab],
-                                            q.dtype)
-                v_all = dequantize_kv_block(v_pool[tab], vs_pool[tab],
-                                            q.dtype)
+                from nezha_tpu.ops.quant import dequantize_kv_rows
+                k_all = dequantize_kv_rows(k_pool[tab], ks_pool[tab],
+                                           q.dtype)
+                v_all = dequantize_kv_rows(v_pool[tab], vs_pool[tab],
+                                           q.dtype)
             else:
                 k_all, v_all = k_pool[tab], v_pool[tab]
-            k_all = k_all.transpose(0, 2, 1, 3, 4).reshape(
-                b, cfg.num_heads, L, d)
-            v_all = v_all.transpose(0, 2, 1, 3, 4).reshape(
-                b, cfg.num_heads, L, d)
+            k_all = _pool_view(k_all, cfg.num_heads)
+            v_all = _pool_view(v_all, cfg.num_heads)
             if per_row:
                 abs_q = pos[:, None] + jnp.arange(s)[None, :]
                 attendable = (jnp.arange(L)[None, None, :]
@@ -1115,10 +1129,14 @@ class GPT2(Module):
                      ) -> dict:
         """The per-layer cache leaves of one pool block: name -> (trailing
         shape, dtype); ``PagedSlotPool`` allocates ``[num_blocks, ...]`` of
-        each. Per-head K and V, and with ``quantized`` int8 blocks plus one
-        float32 absmax scale per (block, head)."""
+        each. K and V are LANE-DENSE rows: ``(block_size, H*D)``, a
+        position's heads side by side in lanes (head ``h`` in lanes
+        ``h*D .. (h+1)*D``), so the pool ``[N, bs, H*D]`` is whole
+        128-lane tiles in the device's own row-major layout and no
+        program copies it between layouts. With ``quantized``, int8 rows
+        plus one float32 absmax scale per (block, head)."""
         cfg = self.cfg
-        kv = (cfg.num_heads, block_size, cfg.hidden_size // cfg.num_heads)
+        kv = (block_size, cfg.hidden_size)
         if quantized:
             return {"k": (kv, jnp.int8), "v": (kv, jnp.int8),
                     "k_scale": ((cfg.num_heads,), jnp.float32),

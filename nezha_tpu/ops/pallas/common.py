@@ -51,25 +51,30 @@ def pick_block(size: int, target: int) -> int:
     return b
 
 
-def gather_row_scales(scales, block_tables):
+def gather_row_scales(scales, block_tables, group: int):
     """Int8-pool scales ``[N, H]`` gathered through ``block_tables
-    [B, M]`` into the kernel operand layout ``[B, H, 1, M]``: one lane
-    vector of per-block scales per (row, head). The TPU lowering refuses
-    a ``(1, 1)`` block over ``[N, H]`` (the last two block dims must be
-    (8, 128)-divisible or span the array's), so the per-block scalar is
-    picked in-kernel by :func:`block_scale` from a block that spans the
-    trailing ``(1, M)`` dims."""
+    [B, M]`` into the kernel operand layout ``[B, H / group, group, M]``:
+    one lane vector of per-block scales per (row, head), the heads in
+    the groups a grid step holds (all ``H`` for the decode kernel, the
+    heads of one lane block of the pool for the prefill kernel). The
+    TPU lowering refuses a ``(1, 1)`` block over ``[N, H]`` (the last
+    two block dims must be (8, 128)-divisible or span the array's), so
+    a step takes the block ``(1, 1, group, M)``, which spans the
+    trailing dims, and picks an entry's scales in-kernel
+    (:func:`block_scale`)."""
     rows = jnp.asarray(scales, jnp.float32)[block_tables]    # [B, M, H]
-    return rows.transpose(0, 2, 1)[:, :, None, :]
+    b, m, h = rows.shape
+    return rows.transpose(0, 2, 1).reshape(b, h // group, group, m)
 
 
 def block_scale(rows_ref, t):
-    """Entry ``t`` of a :func:`gather_row_scales` block as a ``[1, 1]``
-    tile (a masked lane reduction — Mosaic has no dynamic lane index
-    into VMEM). Exact: one selected element plus zeros."""
-    row = rows_ref[0, 0]                                     # [1, M]
-    lane = lax.broadcasted_iota(jnp.int32, row.shape, 1)
-    return jnp.sum(jnp.where(lane == t, row, 0.0), axis=-1, keepdims=True)
+    """Entry ``t`` of a :func:`gather_row_scales` block as a
+    ``[group, 1]`` tile (a masked lane reduction — Mosaic has no
+    dynamic lane index into VMEM). Exact: one selected element plus
+    zeros."""
+    rows = rows_ref[0, 0]                                    # [group, M]
+    lane = lax.broadcasted_iota(jnp.int32, rows.shape, 1)
+    return jnp.sum(jnp.where(lane == t, rows, 0.0), axis=-1, keepdims=True)
 
 
 def scratch_init(m_scr, l_scr, acc_scr):

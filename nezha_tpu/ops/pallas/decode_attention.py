@@ -14,8 +14,9 @@ kernel, not a generic lowering):
   ``(max, sum, acc)`` scratch via online softmax, merged at the final
   block (no score matrix, no mask tensor). The dense layout runs
   ``(B, H, L / block_k)``; the paged layout runs ``(B, M / c)`` — all
-  heads of ``c`` table entries a step (a pool block is contiguous over
-  its heads), see :func:`_paged_call`;
+  heads of ``c`` table entries a step (the paged pool is lane-dense,
+  ``[N, bs, H*D]``: a block is ``bs`` whole rows of all heads), see
+  :func:`_paged_call`;
 - per-row ``lengths`` ride as a SCALAR-PREFETCH operand (SMEM — the TPU
   lowering refuses a ``(1, 128)`` VMEM block over ``[B, 128]``): a
   program whose block starts at or past its row's length SKIPS the block
@@ -114,18 +115,32 @@ _ENTRIES_PER_STEP = 8
 def _paged_decode_kernel(tab_ref, len_ref, q_ref, *refs, scale: float,
                          block_size: int, entries: int, quant: bool):
     """One grid step = ``entries`` consecutive table entries of one row,
-    ALL heads: ``q_ref``/``o_ref`` are ``(1, H, 1, D)``, each of the
-    ``entries`` K and V refs one pool block ``(1, H, bs, D)`` (the index
-    maps of :func:`_paged_call` gathered them through the table). The
-    tiles are folded as one ``entries * bs``-position block of the
-    online softmax: :func:`common.block_step` with a leading head axis
-    (one batched contraction over H; an unrolled loop over heads with
-    per-head scratch read 1.3x slower on a v5e, PERF.md section 6, PR 25).
+    ALL heads. The pool is lane-dense: each of the ``entries`` K and V
+    refs is one pool block ``(1, bs, H*D)`` (the index maps of
+    :func:`_paged_call` gathered them through the table), a position a
+    row, head ``h`` in lanes ``h*D .. (h+1)*D``; stacked they are one
+    ``[span, H*D]`` block of the online softmax, ``span = entries * bs``.
+
+    No lane is sliced per head in the block loop. ``q_ref`` is the
+    query laid out BLOCK-DIAGONALLY, ``(1, H, H*D)``: row ``h`` holds
+    head ``h``'s ``D`` values in that head's lanes and zeros elsewhere,
+    so ONE matmul ``q_bd @ K^T`` gives every head's scores ``[H, span]``
+    (the zeros add nothing: exact), and ``p @ V`` gives ``[H, H*D]``
+    whose diagonal ``D``-lane blocks are the heads' outputs (the
+    off-diagonal blocks are finite junk that is never read). The MXU
+    takes each K/V byte once, as the per-head ``M = 1`` matmuls did;
+    on a v5e this form read 1.85 ms a call at 256 rows x ~6,470 live
+    entries against 3.04 for per-head 64-lane slices and 3.09 for a
+    ``(k * q) @ E`` head-sum on the VPU (PERF.md section 6, PR 27).
+
     On an INT8 pool the row's per-block fp32 scales ride as
-    ``(1, H, 1, M)`` (see :func:`gather_row_scales`) and each tile is
-    dequantized right here — int8 blocks never round-trip through a
-    dense bf16 cache; dequantized tiles are cast to the query's dtype
-    (bf16 dots at the doubled MXU rate), softmax statistics and the
+    ``(1, 1, H, M)`` (see :func:`gather_row_scales`). A head's scale is
+    constant over its own lanes, and row ``h`` of the two products
+    reads only head ``h``'s lanes, so K's scale multiplies the SCORES
+    (``[H, span]``, after the int8 x bf16 dot: int8 is exact in bf16)
+    and V's scale multiplies ``p`` before its dot: the same quantity as
+    ``ops.quant.dequantize_kv_rows`` followed by the dots, without
+    rounding the dequantized tile to bf16. Softmax statistics and the
     accumulator stay fp32."""
     c = entries
     k_refs, v_refs, refs = refs[:c], refs[c:2 * c], refs[2 * c:]
@@ -135,6 +150,7 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, *refs, scale: float,
     o_ref, m_scr, l_scr, acc_scr = refs
     ki = pl.program_id(1)
     span = c * block_size
+    heads, d = o_ref.shape[1], o_ref.shape[3]
 
     @pl.when(ki == 0)
     def _init():
@@ -142,24 +158,21 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, *refs, scale: float,
 
     length = len_ref[pl.program_id(0)]
 
-    def block(pool_refs, scale_ref):
-        """The step's ``[H, span, d]`` block in the query's dtype."""
-        tiles = [r[0] for r in pool_refs]
-        if quant:
-            # THE dequant both attention paths share (see
-            # ops/quant.dequantize_kv_block): int8 * fp32 scale, cast to
-            # the compute dtype — the XLA gather fallback applies the
-            # same expression, so kernel and fallback see identical
-            # tiles. Entry t's scale is lane t of the row's scale
-            # block: a masked lane reduction (Mosaic has no dynamic
-            # lane index into VMEM), one selected element plus zeros.
-            rows = scale_ref[0]                              # [H, 1, M]
-            lane = lax.broadcasted_iota(jnp.int32, rows.shape, 2)
-            tiles = [(t.astype(jnp.float32) * jnp.sum(
-                jnp.where(lane == ki * c + j, rows, 0.0), axis=-1,
-                keepdims=True)).astype(q_ref.dtype)
-                     for j, t in enumerate(tiles)]
-        return jnp.concatenate(tiles, axis=1)
+    def entry_scales(scale_ref):
+        """``[H, span]``: the scale of the entry each position sits in.
+        Entry ``ki * c + j`` is lane ``ki * c + j`` of the row's scale
+        block: a masked lane reduction (Mosaic has no dynamic lane
+        index into VMEM), one selected element plus zeros."""
+        rows = scale_ref[0, 0]                               # [H, M]
+        lane = lax.broadcasted_iota(jnp.int32, rows.shape, 1)
+        entry = lax.broadcasted_iota(
+            jnp.int32, (heads, span), 1) // block_size
+        out = jnp.zeros((heads, span), jnp.float32)
+        for j in range(c):
+            sj = jnp.sum(jnp.where(lane == ki * c + j, rows, 0.0),
+                         axis=-1, keepdims=True)             # [H, 1]
+            out = jnp.where(entry == j, sj, out)
+        return out
 
     # Steps at or past the row's length do nothing: their index maps
     # repeat the blocks of the row's last live step, so the pipeline
@@ -167,23 +180,28 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, *refs, scale: float,
     # slot) runs no step at all and finalizes to an all-zero output.
     @pl.when(ki * span < length)
     def _block():
-        q = q_ref[0]                                         # [H, 1, d]
-        k = block(k_refs, ks_ref)
-        v = block(v_refs, vs_ref)
-        s = lax.dot_general(q.astype(k.dtype), k,
-                            (((2,), (2,)), ((0,), (0,))),
+        q = q_ref[0]                                         # [H, H*D]
+        k = jnp.concatenate([r[0] for r in k_refs], axis=0)  # [span, H*D]
+        v = jnp.concatenate([r[0] for r in v_refs], axis=0)
+        s = lax.dot_general(q, k.astype(q.dtype),
+                            (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-        kpos = ki * span + lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(kpos < length, s, NEG_BIG)             # [H, 1, span]
-        m_prev = m_scr[:, :, :1]
-        l_prev = l_scr[:, :, :1]
+        if quant:
+            s = s * entry_scales(ks_ref)
+        kpos = ki * span + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kpos < length, s, NEG_BIG)             # [H, span]
+        m_prev = m_scr[:, :1]
+        l_prev = l_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
         l_new = corr * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        if quant:
+            p = p * entry_scales(vs_ref)
         acc_scr[:] = acc_scr[:] * corr + lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+            p.astype(q.dtype), v.astype(q.dtype),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [H, H*D]
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
@@ -191,8 +209,11 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, *refs, scale: float,
     def _final():
         # common.softmax_finalize over every head's row (no lse:
         # inference only); a row that folded nothing stays exactly zero.
-        denom = jnp.maximum(l_scr[:, :, :1], 1e-30)
-        o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
+        # Head h's output is the h-th diagonal D-lane block of its row.
+        out = acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)
+        for h in range(heads):
+            o_ref[0, h] = out[h:h + 1, h * d:(h + 1) * d].astype(
+                o_ref.dtype)
 
 
 def _visited_entries(tab, lens, block_size: int, entries: int):
@@ -215,28 +236,32 @@ def _visited_entries(tab, lens, block_size: int, entries: int):
 
 def _paged_call(q, k, v, lengths, block_tables, scale, interpret,
                 block_scales=None):
-    """Paged layout: k/v are BLOCK POOLS ``[N, H, bs, D]`` and
+    """Paged layout: k/v are LANE-DENSE BLOCK POOLS ``[N, bs, H*D]`` and
     ``block_tables [B, M]`` maps row b's KV block ki to pool block
     ``block_tables[b, ki]``. Table and lengths ride as SCALAR-PREFETCH
     operands (pltpu.PrefetchScalarGridSpec); the grid is ``(B, M / c)``
     and each pool is passed ``c`` times, operand ``j`` gathering table
     entry ``ki * c + j`` in its index map (XLA feeds all of them from
-    one buffer). A pool block is contiguous over its heads, so one DMA
-    brings all ``H`` heads of an entry. The table is first rewritten by
+    one buffer). A pool block is ``bs`` whole rows of ``H*D`` lanes,
+    contiguous in HBM, so one DMA brings all ``H`` heads of an entry
+    and no lane of it is padding. The table is first rewritten by
     :func:`_visited_entries` (one index load is then all an index map
     costs the scalar core, which is what a skipped step's time is made
-    of). With ``block_scales`` (int8 pools) the row's per-block fp32
-    scales are pre-gathered through the same rewritten table
-    (:func:`gather_row_scales`) and the kernel dequantizes each tile in
-    the block loop.
+    of). The query goes in block-diagonally (``[B, H, H*D]``, built
+    here by one small XLA fusion; see :func:`_paged_decode_kernel`).
+    With ``block_scales`` (int8 pools) the row's per-block fp32 scales
+    are pre-gathered through the same rewritten table
+    (:func:`gather_row_scales`) and the kernel applies them in the
+    block loop.
 
-    There is no manual-DMA loop over a row's own entries (pools in
-    ``pl.ANY``, ``make_async_copy`` per live entry): the TPU holds the
-    row-major pool as ``[N, H, bs, 128]`` and refuses a 64-lane slice
-    of it as a DMA source ("must be aligned to tiling (128)") until the
-    pool's minor dimension is lane-dense (ROADMAP S-a)."""
+    Not here yet: a manual-DMA loop over a row's own entries (pools in
+    ``pl.ANY``, ``make_async_copy`` per live entry), which the
+    lane-dense pool admits (a ``(bs, H*D)`` block is whole 128-lane
+    tiles) and which would stop the scalar core walking the steps that
+    move nothing (1.44 of 1.85 ms a call: PERF.md section 6, PR 27)."""
     b, h, _, d = q.shape
-    bs = k.shape[2]
+    bs = k.shape[1]
+    hd = h * d
     m = block_tables.shape[1]
     c = _pick_block(m, _ENTRIES_PER_STEP)
     quant = block_scales is not None
@@ -245,27 +270,30 @@ def _paged_call(q, k, v, lengths, block_tables, scale, interpret,
     lens = jnp.clip(jnp.asarray(lengths, jnp.int32), 0, m * bs)
     tab = _visited_entries(jnp.asarray(block_tables, jnp.int32), lens,
                            bs, c)
-    row_spec = pl.BlockSpec((1, h, 1, d),
+    q_bd = (q[:, :, 0, None, :]
+            * jnp.eye(h, dtype=q.dtype)[None, :, :, None]).reshape(b, h, hd)
+    q_spec = pl.BlockSpec((1, h, hd), lambda b_, ki, tab, lens: (b_, 0, 0))
+    out_spec = pl.BlockSpec((1, h, 1, d),
                             lambda b_, ki, tab, lens: (b_, 0, 0, 0))
-    kv_specs = [pl.BlockSpec((1, h, bs, d),
+    kv_specs = [pl.BlockSpec((1, bs, hd),
                              lambda b_, ki, tab, lens, j=j:
-                             (tab[b_, ki * c + j], 0, 0, 0))
+                             (tab[b_, ki * c + j], 0, 0))
                 for j in range(c)]
-    in_specs = [row_spec] + kv_specs * 2
-    operands = [q] + [k] * c + [v] * c
+    in_specs = [q_spec] + kv_specs * 2
+    operands = [q_bd] + [k] * c + [v] * c
     if quant:
-        in_specs += [pl.BlockSpec((1, h, 1, m),
+        in_specs += [pl.BlockSpec((1, 1, h, m),
                                   lambda b_, ki, tab, lens:
                                   (b_, 0, 0, 0))] * 2
-        operands += [gather_row_scales(sc, tab) for sc in block_scales]
+        operands += [gather_row_scales(sc, tab, h) for sc in block_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, m // c),
         in_specs=in_specs,
-        out_specs=row_spec,
-        scratch_shapes=[pltpu.VMEM((h, 1, _LANES), jnp.float32),
-                        pltpu.VMEM((h, 1, _LANES), jnp.float32),
-                        pltpu.VMEM((h, 1, d), jnp.float32)],
+        out_specs=out_spec,
+        scratch_shapes=[pltpu.VMEM((h, _LANES), jnp.float32),
+                        pltpu.VMEM((h, _LANES), jnp.float32),
+                        pltpu.VMEM((h, hd), jnp.float32)],
     )
     return pl.pallas_call(
         kernel,
@@ -295,8 +323,9 @@ def flash_decode_attention(q, k, v, lengths,
     clamped to ``[0, L]``.
 
     With ``block_tables`` (``[B, M]`` int32 — the paged serving
-    layout), k/v are instead BLOCK POOLS shaped
-    ``[num_blocks, H, block_size, D]``: row ``b``'s positions
+    layout), k/v are instead LANE-DENSE BLOCK POOLS shaped
+    ``[num_blocks, block_size, H*D]`` (a position a row, head ``h`` in
+    lanes ``h*D .. (h+1)*D``): row ``b``'s positions
     ``[ki*block_size, (ki+1)*block_size)`` live in pool block
     ``block_tables[b, ki]``, and the kernel gathers KV blocks through
     the table via a scalar-prefetch index map. The per-row length skip
@@ -304,14 +333,14 @@ def flash_decode_attention(q, k, v, lengths,
     fixed number of whole pool blocks, all heads at once).
 
     With ``block_scales`` (paged only — a ``(k_scales, v_scales)`` pair
-    of ``[num_blocks, H]`` fp32 arrays) the pools are INT8 and each
-    gathered tile is dequantized INSIDE the block loop
-    (``tile.astype(f32) * scale -> q.dtype`` — the exact expression of
-    ``ops.quant.dequantize_kv_block``, so the composed XLA fallback
-    dequantizes identically): dots run in the query's dtype over
-    dequantized tiles, softmax statistics and the accumulator stay
-    fp32. (A ``(16, 64)`` int8 block — below the int8 native tile —
-    compiles and runs on a v5e: ``chip_smoke.py``, PR 21.)
+    of ``[num_blocks, H]`` fp32 arrays) the pools are INT8 and the
+    scales are applied INSIDE the block loop, to the scores and to
+    ``p`` (a head's scale is constant over its lanes): the quantity
+    ``ops.quant.dequantize_kv_rows`` + dots computes, which is what
+    the composed XLA fallback does, without rounding the dequantized
+    tile to bf16; dots run in the query's dtype over the int8 values
+    (exact in bf16), softmax statistics and the accumulator stay
+    fp32.
 
     ``block_k`` defaults to the largest divisor of ``L`` that is <= 256
     (KV pools are padded to power-of-two-ish capacities, so real shapes
@@ -328,10 +357,10 @@ def flash_decode_attention(q, k, v, lengths,
         raise ValueError("block_scales requires block_tables (int8 is "
                          "a paged-pool format)")
     if block_tables is not None:
-        if k.shape != v.shape or k.shape[1] != h or k.shape[3] != d:
+        if k.shape != v.shape or k.ndim != 3 or k.shape[2] != h * d:
             raise ValueError(
                 f"paged k/v pools {k.shape}/{v.shape} do not match q "
-                f"{q.shape}")
+                f"{q.shape}: want [num_blocks, block_size, H*D]")
         if block_tables.shape[0] != b:
             raise ValueError(
                 f"block_tables {block_tables.shape} does not match "
@@ -387,9 +416,11 @@ def flash_decode_attention_sharded(q, k, v, lengths, mesh, *,
 
     Heads are embarrassingly parallel in decode attention (each head's
     online softmax reads only its own K/V slice), so sharding
-    ``q [B, H, 1, D]``, the K/V block pools ``[N, H, bs, D]``, and the
-    per-(block, head) scale rows ``[N, H]`` on the H axis runs the
-    Mosaic kernel device-locally on an ``H / tp`` slice — the GSPMD
+    ``q [B, H, 1, D]`` and the per-(block, head) scale rows ``[N, H]``
+    on the H axis and the paged K/V pools ``[N, bs, H*D]`` on the LANE
+    axis (``H / tp`` contiguous heads a shard; the dense layout's
+    ``[B, H, L, D]`` on H) runs the Mosaic kernel device-locally on an
+    ``H / tp`` slice — the GSPMD
     auto-partitioner (which cannot partition a Pallas custom call)
     never sees it, exactly the ``_tp_sharded_flash`` idiom the
     training path proved. The per-row ``lengths`` and the
@@ -406,6 +437,7 @@ def flash_decode_attention_sharded(q, k, v, lengths, mesh, *,
     from nezha_tpu.parallel._compat import shard_map
 
     hspec = P(None, "tp")
+    pspec = P(None, None, "tp")     # a paged pool: heads live in lanes
     rep = P()
 
     if block_scales is not None:
@@ -417,7 +449,7 @@ def flash_decode_attention_sharded(q, k, v, lengths, mesh, *,
                 block_tables=t_, block_scales=(ks_, vs_))
 
         f = shard_map(body_q, mesh=mesh,
-                      in_specs=(hspec, hspec, hspec, rep, rep, hspec,
+                      in_specs=(hspec, pspec, pspec, rep, rep, hspec,
                                 hspec),
                       out_specs=hspec)
         return f(q, k, v, lengths, block_tables, ks, vs)
@@ -428,7 +460,7 @@ def flash_decode_attention_sharded(q, k, v, lengths, mesh, *,
                 block_tables=t_)
 
         f = shard_map(body_t, mesh=mesh,
-                      in_specs=(hspec, hspec, hspec, rep, rep),
+                      in_specs=(hspec, pspec, pspec, rep, rep),
                       out_specs=hspec)
         return f(q, k, v, lengths, block_tables)
 

@@ -116,6 +116,48 @@ def dequantize_kv_block(q: jax.Array, scale: jax.Array,
             * scale[..., None, None]).astype(dtype)
 
 
+# ------------------------------------------- the paged pool's row layout
+# A GPT-2 K/V pool is LANE-DENSE: ``[N, bs, H*D]``, one row a position,
+# head ``h`` in lanes ``h*D .. (h+1)*D`` (a minor dimension of whole
+# 128-lane tiles is the device's own row-major layout, so no program
+# re-lays the pool out: PERF.md section 6, PR 27). The per-head tile
+# form ``[..., H, bs, D]`` above stays the WIRE format (serve/migrate.py,
+# the host tier); these four are the same policy over rows.
+def split_heads(x: jax.Array, heads: int) -> jax.Array:
+    """Pool rows ``[..., bs, H*D]`` -> per-head tiles ``[..., H, bs, D]``
+    (the wire form; a transpose, so not for a serving program)."""
+    x = x.reshape(x.shape[:-1] + (heads, x.shape[-1] // heads))
+    return jnp.swapaxes(x, -3, -2)
+
+
+def merge_heads(x: jax.Array) -> jax.Array:
+    """Per-head tiles ``[..., H, bs, D]`` -> pool rows ``[..., bs, H*D]``."""
+    x = jnp.swapaxes(x, -3, -2)
+    return x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
+
+
+def quantize_kv_rows(x: jax.Array, heads: int):
+    """:func:`quantize_kv_block` over pool rows: ``x [..., bs, H*D]`` ->
+    ``(int8 [..., bs, H*D], fp32 scales [..., H])``, one absmax scale per
+    (block, head); element for element what the tile form gives."""
+    xf = sanitize(x).reshape(x.shape[:-1] + (heads, x.shape[-1] // heads))
+    scale = _scale_of(jnp.max(jnp.abs(xf), axis=(-3, -1)))
+    q = jnp.clip(jnp.round(xf / scale[..., None, :, None]),
+                 -QMAX, QMAX).astype(jnp.int8)
+    return q.reshape(x.shape), scale
+
+
+def dequantize_kv_rows(q: jax.Array, scale: jax.Array,
+                       dtype=jnp.float32) -> jax.Array:
+    """``int8 [..., bs, H*D]`` + ``fp32 scales [..., H]`` -> ``dtype``:
+    :func:`dequantize_kv_block`'s expression with each head's scale
+    over its own lanes."""
+    heads = scale.shape[-1]
+    qf = q.astype(jnp.float32).reshape(
+        q.shape[:-1] + (heads, q.shape[-1] // heads))
+    return (qf * scale[..., None, :, None]).astype(dtype).reshape(q.shape)
+
+
 def kv_roundtrip_error(x: jax.Array) -> jax.Array:
     """Max-abs dequant error of one KV-block quantization of ``x``
     (``[..., bs, D]``) -> scalar fp32. The ``serve.kv.quant_error``

@@ -6,8 +6,8 @@ shares sampling params, and nothing can join or leave mid-flight. The
 serve stack replaces the batch lifecycle with a slot lifecycle:
 
 - ``slots``: the KV pools. Default is the BLOCK-PAGED layout
-  (``PagedSlotPool``): per-layer ``[num_blocks, H, block_size, D]``
-  buffers, a host-side free list of ref-counted blocks, and per-slot
+  (``PagedSlotPool``): per-layer ``[num_blocks, block_size, H*D]``
+  buffers (lane-dense rows: a position's heads side by side), a host-side free list of ref-counted blocks, and per-slot
   block tables threaded into the compiled programs — admission binds
   only what the prompt needs, decode binds lazily as positions
   advance, and a prefix-reuse trie lets a request whose prompt prefix

@@ -205,7 +205,7 @@ class ServeConfig:
     seq_prefill_variant: str = "auto"
     decode_horizon: int = 1
     # KV layout: "paged" (default) is the block-paged pool — per-layer
-    # [kv_num_blocks, H, kv_block_size, D] buffers, ref-counted blocks
+    # [kv_num_blocks, kv_block_size, H*D] buffers, ref-counted blocks
     # bound lazily as positions advance, per-slot block tables threaded
     # into the compiled programs, and (with prefix_cache) shared-prefix
     # prefill reuse. "dense" is the classic [B_max, H, max_len, D]

@@ -58,7 +58,10 @@ from nezha_tpu import faults, obs
 from nezha_tpu.parallel.gspmd import auto_partitioner_scope
 from nezha_tpu.parallel.mesh import make_mesh
 from nezha_tpu.serve.engine import Engine, ServeConfig
-from nezha_tpu.serve.sharded.pool import ShardedPagedSlotPool
+from nezha_tpu.serve.sharded.pool import (
+    ShardedPagedSlotPool,
+    head_sharding,
+)
 from nezha_tpu.serve.sharded.reshard import (place_variables,
                                              serve_tp_rules)
 
@@ -135,10 +138,10 @@ class ShardedEngine(Engine):
                 serve_tp_rules(draft_model.cfg, m))
         # Output-sharding pins for the program wrapper (created BEFORE
         # super().__init__, which builds the programs through the
-        # hooks): cache pytrees stay head-sharded, everything else
-        # replicates. P(None, "tp") partitions axis 1 for both leaf
-        # ranks in play — [N, H, bs, D] K/V blocks and [N, H] scales.
-        self._kv_out = NamedSharding(self.mesh, P(None, "tp"))
+        # hooks): cache pytrees stay head-sharded (each leaf's LAST
+        # axis over tp: the lanes of the [N, bs, H*D] K/V rows, the
+        # heads of the [N, H] scales — pool.head_sharding), everything
+        # else replicates.
         self._rep_out = NamedSharding(self.mesh, P())
         # super().__init__ builds pools and programs through the two
         # subsystem hooks below; a self-draft built inside it SHARES
@@ -207,13 +210,13 @@ class ShardedEngine(Engine):
         (the per-mesh frozen-program contract, at the jit level as
         well as the executor level)."""
         mesh = self.mesh
-        kv_out, rep_out = self._kv_out, self._rep_out
+        rep_out = self._rep_out
 
         def pin(out):
             if isinstance(out, list):       # a per-layer caches list
                 return jax.tree_util.tree_map(
                     lambda x: jax.lax.with_sharding_constraint(
-                        x, kv_out), out)
+                        x, head_sharding(mesh, x.ndim)), out)
             if isinstance(out, tuple):
                 return tuple(pin(o) for o in out)
             return jax.lax.with_sharding_constraint(out, rep_out)

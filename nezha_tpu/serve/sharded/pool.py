@@ -2,10 +2,13 @@
 
 :class:`ShardedPagedSlotPool` is the PR 7 block-paged pool laid out
 across a serve mesh: every per-layer K/V buffer
-(``[num_blocks, H, block_size, D]``) and per-block scale row
-(``[num_blocks, H]``, int8 pools) is committed to the mesh with the
-HEAD axis partitioned over ``tp`` — block ``b`` exists on every device,
-each device holding its ``H / M`` head slice of it. Everything
+(``[num_blocks, block_size, H*D]``, lane-dense rows) and per-block
+scale row (``[num_blocks, H]``, int8 pools) is committed to the mesh
+with its LAST axis — the one the heads lie along: lanes for K/V
+(head ``h`` in lanes ``h*D .. (h+1)*D``, so a shard is ``H / M``
+contiguous heads), heads for scales — partitioned over ``tp``: block
+``b`` exists on every device, each device holding its ``H / M`` head
+slice of it. Everything
 host-side is **deliberately unchanged and unsharded**: the free list,
 ref counts, per-slot block tables, bound counts, and the prefix trie
 are exactly PR 7's single bookkeeping state, because a block is a
@@ -23,7 +26,7 @@ What this buys:
   device's budget serves on ``--mesh M``");
 - the COW / gather / scatter device ops (slots.py module jits) work
   verbatim: they are leading-axis (block-indexed) ops over the caches
-  pytree, so XLA partitions them trivially along the untouched head
+  pytree, so XLA partitions them trivially along the untouched last
   axis, and a donated rewrite stays a per-shard rewrite;
 - migration is GATHER-ON-EXPORT: ``export_block_payload`` already
   converts the gathered blocks to host arrays, which assembles the
@@ -50,6 +53,14 @@ import jax.numpy as jnp
 from nezha_tpu.serve.slots import PagedSlotPool
 
 
+def head_sharding(mesh: Mesh, ndim: int) -> NamedSharding:
+    """The sharding of a block-indexed cache leaf of rank ``ndim``: its
+    LAST axis over ``tp``, the rest replicated. One rule serves both
+    leaf ranks: ``[N, bs, H*D]`` K/V rows (heads lie along the lanes)
+    and ``[N, H]`` scale rows."""
+    return NamedSharding(mesh, P(*([None] * (ndim - 1)), "tp"))
+
+
 class ShardedPagedSlotPool(PagedSlotPool):
     """PR 7's paged pool with device state committed head-sharded over
     a serve mesh (axis name ``tp``). Host bookkeeping is inherited
@@ -73,21 +84,18 @@ class ShardedPagedSlotPool(PagedSlotPool):
         # export gather (gather-on-export assembles full heads from
         # the shards) and promotion the migration install scatter
         # (XLA partitions the leading-axis write along the untouched
-        # head axis), so one host payload format serves every mesh.
+        # last axis), so one host payload format serves every mesh.
         super().__init__(model, capacity, max_len, dtype,
                          block_size=block_size, num_blocks=num_blocks,
                          prefix_cache=prefix_cache, eviction=eviction,
                          quantized=quantized, host_blocks=host_blocks)
         self.mesh = mesh
-        self._kv_sharding = NamedSharding(mesh, P(None, "tp"))
         self.caches = self._place(self.caches)
 
     def _place(self, caches):
-        """Commit every block-indexed leaf to the head sharding. One
-        spec serves both leaf ranks: ``P(None, "tp")`` partitions axis
-        1 (heads) and replicates the rest, for ``[N, H, bs, D]`` data
-        and ``[N, H]`` scale rows alike."""
-        return [{k: jax.device_put(v, self._kv_sharding)
+        """Commit every block-indexed leaf to the head sharding
+        (:func:`head_sharding`)."""
+        return [{k: jax.device_put(v, head_sharding(self.mesh, v.ndim))
                  for k, v in layer.items()} for layer in caches]
 
     # ------------------------------------------------------ accounting

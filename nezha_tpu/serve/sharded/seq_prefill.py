@@ -105,23 +105,24 @@ def _check_divisible(s: int, h: int, world: int):
 
 
 def _composed_shard_attention(qh, k_pool, v_pool, tab, pos, scales,
-                              *, L, d):
+                              *, L):
     """The replicated composed masked-attention expression, restricted
     to one shard's head slice — kept in lockstep with
     ``models/gpt2._apply_paged``'s composed branch so the ulysses
     mirror stays bit-identical to the single-device path."""
     from nezha_tpu import ops
+    from nezha_tpu.models.gpt2 import _pool_view
 
-    b, hh, s, _ = qh.shape
+    _, hh, s, _ = qh.shape
     if scales is not None:
-        from nezha_tpu.ops.quant import dequantize_kv_block
+        from nezha_tpu.ops.quant import dequantize_kv_rows
         ks, vs = scales
-        k_all = dequantize_kv_block(k_pool[tab], ks[tab], qh.dtype)
-        v_all = dequantize_kv_block(v_pool[tab], vs[tab], qh.dtype)
+        k_all = dequantize_kv_rows(k_pool[tab], ks[tab], qh.dtype)
+        v_all = dequantize_kv_rows(v_pool[tab], vs[tab], qh.dtype)
     else:
         k_all, v_all = k_pool[tab], v_pool[tab]
-    k_all = k_all.transpose(0, 2, 1, 3, 4).reshape(b, hh, L, d)
-    v_all = v_all.transpose(0, 2, 1, 3, 4).reshape(b, hh, L, d)
+    k_all = _pool_view(k_all, hh)
+    v_all = _pool_view(v_all, hh)
     abs_q = pos + jnp.arange(s)[:, None]
     attendable = jnp.arange(L)[None, :] <= abs_q
     mask = jnp.where(attendable, 0.0, -jnp.inf).astype(jnp.float32)
@@ -129,18 +130,13 @@ def _composed_shard_attention(qh, k_pool, v_pool, tab, pos, scales,
                                      v_all.astype(qh.dtype), mask=mask)
 
 
-def _float_scatter_write(kp, vp, tab, pos, kh, vh, *, L, bs_kv, m):
-    """The replicated float chunk write (one XLA scatter through the
-    table), per shard on its own heads — same expression as
-    ``_apply_paged``."""
-    s = kh.shape[2]
-    ppos = jnp.minimum(pos + jnp.arange(s), L - 1)
-    bi = jnp.clip(ppos // bs_kv, 0, m - 1)
-    blk = tab[:, bi]                                        # [b, s]
-    off = (ppos % bs_kv)[None, :]                           # [1, s]
-    kp = kp.at[blk, :, off, :].set(kh.transpose(0, 2, 1, 3).astype(kp.dtype))
-    vp = vp.at[blk, :, off, :].set(vh.transpose(0, 2, 1, 3).astype(vp.dtype))
-    return kp, vp
+def _float_scatter_write(kp, vp, tab, pos, kh, vh):
+    """The replicated float chunk write (one XLA row scatter through
+    the table a pool), per shard on its own heads — ``_apply_paged``'s
+    own expression."""
+    from nezha_tpu.models.gpt2 import _float_chunk_write
+    return (_float_chunk_write(kp, tab, pos, kh),
+            _float_chunk_write(vp, tab, pos, vh))
 
 
 def seq_prefill_attention(q, k_chunk, v_chunk, k_pool, v_pool,
@@ -155,8 +151,9 @@ def seq_prefill_attention(q, k_chunk, v_chunk, k_pool, v_pool,
     Same operand contract as
     :func:`~nezha_tpu.ops.pallas.flash_prefill_attention`:
     ``q/k_chunk/v_chunk [B, H, S, D]`` fresh chunk projections (global
-    values under the engine's auto-partitioner trace), pools
-    ``[N, H, bs, D]`` head-sharded ``P(None, "tp")`` on ``mesh``,
+    values under the engine's auto-partitioner trace), lane-dense pools
+    ``[N, bs, H*D]`` head-sharded along the lanes
+    (``P(None, None, "tp")``) on ``mesh``,
     ``block_tables [B, M]`` / ``starts [B]`` replicated host
     bookkeeping. ``starts`` must be a per-row broadcast of the chunk's
     scalar offset (the engine's chunk programs guarantee it — the
@@ -176,7 +173,7 @@ def seq_prefill_attention(q, k_chunk, v_chunk, k_pool, v_pool,
     _check_divisible(s, H, world)
     hh = H // world
     s_loc = s // world
-    bs_kv = k_pool.shape[2]
+    bs_kv = k_pool.shape[1]
     m = block_tables.shape[1]
     L = m * bs_kv
     quant = block_scales is not None
@@ -187,7 +184,8 @@ def seq_prefill_attention(q, k_chunk, v_chunk, k_pool, v_pool,
             f"ulysses needs num_heads ({H}) divisible by mesh ({world})")
 
     sspec = P(None, None, axis, None)   # activations: sequence axis
-    hspec = P(None, axis)               # pools/scales: head axis
+    hspec = P(None, axis)               # scales [N, H]: head axis
+    pspec = P(None, None, axis)         # pools [N, bs, H*D]: the lanes
     rep = P()
 
     def seq_to_heads(x):
@@ -205,19 +203,19 @@ def seq_prefill_attention(q, k_chunk, v_chunk, k_pool, v_pool,
     if variant == "ulysses":
         return _ulysses(q, k_chunk, v_chunk, k_pool, v_pool, tab32,
                         starts32, block_scales, shard_map, mesh, axis,
-                        sspec, hspec, rep, seq_to_heads, heads_to_seq,
-                        use_kernel=use_kernel, scale=scale,
-                        interpret=interpret, L=L, bs_kv=bs_kv, m=m, d=d)
+                        sspec, hspec, pspec, rep, seq_to_heads,
+                        heads_to_seq, use_kernel=use_kernel, scale=scale,
+                        interpret=interpret, L=L)
     return _ring(q, k_chunk, v_chunk, k_pool, v_pool, tab32, starts32,
                  block_scales, shard_map, mesh, axis, sspec, hspec,
-                 rep, world=world, hh=hh, s_loc=s_loc,
+                 pspec, rep, world=world, hh=hh, s_loc=s_loc,
                  use_kernel=use_kernel, scale=scale,
-                 interpret=interpret, L=L, bs_kv=bs_kv, m=m, d=d)
+                 interpret=interpret, L=L, d=d)
 
 
 def _ulysses(q, k, v, kp, vp, tab, starts, block_scales, shard_map,
-             mesh, axis, sspec, hspec, rep, seq_to_heads, heads_to_seq,
-             *, use_kernel, scale, interpret, L, bs_kv, m, d):
+             mesh, axis, sspec, hspec, pspec, rep, seq_to_heads,
+             heads_to_seq, *, use_kernel, scale, interpret, L):
     """All-to-all variant: per shard, the EXACT replicated computation
     on its own head group — bitwise parity by construction."""
     from nezha_tpu.ops.pallas import flash_prefill_attention
@@ -243,14 +241,14 @@ def _ulysses(q, k, v, kp, vp, tab, starts, block_scales, shard_map,
                                                       pos, vh, sc)
                 qerr = jnp.maximum(ek, ev)
                 out = _composed_shard_attention(
-                    qh, kp_n, vp_n, tab_, pos, (ks_n, vs_n), L=L, d=d)
+                    qh, kp_n, vp_n, tab_, pos, (ks_n, vs_n), L=L)
             return (heads_to_seq(out), kp_n, vp_n, ks_n, vs_n,
                     lax.pmax(qerr, axis))
 
         f = shard_map(body, mesh=mesh,
-                      in_specs=(sspec, sspec, sspec, hspec, hspec, rep,
+                      in_specs=(sspec, sspec, sspec, pspec, pspec, rep,
                                 rep, hspec, hspec),
-                      out_specs=(sspec, hspec, hspec, hspec, hspec,
+                      out_specs=(sspec, pspec, pspec, hspec, hspec,
                                  rep))
         out, kp_n, vp_n, ks_n, vs_n, qerr = f(q, k, v, kp, vp, tab,
                                               starts, ks, vs)
@@ -260,28 +258,27 @@ def _ulysses(q, k, v, kp, vp, tab, starts, block_scales, shard_map,
         qh, kh, vh = (seq_to_heads(q_), seq_to_heads(k_),
                       seq_to_heads(v_))
         pos = st_[0]
-        kp_n, vp_n = _float_scatter_write(kp_, vp_, tab_, pos, kh, vh,
-                                          L=L, bs_kv=bs_kv, m=m)
+        kp_n, vp_n = _float_scatter_write(kp_, vp_, tab_, pos, kh, vh)
         if use_kernel:
             out = flash_prefill_attention(qh, kh, vh, kp_n, vp_n, tab_,
                                           st_, scale=scale,
                                           interpret=interpret)
         else:
             out = _composed_shard_attention(qh, kp_n, vp_n, tab_, pos,
-                                            None, L=L, d=d)
+                                            None, L=L)
         return heads_to_seq(out), kp_n, vp_n
 
     f = shard_map(body, mesh=mesh,
-                  in_specs=(sspec, sspec, sspec, hspec, hspec, rep,
+                  in_specs=(sspec, sspec, sspec, pspec, pspec, rep,
                             rep),
-                  out_specs=(sspec, hspec, hspec))
+                  out_specs=(sspec, pspec, pspec))
     out, kp_n, vp_n = f(q, k, v, kp, vp, tab, starts)
     return out, kp_n, vp_n, None, None, None
 
 
 def _ring(q, k, v, kp, vp, tab, starts, block_scales, shard_map, mesh,
-          axis, sspec, hspec, rep, *, world, hh, s_loc, use_kernel,
-          scale, interpret, L, bs_kv, m, d):
+          axis, sspec, hspec, pspec, rep, *, world, hh, s_loc, use_kernel,
+          scale, interpret, L, d):
     """Neighbour-hop variant. Float + kernel circulates Q blocks
     ("ring-q", bitwise); otherwise the gathered own-head paged prefix
     circulates and merges with the chunk's ring attention by
@@ -308,8 +305,7 @@ def _ring(q, k, v, kp, vp, tab, starts, block_scales, shard_map, mesh,
             kh, vh = head_domain(k_), head_domain(v_)
             pos = st_[0]
             kp_n, vp_n = _float_scatter_write(kp_, vp_, tab_, pos, kh,
-                                              vh, L=L, bs_kv=bs_kv,
-                                              m=m)
+                                              vh)
 
             def hop(i, carry):
                 q_cur, o_cur = carry
@@ -332,9 +328,9 @@ def _ring(q, k, v, kp, vp, tab, starts, block_scales, shard_map, mesh,
             return out, kp_n, vp_n
 
         f = shard_map(body, mesh=mesh,
-                      in_specs=(sspec, sspec, sspec, hspec, hspec, rep,
+                      in_specs=(sspec, sspec, sspec, pspec, pspec, rep,
                                 rep),
-                      out_specs=(sspec, hspec, hspec))
+                      out_specs=(sspec, pspec, pspec))
         out, kp_n, vp_n = f(q, k, v, kp, vp, tab, starts)
         return out, kp_n, vp_n, None, None, None
 
@@ -353,19 +349,19 @@ def _ring(q, k, v, kp, vp, tab, starts, block_scales, shard_map, mesh,
             vp_n, vs_n, ev = _quant_prefill_write(vp_, vs_, tab_, pos,
                                                   vh, s)
             qerr = lax.pmax(jnp.maximum(ek, ev), axis)
-            from nezha_tpu.ops.quant import dequantize_kv_block
-            kd = dequantize_kv_block(kp_n[tab_], ks_n[tab_], q_.dtype)
-            vd = dequantize_kv_block(vp_n[tab_], vs_n[tab_], q_.dtype)
+            from nezha_tpu.ops.quant import dequantize_kv_rows
+            kd = dequantize_kv_rows(kp_n[tab_], ks_n[tab_], q_.dtype)
+            vd = dequantize_kv_rows(vp_n[tab_], vs_n[tab_], q_.dtype)
         else:
             kp_n, vp_n = _float_scatter_write(kp_, vp_, tab_, pos, kh,
-                                              vh, L=L, bs_kv=bs_kv,
-                                              m=m)
+                                              vh)
             kd = kp_n[tab_].astype(q_.dtype)
             vd = vp_n[tab_].astype(q_.dtype)
         # Own-head dense prefix view [b, hh, L, d] — this is the block
         # that circulates ("ring-passed paged K/V").
-        kd = kd.transpose(0, 2, 1, 3, 4).reshape(b, hh, L, d)
-        vd = vd.transpose(0, 2, 1, 3, 4).reshape(b, hh, L, d)
+        from nezha_tpu.models.gpt2 import _pool_view
+        kd = _pool_view(kd, hh)
+        vd = _pool_view(vd, hh)
 
         # Chunk part: parallel/ring.py's online-softmax hop fold over
         # the fresh seq-sharded operands (all heads, local queries).
@@ -426,16 +422,16 @@ def _ring(q, k, v, kp, vp, tab, starts, block_scales, shard_map, mesh,
     if quant:
         ks, vs = block_scales
         f = shard_map(body, mesh=mesh,
-                      in_specs=(sspec, sspec, sspec, hspec, hspec, rep,
+                      in_specs=(sspec, sspec, sspec, pspec, pspec, rep,
                                 rep, hspec, hspec),
-                      out_specs=(sspec, hspec, hspec, hspec, hspec,
+                      out_specs=(sspec, pspec, pspec, hspec, hspec,
                                  rep))
         out, kp_n, vp_n, ks_n, vs_n, qerr = f(q, k, v, kp, vp, tab,
                                               starts, ks, vs)
         return out, kp_n, vp_n, ks_n, vs_n, qerr
     f = shard_map(body, mesh=mesh,
-                  in_specs=(sspec, sspec, sspec, hspec, hspec, rep,
+                  in_specs=(sspec, sspec, sspec, pspec, pspec, rep,
                             rep),
-                  out_specs=(sspec, hspec, hspec))
+                  out_specs=(sspec, pspec, pspec))
     out, kp_n, vp_n = f(q, k, v, kp, vp, tab, starts)
     return out, kp_n, vp_n, None, None, None

@@ -9,7 +9,10 @@ Two layouts share one slot-level contract (``alloc``/``free``/
   concurrency: a 10-token request holds the same rows as a full one.
 - :class:`PagedSlotPool` — the block-paged layout
   (``ServeConfig.kv_layout="paged"``, the default): per-layer K/V
-  buffers shaped ``[num_blocks, H, block_size, D]``, a host-side free
+  buffers shaped ``[num_blocks, block_size, H*D]`` (lane-dense rows:
+  one row a position, its heads side by side in lanes, which is the
+  device's own row-major layout, so no program copies a pool between
+  layouts; PERF.md section 6, PR 27), a host-side free
   list of blocks with REF COUNTS, and a per-slot block table
   (``[max_blocks_per_row]`` int32) threaded into the compiled programs.
   Admission binds only the blocks the prompt needs and decode binds
@@ -93,6 +96,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from nezha_tpu import faults, obs
+from nezha_tpu.ops import quant
 
 
 class KVBlocksExhausted(RuntimeError):
@@ -379,7 +383,7 @@ class PrefixTrie:
 
 def _copy_block(caches: list, src, dst) -> list:
     """Device-side block copy across every layer's K and V pool:
-    ``caches[l][kv] [N, H, bs, D]`` with block ``src`` copied over
+    ``caches[l][kv] [N, bs, H*D]`` with block ``src`` copied over
     block ``dst``. The COW move. Leading-axis tree_map means every
     block-indexed leaf moves together — int8 pools' ``[N, H]`` scale
     rows copy with their blocks in the same call (the "a block and its
@@ -408,28 +412,42 @@ _copy_block_jit = jax.jit(_copy_block, donate_argnums=(0,))
 # migration is not a serving program. jax.jit keys on the index shape,
 # so one program per distinct block count — block counts are small and
 # bounded by blocks_per_slot.
+# The WIRE carries per-head tiles, ``[n, H, bs, D]`` int8 + ``[n, H]``
+# scales (serve/migrate.py; the host tier keeps the same arrays), as it
+# did when the pool itself was ``[N, H, bs, D]``. The pool is lane-dense
+# now (``[N, bs, H*D]``), so export splits the heads out of the rows and
+# install merges them back: a transpose of a few blocks, in programs
+# that are not serving programs. Old payloads and other replicas' pulls
+# install unchanged.
 def _gather_blocks_quantized(caches, idx):
-    """int8 pool -> wire: the blocks ARE the wire format already (int8
-    data + fp32 per-(block, head) scales), so export is a pure gather —
-    a migrated block lands on the destination bit-identical."""
-    return [{k: jnp.take(layer[k], idx, axis=0)
-             for k in ("k", "v", "k_scale", "v_scale")}
-            for layer in caches]
+    """int8 pool -> wire: the int8 values and fp32 per-(block, head)
+    scales ARE the wire's (only the layout differs), so export is a
+    gather — a migrated block lands on the destination bit-identical."""
+    out = []
+    for layer in caches:
+        heads = layer["k_scale"].shape[1]
+        entry = {}
+        for kv in ("k", "v"):
+            entry[kv] = quant.split_heads(
+                jnp.take(layer[kv], idx, axis=0), heads)
+            entry[f"{kv}_scale"] = jnp.take(layer[f"{kv}_scale"], idx,
+                                            axis=0)
+        out.append(entry)
+    return out
 
 
-def _gather_quantize_blocks(caches, idx):
+def _gather_quantize_blocks(caches, idx, heads):
     """bf16/f32 pool -> wire: gather the blocks and quantize them to
     the int8+scales wire format (ops/quant.py — the EQuARX recipe the
     wire collectives use, ~4x fewer bytes than bf16). Lossy at the
     quantizer's amax/254 per-block bound; int8 pools take the lossless
     path above."""
-    from nezha_tpu.ops import quant
     out = []
     for layer in caches:
         entry = {}
         for kv in ("k", "v"):
-            q, s = quant.quantize_kv_block(
-                jnp.take(layer[kv], idx, axis=0))
+            q, s = quant.quantize_kv_block(quant.split_heads(
+                jnp.take(layer[kv], idx, axis=0), heads))
             entry[kv] = q
             entry[f"{kv}_scale"] = s
         out.append(entry)
@@ -439,28 +457,35 @@ def _gather_quantize_blocks(caches, idx):
 def _scatter_blocks_quantized(caches, idx, payload):
     """Wire -> int8 pool: write int8 blocks + scale rows verbatim at
     the freshly allocated (ref == 1) indices."""
-    return [{k: layer[k].at[idx].set(pay[k].astype(layer[k].dtype))
-             for k in layer}
-            for layer, pay in zip(caches, payload)]
+    out = []
+    for layer, pay in zip(caches, payload):
+        new = dict(layer)
+        for kv in ("k", "v"):
+            new[kv] = layer[kv].at[idx].set(
+                quant.merge_heads(pay[kv]).astype(layer[kv].dtype))
+            sc = f"{kv}_scale"
+            new[sc] = layer[sc].at[idx].set(pay[sc].astype(layer[sc].dtype))
+        out.append(new)
+    return out
 
 
 def _scatter_blocks_dequant(caches, idx, payload):
     """Wire -> bf16/f32 pool: dequantize the int8 blocks to the pool
     dtype and write them at the freshly allocated indices."""
-    from nezha_tpu.ops import quant
     out = []
     for layer, pay in zip(caches, payload):
         new = dict(layer)
         for kv in ("k", "v"):
             blk = quant.dequantize_kv_block(
                 pay[kv], pay[f"{kv}_scale"], layer[kv].dtype)
-            new[kv] = layer[kv].at[idx].set(blk)
+            new[kv] = layer[kv].at[idx].set(quant.merge_heads(blk))
         out.append(new)
     return out
 
 
 _gather_blocks_quantized_jit = jax.jit(_gather_blocks_quantized)
-_gather_quantize_blocks_jit = jax.jit(_gather_quantize_blocks)
+_gather_quantize_blocks_jit = jax.jit(_gather_quantize_blocks,
+                                      static_argnums=(2,))
 _scatter_blocks_quantized_jit = jax.jit(_scatter_blocks_quantized,
                                         donate_argnums=(0,))
 _scatter_blocks_dequant_jit = jax.jit(_scatter_blocks_dequant,
@@ -471,7 +496,9 @@ class PagedSlotPool:
     """Block-paged KV pool: ref-counted blocks + per-slot block tables.
 
     Device state: ``caches`` (per-layer ``{"k", "v"}`` pools shaped
-    ``[num_blocks, H, block_size, D]``) and — uploaded per dispatch from
+    ``[num_blocks, block_size, H*D]``: lane-dense rows, a position's
+    heads side by side, head ``h`` in lanes ``h*D .. (h+1)*D``) and —
+    uploaded per dispatch from
     the host mirror — ``tables_host`` (``[capacity, blocks_per_slot]``
     int32; entry ``[s, i]`` is the pool block holding slot ``s``'s
     positions ``[i*bs, (i+1)*bs)``, or 0/scratch when unbound). Host
@@ -555,8 +582,8 @@ class PagedSlotPool:
         # The model DECLARES its per-layer cache leaves (name -> trailing
         # shape and dtype of one block); the pool allocates
         # ``[num_blocks, ...]`` of each and does its byte accounting from
-        # the same declaration. GPT-2: per-head ``k`` / ``v``
-        # ``[N, H, bs, D]`` and, with ``ServeConfig.kv_dtype="int8"``,
+        # the same declaration. GPT-2: lane-dense ``k`` / ``v`` rows
+        # ``[N, bs, H*D]`` and, with ``ServeConfig.kv_dtype="int8"``,
         # int8 blocks plus one fp32 absmax scale per (block, head) — the
         # ``[num_blocks, H]`` scale buffers ride IN the caches pytree, so
         # everything that moves a block (program donation, COW copy,
@@ -577,9 +604,15 @@ class PagedSlotPool:
             math.prod(shape) * jnp.dtype(dt).itemsize
             for shape, dt in leaves.values())
         # Migration, peer pulls and the host tier speak one wire format:
-        # int8 K/V blocks + per-(block, head) scales. A pool of other
-        # leaves has none.
+        # int8 K/V blocks as per-head tiles ``[n, H, bs, D]`` +
+        # per-(block, head) scales ``[n, H]``. A pool of other leaves
+        # has none.
         self.kv_wire = {"k", "v"} <= set(leaves)
+        self.wire_block_shape: Optional[Tuple[int, int, int]] = None
+        if self.kv_wire:
+            heads = model.cfg.num_heads
+            self.wire_block_shape = (
+                heads, block_size, leaves["k"][0][-1] // heads)
         self.tables_host = np.zeros((capacity, self.blocks_per_slot),
                                     np.int32)
         self._free_slots: List[int] = list(range(capacity - 1, -1, -1))
@@ -595,7 +628,8 @@ class PagedSlotPool:
         # payloads, LRU-ordered (oldest first), keyed by the FULL
         # prompt-prefix token path that block's K/V encodes. One entry
         # is one block: per-layer {"k","v","k_scale","v_scale"} host
-        # arrays shaped [1, H, bs, D] / [1, H].
+        # arrays shaped [1, H, bs, D] / [1, H] (the wire's per-head
+        # tiles, not the pool's rows).
         self.host_blocks = host_blocks
         self._host_tier: "collections.OrderedDict[Tuple[int, ...], list]" \
             = collections.OrderedDict()
@@ -1101,7 +1135,8 @@ class PagedSlotPool:
         if self.quantized:
             layers = _gather_blocks_quantized_jit(self.caches, idx)
         else:
-            layers = _gather_quantize_blocks_jit(self.caches, idx)
+            layers = _gather_quantize_blocks_jit(
+                self.caches, idx, self.wire_block_shape[0])
         host = [{k: np.asarray(v) for k, v in layer.items()}
                 for layer in layers]
         nbytes = sum(a.nbytes for layer in host for a in layer.values())
@@ -1163,7 +1198,8 @@ class PagedSlotPool:
             if self.quantized:
                 layers = _gather_blocks_quantized_jit(self.caches, idx)
             else:
-                layers = _gather_quantize_blocks_jit(self.caches, idx)
+                layers = _gather_quantize_blocks_jit(
+                    self.caches, idx, self.wire_block_shape[0])
             host = [{k: np.asarray(v) for k, v in layer.items()}
                     for layer in layers]
         if host_entries:
@@ -1214,7 +1250,7 @@ class PagedSlotPool:
                 f"payload carries {nblocks} block(s) but only "
                 f"{len(tokens)} token(s) key them "
                 f"(block_size {bs})")
-        shape = tuple(self.caches[0]["k"].shape[1:])
+        shape = self.wire_block_shape
         got = tuple(layers[0]["k"].shape[1:])
         if len(layers) != len(self.caches) or got != shape:
             raise ValueError(
@@ -1280,14 +1316,13 @@ class PagedSlotPool:
                             f"layer {li} {kv} pool dtype drifted to "
                             f"{layer[kv].dtype} (expected int8)")
                     sc = layer.get(f"{kv}_scale")
-                    if sc is None or tuple(sc.shape) != (
-                            self.num_blocks, layer[kv].shape[1]):
+                    want = (self.num_blocks, self.wire_block_shape[0])
+                    if sc is None or tuple(sc.shape) != want:
                         raise AssertionError(
                             f"layer {li} {kv}_scale buffer missing or "
                             f"mis-shaped: "
                             f"{None if sc is None else sc.shape} "
-                            f"(expected [{self.num_blocks}, "
-                            f"{layer[kv].shape[1]}])")
+                            f"(expected {list(want)})")
         # Host-tier column of the oracle: entry count within budget,
         # byte books balanced, every entry shaped like this pool's
         # blocks and keyed by a whole number of full blocks. A drift
@@ -1304,7 +1339,7 @@ class PagedSlotPool:
                 raise AssertionError(
                     f"host tier byte books off: {self._host_bytes} "
                     f"recorded, {nbytes} resident")
-            shape = tuple(self.caches[0]["k"].shape[1:])
+            shape = self.wire_block_shape
             for key, entry in self._host_tier.items():
                 if len(key) % self.block_size or \
                         len(key) // self.block_size == 0:

@@ -141,6 +141,42 @@ def test_quant_nonfinite_inputs_saturate_deterministically():
     assert np.isfinite(float(quant.kv_roundtrip_error(bad)))
 
 
+@pytest.mark.parametrize("h,d", [(4, 16), (12, 64)], ids=["hd64", "hd768"])
+@pytest.mark.parametrize("fill", ["normal", "zeros", "nonfinite"])
+def test_row_layout_policy_is_the_tile_policy_bit_for_bit(fill, h, d):
+    """The paged pool stores lane-dense rows ``[n, bs, H*D]`` (PR 27);
+    ``quantize_kv_rows`` / ``dequantize_kv_rows`` are the per-head tile
+    policy above (still the WIRE's form) over that layout: same int8
+    values, same per-(block, head) scales, same dequantized values,
+    through ``split_heads`` / ``merge_heads`` — at a width under one
+    128-lane tile and at GPT-2's 768, zero blocks and non-finite inputs
+    included."""
+    rng = np.random.default_rng(1)
+    tiles = rng.normal(size=(5, h, 8, d)).astype(np.float32) * 2.0
+    if fill == "zeros":
+        tiles[1:3] = 0.0
+    elif fill == "nonfinite":
+        tiles[0, 0, 0, :3] = (np.nan, np.inf, -np.inf)
+    tiles = jnp.asarray(tiles)
+    rows = quant.merge_heads(tiles)
+    assert rows.shape == (5, 8, h * d)
+    assert np.array_equal(np.asarray(quant.split_heads(rows, h)),
+                          np.asarray(tiles), equal_nan=True)
+    # head g of a position sits in lanes g*d .. (g+1)*d
+    assert np.array_equal(np.asarray(rows)[:, :, d:2 * d],
+                          np.asarray(tiles)[:, 1], equal_nan=True)
+    qt, st = quant.quantize_kv_block(tiles)
+    qr, sr = quant.quantize_kv_rows(rows, h)
+    assert qr.dtype == jnp.int8 and sr.shape == (5, h)
+    assert np.array_equal(np.asarray(qr), np.asarray(quant.merge_heads(qt)))
+    assert np.array_equal(np.asarray(sr), np.asarray(st))
+    for dtype in (jnp.float32, jnp.bfloat16):
+        assert np.array_equal(
+            np.asarray(quant.dequantize_kv_rows(qr, sr, dtype), np.float32),
+            np.asarray(quant.merge_heads(
+                quant.dequantize_kv_block(qt, st, dtype)), np.float32))
+
+
 def test_wire_collectives_bit_identical_after_extraction():
     """The regression pin ISSUE 9 demands: parallel/quantized.py's
     quantize/dequantize (now imported from ops/quant.py) must be

@@ -90,6 +90,62 @@ def test_wire_codec_roundtrip_and_validation(tiny_model):
     eng.pool.leak_check()
 
 
+def test_wire_format_is_the_parents_and_round_trips_bit_exactly(tiny_model):
+    """The pool is lane-dense rows (``[N, bs, H*D]``, PR 27); the WIRE is
+    what ``serve/migrate.py`` wrote before: per-head tiles
+    ``[n, heads, bs, d]`` int8 + ``[n, heads]`` scales. Held against a
+    payload RECORDED from the parent commit's code (PR 26: same tiny
+    model, seed, prompt, int8 pool; ``tests/data/kv_wire_pr26_int8.json``):
+    today's export writes the same header and the same bytes; the old
+    payload installs into a fresh pool of today's layout; and a block
+    exported from that pool is the installed payload again, bit for
+    bit, as are the pool's own rows."""
+    import numpy as np
+    from nezha_tpu.ops.quant import merge_heads
+
+    with open(os.path.join(_ROOT, "tests", "data",
+                           "kv_wire_pr26_int8.json")) as f:
+        recorded = json.load(f)
+    assert (recorded["heads"], recorded["head_dim"], recorded["nblocks"],
+            recorded["block_size"]) == (4, 16, 2, 8)
+
+    src = _engine(tiny_model, kv_dtype="int8")
+    ss = Scheduler(src)
+    prompt = _prompt(21)
+    ss.submit(Request(prompt=prompt, max_new_tokens=4, request_id="w",
+                      prefill_only=True))
+    ss.run_until_idle()
+    wire = ss.export_parked("w")
+    assert wire == recorded            # header AND payload bytes
+    ss.ack_parked("w")
+    src.pool.leak_check()
+
+    # The parent's payload into a fresh pool of today's layout ...
+    tokens, layers, nbytes = migrate.decode_wire(recorded)
+    assert layers[0]["k"].shape == (2, 4, 8, 16)
+    assert layers[0]["k_scale"].shape == (2, 4)
+    dst = _engine(tiny_model, kv_dtype="int8")
+    sd = Scheduler(dst)
+    assert sd.install_migrated(tokens, layers, nbytes) == 2
+    pool = dst.pool
+    assert pool.caches[0]["k"].shape == (pool.num_blocks, 8, 4 * 16)
+    # ... lands as rows: head h of a position in lanes h*16 .. (h+1)*16
+    blocks = np.asarray(pool.trie.match(tokens))
+    for layer, pay in zip(pool.caches, layers):
+        for kv in ("k", "v"):
+            assert np.array_equal(np.asarray(layer[kv])[blocks],
+                                  np.asarray(merge_heads(pay[kv])))
+            assert np.array_equal(
+                np.asarray(layer[f"{kv}_scale"])[blocks],
+                pay[f"{kv}_scale"])
+    # ... and comes out again as the same wire, bit for bit.
+    covered, out, out_bytes = pool.export_prefix_payload(tokens)
+    assert covered == tokens and out_bytes == nbytes
+    again = migrate.encode_wire(covered, out, pool.block_size)
+    assert again == recorded
+    pool.leak_check()
+
+
 # ------------------------------------------------- scheduler lifecycle
 def test_park_export_install_ack_bf16(tiny_model):
     """The two-phase handoff at scheduler level: park on A, pull into

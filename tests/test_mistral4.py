@@ -163,7 +163,8 @@ def test_dropless_when_every_token_picks_the_same_expert():
     assert float(jnp.abs(y).min(axis=1).max()) > 0      # no zeroed token
 
 
-# (e) GPT-2's pool from its declaration is leaf for leaf what it was
+# (e) GPT-2's pool from its declaration: lane-dense K/V rows (PR 27; the
+# same bytes a block as the per-head tiles it replaced), scales per head
 @pytest.mark.parametrize("quantized", [False, True])
 def test_gpt2_pool_leaves_unchanged(quantized):
     model = GPT2(GPT2Config(vocab_size=64, max_positions=64, num_layers=3,
@@ -172,8 +173,8 @@ def test_gpt2_pool_leaves_unchanged(quantized):
                          quantized=quantized)
     n, h, bs, d = pool.num_blocks, 4, 16, 8
     assert n == 1 + 2 * 4 and len(pool.caches) == 3
-    want = {"k": ((n, h, bs, d), jnp.int8 if quantized else jnp.bfloat16),
-            "v": ((n, h, bs, d), jnp.int8 if quantized else jnp.bfloat16)}
+    want = {"k": ((n, bs, h * d), jnp.int8 if quantized else jnp.bfloat16),
+            "v": ((n, bs, h * d), jnp.int8 if quantized else jnp.bfloat16)}
     if quantized:
         want.update(k_scale=((n, h), jnp.float32), v_scale=((n, h), jnp.float32))
     for layer in pool.caches:
@@ -182,7 +183,7 @@ def test_gpt2_pool_leaves_unchanged(quantized):
             assert layer[name].shape == shape and layer[name].dtype == dt
     kv_bytes = h * bs * d * (1 if quantized else 2)
     assert pool.bytes_per_block == 2 * 3 * (kv_bytes + (h * 4 if quantized else 0))
-    assert pool.kv_wire
+    assert pool.kv_wire and pool.wire_block_shape == (h, bs, d)
 
 
 def test_latent_pool_leaves_and_bytes(tiny):

@@ -143,6 +143,7 @@ def test_flash_decode_block_table_operand_parity():
     matches the dense kernel over an explicit gather — including the
     per-row length skip (length 0 row stays exactly zero)."""
     from nezha_tpu.ops.pallas import flash_decode_attention
+    from nezha_tpu.ops.quant import merge_heads
 
     rng = np.random.default_rng(0)
     b, h, d, bs, m, n = 3, 2, 16, 8, 4, 10
@@ -151,11 +152,13 @@ def test_flash_decode_block_table_operand_parity():
     vp = jnp.asarray(rng.normal(size=(n, h, bs, d)), jnp.float32)
     tables = jnp.asarray(rng.integers(0, n, size=(b, m)), jnp.int32)
     lengths = jnp.asarray([0, 13, 32], jnp.int32)
-    paged = flash_decode_attention(q, kp, vp, lengths,
-                                   block_tables=tables)
     kd = kp[tables].transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
     vd = vp[tables].transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
     dense = flash_decode_attention(q, kd, vd, lengths)
+    # the pool itself is lane-dense rows [n, bs, H*D]
+    kp, vp = merge_heads(kp), merge_heads(vp)
+    paged = flash_decode_attention(q, kp, vp, lengths,
+                                   block_tables=tables)
     np.testing.assert_allclose(np.asarray(paged), np.asarray(dense),
                                atol=1e-5)
     assert np.all(np.asarray(paged)[0] == 0.0)   # inactive row
@@ -172,13 +175,19 @@ def _paged_case(kind, m, seed=0, b=8, h=3, d=16, bs=8):
     row's blocks are in order) whose lengths sit on every edge of the
     kernel's iteration space: empty, one position, one block, one block
     and one, one grid step (c entries), one step and one, the full
-    table less one, the full table. Returns the kernel's operands and
-    the composed path's answer (gather, dequantize, masked dense
-    attention in float32 over the same stored values)."""
+    table less one, the full table. Returns the kernel's operands (the
+    pools as lane-dense rows ``[n, bs, H*D]``; the reference below
+    thinks in per-head tiles and ``merge_heads`` converts, in this one
+    place) and the composed path's answer (gather, dequantize, masked
+    dense attention in float32 over the same stored values)."""
     from nezha_tpu import ops
     from nezha_tpu.ops.pallas import decode_attention as da
     from nezha_tpu.ops.pallas.common import pick_block
-    from nezha_tpu.ops.quant import dequantize_kv_block, quantize_kv_block
+    from nezha_tpu.ops.quant import (
+        dequantize_kv_block,
+        merge_heads,
+        quantize_kv_block,
+    )
 
     c = pick_block(m, da._ENTRIES_PER_STEP)
     lengths = np.minimum([0, 1, bs, bs + 1, c * bs, c * bs + 1,
@@ -210,8 +219,8 @@ def _paged_case(kind, m, seed=0, b=8, h=3, d=16, bs=8):
     owned = np.zeros(n, bool)
     for row, length in zip(tables, lengths):
         owned[row[:-(-int(length) // bs)]] = True
-    return (q, kp, vp, jnp.asarray(lengths), jnp.asarray(tables), scales,
-            ref, owned)
+    return (q, merge_heads(kp), merge_heads(vp), jnp.asarray(lengths),
+            jnp.asarray(tables), scales, ref, owned)
 
 
 # bf16 tiles dot in bf16 (scores and P both rounded to 8 bits of
@@ -219,17 +228,21 @@ def _paged_case(kind, m, seed=0, b=8, h=3, d=16, bs=8):
 _PAGED_TOL = {"f32": 2e-6, "int8": 2e-6, "bf16": 3e-2}
 
 
-@pytest.mark.parametrize("m", [16, 12, 3],
-                         ids=["m16", "m12-not-a-multiple", "m3-one-step"])
+@pytest.mark.parametrize("m,h,d", [(16, 3, 16), (12, 3, 16), (3, 3, 16),
+                                   (16, 12, 64)],
+                         ids=["m16", "m12-not-a-multiple", "m3-one-step",
+                              "m16-hd768"])
 @pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
-def test_paged_decode_kernel_matches_composed_on_every_edge(kind, m):
+def test_paged_decode_kernel_matches_composed_on_every_edge(kind, m, h, d):
     """The paged kernel (interpret mode) against the composed path, all
     edge lengths mixed in one batch: M a multiple of the module's
     entries-per-step constant, M that is not (the largest divisor
-    steps it: 12 -> 6 or 4), and a table shorter than one step."""
+    steps it: 12 -> 6 or 4), and a table shorter than one step, at a
+    pool width under one 128-lane tile (48) and at GPT-2's (768)."""
     from nezha_tpu.ops.pallas import flash_decode_attention
 
-    q, kp, vp, lengths, tables, scales, ref, _ = _paged_case(kind, m)
+    q, kp, vp, lengths, tables, scales, ref, _ = _paged_case(kind, m, h=h,
+                                                             d=d)
     out = np.asarray(flash_decode_attention(
         q, kp, vp, lengths, block_tables=tables, block_scales=scales,
         interpret=True), np.float32)
@@ -250,7 +263,7 @@ def test_paged_decode_kernel_reads_no_block_a_row_does_not_own(kind):
         scales = tuple(jnp.where(owned[:, None], sc, jnp.nan)
                        for sc in scales)
     else:
-        kp, vp = (jnp.where(owned[:, None, None, None], pool, jnp.nan)
+        kp, vp = (jnp.where(owned[:, None, None], pool, jnp.nan)
                   for pool in (kp, vp))
     out = np.asarray(flash_decode_attention(
         q, kp, vp, lengths, block_tables=tables, block_scales=scales,

@@ -103,6 +103,25 @@ def _greedy(engine, prompts=PROMPTS, max_new=6):
 
 
 # --------------------------------------------------- kernel-level refs
+# The references below think in per-head tiles ``[n, H, bs, D]`` (the
+# wire's form); the pool the kernels take is lane-dense rows
+# ``[n, bs, H*D]``. ONE pair of helpers converts, the library's own.
+def _rows(tiles, dtype=None):
+    """Per-head tiles ``[n, H, bs, D]`` -> the pool's rows."""
+    return quant.merge_heads(jnp.asarray(tiles, dtype))
+
+
+def _tiles(rows, h):
+    """The pool's rows -> per-head tiles (numpy)."""
+    return np.asarray(quant.split_heads(jnp.asarray(rows), h))
+
+
+# (heads, head_dim): 64 lanes (under one 128-lane tile: a step holds all
+# heads) and GPT-2's 768 (a step holds the two heads of a lane block).
+WIDTHS = [(4, 16), (12, 64)]
+WIDTH_IDS = ["hd64", "hd768"]
+
+
 def _ref_attn(q, k_all, v_all, starts, s_chunk):
     """Dense masked reference: rows attend their pool prefix plus the
     causal part of their own chunk."""
@@ -169,19 +188,21 @@ def _gather(pool_k, pool_v, tab, starts, kc, vc, bs, m, s_chunk,
     return k_all, v_all
 
 
+@pytest.mark.parametrize("h,d", WIDTHS, ids=WIDTH_IDS)
 @pytest.mark.parametrize("starts", [(0, 0), (8, 24), (5, 13)],
                          ids=["cold", "block-aligned", "mid-block"])
-def test_kernel_matches_masked_reference_f32(starts):
+def test_kernel_matches_masked_reference_f32(starts, h, d):
     """One compiled shape serves cold prefills, chunked continuations
     (block-aligned starts), and shared-prefix partial prefills
-    (mid-block starts) — all within 1e-5 of the dense masked path."""
+    (mid-block starts) — all within 1e-5 of the dense masked path,
+    at a pool width under one lane tile and at GPT-2's."""
     rng = np.random.RandomState(0)
     bs, m, s_chunk = 8, 12, 16
-    q, kc, vc, pk, pv, tab = _case(rng, starts, bs=bs, m=m,
+    q, kc, vc, pk, pv, tab = _case(rng, starts, h=h, d=d, bs=bs, m=m,
                                    s_chunk=s_chunk)
     out = flash_prefill_attention(
         jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
-        jnp.asarray(pk), jnp.asarray(pv), jnp.asarray(tab),
+        _rows(pk), _rows(pv), jnp.asarray(tab),
         jnp.asarray(starts, jnp.int32), interpret=True)
     k_all, v_all = _gather(pk, pv, tab, starts, kc, vc, bs, m, s_chunk)
     ref = _ref_attn(q, k_all, v_all, starts, s_chunk)
@@ -200,8 +221,9 @@ def test_kernel_matches_masked_reference_bf16():
     to_bf = lambda x: jnp.asarray(x, jnp.bfloat16)
     back = lambda x: np.asarray(jnp.asarray(to_bf(x), jnp.float32))
     out = flash_prefill_attention(
-        to_bf(q), to_bf(kc), to_bf(vc), to_bf(pk), to_bf(pv),
-        jnp.asarray(tab), jnp.asarray(starts, jnp.int32), interpret=True)
+        to_bf(q), to_bf(kc), to_bf(vc), _rows(pk, jnp.bfloat16),
+        _rows(pv, jnp.bfloat16), jnp.asarray(tab),
+        jnp.asarray(starts, jnp.int32), interpret=True)
     k_all, v_all = _gather(back(pk), back(pv), tab, starts, back(kc),
                            back(vc), bs, m, s_chunk)
     ref = _ref_attn(back(q), k_all, v_all, starts, s_chunk)
@@ -214,9 +236,10 @@ def test_kernel_matches_masked_reference_bf16():
         np.asarray(jnp.asarray(out, jnp.float32)), ref, atol=2e-2)
 
 
+@pytest.mark.parametrize("h,d", WIDTHS, ids=WIDTH_IDS)
 @pytest.mark.parametrize("starts", [(0, 0), (5, 13)],
                          ids=["cold", "mid-block"])
-def test_int8_fused_write_matches_quant_policy(starts):
+def test_int8_fused_write_matches_quant_policy(starts, h, d):
     """The epilogue write IS ``_quant_prefill_write``: merged
     old-prefix/fresh-chunk rows, stale positions zeroed, sanitize,
     fresh per-(block, head) scales via the exact ``quantize_kv_block``
@@ -228,7 +251,7 @@ def test_int8_fused_write_matches_quant_policy(starts):
     block 0 is zeroed with unit scales."""
     rng = np.random.RandomState(2)
     bs, m, s_chunk = 8, 12, 16
-    q, kc, vc, pk_f, pv_f, tab = _case(rng, starts, bs=bs, m=m,
+    q, kc, vc, pk_f, pv_f, tab = _case(rng, starts, h=h, d=d, bs=bs, m=m,
                                        s_chunk=s_chunk, extra_blocks=1)
     pk = rng.randint(-127, 128, pk_f.shape).astype(np.int8)
     pv = rng.randint(-127, 128, pv_f.shape).astype(np.int8)
@@ -238,10 +261,11 @@ def test_int8_fused_write_matches_quant_policy(starts):
         np.float32)
     out, kp_n, vp_n, ks_n, vs_n, qerr = flash_prefill_attention(
         jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
-        jnp.asarray(pk), jnp.asarray(pv), jnp.asarray(tab),
+        _rows(pk), _rows(pv), jnp.asarray(tab),
         jnp.asarray(starts, jnp.int32),
         block_scales=(jnp.asarray(ks), jnp.asarray(vs)), interpret=True)
-    kp_n, vp_n, ks_n, vs_n = map(np.asarray, (kp_n, vp_n, ks_n, vs_n))
+    kp_n, vp_n = _tiles(kp_n, h), _tiles(vp_n, h)
+    ks_n, vs_n = np.asarray(ks_n), np.asarray(vs_n)
 
     exp_kp, exp_vp = pk.copy(), pv.copy()
     exp_ks, exp_vs = ks.copy(), vs.copy()
@@ -302,10 +326,10 @@ def test_sharded_mesh2_matches_unsharded():
     mesh = make_mesh({"tp": 2}, devices=jax.devices()[:2])
     args = (jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc))
     ref = flash_prefill_attention(
-        *args, jnp.asarray(pk), jnp.asarray(pv), jnp.asarray(tab),
+        *args, _rows(pk), _rows(pv), jnp.asarray(tab),
         jnp.asarray(starts, jnp.int32), interpret=True)
     got = flash_prefill_attention_sharded(
-        *args, jnp.asarray(pk), jnp.asarray(pv), jnp.asarray(tab),
+        *args, _rows(pk), _rows(pv), jnp.asarray(tab),
         jnp.asarray(starts, jnp.int32), mesh, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-6, atol=1e-6)
@@ -316,7 +340,7 @@ def test_sharded_mesh2_matches_unsharded():
         np.float32)
     vs = (np.abs(rng.randn(*pv.shape[:2])) * 0.02 + 0.01).astype(
         np.float32)
-    q8 = (jnp.asarray(pk8), jnp.asarray(pv8), jnp.asarray(tab),
+    q8 = (_rows(pk8), _rows(pv8), jnp.asarray(tab),
           jnp.asarray(starts, jnp.int32))
     scales = (jnp.asarray(ks), jnp.asarray(vs))
     ref8 = flash_prefill_attention(*args, *q8, block_scales=scales,
@@ -345,10 +369,10 @@ def test_q_offsets_full_chunk_bitwise_default(starts):
     q, kc, vc, pk, pv, tab = _case(rng, starts, bs=bs, m=m,
                                    s_chunk=s_chunk)
     A, st32 = jnp.asarray, jnp.asarray(starts, jnp.int32)
-    ref = flash_prefill_attention(A(q), A(kc), A(vc), A(pk), A(pv),
-                                  A(tab), st32, interpret=True)
-    got = flash_prefill_attention(A(q), A(kc), A(vc), A(pk), A(pv),
-                                  A(tab), st32, q_offsets=st32,
+    ref = flash_prefill_attention(A(q), A(kc), A(vc), _rows(pk),
+                                  _rows(pv), A(tab), st32, interpret=True)
+    got = flash_prefill_attention(A(q), A(kc), A(vc), _rows(pk),
+                                  _rows(pv), A(tab), st32, q_offsets=st32,
                                   interpret=True)
     assert np.array_equal(np.asarray(got), np.asarray(ref))
 
@@ -368,12 +392,13 @@ def test_q_offsets_shard_slices_bitwise(starts):
                                    s_chunk=s_chunk)
     A, st32 = jnp.asarray, jnp.asarray(starts, jnp.int32)
     full = np.asarray(flash_prefill_attention(
-        A(q), A(kc), A(vc), A(pk), A(pv), A(tab), st32, interpret=True))
+        A(q), A(kc), A(vc), _rows(pk), _rows(pv), A(tab), st32,
+        interpret=True))
     half = s_chunk // 2
     for k in range(2):
         got = flash_prefill_attention(
-            A(q[:, :, k * half:(k + 1) * half]), A(kc), A(vc), A(pk),
-            A(pv), A(tab), st32, q_offsets=st32 + k * half,
+            A(q[:, :, k * half:(k + 1) * half]), A(kc), A(vc), _rows(pk),
+            _rows(pv), A(tab), st32, q_offsets=st32 + k * half,
             interpret=True)
         assert np.array_equal(np.asarray(got),
                               full[:, :, k * half:(k + 1) * half])
@@ -394,7 +419,7 @@ def test_q_offsets_rejects_int8_pools():
     A, st32 = jnp.asarray, jnp.asarray(starts, jnp.int32)
     with pytest.raises(ValueError, match="float path"):
         flash_prefill_attention(
-            A(q), A(kc), A(vc), A(pk8), A(pv8), A(tab), st32,
+            A(q), A(kc), A(vc), _rows(pk8), _rows(pv8), A(tab), st32,
             block_scales=(A(ks), A(vs)), q_offsets=st32, interpret=True)
 
 

@@ -438,18 +438,23 @@ def test_flash_decode_sharded_matches_unsharded_kernel():
     vp = jax.random.normal(kv2, (nblk, h, bs, d), jnp.float32)
     tables = jax.random.randint(ks, (b, 4), 1, nblk).astype(jnp.int32)
     lengths = jnp.asarray([5, 0, 17], jnp.int32)
-    ref = flash_decode_attention(q, kp, vp, lengths,
-                                 block_tables=tables, interpret=True)
+    # the pool is lane-dense rows [n, bs, H*D]: a shard is the lanes of
+    # H / tp contiguous heads
+    from nezha_tpu.ops.quant import merge_heads, quantize_kv_block
+    ref = flash_decode_attention(q, merge_heads(kp), merge_heads(vp),
+                                 lengths, block_tables=tables,
+                                 interpret=True)
     mesh = make_mesh({"tp": 2}, devices=jax.devices()[:2])
-    got = flash_decode_attention_sharded(q, kp, vp, lengths, mesh,
+    got = flash_decode_attention_sharded(q, merge_heads(kp),
+                                         merge_heads(vp), lengths, mesh,
                                          block_tables=tables,
                                          interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-6, atol=1e-6)
     # Int8 pools: scale rows shard with their heads.
-    from nezha_tpu.ops.quant import quantize_kv_block
     kq8, ksc = quantize_kv_block(kp)
     vq8, vsc = quantize_kv_block(vp)
+    kq8, vq8 = merge_heads(kq8), merge_heads(vq8)
     ref8 = flash_decode_attention(q, kq8, vq8, lengths,
                                   block_tables=tables,
                                   block_scales=(ksc, vsc),

@@ -83,7 +83,7 @@ CELL_BATCH, CELL_POOL = 256, 16385
 
 
 def _decode(pool_dtype, batch=BATCH, blocks=POOL):
-    pool = ((blocks, H, BLOCK, D), pool_dtype)
+    pool = ((blocks, BLOCK, H * D), pool_dtype)     # lane-dense rows
     args = [((batch, H, 1, D), BF16), pool, pool, ((batch,), jnp.int32),
             ((batch, TABLE), jnp.int32)]
     if pool_dtype == jnp.int8:
@@ -95,7 +95,7 @@ def _decode(pool_dtype, batch=BATCH, blocks=POOL):
 
 def _prefill(pool_dtype, chunk):
     q = ((1, H, chunk, D), BF16)
-    pool = ((POOL, H, BLOCK, D), pool_dtype)
+    pool = ((POOL, BLOCK, H * D), pool_dtype)
     args = [q, q, q, pool, pool, ((1, TABLE), jnp.int32),
             ((1,), jnp.int32)]
     if pool_dtype == jnp.int8:
@@ -242,10 +242,16 @@ def test_nested_shard_map_kernel_compiles_for_v5e_mesh4(v5e_devices, case):
     mesh = Mesh(np.array(v5e_devices).reshape(4), ("tp",))
     decode = sharded is flash_decode_attention_sharded
 
-    # int32 operands (lengths / tables / starts) replicate; q, chunks,
-    # pools and scales shard on the head axis.
+    # int32 operands (lengths / tables / starts) replicate; q, chunks
+    # and scales shard on the head axis, the pools ([N, bs, H*D]) on the
+    # lanes: H / 4 contiguous heads a shard.
+    def spec(shape, dtype):
+        if dtype == jnp.int32:
+            return P()
+        return P(None, None, "tp") if len(shape) == 3 else P(None, "tp")
+
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(
-                mesh, P() if dtype == jnp.int32 else P(None, "tp")))
+                mesh, spec(shape, dtype)))
             for shape, dtype in shapes]
     if decode:
         def fn(q, k, v, lens, tab, *sc):
@@ -257,6 +263,100 @@ def test_nested_shard_map_kernel_compiles_for_v5e_mesh4(v5e_devices, case):
                            block_scales=sc or None, interpret=False)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---- GPT-2's serve programs at gpt2-124m.batch-gen's deployment (PR 27):
+# the ENGINE's step and 256-token prefill programs, one layer deep, 256
+# slots, table 64, block 16, the default pool of 16,385 blocks. The K/V
+# pool is lane-dense (``[N, bs, H*D]``: whole 128-lane tiles, the device's
+# own row-major layout), so the parameter, the scatter that writes a token
+# and both paged kernels take ONE layout and nothing re-lays the pool out.
+# Before PR 27 (``[N, H, bs, D]``, a 64-wide minor dimension) every program
+# copied each pool three times: 86.7% of the cell's device time.
+def _serve_programs(model, quantized, v5e, *, slots, table, block, chunk,
+                    logits):
+    """{"step" | "prefill": compiled program} of ``model`` for one v5e."""
+    from nezha_tpu.serve.engine import _build_prefill, _build_step
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=v5e)
+
+    variables = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    caches = [{name: spec((1 + slots * table,) + tuple(shape), dt)
+               for name, (shape, dt) in
+               model.cache_leaves(block, BF16, quantized).items()}
+              for _ in range(model.cfg.num_layers)]
+    tables = spec((slots, table), jnp.int32)
+    b = slots
+    state = (spec((b, logits), jnp.float32),
+             spec((b,), jnp.int32), spec((b, 2), jnp.uint32),
+             spec((b,), jnp.float32), spec((b,), jnp.int32),
+             spec((b,), jnp.float32), spec((b,), jnp.int32),
+             spec((b,), jnp.int32))
+    last, pos, keys, temps, top_ks, top_ps, eos, budgets = state
+    step = jax.jit(_build_step(model, 64, 0, 1, paged=True),
+                   donate_argnums=(1,)).lower(
+        variables, caches, tables, last, pos, spec((b,), jnp.bool_), keys,
+        temps, top_ks, top_ps, eos, budgets).compile()
+    i32, f32 = spec((), jnp.int32), spec((), jnp.float32)
+    prefill = jax.jit(_build_prefill(model, chunk, paged=True,
+                                     quantized=quantized),
+                      donate_argnums=(1,)).lower(
+        variables, caches, tables, spec((1, chunk), jnp.int32), i32, i32, i32,
+        i32, f32, i32, f32, i32, i32, *state).compile()
+    return {"step": step, "prefill": prefill}
+
+
+CELL_TABLE, CELL_CHUNK = MAX_LEN // BLOCK, 256
+
+
+@pytest.fixture(scope="module")
+def gpt2_programs(v5e):
+    """pool kind -> {"step" | "prefill": compiled program}, compiled once
+    for the tests below. ``auto`` takes the kernels on a TPU backend
+    only, and this process's backend is the CPU: the TEST says "tpu"
+    around the trace (no option of the program does)."""
+    from nezha_tpu.models.gpt2 import gpt2_124m
+
+    model = gpt2_124m(num_layers=1)
+    programs = {}
+
+    def of(kind):
+        if kind not in programs:
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(jax, "default_backend", lambda: "tpu")
+                programs[kind] = _serve_programs(
+                    model, kind == "int8", v5e, slots=CELL_BATCH,
+                    table=CELL_TABLE, block=BLOCK, chunk=CELL_CHUNK,
+                    logits=model.cfg.vocab_size)
+        return programs[kind]
+
+    return of
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_gpt2_serve_program_has_no_pool_shaped_copy(gpt2_programs, kind,
+                                                    program):
+    """The mechanism's "does it engage" reading, at compile time: no
+    ``copy`` (alone or as a fusion's root) whose result has the pool's
+    shape, in the step or the 256-token prefill program, for the bf16 and
+    the int8 pool; the paged kernel is in the program; and the program's
+    temporaries are smaller than ONE pool (a pool-shaped temporary is
+    what a re-layout costs in memory)."""
+    compiled = gpt2_programs(kind)[program]
+    text = compiled.as_text()
+    dt = "s8" if kind == "int8" else "bf16"
+    pool = re.escape(f"{dt}[{CELL_POOL},{BLOCK},{H * D}]")
+    assert re.search(pool, text)                # the lane-dense pool
+    assert not re.findall(r" = " + pool + r"\S* copy\(", text)
+    kernel = ("nezha_decode_attention_paged" if program == "step"
+              else "nezha_prefill_attention_paged")
+    assert re.search(r"%?" + kernel + r"\S* = .*custom-call\(", text)
+    pool_bytes = CELL_POOL * BLOCK * H * D * (1 if kind == "int8" else 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
 # ---- Mistral-Small-4's serve programs (PR 26): no Pallas kernel of their
@@ -271,38 +371,12 @@ M4_SLOTS, M4_MAX_LEN, M4_BLOCK, M4_CHUNK = 128, 4096, 64, 1024
 def mistral4_programs(v5e):
     """{"step" | "prefill": HLO text}: compiled once for the tests below."""
     from nezha_tpu.models.mistral4 import mistral_small4
-    from nezha_tpu.serve.engine import _build_prefill, _build_step
 
     model = mistral_small4("full", num_hidden_layers=1)
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=v5e)
-
-    variables = jax.tree_util.tree_map(
-        lambda a: spec(a.shape, a.dtype),
-        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    table = M4_MAX_LEN // M4_BLOCK
-    caches = [{name: spec((1 + M4_SLOTS * table,) + tuple(shape), dt)
-               for name, (shape, dt) in
-               model.cache_leaves(M4_BLOCK, BF16).items()}]
-    tables = spec((M4_SLOTS, table), jnp.int32)
-    b = M4_SLOTS
-    state = (spec((b, model.cfg.vocab_held), jnp.float32),
-             spec((b,), jnp.int32), spec((b, 2), jnp.uint32),
-             spec((b,), jnp.float32), spec((b,), jnp.int32),
-             spec((b,), jnp.float32), spec((b,), jnp.int32),
-             spec((b,), jnp.int32))
-    last, pos, keys, temps, top_ks, top_ps, eos, budgets = state
-    step = jax.jit(_build_step(model, 64, 0, 1, paged=True),
-                   donate_argnums=(1,)).lower(
-        variables, caches, tables, last, pos, spec((b,), jnp.bool_), keys,
-        temps, top_ks, top_ps, eos, budgets).compile()
-    i32, f32 = spec((), jnp.int32), spec((), jnp.float32)
-    prefill = jax.jit(_build_prefill(model, M4_CHUNK, paged=True),
-                      donate_argnums=(1,)).lower(
-        variables, caches, tables, spec((1, M4_CHUNK), jnp.int32), i32, i32, i32,
-        i32, f32, i32, f32, i32, i32, *state).compile()
-    return {"step": step.as_text(), "prefill": prefill.as_text()}
+    programs = _serve_programs(
+        model, False, v5e, slots=M4_SLOTS, table=M4_MAX_LEN // M4_BLOCK,
+        block=M4_BLOCK, chunk=M4_CHUNK, logits=model.cfg.vocab_held)
+    return {name: compiled.as_text() for name, compiled in programs.items()}
 
 
 @pytest.mark.parametrize("program", ["step", "prefill"])
