@@ -104,24 +104,18 @@ def build_parser() -> argparse.ArgumentParser:
                         "horizon — one engine + fresh warmup per value, "
                         "with per-horizon sub-records (and per-horizon "
                         "run-dir subdirectories h<N>/) in the output")
-    p.add_argument("--kv-layout", choices=["paged", "dense"],
-                   default="paged",
-                   help="KV pool layout: paged = block-paged pool with "
-                        "ref-counted blocks + prefix reuse (default); "
-                        "dense = classic worst-case per-slot "
-                        "reservation (the before/after knob)")
     p.add_argument("--kv-block-size", type=int, default=16,
-                   help="paged: tokens per KV block")
+                   help="tokens per KV block")
     p.add_argument("--kv-num-blocks", type=int, default=None,
-                   help="paged: total pool blocks (block 0 scratch); "
-                        "default = dense-equivalent capacity. Set it "
-                        "BELOW the dense equivalent to measure "
+                   help="total pool blocks (block 0 scratch); "
+                        "default = every slot can reach max_len. Set it "
+                        "BELOW that to measure "
                         "block-budget admission: concurrency then "
                         "tracks resident tokens, not slots")
     p.add_argument("--kv-dtype", choices=["bf16", "int8"],
                    default="bf16",
                    help="KV block storage: int8 stores blocks as int8 "
-                        "+ per-block fp32 scales (paged only) — the "
+                        "+ per-block fp32 scales — the "
                         "capacity-at-equal-memory knob; the record "
                         "reports peak resident bytes so equal-byte "
                         "budgets compare directly")
@@ -149,7 +143,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "(default 4 KV blocks); must be block-aligned "
                         "for the full prefix to be cacheable")
     p.add_argument("--prefix-cache", choices=["on", "off"], default="on",
-                   help="paged: shared-prefix prefill reuse on/off")
+                   help="shared-prefix prefill reuse on/off")
     p.add_argument("--shared-prefix-frac", type=float, default=0.0,
                    help="templated traffic: this fraction of requests "
                         "share one common prompt prefix — with the "
@@ -467,7 +461,7 @@ def _run_one(args, model, variables, decode_horizon: int,
         queue_capacity=args.queue_capacity, cache_dtype=jnp.bfloat16,
         decode_impl=args.decode_impl, decode_horizon=decode_horizon,
         prefill_impl=getattr(args, "prefill_impl", None),
-        kv_layout=args.kv_layout, kv_block_size=args.kv_block_size,
+        kv_block_size=args.kv_block_size,
         kv_num_blocks=args.kv_num_blocks,
         prefix_cache=args.prefix_cache == "on",
         kv_dtype=args.kv_dtype,
@@ -631,23 +625,22 @@ def _run_one(args, model, variables, decode_horizon: int,
             prompt=[(131 * j + 7 * i + 1) % vocab for i in range(n)],
             max_new_tokens=1, request_id=f"warmup-{j}"))
     sched.run_until_idle()
-    if engine.paged:
-        # Warmup must not leak into the measured record: drop its
-        # cached blocks (and any host-demoted ones) and zero the reuse
-        # counters so prefix_hit_rate, blocks-resident peaks, and the
-        # demote/promote ledgers describe the measured load only.
-        engine.pool.clear_prefix_cache()
-        engine.pool.prefix_hits = 0
-        engine.pool.cow_copies = 0
-        if engine.pool.host_blocks:
-            # Warm the demote/promote maintenance programs too — the
-            # first eviction-demotion or promote-hit of the measured
-            # load must not pay their compiles inside a TTFT window.
-            engine.pool.warm_host_tier_programs()
-            engine.pool.clear_host_tier()
-            engine.pool.demotions = 0
-            engine.pool.promotions = 0
-            engine.pool.promote_failures = 0
+    # Warmup must not leak into the measured record: drop its
+    # cached blocks (and any host-demoted ones) and zero the reuse
+    # counters so prefix_hit_rate, blocks-resident peaks, and the
+    # demote/promote ledgers describe the measured load only.
+    engine.pool.clear_prefix_cache()
+    engine.pool.prefix_hits = 0
+    engine.pool.cow_copies = 0
+    if engine.pool.host_blocks:
+        # Warm the demote/promote maintenance programs too — the
+        # first eviction-demotion or promote-hit of the measured
+        # load must not pay their compiles inside a TTFT window.
+        engine.pool.warm_host_tier_programs()
+        engine.pool.clear_host_tier()
+        engine.pool.demotions = 0
+        engine.pool.promotions = 0
+        engine.pool.promote_failures = 0
 
     # Chaos mode: a seeded probabilistic plan armed AFTER warmup (a
     # faulted warmup would skip compiling a bucket program) injecting
@@ -711,8 +704,9 @@ def _run_one(args, model, variables, decode_horizon: int,
     def _track_peaks():
         # The paged-pool occupancy claim: how many requests were
         # RESIDENT (decoding concurrently) and how many KV blocks that
-        # took — dense reserves worst-case rows, paged only what's
-        # written, so at equal device memory paged peaks strictly
+        # took — a worst-case reservation holds max_len rows a
+        # request, the paged pool only what's
+        # written, so at equal device memory it peaks strictly
         # higher on under-max_len traffic. The host-tier peak rides
         # along (0 without a tier).
         nonlocal peak_resident, peak_blocks, peak_host_blocks
@@ -838,19 +832,17 @@ def _run_one(args, model, variables, decode_horizon: int,
         "compile_cache": engine.compile_stats(),
         # Paged-pool occupancy record: resident-request and
         # blocks-resident peaks are THE concurrency-at-equal-memory
-        # comparison against a dense run (dense peaks at its slot
-        # count; paged at what the block budget admits).
+        # comparison (what the block budget admits against the
+        # budget // max_len a worst-case reservation would).
         "kv": {
-            "layout": args.kv_layout,
+            "layout": "paged",
             "dtype": args.kv_dtype,
             "block_size": args.kv_block_size,
-            "num_blocks": (engine.pool.num_blocks if engine.paged
-                           else None),
-            "bytes_per_block": (engine.pool.bytes_per_block
-                                if engine.paged else None),
+            "num_blocks": engine.pool.num_blocks,
+            "bytes_per_block": engine.pool.bytes_per_block,
             "prefix_cache": args.prefix_cache == "on",
-            "prefix_hits": getattr(engine.pool, "prefix_hits", 0),
-            "cow_copies": getattr(engine.pool, "cow_copies", 0),
+            "prefix_hits": engine.pool.prefix_hits,
+            "cow_copies": engine.pool.cow_copies,
             # Host spill tier (all 0 when --kv-host-blocks is off):
             # the demote/promote ledgers plus the tier's peak
             # occupancy — "promotions tracking demotions" is the
@@ -865,10 +857,8 @@ def _run_one(args, model, variables, decode_horizon: int,
             # Peak device bytes the resident KV held — the number the
             # int8-vs-bf16 equal-memory comparison is actually about
             # (blocks are not comparable across dtypes; bytes are).
-            "peak_bytes_resident": (
-                peak_blocks * engine.pool.bytes_per_block
-                if engine.paged else peak_resident
-                * engine.pool._slot_bytes),
+            "peak_bytes_resident":
+                peak_blocks * engine.pool.bytes_per_block,
         },
         "faults": {
             "rate": args.fault_rate,
@@ -1033,7 +1023,6 @@ def _run_replicas(args, decode_horizon: int) -> dict:
              # plain replica runs get the same defaults they always
              # did), and the digest knobs ride along so /healthz
              # advertises what the affinity scorer consumes.
-             "--kv-layout", args.kv_layout,
              "--kv-block-size", str(args.kv_block_size),
              "--kv-dtype", args.kv_dtype,
              "--kv-host-blocks", str(getattr(args, "kv_host_blocks", 0)),
@@ -1360,9 +1349,9 @@ def _run_fleet(args, decode_horizon: int) -> dict:
             f"--churn-prefix-len {churn_plen} + tail 2 + "
             f"max_new_tokens {args.max_new_tokens} exceeds "
             f"--max-len {args.max_len}")
-    if args.kv_layout != "paged" or args.prefix_cache != "on":
-        raise SystemExit("the fleet scenario needs --kv-layout paged "
-                         "with --prefix-cache on (digests summarize "
+    if args.prefix_cache != "on":
+        raise SystemExit("the fleet scenario needs "
+                         "--prefix-cache on (digests summarize "
                          "the prefix trie)")
     visits = max(2, -(-args.requests // users))
     affinity = args.affinity_routing == "on"
@@ -1375,7 +1364,6 @@ def _run_fleet(args, decode_horizon: int) -> dict:
              "--queue-capacity", str(args.queue_capacity),
              "--decode-horizon", str(decode_horizon),
              "--max-new-tokens", str(args.max_new_tokens),
-             "--kv-layout", args.kv_layout,
              "--kv-block-size", str(args.kv_block_size),
              "--kv-dtype", args.kv_dtype,
              "--kv-host-blocks", str(getattr(args, "kv_host_blocks", 0)),

@@ -57,8 +57,8 @@ _SERVE_COUNTERS = {"serve.admitted_total", "serve.rejected_total",
                    "faults.injected_total",
                    # Paged-KV pool (PR 8): requests that took cached
                    # prefix references instead of re-prefilling, and
-                   # copy-on-write block copies. Layout-invariant: a
-                   # dense-layout run reports 0s, never omits them.
+                   # copy-on-write block copies. A run with the prefix
+                   # cache off reports 0s, never omits them.
                    "serve.kv.prefix_hits_total",
                    "serve.kv.cow_copies_total",
                    # Serving-side expert layer (PR 26): token-expert

@@ -10,7 +10,8 @@ run must never regress (or overwrite) a TPU baseline. This entry point
    explicit CPU pin (tests and CPU correctness records),
 2. runs the closed-loop serving sweep (``benchmarks/serving.py``: the
    decode-horizon sweep, the paged-KV shared-prefix record, the
-   paged-vs-dense and paged-int8-vs-paged-bf16 equal-memory occupancy
+   paged occupancy at a fixed block budget and the
+   paged-int8-vs-paged-bf16 equal-memory occupancy
    records) and the decode-attention microbench
    (``benchmarks/decode_attention.py``),
 3. compares the headline numbers against the committed baselines
@@ -206,30 +207,26 @@ def _run_serving(args, platform: str) -> dict:
                    "--platform", platform]
     shared = serving_bench.run(serving_bench.build_parser().parse_args(
         shared_argv))
-    # Equal-memory occupancy: dense and paged runs whose device KV
-    # budgets hold the SAME number of token-positions — dense peaks at
-    # its slot count, paged at what the block budget admits (strictly
+    # Occupancy at a fixed budget of token-positions: a worst-case
+    # reservation of max_len rows a request holds budget // max_len
+    # residents, the paged pool what the block budget admits (strictly
     # more on under-max_len traffic; the ISSUE 8 acceptance record).
     if args.quick:
-        budget_note = "64 token-positions each"
-        dense_argv = ["--kv-layout", "dense", "--max-batch-size", "2",
-                      "--max-len", "32"]
+        budget_note = "64 token-positions"
+        reserved = 64 // 32
         paged_argv = ["--max-batch-size", "4", "--max-len", "32",
                       "--kv-block-size", "4", "--kv-num-blocks", "17"]
         load = ["--requests", str(requests), "--concurrency", "8",
                 "--prompt-len", "4", "--max-new-tokens", "4",
                 "--max-prefill-len", "8", "--platform", platform]
     else:
-        budget_note = "256 token-positions each"
-        dense_argv = ["--kv-layout", "dense", "--max-batch-size", "4",
-                      "--max-len", "64"]
+        budget_note = "256 token-positions"
+        reserved = 256 // 64
         paged_argv = ["--max-batch-size", "8", "--max-len", "64",
                       "--kv-block-size", "16", "--kv-num-blocks", "17"]
         load = ["--requests", str(requests), "--concurrency", "8",
                 "--prompt-len", "8", "--max-new-tokens", "16",
                 "--max-prefill-len", "16", "--platform", platform]
-    dense = serving_bench.run(serving_bench.build_parser().parse_args(
-        dense_argv + load))
     paged = serving_bench.run(serving_bench.build_parser().parse_args(
         paged_argv + load))
     # Equal-memory int8 vs bf16 (ISSUE 9 acceptance): paged pools whose
@@ -355,11 +352,10 @@ def _run_serving(args, platform: str) -> dict:
                     / max(coloc["tpot_s"]["p50"], 1e-9)),
             },
             "shared_prefix_0.8": shared,
-            "paged_vs_dense_equal_memory": {
+            "paged_occupancy_at_fixed_budget": {
                 "kv_budget": budget_note,
-                "dense": dense, "paged": paged,
-                "dense_peak_resident":
-                    dense["kv"]["peak_resident_requests"],
+                "paged": paged,
+                "worst_case_reservation_residents": reserved,
                 "paged_peak_resident":
                     paged["kv"]["peak_resident_requests"],
             },

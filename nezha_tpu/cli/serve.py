@@ -118,8 +118,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "elsewhere, kernel = force the kernel "
                         "(interpret off-TPU; int8 pools fuse the block "
                         "write into its epilogue), xla = force the "
-                        "composed masked path; NEZHA_NO_PREFILL_KERNEL=1 "
-                        "is the env escape hatch; default: the model "
+                        "composed masked path; default: the model "
                         "config's choice (auto)")
     p.add_argument("--prefill-mode", choices=["replicated", "sequence"],
                    default="replicated",
@@ -129,9 +128,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "axis of the 1xM mesh (ring/ulysses attention, "
                         "blocks land head-sharded in the paged pool — "
                         "long-context prompts, docs/RUNBOOK.md §8). "
-                        "Requires --mesh M > 1; "
-                        "NEZHA_NO_SEQ_PREFILL=1 is the env escape "
-                        "hatch back to replicated")
+                        "Requires --mesh M > 1")
     p.add_argument("--long-prefill-buckets", default=None,
                    help="comma-separated extra prefill pad widths "
                         "ABOVE --max-prefill-len (one compiled program "
@@ -155,17 +152,12 @@ def build_parser() -> argparse.ArgumentParser:
                         "per-token events, but deadline/drain "
                         "granularity coarsens to one horizon "
                         "(docs/RUNBOOK.md §8)")
-    p.add_argument("--kv-layout", choices=["paged", "dense"],
-                   default="paged",
-                   help="KV pool layout: paged = block-paged pool with "
-                        "ref-counted blocks, lazy binding, and shared-"
-                        "prefix prefill reuse (default); dense = the "
-                        "classic worst-case per-slot reservation")
     p.add_argument("--kv-block-size", type=int, default=16,
-                   help="paged layout: tokens per KV block")
+                   help="tokens per block of the paged KV pool")
     p.add_argument("--kv-num-blocks", type=int, default=None,
-                   help="paged layout: total pool blocks (block 0 is "
-                        "scratch); default = dense-equivalent capacity "
+                   help="total pool blocks (block 0 is "
+                        "scratch); default = every slot can reach "
+                        "max_len "
                         "(1 + max_batch_size * ceil(max_len/block)); "
                         "smaller makes resident tokens, not slots, the "
                         "admission limit")
@@ -173,17 +165,17 @@ def build_parser() -> argparse.ArgumentParser:
                    default="bf16",
                    help="KV block storage: bf16 = store --cache-dtype "
                         "(bit-identical to the classic engine); int8 = "
-                        "int8 blocks + per-block fp32 scales (paged "
-                        "layout only) — ~2x resident requests at the "
+                        "int8 blocks + per-block fp32 scales "
+                        "— ~2x resident requests at the "
                         "same device budget, dequantized inside the "
                         "flash-decode kernel (docs/RUNBOOK.md §8)")
     p.add_argument("--prefix-cache", choices=["on", "off"], default="on",
-                   help="paged layout: reuse cached blocks for "
+                   help="reuse cached blocks for "
                         "requests whose prompt prefix matches (TTFT "
                         "collapses for templated traffic)")
     p.add_argument("--kv-eviction", choices=["lru", "none"],
                    default="lru",
-                   help="paged layout: when the free list runs dry, "
+                   help="when the free list runs dry, "
                         "evict LRU prefix-cache blocks (lru) or go "
                         "straight to typed backpressure (none)")
     p.add_argument("--kv-host-blocks", type=int, default=0,
@@ -320,7 +312,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "corrupt checkpoint refuses to start. Composes "
                         "with --replicas: N routed replicas x M-device "
                         "meshes (docs/RUNBOOK.md §10). Requires "
-                        "kv-layout=paged and num_heads %% M == 0")
+                        "num_heads %% M == 0")
     p.add_argument("--replica-backend", choices=["process", "thread"],
                    default="process",
                    help="how workers are hosted: 'process' spawns real "
@@ -415,7 +407,6 @@ def _build_stack(args):
             ("--mesh", mesh_m > 1),
             ("--kv-dtype int8", args.kv_dtype == "int8"),
             ("--kv-host-blocks", bool(args.kv_host_blocks)),
-            ("--kv-layout dense", args.kv_layout != "paged"),
             ("--speculative", bool(getattr(args, "speculative", False))),
             ("--role prefill/decode (KV migration)",
              getattr(args, "role", "both") != "both"),
@@ -451,10 +442,6 @@ def _build_stack(args):
                 f"--mesh {mesh_m}: num_heads="
                 f"{model.cfg.num_heads} not divisible by the mesh — "
                 f"K/V pools shard on the head axis")
-        if args.kv_layout != "paged":
-            raise SystemExit(
-                f"--mesh {mesh_m} requires --kv-layout paged (the "
-                f"dense layout has no head-sharded pool)")
         mesh = make_mesh({"tp": mesh_m},
                          devices=_jax.devices()[:mesh_m])
         try:
@@ -539,7 +526,6 @@ def _build_stack(args):
             decode_impl=args.decode_impl,
             prefill_impl=args.prefill_impl,
             decode_horizon=args.decode_horizon,
-            kv_layout=args.kv_layout,
             kv_block_size=args.kv_block_size,
             kv_num_blocks=args.kv_num_blocks,
             prefix_cache=args.prefix_cache == "on",
@@ -966,7 +952,7 @@ def run_http(scheduler, args, tokenizer, eos_id, port: int,
                 "tenants": scheduler.tenant_queue_depths(),
                 "preempted": scheduler.preempted_count,
                 # Host spill tier occupancy (0/0 when --kv-host-blocks
-                # is off or the layout is dense): what the router's
+                # is off): what the router's
                 # replica table and operators size the tier against.
                 "host_blocks": pool.host_blocks,
                 "host_blocks_used": pool.host_blocks_used}
@@ -1315,7 +1301,6 @@ def _worker_argv(args, rid: int, port: int, role: Optional[str] = None
              "--max-new-tokens", str(args.max_new_tokens),
              "--cache-dtype", args.cache_dtype,
              "--decode-horizon", str(args.decode_horizon),
-             "--kv-layout", args.kv_layout,
              "--kv-block-size", str(args.kv_block_size),
              "--kv-dtype", args.kv_dtype,
              "--prefix-cache", args.prefix_cache,

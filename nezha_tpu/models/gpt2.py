@@ -70,8 +70,6 @@ class GPT2Config:
     # elsewhere, and wherever attn_impl itself forces "xla");
     # "kernel" forces the kernel (interpret mode off-TPU — the parity-
     # test path); "xla" forces the composed masked path.
-    # NEZHA_NO_DECODE_KERNEL=1 is the day-1 escape hatch back to the
-    # composed path without editing configs.
     decode_impl: str = "auto"
     # Paged prefill-chunk attention (the serving TTFT path): "auto"
     # (default) runs the Pallas flash-prefill kernel
@@ -81,10 +79,8 @@ class GPT2Config:
     # _quant_prefill_write gather/requant round trip) under the same
     # backend policy as decode_impl; "kernel" forces it (interpret mode
     # off-TPU — the parity-test path); "xla" forces the composed
-    # masked path. NEZHA_NO_PREFILL_KERNEL=1 is the escape hatch back
-    # to the composed path without editing configs. Only the paged
-    # cache layout routes here — dense-slot prefill keeps the
-    # attn_impl-resolved path.
+    # masked path. Only the paged cache routes here: the whole-cache
+    # prefill of models/generate.py keeps the attn_impl-resolved path.
     prefill_impl: str = "auto"
     # "pallas" opts layer norms into the fused kernel (fwd + bwd) on TPU.
     ln_impl: str = "xla"
@@ -168,16 +164,11 @@ def _resolve_auto_impl(cfg) -> str:
 def _decode_flash_ok(cfg) -> bool:
     """Whether the single-token decode step takes the flash-decode kernel.
 
-    Same escape-hatch shape as the prefill flash path: an env kill switch
-    (``NEZHA_NO_DECODE_KERNEL=1``), an explicit config override
+    An explicit config override
     (``decode_impl="kernel"``/``"xla"``), and otherwise the shared
     ``attn_impl`` resolution — the kernel fires exactly where prefill
     flash would (TPU backend, not under the auto-partitioner), so one
     flag set governs the whole attention surface."""
-    import os
-
-    if os.environ.get("NEZHA_NO_DECODE_KERNEL"):
-        return False
     if cfg.decode_impl == "kernel":
         return True
     if cfg.decode_impl != "auto":
@@ -196,16 +187,12 @@ def _decode_flash_shmap_mesh(cfg):
     the prefill ``flash_shmap`` idiom — TPU backend, a ``tp`` axis
     dividing the heads — plus the
     decode kernel's own switches (``decode_impl``, the shared
-    ``attn_impl`` resolution, ``NEZHA_NO_DECODE_KERNEL``).
+    ``attn_impl`` resolution).
     ``decode_impl="kernel"`` honors the force on ANY backend (interpret
     mode off-TPU, the parity-test path — under the partitioner the raw
     Mosaic call is never an option, so the nested variant IS the forced
     kernel). Otherwise, off-TPU the composed masked path simply
     auto-partitions under the mesh."""
-    import os
-
-    if os.environ.get("NEZHA_NO_DECODE_KERNEL"):
-        return None
     if cfg.decode_impl == "xla":
         return None
     if cfg.decode_impl == "auto" and cfg.attn_impl not in ("auto",
@@ -223,15 +210,10 @@ def _decode_flash_shmap_mesh(cfg):
 
 def _prefill_flash_ok(cfg) -> bool:
     """Whether the paged prefill-chunk branch takes the flash-prefill
-    kernel — the same escape-hatch shape as :func:`_decode_flash_ok`:
-    an env kill switch (``NEZHA_NO_PREFILL_KERNEL=1``), an explicit
+    kernel — the same shape as :func:`_decode_flash_ok`: an explicit
     config override (``prefill_impl="kernel"``/``"xla"``), and
     otherwise the shared ``attn_impl`` resolution, so one flag set
     governs the whole attention surface."""
-    import os
-
-    if os.environ.get("NEZHA_NO_PREFILL_KERNEL"):
-        return False
     if cfg.prefill_impl == "kernel":
         return True
     if cfg.prefill_impl != "auto":
@@ -247,16 +229,12 @@ def _prefill_flash_shmap_mesh(cfg):
     kernel can run per-shard under a nested ``shard_map`` (the sharded
     serve engine's path, ops/pallas/prefill_attention.py
     ``flash_prefill_attention_sharded``); None otherwise. Same gates
-    as :func:`_decode_flash_shmap_mesh` with the prefill knobs
-    (``prefill_impl``, ``NEZHA_NO_PREFILL_KERNEL``) swapped in:
+    as :func:`_decode_flash_shmap_mesh` with ``prefill_impl``
+    swapped in:
     ``prefill_impl="kernel"`` honors the force on ANY backend
     (interpret mode off-TPU — under the partitioner the raw Mosaic
     call is never an option, so the nested variant IS the forced
     kernel)."""
-    import os
-
-    if os.environ.get("NEZHA_NO_PREFILL_KERNEL"):
-        return None
     if cfg.prefill_impl == "xla":
         return None
     if cfg.prefill_impl == "auto" and cfg.attn_impl not in ("auto",
@@ -408,7 +386,7 @@ class Attention(Module):
             # attention either runs the flash-decode kernel directly on
             # the pools (block-table gather operand, per-row length
             # skip preserved) or gathers the row's blocks and takes the
-            # same masked path as the dense layout. Non-emitting rows
+            # composed masked path. Non-emitting rows
             # (``active`` False) route their frozen-position pad write
             # to block 0 — the pool's reserved scratch block — so a
             # retired slot can never scribble on a block that was
@@ -417,54 +395,25 @@ class Attention(Module):
                                      prefill, active, states,
                                      training=training)
         if cache is not None:
-            # Incremental decoding: append this chunk's K/V at `pos` in the
+            # Incremental decoding over a whole-batch cache
+            # (models/generate.py): append this chunk's K/V at `pos` in the
             # fixed-size cache and attend causally over everything written
             # so far. Static shapes throughout — `pos` is a traced scalar,
-            # so one compiled program serves every decode step. A [B]
-            # position VECTOR means per-row positions (the serve engine's
-            # slot pool: every row is an independent request at its own
-            # depth) — writes become a vmapped per-row update and the
-            # causal mask gains a batch dim.
+            # so one compiled program serves every decode step.
             import jax.lax as lax
-            per_row = getattr(pos, "ndim", 0) == 1
+            if getattr(pos, "ndim", 0) != 0:
+                raise ValueError(
+                    f"a cache without 'tables' takes one scalar position "
+                    f"for the whole batch, got pos of shape "
+                    f"{tuple(pos.shape)}: per-row positions are the paged "
+                    f"cache's (the serve engine's block tables)")
             zero = jnp.zeros((), jnp.int32)
-            if per_row and s == 1:
-                def _row_update(c, new, p):
-                    return lax.dynamic_update_slice(c, new, (zero, p, zero))
-
-                k_all = jax.vmap(_row_update)(
-                    cache["k"], k.astype(cache["k"].dtype), pos)
-                v_all = jax.vmap(_row_update)(
-                    cache["v"], v.astype(cache["v"].dtype), pos)
-            elif per_row:
-                # Speculative verify window: s tokens per row at
-                # PER-ROW offsets. A dynamic_update_slice would CLAMP a
-                # near-capacity row's window start backwards and
-                # overwrite valid prefix K/V, so the write is a
-                # per-position scatter with out-of-range (and
-                # non-emitting-row) positions routed to the DROP index
-                # — rejected draft positions within range just hold
-                # garbage until the next window overwrites them (never
-                # attended: each row's mask stops at its own depth).
-                L_d = cache["k"].shape[2]
-                ppos = pos[:, None] + jnp.arange(s)[None, :]   # [B, s]
-                if active is not None:
-                    ppos = jnp.where(active[:, None], ppos, L_d)
-                ppos = jnp.where(ppos < L_d, ppos, L_d)        # OOB: drop
-                bidx = jnp.arange(b)[:, None]
-                k_all = cache["k"].at[bidx, :, ppos, :].set(
-                    k.transpose(0, 2, 1, 3).astype(cache["k"].dtype),
-                    mode="drop")
-                v_all = cache["v"].at[bidx, :, ppos, :].set(
-                    v.transpose(0, 2, 1, 3).astype(cache["v"].dtype),
-                    mode="drop")
-            else:
-                k_all = lax.dynamic_update_slice(
-                    cache["k"], k.astype(cache["k"].dtype),
-                    (zero, zero, pos, zero))
-                v_all = lax.dynamic_update_slice(
-                    cache["v"], v.astype(cache["v"].dtype),
-                    (zero, zero, pos, zero))
+            k_all = lax.dynamic_update_slice(
+                cache["k"], k.astype(cache["k"].dtype),
+                (zero, zero, pos, zero))
+            v_all = lax.dynamic_update_slice(
+                cache["v"], v.astype(cache["v"].dtype),
+                (zero, zero, pos, zero))
             use_flash_prefill = False
             if prefill and s > 1:
                 # Prefill contract (ADVICE r5): ``prefill=True`` promises
@@ -514,26 +463,17 @@ class Attention(Module):
                     out = flash_attention(q, k, v, causal=True)
             elif use_decode_kernel:
                 # Single-token decode: the flash-decode kernel attends the
-                # one query row over the cache prefix [0, pos] with per-row
-                # lengths — rows only touch KV blocks below their own
-                # depth, and inactive rows (the serve engine's empty slots)
-                # skip every block instead of computing masked garbage.
+                # one query row over the cache prefix [0, pos]; its
+                # lengths operand skips the KV blocks above it.
                 from nezha_tpu.ops.pallas import flash_decode_attention
-                lengths = (pos if per_row
-                           else jnp.broadcast_to(pos, (b,))) + 1
+                lengths = jnp.broadcast_to(pos, (b,)) + 1
                 if active is not None:
                     lengths = jnp.where(active, lengths, 0)
                 out = flash_decode_attention(q, k_all, v_all, lengths)
             else:
                 L = k_all.shape[2]
-                if per_row:
-                    # [B, 1, S, L]: each row masks against its own depth.
-                    abs_q = pos[:, None] + jnp.arange(s)[None, :]
-                    attendable = (jnp.arange(L)[None, None, :]
-                                  <= abs_q[:, :, None])[:, None, :, :]
-                else:
-                    abs_q = pos + jnp.arange(s)[:, None]  # absolute positions
-                    attendable = jnp.arange(L)[None, :] <= abs_q
+                abs_q = pos + jnp.arange(s)[:, None]  # absolute positions
+                attendable = jnp.arange(L)[None, :] <= abs_q
                 mask = jnp.where(attendable, 0.0, -jnp.inf).astype(jnp.float32)
                 out = ops.dot_product_attention(q, k_all.astype(q.dtype),
                                                 v_all.astype(q.dtype),
@@ -650,10 +590,10 @@ class Attention(Module):
                 v_pool = vp.at[blk, off, :].set(
                     _pool_rows(v).astype(vp.dtype))
         elif per_row:
-            # Decode: one token per row at its own depth. Clamp matches
-            # the dense layout's update-slice clamp (a capacity-filled
-            # row is done — its pad write may land on its own last
-            # position, never past it), and inactive rows write scratch.
+            # Decode: one token per row at its own depth. The clamp
+            # keeps a capacity-filled row (it is done) writing its pad on
+            # its own last position, never past it, and inactive rows
+            # write scratch.
             pos_w = jnp.minimum(pos, L - 1)
             bi = jnp.clip(pos_w // bs_kv, 0, m - 1)
             blk = jnp.take_along_axis(tab, bi[:, None], axis=1)[:, 0]
@@ -686,8 +626,8 @@ class Attention(Module):
             # and the qerr sample included. Composed fallback: scatter
             # the s tokens through the table (pads beyond the prompt
             # land in the row's own bound blocks and are overwritten by
-            # decode before any mask attends them — same argument as
-            # dense), then masked attention over the gathered pool.
+            # decode before any mask attends them), then masked
+            # attention over the gathered pool.
             # Sequence-sharded prefill: a trace-time scope the sharded
             # engine enters while tracing its bucket programs
             # (prefill_mode="sequence"). The sys.modules probe keeps
@@ -715,8 +655,7 @@ class Attention(Module):
                 # The nested shard_map owns BOTH the pool write and
                 # the chunk attention; the kernel-vs-composed choice
                 # mirrors prefill_impl exactly (the shmap-mesh
-                # resolver honors NEZHA_NO_PREFILL_KERNEL and is
-                # backend-aware).
+                # resolver is backend-aware).
                 starts = jnp.broadcast_to(
                     jnp.asarray(pos, jnp.int32), (b,))
                 use_k = _prefill_flash_shmap_mesh(cfg) is not None
@@ -816,22 +755,13 @@ class Attention(Module):
                                   else None))
         else:
             # Composed path: gather the rows' blocks ([b, M, bs, H*D])
-            # into the dense [b, H, L, D] view and run the same masked
-            # attention the dense layout uses (unbound table entries
-            # gather scratch — always masked, since they sit at/past the
-            # row's length).
+            # into the per-head [b, H, L, D] view and run masked
+            # attention over it (unbound table entries gather scratch —
+            # always masked, since they sit at/past the row's length).
             # Int8 pools dequantize the gathered blocks with the SAME
             # expression as the kernel's in-loop dequant
             # (ops.quant.dequantize_kv_rows), so decode_impl="xla"
-            # stays a faithful escape hatch for the quantized cache.
-            # Prefill cost note: the serve engine's chunks always reach
-            # here (a traced pos can never take the static-pos-0 flash
-            # branch — true for the DENSE engine too), and dense chunk
-            # attention is already masked-dense over the full L_max
-            # rows, so paged adds only the gather copy itself, not a
-            # new O(L) attention term. A diagonal-offset flash prefill
-            # kernel (the engine docstring's "obvious next kernel")
-            # would lift both layouts at once.
+            # stays a faithful reference for the quantized cache.
             if quant:
                 from nezha_tpu.ops.quant import dequantize_kv_rows
                 k_all = dequantize_kv_rows(k_pool[tab], ks_pool[tab],

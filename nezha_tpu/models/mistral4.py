@@ -285,8 +285,8 @@ class MLAttention(Module):
         else:
             if "tables" not in cache:
                 raise ValueError(
-                    "the latent cache is block-paged only (kv_layout="
-                    "'paged'): no dense-slot layout is declared for it")
+                    "the latent cache is block-paged only: it takes a cache "
+                    "with 'tables' (the serve engine's block tables)")
             if per_row and s > 1:
                 raise ValueError(
                     "multi-token steps at per-row positions (speculative "

@@ -36,8 +36,8 @@ kernel, not a generic lowering):
 
 Decode is inference-only, so there is no VJP; ``models/gpt2.py`` routes
 its single-token cache branch here behind the ``attn_impl="auto"``
-resolution (``GPT2Config.decode_impl`` / ``NEZHA_NO_DECODE_KERNEL=1``
-are the escape hatches back to the composed masked path).
+resolution (``GPT2Config.decode_impl="xla"`` is the composed masked
+path).
 """
 
 from __future__ import annotations

@@ -59,8 +59,7 @@ program; prefix blocks are read-only and may be shared freely).
 tests exercise the same kernel code that compiles on hardware.
 Inference-only: no VJP. ``models/gpt2.py`` routes its paged
 prefill-chunk branch here behind ``GPT2Config.prefill_impl``
-(``NEZHA_NO_PREFILL_KERNEL=1`` is the escape hatch back to the
-composed masked path).
+(``"xla"`` is the composed masked path).
 """
 
 from __future__ import annotations

@@ -17,8 +17,7 @@ serve stack replaces the batch lifecycle with a slot lifecycle:
   blocks as int8 with per-(block, head) fp32 absmax scales (the shared
   ``ops/quant.py`` core): ~2x resident requests at the same device
   budget, with the dequant fused into the flash-decode kernel's block
-  loop. ``SlotPool`` is the classic dense ``[B_max, H, L_max, D]``
-  worst-case-reservation layout (``ServeConfig.kv_layout="dense"``).
+  loop.
 - ``sampling``: per-row temperature / top-k / top-p as traced arrays, so
   one compiled program serves every mix of requests (top-k masks by
   per-row k under a static ``k_max`` cap — ``lax.top_k``'s k is static).
@@ -110,7 +109,7 @@ from nezha_tpu.serve.scheduler import (
     TenantOverLimit,
 )
 from nezha_tpu.serve.slots import (KVBlocksExhausted, PagedSlotPool,
-                                   PrefixTrie, SlotPool)
+                                   PrefixTrie)
 from nezha_tpu.serve.supervisor import (
     ProcessBackend,
     RouterConfig,
@@ -120,7 +119,7 @@ from nezha_tpu.serve.supervisor import (
 
 __all__ = [
     "Engine", "ServeConfig", "SpeculativeConfig", "self_draft",
-    "SlotPool", "PagedSlotPool", "PrefixTrie",
+    "PagedSlotPool", "PrefixTrie",
     "KVBlocksExhausted", "sample_tokens",
     "Scheduler", "Request", "RequestResult", "QueueFull",
     "TenantOverLimit", "PRIORITIES", "FinishReason",

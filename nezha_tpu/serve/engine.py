@@ -6,23 +6,21 @@ latency flat:
 
 - **prefill** — one compiled program per PREFILL BUCKET (static prompt
   pad widths, default powers of two up to ``max_prefill_len``). A
-  prompt's tokens are padded to the smallest bucket that fits, the
-  slot's pooled cache rows are sliced out (``read_slot``), the chunk
-  runs through the model at its TRACED position offset via the masked
-  attention path (which attends everything previously written to the
-  slot), and the updated rows are written back (``write_slot``).
+  prompt's tokens are padded to the smallest bucket that fits and the
+  chunk runs through the model at its TRACED position offset against
+  the slot's block-table row: its K/V is scattered through the table
+  into the shared block pools and it attends everything previously
+  written to (or referenced by) the slot.
   Prompts longer than ``max_prefill_len`` are no longer rejected: they
   prefill in successive chunks — full ``max_prefill_len``-wide chunks,
   then a bucketed tail — reusing the same bucket programs at advancing
   offsets, so CHUNKING ADDS NO PROGRAMS. Bucket pads beyond the prompt
   write garbage K/V that is never attended (the masks stop at the
   written prefix, and decode overwrites pad positions before its mask
-  reaches them). The traced offset is the trade the chunk contract
-  buys: a traced ``pos`` cannot take the static-pos-0 flash-prefill
-  path, so chunk attention is masked-dense over the slot's ``L_max``
-  rows — paid once per request, versus the per-token decode win; a
-  diagonal-offset flash prefill kernel would recover it without
-  touching the program count and is the obvious next kernel.
+  reaches them). On TPU the chunk's attention is the paged
+  flash-prefill kernel (ops/pallas/prefill_attention.py), which takes
+  the traced offset as an operand; ``prefill_impl="xla"`` is the
+  composed masked path over the gathered table row.
 - **step** — one batched decode BLOCK over all ``B_max`` rows: a
   ``lax.scan`` of ``decode_horizon`` single-token steps, the whole
   horizon inside one compiled program. Each scan step samples per row
@@ -51,12 +49,11 @@ latency flat:
   (default) runs the scan body once inline — bit-identical to the
   classic one-token step.
 
-Both KV layouts run through the SAME program set: on the default
-block-paged pool (``ServeConfig.kv_layout="paged"``) every program takes
-one extra static-shaped operand — the per-slot block tables, uploaded
-from the pool's host mirror each dispatch — and the model's cache path
-scatters K/V through the table into shared block pools instead of
-slicing slot rows (prefix-hit requests prefill only their un-cached
+The KV cache is the block-paged pool (serve/slots.py): every program
+takes the per-slot block tables as a static-shaped operand, uploaded
+from the pool's host mirror each dispatch, and the model's cache path
+scatters K/V through the table into shared block pools (prefix-hit
+requests prefill only their un-cached
 suffix, through the same bucket programs at a nonzero start offset;
 lazy block binding and copy-on-write happen host-side BEFORE each
 dispatch, so in-program writes always land in exclusively-owned
@@ -81,6 +78,7 @@ request is bounced before it ever holds a slot.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, List, Optional, Sequence, Tuple
 
@@ -96,8 +94,7 @@ from nezha_tpu.serve.sampling import (accept_mask, categorical_rows,
                                       filter_logits, filtered_probs,
                                       finite_rows, residual_logits,
                                       sample_tokens, split_and_sample)
-from nezha_tpu.serve.slots import (KVBlocksExhausted, PagedSlotPool,
-                                   SlotPool, read_slot, write_slot)
+from nezha_tpu.serve.slots import KVBlocksExhausted, PagedSlotPool
 
 
 def default_prefill_buckets(max_prefill_len: int) -> Tuple[int, ...]:
@@ -179,17 +176,15 @@ class ServeConfig:
     # by backend, "kernel" forces it (interpret off-TPU — the parity
     # path; on int8 pools the block write fuses into the kernel
     # epilogue), "xla" forces the composed masked path.
-    # NEZHA_NO_PREFILL_KERNEL=1 is the env escape hatch.
     prefill_impl: Optional[str] = None
     # Long-context prefill (PR 20). prefill_mode="sequence" shards each
     # prefill chunk's attention over the serve mesh (ShardedEngine
     # only — the single-device engine rejects it): ulysses all-to-all
     # when H % M == 0 (bitwise parity with the replicated path) or
     # ppermute ring hops (serve/sharded/seq_prefill.py).
-    # "replicated" is the pre-PR-20 path, bit for bit.
-    # NEZHA_NO_SEQ_PREFILL=1 is the env escape hatch (the sharded
-    # engine silently falls back to replicated — long buckets keep
-    # serving the same prompts either way).
+    # "replicated" is the pre-PR-20 path, bit for bit, and the way
+    # back from sequence sharding (long buckets keep serving the same
+    # prompts either way).
     prefill_mode: str = "replicated"
     # Extra static chunk widths ABOVE max_prefill_len (each >
     # max_prefill_len, <= max_len, strictly increasing): one more
@@ -204,20 +199,17 @@ class ServeConfig:
     # "ulysses", or "ring" (docs/RUNBOOK.md §8 selection table).
     seq_prefill_variant: str = "auto"
     decode_horizon: int = 1
-    # KV layout: "paged" (default) is the block-paged pool — per-layer
+    # The block-paged KV pool: per-layer
     # [kv_num_blocks, kv_block_size, H*D] buffers, ref-counted blocks
     # bound lazily as positions advance, per-slot block tables threaded
     # into the compiled programs, and (with prefix_cache) shared-prefix
-    # prefill reuse. "dense" is the classic [B_max, H, max_len, D]
-    # worst-case-reservation pool. kv_num_blocks None = dense-equivalent
-    # capacity (1 scratch + max_batch_size * ceil(max_len/block_size)),
-    # so the default paged pool can serve everything dense could;
+    # prefill reuse. kv_num_blocks None = every slot can reach max_len
+    # (1 scratch + max_batch_size * ceil(max_len/block_size));
     # smaller values make block budget (tokens actually resident) the
     # admission limit instead of slot count. kv_eviction governs what
     # happens when the free list runs dry: "lru" evicts prefix-cache
     # blocks held only by the trie, "none" goes straight to typed
     # backpressure (KVBlocksExhausted).
-    kv_layout: str = "paged"
     kv_block_size: int = 16
     kv_num_blocks: Optional[int] = None
     prefix_cache: bool = True
@@ -228,14 +220,14 @@ class ServeConfig:
     # a later trie hit whose blocks were demoted promotes them back
     # with an async host->device copy dispatched ahead of the bucketed
     # prefill, so a returning chat user pays one tail chunk instead of
-    # a full cold prefill. Requires the paged layout, kv_dtype="int8"
+    # a full cold prefill. Requires kv_dtype="int8"
     # (demotion moves the lossless wire-format bytes verbatim), and
     # prefix_cache — host RAM typically holds ~100x the device's
     # resident conversations at int8 (docs/RUNBOOK.md §8).
     kv_host_blocks: int = 0
     # KV storage dtype. "bf16" (default) stores blocks in cache_dtype —
-    # bit-identical to the pre-quantization engine. "int8" (paged
-    # layout only) stores K/V blocks as int8 with one fp32 absmax
+    # bit-identical to the pre-quantization engine. "int8"
+    # stores K/V blocks as int8 with one fp32 absmax
     # scale per (block, head) (ops/quant.py — the EQuARX recipe the
     # wire collectives already use): ~2x the resident blocks at the
     # same device budget (scale overhead 4/(block_size*D) per
@@ -243,7 +235,7 @@ class ServeConfig:
     # serve.kv.quant_error histogram samples. The dequant is fused
     # into the flash-decode kernel's block loop (and applied
     # identically on the gathered XLA fallback), so int8 blocks never
-    # round-trip through a dense bf16 cache.
+    # round-trip through a bf16 copy of the cache.
     kv_dtype: str = "bf16"
     # Speculative decoding (None = off, bit-identical to the classic
     # horizon engine): a cheap DRAFT model proposes draft_k tokens per
@@ -296,10 +288,6 @@ class ServeConfig:
     def __post_init__(self):
         if self.max_batch_size < 1:
             raise ValueError("max_batch_size must be >= 1")
-        if self.kv_layout not in ("paged", "dense"):
-            raise ValueError(
-                f"kv_layout must be 'paged' or 'dense', got "
-                f"{self.kv_layout!r}")
         if self.kv_block_size < 1:
             raise ValueError(
                 f"kv_block_size must be >= 1, got {self.kv_block_size}")
@@ -315,18 +303,14 @@ class ServeConfig:
             raise ValueError(
                 f"kv_dtype must be 'bf16' or 'int8', got "
                 f"{self.kv_dtype!r}")
-        if self.kv_dtype == "int8" and self.kv_layout != "paged":
-            raise ValueError(
-                "kv_dtype='int8' requires kv_layout='paged' (scales "
-                "are per-block state; the dense pool has no blocks)")
         if self.kv_host_blocks < 0:
             raise ValueError(
                 f"kv_host_blocks must be >= 0, got "
                 f"{self.kv_host_blocks}")
         if self.kv_host_blocks:
-            if self.kv_layout != "paged" or self.kv_dtype != "int8":
+            if self.kv_dtype != "int8":
                 raise ValueError(
-                    "kv_host_blocks requires kv_layout='paged' and "
+                    "kv_host_blocks requires "
                     "kv_dtype='int8' — the host tier demotes the "
                     "int8+scales block payload verbatim (lossless); "
                     "a bf16 tier would serve quantize-dequant blocks "
@@ -536,20 +520,18 @@ class Engine:
         # model cut to a chip's share holds a slice).
         self.vocab = getattr(model.cfg, "vocab_held", model.cfg.vocab_size)
         self.k_max = min(cfg.k_max, self.vocab)
-        self.paged = cfg.kv_layout == "paged"
         self.kv_quant = cfg.kv_dtype == "int8"
         # What the model caches a layer: per-head K/V (GPT-2), or another
         # leaf set (a latent row). Everything that is written for K/V
         # only refuses here, typed, instead of taking a wrong path: the
-        # model's own declaration refuses an int8 pool; the dense slot
-        # layout, the draft pool of speculative decoding and the mesh's
-        # head sharding derive their shapes from K/V heads.
+        # model's own declaration refuses an int8 pool; the draft pool
+        # of speculative decoding and the mesh's head sharding derive
+        # their shapes from K/V heads.
         leaves = sorted(model.cache_leaves(
             cfg.kv_block_size, cfg.cache_dtype, self.kv_quant))
         self.kv_heads_cache = {"k", "v"} <= set(leaves)
         if not self.kv_heads_cache:
             unsupported = [what for what, on in (
-                ("kv_layout='dense'", not self.paged),
                 ("speculative decoding", cfg.speculative is not None),
                 # only the mesh-sharded engine sets this
                 ("a device mesh (--mesh)", self._seq_prefill_capable))
@@ -559,36 +541,30 @@ class Engine:
                     f"{type(model).__name__} caches {leaves}, not "
                     f"per-head K/V: {', '.join(unsupported)} not supported "
                     f"with it")
-        # Resolve ONCE whether paged prefill chunks dispatch through the
-        # flash-prefill kernel. The model re-resolves at trace time
-        # from the same knobs (config + env) — this mirror only drives
-        # telemetry: the pinned ``serve.prefill.kernel_active`` gauge
+        # Whether prefill chunks dispatch through the flash-prefill
+        # kernel (the model's own resolution, asked once). It drives
+        # telemetry only: the pinned ``serve.prefill.kernel_active`` gauge
         # lets dashboards and `nezha-telemetry` label the prefill line
         # with the active impl without scraping model config, and it
         # selects the kernel span / fused-write accounting in
         # :meth:`prefill`.
         self.prefill_kernel_active = bool(
-            self.paged and model.paged_prefill_uses_kernel())
+            model.paged_prefill_uses_kernel())
         obs.gauge("serve.prefill.kernel_active").set(
             1.0 if self.prefill_kernel_active else 0.0)
-        if self.paged:
-            self.pool = self._make_paged_pool(
-                model, num_blocks=cfg.kv_num_blocks,
-                prefix_cache=cfg.prefix_cache, eviction=cfg.kv_eviction,
-                quantized=self.kv_quant,
-                host_blocks=cfg.kv_host_blocks)
-            # Host mirrors of each row's next write position and
-            # remaining token budget (set at prefill, advanced/decayed
-            # by the block's emitted count): the lazy block binder must
-            # size the write window BEFORE a dispatch without a device
-            # sync, and must not bind blocks a nearly-finished row can
-            # never write.
-            self.host_positions = np.zeros((cfg.max_batch_size,),
-                                           np.int64)
-            self.host_budgets = np.zeros((cfg.max_batch_size,),
-                                         np.int64)
-        else:
-            self.pool = self._make_dense_pool(model)
+        self.pool = self._make_paged_pool(
+            model, num_blocks=cfg.kv_num_blocks,
+            prefix_cache=cfg.prefix_cache, eviction=cfg.kv_eviction,
+            quantized=self.kv_quant,
+            host_blocks=cfg.kv_host_blocks)
+        # Host mirrors of each row's next write position and
+        # remaining token budget (set at prefill, advanced/decayed
+        # by the block's emitted count): the lazy block binder must
+        # size the write window BEFORE a dispatch without a device
+        # sync, and must not bind blocks a nearly-finished row can
+        # never write.
+        self.host_positions = np.zeros((cfg.max_batch_size,), np.int64)
+        self.host_budgets = np.zeros((cfg.max_batch_size,), np.int64)
         b = cfg.max_batch_size
         self.last_logits = jnp.zeros((b, self.vocab), jnp.float32)
         # [B] bool from the latest step: False where that row's logits
@@ -624,7 +600,7 @@ class Engine:
         self.last_prefill_chunks = 0
         # Donate the pooled caches (positional arg 1 in EVERY program):
         # without donation every decoded token would copy the whole
-        # [B_max, H, L_max, D] K/V pool per layer just to write one row —
+        # K/V pool per layer just to write one row —
         # double the KV memory and a full-pool bandwidth tax on the
         # latency-bound loop. The engine rebinds the returned buffers
         # immediately, so the invalidated inputs are never reused.
@@ -632,15 +608,11 @@ class Engine:
         # One prefill program per bucket width — long-context buckets
         # included (compiled lazily: the executor keys on the function
         # object, so each closure is its own cache entry the first time
-        # a prompt lands in its bucket). The paged variants take the
-        # block tables as one extra operand — shapes are static, so the
-        # "1 step + len(all_prefill_buckets) programs" contract is
-        # layout-invariant. Prefill programs route through the
+        # a prompt lands in its bucket). Prefill programs route through the
         # dedicated _wrap_prefill_program hook: the sharded engine in
         # sequence mode nests the seq-prefill scope around the trace.
         self._prefill_fns = {w: self._wrap_prefill_program(
                                     _build_prefill(self.model, w,
-                                                   paged=self.paged,
                                                    quantized=self.kv_quant))
                              for w in cfg.all_prefill_buckets}
         # Speculative decoding: a DRAFT engine rides along — its own
@@ -682,22 +654,19 @@ class Engine:
                     f"max_len {cfg.max_len} exceeds the draft model's "
                     f"max_positions {dm.cfg.max_positions}")
             self.draft_model, self.draft_variables = dm, dv
-            if self.paged:
-                # Dense-equivalent block budget + no prefix cache: the
-                # draft pool is bookkeeping-cheap (draft blocks are a
-                # fraction of target bytes) and must NEVER be the
-                # backpressure source — admission budgets are sized
-                # against the target pool alone.
-                self.draft_pool = self._make_paged_pool(
-                    dm, num_blocks=None, prefix_cache=False,
-                    eviction="none", quantized=self.kv_quant)
-            else:
-                self.draft_pool = self._make_dense_pool(dm)
+            # Every slot can reach max_len + no prefix cache: the
+            # draft pool is bookkeeping-cheap (draft blocks are a
+            # fraction of target bytes) and must NEVER be the
+            # backpressure source — admission budgets are sized
+            # against the target pool alone.
+            self.draft_pool = self._make_paged_pool(
+                dm, num_blocks=None, prefix_cache=False,
+                eviction="none", quantized=self.kv_quant)
             self.pool.mirror = self.draft_pool
             self.draft_executor = Executor(donate_argnums=(1,))
             self._draft_prefill_fns = {
                 w: self._wrap_prefill_program(
-                    _build_draft_prefill(dm, w, paged=self.paged))
+                    _build_draft_prefill(dm, w))
                 for w in cfg.all_prefill_buckets}
             # Carried residual-distribution flag: True where the row's
             # last_logits hold the rejection residual (already-filtered
@@ -709,21 +678,21 @@ class Engine:
             self.spec_accepted = 0
             self._step_fn = self._wrap_program(_build_spec_step(
                 self.model, dm, self.k_max, cfg.pad_id,
-                cfg.decode_horizon, self.spec.draft_k, paged=self.paged))
+                cfg.decode_horizon, self.spec.draft_k))
         else:
             self._step_fn = self._wrap_program(
                 _build_step(self.model, self.k_max, cfg.pad_id,
-                            cfg.decode_horizon, paged=self.paged))
+                            cfg.decode_horizon))
 
     # ----------------------------------------------- subsystem hooks
     # The tensor-sharded engine (serve/sharded/engine.py) specializes
     # the engine at a handful of seams — where pools are built and where
     # built programs are handed to the executor — so every other line
-    # of the admission/decode machinery stays layout-blind. Single-
+    # of the admission/decode machinery stays sharding-blind. Single-
     # device serving goes through the identity versions below.
     def _make_paged_pool(self, model, *, num_blocks, prefix_cache,
                          eviction, quantized, host_blocks=0):
-        """Paged-pool constructor hook (target AND draft pools route
+        """Pool constructor hook (target AND draft pools route
         through here — the draft always passes ``host_blocks=0``: its
         pool keeps no prefix cache, so there is nothing to demote).
         Overridden by the sharded engine to lay the block pools out
@@ -734,12 +703,6 @@ class Engine:
             block_size=cfg.kv_block_size, num_blocks=num_blocks,
             prefix_cache=prefix_cache, eviction=eviction,
             quantized=quantized, host_blocks=host_blocks)
-
-    def _make_dense_pool(self, model):
-        """Dense-pool constructor hook (see :meth:`_make_paged_pool`)."""
-        cfg = self.cfg
-        return SlotPool(model, cfg.max_batch_size, cfg.max_len,
-                        cfg.cache_dtype)
 
     def _wrap_program(self, fn):
         """Program hook: every built prefill/step program passes through
@@ -833,7 +796,7 @@ class Engine:
 
     def prefill_blocks_needed(self, n: int) -> int:
         """Worst-case (no prefix hit) block count an ``n``-token prompt
-        binds at prefill. Paged layout only."""
+        binds at prefill."""
         return self.pool.blocks_for_span(self.prefill_span(n))
 
     def prefill(self, slot: int, tokens: Sequence[int], *, seed: int = 0,
@@ -848,7 +811,7 @@ class Engine:
         allows). ``tokens`` may be up to ``max_len - 1`` long (room for
         at least one generated token); prompts wider than
         ``max_prefill_len`` run as successive chunks through the same
-        bucket programs. On the paged layout the prompt's full-block
+        bucket programs. The prompt's full-block
         prefix is first matched against the prefix cache — matched
         blocks are REFERENCED, not recomputed, and only the suffix
         prefills (``KVBlocksExhausted`` from binding is typed
@@ -880,45 +843,42 @@ class Engine:
         budget = cap if max_new_tokens is None else min(max_new_tokens,
                                                         cap)
         tokens = np.asarray(tokens, np.int32)
-        start = 0
-        if self.paged:
-            # Prefix reuse: take references on cached blocks covering
-            # the prompt's full-block prefix (capped at n-1 — the last
-            # token always re-runs so its logits seed decoding), then
-            # bind/COW everything the planned chunks will write. With a
-            # host tier the bind also PROMOTES host-demoted blocks: the
-            # async host->device scatter is dispatched inside this call
-            # — ahead of every chunk dispatch below — so the partial-
-            # prefix chunk programs start from the promoted span and
-            # queue behind the copy on the device stream (dataflow
-            # through pool.caches orders them; no host sync anywhere).
-            start = self.pool.bind_for_prompt(slot, tokens.tolist())
+        # Prefix reuse: take references on cached blocks covering
+        # the prompt's full-block prefix (capped at n-1 — the last
+        # token always re-runs so its logits seed decoding), then
+        # bind/COW everything the planned chunks will write. With a
+        # host tier the bind also PROMOTES host-demoted blocks: the
+        # async host->device scatter is dispatched inside this call
+        # — ahead of every chunk dispatch below — so the partial-
+        # prefix chunk programs start from the promoted span and
+        # queue behind the copy on the device stream (dataflow
+        # through pool.caches orders them; no host sync anywhere).
+        start = self.pool.bind_for_prompt(slot, tokens.tolist())
         chunks = self._plan_chunks(n, start)
-        if self.paged:
-            try:
-                self.pool.prepare_write(
-                    slot, min(off for off, _, _ in chunks),
-                    max(off + width for off, _, width in chunks))
-            except KVBlocksExhausted:
-                if start == 0:
-                    raise
-                # Tight-pool edge: the hit's own references pinned the
-                # evictable blocks its copy-on-write then needed. Fall
-                # back to a COLD prefill — releasing our references
-                # makes those blocks reclaimable again, and admission
-                # sized its budget for exactly this no-hit footprint.
-                self.pool.release_blocks(slot)
-                start = 0
-                chunks = self._plan_chunks(n, 0)
-                self.pool.prepare_write(
-                    slot, 0,
-                    max(off + width for off, _, width in chunks))
-            if start > 0:
-                # Count the hit only once its binding MATERIALIZED —
-                # the cold fallback above must not inflate cache wins.
-                self.pool.count_prefix_hit()
-            self.host_positions[slot] = n
-            self.host_budgets[slot] = budget
+        try:
+            self.pool.prepare_write(
+                slot, min(off for off, _, _ in chunks),
+                max(off + width for off, _, width in chunks))
+        except KVBlocksExhausted:
+            if start == 0:
+                raise
+            # Tight-pool edge: the hit's own references pinned the
+            # evictable blocks its copy-on-write then needed. Fall
+            # back to a COLD prefill — releasing our references
+            # makes those blocks reclaimable again, and admission
+            # sized its budget for exactly this no-hit footprint.
+            self.pool.release_blocks(slot)
+            start = 0
+            chunks = self._plan_chunks(n, 0)
+            self.pool.prepare_write(
+                slot, 0,
+                max(off + width for off, _, width in chunks))
+        if start > 0:
+            # Count the hit only once its binding MATERIALIZED —
+            # the cold fallback above must not inflate cache wins.
+            self.pool.count_prefix_hit()
+        self.host_positions[slot] = n
+        self.host_budgets[slot] = budget
         obs.counter("serve.prefill.chunks_total").inc(len(chunks))
         # Re-pin per call, not just at init: benchmark harnesses reset
         # the registry after warmup, and the impl label must survive
@@ -954,37 +914,26 @@ class Engine:
                 state = (self.last_logits, self.positions, self.keys,
                          self.temps, self.top_ks, self.top_ps,
                          self.eos_ids, self.budgets)
-                if self.paged and self.prefill_kernel_active:
-                    # Pinned kernel span: brackets the chunk's DISPATCH
-                    # through the flash-prefill kernel program (async
-                    # under jit — wall time covers Python dispatch plus
-                    # any blocking first-trace compile, the executor's
-                    # usual measurement idiom). On an int8 pool every
-                    # layer fused its K and V block writes into the
-                    # kernel epilogue instead of the gather/requant
-                    # round-trip — count them so the fused-write rate
-                    # is auditable against chunk throughput.
-                    with obs.span("serve.prefill.kernel_s", width=width):
-                        out = self.executor.run(
-                            self._prefill_fns[width], self.variables,
-                            self.pool.caches,
-                            jnp.asarray(self.pool.tables_host),
-                            jnp.asarray(padded), *scalars, *state)
-                    if self.kv_quant:
-                        obs.counter(
-                            "serve.prefill.fused_writes_total").inc(
-                            getattr(self.model.cfg, "num_layers", 1))
-                elif self.paged:
+                # Pinned kernel span: brackets the chunk's DISPATCH
+                # through the flash-prefill kernel program (async
+                # under jit — wall time covers Python dispatch plus
+                # any blocking first-trace compile, the executor's
+                # usual measurement idiom). On an int8 pool every
+                # layer fused its K and V block writes into the
+                # kernel epilogue instead of the gather/requant
+                # round-trip — count them so the fused-write rate
+                # is auditable against chunk throughput.
+                with (obs.span("serve.prefill.kernel_s", width=width)
+                      if self.prefill_kernel_active
+                      else contextlib.nullcontext()):
                     out = self.executor.run(
                         self._prefill_fns[width], self.variables,
                         self.pool.caches,
                         jnp.asarray(self.pool.tables_host),
                         jnp.asarray(padded), *scalars, *state)
-                else:
-                    out = self.executor.run(
-                        self._prefill_fns[width], self.variables,
-                        self.pool.caches, jnp.asarray(padded),
-                        *scalars, *state)
+                if self.prefill_kernel_active and self.kv_quant:
+                    obs.counter("serve.prefill.fused_writes_total").inc(
+                        getattr(self.model.cfg, "num_layers", 1))
                 if self.kv_quant:
                     # The quantized prefill program's extra output: this
                     # chunk's max-abs dequant error. Collect the DEVICE
@@ -1011,40 +960,32 @@ class Engine:
             # request and frees the slot — the mirror releases the
             # draft pool's partial binds in the same free().
             dchunks = self._plan_chunks(n, 0)
-            if self.paged:
-                self.draft_pool.prepare_write(
-                    slot, 0,
-                    max(off + width for off, _, width in dchunks))
+            self.draft_pool.prepare_write(
+                slot, 0,
+                max(off + width for off, _, width in dchunks))
             for off, ln, width in dchunks:
                 padded = np.zeros((1, width), np.int32)
                 padded[0, :ln] = tokens[off:off + ln]
                 dscalars = (np.int32(ln), np.int32(slot), np.int32(off))
-                if self.paged:
-                    self.draft_pool.caches = self.draft_executor.run(
-                        self._draft_prefill_fns[width],
-                        self.draft_variables, self.draft_pool.caches,
-                        jnp.asarray(self.draft_pool.tables_host),
-                        jnp.asarray(padded), *dscalars)
-                else:
-                    self.draft_pool.caches = self.draft_executor.run(
-                        self._draft_prefill_fns[width],
-                        self.draft_variables, self.draft_pool.caches,
-                        jnp.asarray(padded), *dscalars)
+                self.draft_pool.caches = self.draft_executor.run(
+                    self._draft_prefill_fns[width],
+                    self.draft_variables, self.draft_pool.caches,
+                    jnp.asarray(self.draft_pool.tables_host),
+                    jnp.asarray(padded), *dscalars)
             # Fresh request: its carried logits are real target logits,
             # not a residual distribution.
             self.residual = self.residual.at[slot].set(False)
-        if self.paged:
-            # Index this prompt's full blocks for future prefix hits
-            # (the trie takes its own references — the cache outlives
-            # this request's slot).
-            self.pool.register_prefix(slot, tokens.tolist())
+        # Index this prompt's full blocks for future prefix hits
+        # (the trie takes its own references — the cache outlives
+        # this request's slot).
+        self.pool.register_prefix(slot, tokens.tolist())
         if faults.enabled():
             self.last_logits = faults.corrupt(
                 "serve.prefill.logits", self.last_logits, rows=(slot,))
 
     def _bind_decode_windows(self, active: np.ndarray, cap: int,
                              pools) -> None:
-        """Lazy binding (paged layout): make every active row's write
+        """Lazy binding: make every active row's write
         window for this block — ``[pos, pos + min(cap, budget))``,
         clamped to capacity — exclusively owned in each of ``pools``
         BEFORE the dispatch. The bound is what the row can actually
@@ -1071,18 +1012,16 @@ class Engine:
 
     def _dispatch_attrs(self, active: np.ndarray) -> dict:
         """What the ``serve.engine.dispatch`` span says of a step: the
-        active ``rows`` and, on a paged pool, the table entries they
-        hold going in (``blocks``). ``blocks / (rows * M)`` is the share
-        of the block table the paged decode kernel visits: it skips,
-        without a DMA, every entry past a row's length."""
+        active ``rows`` and the table entries they hold going in
+        (``blocks``; ``latent_blocks`` on a latent pool).
+        ``blocks / (rows * M)`` is the share of the block table the
+        paged decode kernel visits: it skips, without a DMA, every entry
+        past a row's length."""
         active = np.asarray(active, bool)
-        attrs = {"rows": int(np.count_nonzero(active))}
-        if self.paged:
-            blocks = int(np.sum(
-                self.host_positions[active] // self.cfg.kv_block_size + 1))
-            attrs["blocks" if self.kv_heads_cache else "latent_blocks"] = \
-                blocks
-        return attrs
+        blocks = int(np.sum(
+            self.host_positions[active] // self.cfg.kv_block_size + 1))
+        return {"rows": int(np.count_nonzero(active)),
+                "blocks" if self.kv_heads_cache else "latent_blocks": blocks}
 
     def step(self, active: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """Decode one BLOCK of up to ``decode_horizon`` tokens for every
@@ -1102,23 +1041,15 @@ class Engine:
             return self._spec_step(active)
         with obs.annotate("serve.engine.dispatch",
                           **self._dispatch_attrs(active)):
-            if self.paged:
-                self._bind_decode_windows(
-                    active, self.cfg.decode_horizon, (self.pool,))
-                out = self.executor.run(
-                    self._step_fn, self.variables, self.pool.caches,
-                    jnp.asarray(self.pool.tables_host),
-                    self.last_logits, self.positions,
-                    jnp.asarray(active, bool), self.keys,
-                    self.temps, self.top_ks, self.top_ps,
-                    self.eos_ids, self.budgets)
-            else:
-                out = self.executor.run(
-                    self._step_fn, self.variables, self.pool.caches,
-                    self.last_logits, self.positions,
-                    jnp.asarray(active, bool), self.keys,
-                    self.temps, self.top_ks, self.top_ps,
-                    self.eos_ids, self.budgets)
+            self._bind_decode_windows(
+                active, self.cfg.decode_horizon, (self.pool,))
+            out = self.executor.run(
+                self._step_fn, self.variables, self.pool.caches,
+                jnp.asarray(self.pool.tables_host),
+                self.last_logits, self.positions,
+                jnp.asarray(active, bool), self.keys,
+                self.temps, self.top_ks, self.top_ps,
+                self.eos_ids, self.budgets)
             (tok, emitted, ok, caches, last, pos, keys, budgets,
              *load) = out
             # Start the block's device->host transfers NOW, before any
@@ -1142,14 +1073,13 @@ class Engine:
                 self.last_expert_load = np.asarray(load[0])
         if load:
             self._record_expert_load(int(np.count_nonzero(active)))
-        if self.paged:
-            # Advance the host position/budget mirrors by the block's
-            # emitted counts (positions advance and budgets decay on
-            # device exactly once per emitted token; a NaN-frozen row
-            # may lag by one — it is retired this iteration, so its
-            # window is never grown).
-            self.host_positions += emitted_h.astype(np.int64)
-            self.host_budgets -= emitted_h.astype(np.int64)
+        # Advance the host position/budget mirrors by the block's
+        # emitted counts (positions advance and budgets decay on
+        # device exactly once per emitted token; a NaN-frozen row
+        # may lag by one — it is retired this iteration, so its
+        # window is never grown).
+        self.host_positions += emitted_h.astype(np.int64)
+        self.host_budgets -= emitted_h.astype(np.int64)
         return tok_h, emitted_h
 
     def _record_expert_load(self, rows: int) -> None:
@@ -1180,31 +1110,21 @@ class Engine:
         cap = self.cfg.decode_horizon * (k + 1)
         with obs.annotate("serve.engine.dispatch",
                           **self._dispatch_attrs(active)):
-            if self.paged:
-                # Both pools bind the same window: verify/draft writes
-                # past it are garbage by construction and route to the
-                # scratch block through the unbound table tail.
-                self._bind_decode_windows(active, cap,
-                                          (self.pool, self.draft_pool))
-                out = self.executor.run(
-                    self._step_fn, self.variables,
-                    (self.pool.caches, self.draft_pool.caches),
-                    self.draft_variables,
-                    jnp.asarray(self.pool.tables_host),
-                    jnp.asarray(self.draft_pool.tables_host),
-                    self.last_logits, self.positions,
-                    jnp.asarray(active, bool), self.keys,
-                    self.temps, self.top_ks, self.top_ps,
-                    self.eos_ids, self.budgets, self.residual)
-            else:
-                out = self.executor.run(
-                    self._step_fn, self.variables,
-                    (self.pool.caches, self.draft_pool.caches),
-                    self.draft_variables,
-                    self.last_logits, self.positions,
-                    jnp.asarray(active, bool), self.keys,
-                    self.temps, self.top_ks, self.top_ps,
-                    self.eos_ids, self.budgets, self.residual)
+            # Both pools bind the same window: verify/draft writes
+            # past it are garbage by construction and route to the
+            # scratch block through the unbound table tail.
+            self._bind_decode_windows(active, cap,
+                                      (self.pool, self.draft_pool))
+            out = self.executor.run(
+                self._step_fn, self.variables,
+                (self.pool.caches, self.draft_pool.caches),
+                self.draft_variables,
+                jnp.asarray(self.pool.tables_host),
+                jnp.asarray(self.draft_pool.tables_host),
+                self.last_logits, self.positions,
+                jnp.asarray(active, bool), self.keys,
+                self.temps, self.top_ks, self.top_ps,
+                self.eos_ids, self.budgets, self.residual)
             (tok, emitted, ok, win_emitted, caches_all, last, pos, keys,
              budgets, residual) = out
             _start_host_copies(tok, emitted, ok, win_emitted)
@@ -1248,9 +1168,8 @@ class Engine:
             hist = obs.histogram("serve.spec.accepted_len")
             for v in (ran - 1).tolist():
                 hist.observe(v)
-        if self.paged:
-            self.host_positions += emitted_h.astype(np.int64)
-            self.host_budgets -= emitted_h.astype(np.int64)
+        self.host_positions += emitted_h.astype(np.int64)
+        self.host_budgets -= emitted_h.astype(np.int64)
         return tok_h, emitted_h
 
     @property
@@ -1288,59 +1207,54 @@ def _start_host_copies(*arrays) -> None:
             copy_async()
 
 
-def _build_prefill(model, width: int, paged: bool = False,
-                   quantized: bool = False):
-    def core(variables, caches, tables, tokens, length, slot, pos,
-             seed, temperature, top_k, top_p, eos_id, budget,
-             last_logits, positions, keys, temps, top_ks, top_ps,
-             eos_ids, budgets):
+def _with_tables(caches, tables):
+    """The model's paged cache rows: each layer's pool leaves plus the
+    block ``tables``. The dict-merge keeps every leaf riding into the
+    model (int8 pools carry k_scale/v_scale beside k/v: the scales are
+    cache state like any other)."""
+    return [{**pool, "tables": tables} for pool in caches]
+
+
+def _pool_leaves(new_rows, caches):
+    """The pool's own leaves back out of the rows the model returned
+    (which also hold the tables and, on an int8 prefill, ``qerr``)."""
+    kept = tuple(caches[0])
+    return [{kk: r[kk] for kk in kept} for r in new_rows]
+
+
+def _build_prefill(model, width: int, quantized: bool = False):
+    def prefill(variables, caches, tables, tokens, length, slot, pos,
+                seed, temperature, top_k, top_p, eos_id, budget,
+                last_logits, positions, keys, temps, top_ks, top_ps,
+                eos_ids, budgets):
         # One prompt chunk, padded to this bucket's static `width`, runs
-        # against the slot's own cache storage at a traced offset: the
-        # masked attention path sees the prefix earlier chunks wrote
-        # (pos > 0) or nothing (pos == 0), so the same program serves
-        # first chunks, middle chunks, and bucketed tails. Rows past
-        # `length` are pad — their K/V lands above the prompt and is
-        # overwritten by decode before any mask attends it. Dense: the
-        # slot's pooled rows are sliced out (read_slot) and written
-        # back (write_slot). Paged: the chunk runs against the slot's
-        # TABLE ROW (one [1, M] slice of the uploaded tables) — the
-        # model scatters K/V through it into the shared block pools and
+        # against the slot's own cache storage at a traced offset: it
+        # sees the prefix earlier chunks wrote (pos > 0) or nothing
+        # (pos == 0), so the same program serves first chunks, middle
+        # chunks, and bucketed tails. Rows past `length` are pad — their
+        # K/V lands above the prompt and is overwritten by decode before
+        # any mask attends it. The chunk runs against the slot's TABLE
+        # ROW (one [1, M] slice of the uploaded tables) — the model
+        # scatters K/V through it into the shared block pools and
         # attends the gathered prefix, so a shared-prefix request
         # starting at a nonzero `pos` sees the cached blocks it
         # referenced instead of recomputing them.
-        if paged:
-            zero = jnp.zeros((), jnp.int32)
-            tab_row = lax.dynamic_slice(
-                tables, (slot, zero), (1, tables.shape[1]))
-            # Dict-merge keeps every pool leaf (int8 pools carry
-            # k_scale/v_scale rows alongside k/v) riding into the model
-            # and back out — the scales are cache state like any other.
-            rows = [{**pool, "tables": tab_row} for pool in caches]
-        else:
-            rows = [{"k": read_slot(pool["k"], slot),
-                     "v": read_slot(pool["v"], slot)}
-                    for pool in caches]
+        zero = jnp.zeros((), jnp.int32)
+        tab_row = lax.dynamic_slice(
+            tables, (slot, zero), (1, tables.shape[1]))
+        rows = _with_tables(caches, tab_row)
         logits, states = model.apply(variables, tokens, training=False,
                                      cache=rows, pos=pos)
         new_rows = model.caches_from_states(states, rows)
-        if paged:
-            keys_kept = tuple(caches[0].keys())
-            new_caches = [{kk: r[kk] for kk in keys_kept}
-                          for r in new_rows]
-            qerr = None
-            if quantized:
-                # Max-abs dequant error across layers (each attention
-                # write reported its chunk's error) — returned as one
-                # extra scalar output the engine host-observes into
-                # serve.kv.quant_error.
-                errs = [r["qerr"] for r in new_rows if "qerr" in r]
-                qerr = jnp.max(jnp.stack(errs)) if errs \
-                    else jnp.zeros((), jnp.float32)
-        else:
-            new_caches = [
-                {"k": write_slot(pool["k"], rk["k"], slot),
-                 "v": write_slot(pool["v"], rk["v"], slot)}
-                for pool, rk in zip(caches, new_rows)]
+        new_caches = _pool_leaves(new_rows, caches)
+        if quantized:
+            # Max-abs dequant error across layers (each attention
+            # write reported its chunk's error) — returned as one
+            # extra scalar output the engine host-observes into
+            # serve.kv.quant_error.
+            errs = [r["qerr"] for r in new_rows if "qerr" in r]
+            qerr = jnp.max(jnp.stack(errs)) if errs \
+                else jnp.zeros((), jnp.float32)
         row = lax.dynamic_slice(
             logits, (0, length - 1, jnp.zeros((), jnp.int32)),
             (1, 1, logits.shape[-1]))[:, 0, :]          # [1, V] last REAL row
@@ -1364,24 +1278,12 @@ def _build_prefill(model, width: int, paged: bool = False,
                set_row(top_ps, top_p),
                set_row(eos_ids, eos_id),
                set_row(budgets, budget))
-        if paged and quantized:
-            return out + (qerr,)
-        return out
-
-    # One source for both layouts; only the operand list differs (the
-    # paged variant takes the uploaded block tables after the caches).
-    if paged:
-        def prefill(variables, caches, tables, tokens, *rest):
-            return core(variables, caches, tables, tokens, *rest)
-    else:
-        def prefill(variables, caches, tokens, *rest):
-            return core(variables, caches, None, tokens, *rest)
+        return out + (qerr,) if quantized else out
 
     return prefill
 
 
-def _build_step(model, k_max: int, pad_id: int, horizon: int,
-                paged: bool = False):
+def _build_step(model, k_max: int, pad_id: int, horizon: int):
     def body(active, temps, top_ks, top_ps, eos_ids, budgets,
              variables, tables, carry):
         """One fused decode step: the single-token body the horizon scan
@@ -1405,8 +1307,8 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int,
           way). Keys advance only on emit — a request's RNG stream is a
           function of (seed, emitted count), horizon-invariant.
 
-        ONE source for both KV layouts: with ``paged`` the per-slot
-        block tables thread into each layer's cache dict — the model
+        The per-slot block tables thread into each layer's cache dict —
+        the model
         scatters emitted tokens' K/V through them (non-emitting rows
         write the scratch block) and the flash-decode kernel gathers KV
         blocks via the table with the per-row length skip intact.
@@ -1421,13 +1323,7 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int,
         next_keys, tok = split_and_sample(keys, last_logits, temps,
                                           top_ks, top_ps, k_max)
         tok = jnp.where(emit, tok, pad_id)
-        if paged:
-            # Dict-merge: int8 pools' k_scale/v_scale leaves thread
-            # through with k/v (the model's quantized write returns
-            # updated scale buffers the scan must carry).
-            rows = [{**c, "tables": tables} for c in caches]
-        else:
-            rows = caches
+        rows = _with_tables(caches, tables)
         logits, states = model.apply(variables, tok[:, None],
                                      training=False, cache=rows,
                                      pos=positions, active=emit)
@@ -1436,12 +1332,7 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int,
         # (a model with no serving-side expert layer: nothing is added
         # to its program).
         load = model.expert_load(states)
-        if paged:
-            keys_kept = tuple(caches[0].keys())
-            new_caches = [{kk: r[kk] for kk in keys_kept}
-                          for r in new_rows]
-        else:
-            new_caches = new_rows
+        new_caches = _pool_leaves(new_rows, caches)
         row_logits = logits[:, -1, :]
         ok = jnp.where(emit, ok & finite_rows(row_logits), ok)
         counted = emit & ok
@@ -1455,7 +1346,7 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int,
                 jnp.where(act, next_keys, keys),
                 done, ok, emitted), (tok, load)
 
-    def core(variables, caches, tables, last_logits, positions, active,
+    def step(variables, caches, tables, last_logits, positions, active,
              keys, temps, top_ks, top_ps, eos_ids, budgets):
         b = positions.shape[0]
         init = (caches, last_logits, positions, keys,
@@ -1482,17 +1373,10 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int,
                keys, jnp.maximum(budgets - emitted, 0))
         return out if load is None else out + (load,)
 
-    if paged:
-        def step(variables, caches, tables, *rest):
-            return core(variables, caches, tables, *rest)
-    else:
-        def step(variables, caches, *rest):
-            return core(variables, caches, None, *rest)
-
     return step
 
 
-def _build_draft_prefill(model, width: int, paged: bool = False):
+def _build_draft_prefill(model, width: int):
     """The draft engine's bucket prefill: the same chunk-at-traced-
     offset move as the target's (:func:`_build_prefill`) minus all
     sampling/completion state — the draft only needs its KV loaded.
@@ -1500,39 +1384,21 @@ def _build_draft_prefill(model, width: int, paged: bool = False):
     dropped with the dict re-filter (draft quant error is not a
     serving metric — the accept test measures draft fidelity end to
     end)."""
-    def core(variables, caches, tables, tokens, length, slot, pos):
+    def prefill(variables, caches, tables, tokens, length, slot, pos):
         del length
-        if paged:
-            zero = jnp.zeros((), jnp.int32)
-            tab_row = lax.dynamic_slice(
-                tables, (slot, zero), (1, tables.shape[1]))
-            rows = [{**pool, "tables": tab_row} for pool in caches]
-        else:
-            rows = [{"k": read_slot(pool["k"], slot),
-                     "v": read_slot(pool["v"], slot)}
-                    for pool in caches]
+        zero = jnp.zeros((), jnp.int32)
+        tab_row = lax.dynamic_slice(
+            tables, (slot, zero), (1, tables.shape[1]))
+        rows = _with_tables(caches, tab_row)
         _, states = model.apply(variables, tokens, training=False,
                                 cache=rows, pos=pos)
-        new_rows = model.caches_from_states(states, rows)
-        if paged:
-            kept = tuple(caches[0].keys())
-            return [{kk: r[kk] for kk in kept} for r in new_rows]
-        return [{"k": write_slot(pool["k"], rk["k"], slot),
-                 "v": write_slot(pool["v"], rk["v"], slot)}
-                for pool, rk in zip(caches, new_rows)]
-
-    if paged:
-        def prefill(variables, caches, tables, tokens, *rest):
-            return core(variables, caches, tables, tokens, *rest)
-    else:
-        def prefill(variables, caches, tokens, *rest):
-            return core(variables, caches, None, tokens, *rest)
+        return _pool_leaves(model.caches_from_states(states, rows), caches)
 
     return prefill
 
 
 def _build_spec_step(model, draft_model, k_max: int, pad_id: int,
-                     horizon: int, draft_k: int, paged: bool = False):
+                     horizon: int, draft_k: int):
     """The fused speculative step: ONE compiled program scanning
     ``horizon`` draft→verify→accept windows, device-resident end to
     end. Each window:
@@ -1591,19 +1457,12 @@ def _build_spec_step(model, draft_model, k_max: int, pad_id: int,
 
         def dstep(c, j):
             dc, tok_in = c
-            if paged:
-                rows = [{**cc, "tables": dtables} for cc in dc]
-            else:
-                rows = dc
+            rows = _with_tables(dc, dtables)
             dlog, dstates = draft_model.apply(
                 dvariables, tok_in[:, None], training=False,
                 cache=rows, pos=positions + j, active=emit0)
-            new_rows = draft_model.caches_from_states(dstates, rows)
-            if paged:
-                kept = tuple(dc[0].keys())
-                dc2 = [{kk: r[kk] for kk in kept} for r in new_rows]
-            else:
-                dc2 = new_rows
+            dc2 = _pool_leaves(
+                draft_model.caches_from_states(dstates, rows), dc)
             row = dlog[:, -1, :]
             # The draft proposes from the row's FILTERED distribution
             # (same temperature/top-k/top-p as the target side): the
@@ -1625,19 +1484,12 @@ def _build_spec_step(model, draft_model, k_max: int, pad_id: int,
         win = jnp.concatenate(
             [t0[:, None], jnp.transpose(d_all[:k], (1, 0))], axis=1)
 
-        if paged:
-            vrows = [{**cc, "tables": tables} for cc in caches]
-        else:
-            vrows = caches
+        vrows = _with_tables(caches, tables)
         vlog, vstates = model.apply(variables, win, training=False,
                                     cache=vrows, pos=positions,
                                     active=emit0)
-        new_rows = model.caches_from_states(vstates, vrows)
-        if paged:
-            kept = tuple(caches[0].keys())
-            new_caches = [{kk: r[kk] for kk in kept} for r in new_rows]
-        else:
-            new_caches = new_rows
+        new_caches = _pool_leaves(
+            model.caches_from_states(vstates, vrows), caches)
         # Health: the whole verify window must be finite — a poisoned
         # window emits NOTHING (the conservative discard of the classic
         # step at window granularity); pre-window tokens were already
@@ -1709,9 +1561,9 @@ def _build_spec_step(model, draft_model, k_max: int, pad_id: int,
                  done, ok, emitted_new, residual_new),
                 (tok_out, emit_w, e))
 
-    def core(variables, caches_all, dvariables, tables, dtables,
-             last_logits, positions, active, keys, temps, top_ks,
-             top_ps, eos_ids, budgets, residual):
+    def spec_step(variables, caches_all, dvariables, tables, dtables,
+                  last_logits, positions, active, keys, temps, top_ks,
+                  top_ps, eos_ids, budgets, residual):
         caches, dcaches = caches_all
         b = positions.shape[0]
         init = (caches, dcaches, last_logits, positions, keys,
@@ -1754,15 +1606,5 @@ def _build_spec_step(model, draft_model, k_max: int, pad_id: int,
         return (tok_block, emitted, ok, win_emitted,
                 (caches, dcaches), last_logits, positions, keys,
                 jnp.maximum(budgets - emitted, 0), residual)
-
-    if paged:
-        def spec_step(variables, caches_all, dvariables, tables,
-                      dtables, *rest):
-            return core(variables, caches_all, dvariables, tables,
-                        dtables, *rest)
-    else:
-        def spec_step(variables, caches_all, dvariables, *rest):
-            return core(variables, caches_all, dvariables, None, None,
-                        *rest)
 
     return spec_step

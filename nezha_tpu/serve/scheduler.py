@@ -217,8 +217,7 @@ def register_serve_instruments() -> None:
     # (0 when no plan is active) so chaos runs and clean runs share one
     # schema — dashboards can divide errors by injections.
     obs.counter("faults.injected_total")
-    # Paged-KV instruments (schema-pinned for every serving run so the
-    # summary shape is layout-invariant — a dense run reports 0s):
+    # Paged-KV instruments (schema-pinned for every serving run):
     # blocks resident, requests that took cached prefix references
     # instead of re-prefilling, and copy-on-write block copies.
     obs.counter("serve.kv.prefix_hits_total")
@@ -254,7 +253,7 @@ def register_serve_instruments() -> None:
     obs.counter("serve.kv.fleet_hits_host_total")
     obs.counter("serve.kv.fleet_hits_peer_total")
     obs.counter("serve.kv.pull_bytes")
-    # KV quantization instruments (schema-pinned, layout/dtype
+    # KV quantization instruments (schema-pinned, dtype
     # invariant): device bytes the resident KV actually holds (the
     # capacity lever int8 moves), the storage width in bits (8 = int8,
     # 16 = bf16, 32 = f32 — lets the report label the dtype), and the
@@ -426,21 +425,20 @@ class Scheduler:
             raise ValueError(
                 f"prompt ({n}) + max_new_tokens ({req.max_new_tokens}) "
                 f"exceeds max_len {cfg.max_len}")
-        if self.engine.paged:
-            # A request whose prefill span (or full resident footprint)
-            # needs more blocks than the pool could EVER free can never
-            # be served — bounce it here, before it wedges the queue
-            # head forever waiting for blocks that cannot exist.
-            pool = self.engine.pool
-            need = max(self.engine.prefill_blocks_needed(n),
-                       pool.blocks_for_span(n + req.max_new_tokens))
-            if need > pool.max_request_blocks:
-                raise ValueError(
-                    f"request needs {need} KV blocks "
-                    f"(block_size {pool.block_size}) but the pool can "
-                    f"bind at most {pool.max_request_blocks} per "
-                    f"request — raise kv_num_blocks or lower the "
-                    f"request's footprint")
+        # A request whose prefill span (or full resident footprint)
+        # needs more blocks than the pool could EVER free can never
+        # be served — bounce it here, before it wedges the queue
+        # head forever waiting for blocks that cannot exist.
+        pool = self.engine.pool
+        need = max(self.engine.prefill_blocks_needed(n),
+                   pool.blocks_for_span(n + req.max_new_tokens))
+        if need > pool.max_request_blocks:
+            raise ValueError(
+                f"request needs {need} KV blocks "
+                f"(block_size {pool.block_size}) but the pool can "
+                f"bind at most {pool.max_request_blocks} per "
+                f"request — raise kv_num_blocks or lower the "
+                f"request's footprint")
         vocab = self.engine.vocab
         if not all(0 <= t < vocab for t in req.prompt):
             # Admission IS the validation boundary (the engine trusts its
@@ -774,10 +772,9 @@ class Scheduler:
         where admission pressure can LRU-evict them and, with a host
         tier, demote them through the serve.kv.demotions_total path —
         free the slot, and park the request in ``_preempted`` for
-        resume. On the dense layout (or with the cache off /
-        kv_eviction="none", where trie refs would pin blocks forever)
-        nothing is indexed: resume pays a cold re-prefill, trading
-        compute instead of leaking capacity. The ``scheduler.preempt``
+        resume. With the cache off (or kv_eviction="none", where trie
+        refs would pin blocks forever) nothing is indexed: resume pays a
+        cold re-prefill, trading compute instead of leaking capacity. The ``scheduler.preempt``
         fault point fires FIRST: an injected error is the typed
         degradation drill — the victim simply keeps decoding."""
         try:
@@ -788,8 +785,7 @@ class Scheduler:
         with obs.span("serve.preempt_s", request_id=live.request_id,
                       priority=live.req.priority,
                       tokens=len(live.tokens)):
-            if (self.engine.paged and pool.prefix_cache_enabled
-                    and pool.eviction == "lru"):
+            if pool.prefix_cache_enabled and pool.eviction == "lru":
                 pool.register_prefix(
                     slot, list(live.req.prompt) + live.tokens)
             del self._live[slot]
@@ -885,53 +881,52 @@ class Scheduler:
                     return granted
                 preempts += 1
                 continue
-            if self.engine.paged:
-                # Admission budget is FREE BLOCKS, not free slots: only
-                # admit the pick if its worst-case (no prefix hit)
-                # prefill binding fits the free list plus what cache
-                # eviction could reclaim. The worst case also COVERS a
-                # host-tier promotion: a promoted span allocates
-                # exactly the device blocks a cold prefill of that
-                # span would have bound (promotion substitutes a
-                # host->device copy for recompute, never extra
-                # footprint), so promotable requests need no separate
-                # budget line. A resumed request budgets its full
-                # context (prompt + emitted tokens). Otherwise wait —
-                # live rows retire and release blocks, and lane order
-                # holds (skipping ahead would starve long prompts).
-                ctx = len(target.req.prompt) + (len(target.tokens)
-                                                if use_pre else 0)
-                need = self.engine.prefill_blocks_needed(ctx)
-                if pool.available_blocks() < need:
-                    if self._maybe_preempt(target, preempts):
-                        # The victim's blocks moved to the trie (or
-                        # the free list): re-check the budget.
-                        preempts += 1
-                        continue
-                    if not self._live:
-                        # Nothing in flight will EVER free more blocks
-                        # (with kv_eviction="none" the prefix cache
-                        # pins its blocks permanently): waiting would
-                        # livelock, so retire the pick with a typed
-                        # error instead — later, smaller requests may
-                        # still be servable.
-                        if use_pre:
-                            # Already counted admitted once — balance
-                            # the books with a retirement.
-                            self._pop_preempted(target.request_id)
-                            obs.counter("serve.retired_total").inc()
-                        else:
-                            self._pop_next()
-                        obs.counter("serve.errors_total").inc()
-                        self._finish(
-                            target, FinishReason.ERROR,
-                            error=f"kv blocks exhausted: need {need}, "
-                                  f"{pool.available_blocks()} "
-                                  f"reclaimable, {pool.blocks_used} "
-                                  f"in use (kv_eviction="
-                                  f"{pool.eviction!r})")
-                        continue
-                    return granted
+            # Admission budget is FREE BLOCKS, not free slots: only
+            # admit the pick if its worst-case (no prefix hit)
+            # prefill binding fits the free list plus what cache
+            # eviction could reclaim. The worst case also COVERS a
+            # host-tier promotion: a promoted span allocates
+            # exactly the device blocks a cold prefill of that
+            # span would have bound (promotion substitutes a
+            # host->device copy for recompute, never extra
+            # footprint), so promotable requests need no separate
+            # budget line. A resumed request budgets its full
+            # context (prompt + emitted tokens). Otherwise wait —
+            # live rows retire and release blocks, and lane order
+            # holds (skipping ahead would starve long prompts).
+            ctx = len(target.req.prompt) + (len(target.tokens)
+                                            if use_pre else 0)
+            need = self.engine.prefill_blocks_needed(ctx)
+            if pool.available_blocks() < need:
+                if self._maybe_preempt(target, preempts):
+                    # The victim's blocks moved to the trie (or
+                    # the free list): re-check the budget.
+                    preempts += 1
+                    continue
+                if not self._live:
+                    # Nothing in flight will EVER free more blocks
+                    # (with kv_eviction="none" the prefix cache
+                    # pins its blocks permanently): waiting would
+                    # livelock, so retire the pick with a typed
+                    # error instead — later, smaller requests may
+                    # still be servable.
+                    if use_pre:
+                        # Already counted admitted once — balance
+                        # the books with a retirement.
+                        self._pop_preempted(target.request_id)
+                        obs.counter("serve.retired_total").inc()
+                    else:
+                        self._pop_next()
+                    obs.counter("serve.errors_total").inc()
+                    self._finish(
+                        target, FinishReason.ERROR,
+                        error=f"kv blocks exhausted: need {need}, "
+                              f"{pool.available_blocks()} "
+                              f"reclaimable, {pool.blocks_used} "
+                              f"in use (kv_eviction="
+                              f"{pool.eviction!r})")
+                    continue
+                return granted
             granted += 1
             if use_pre:
                 self._resume_one(target)
@@ -1253,7 +1248,7 @@ class Scheduler:
         parked refs survive until :meth:`ack_parked` (the two-phase
         commit) or the TTL. Raises ``KeyError`` for an unknown/expired
         park and :class:`~nezha_tpu.serve.migrate.MigrationError` when
-        this engine's layout cannot export. Runs under the scheduler
+        this pool's leaves have no K/V wire format. Runs under the scheduler
         lock: the gather must not race a decode dispatch that donates
         the cache buffers."""
         from nezha_tpu.serve import migrate
@@ -1269,10 +1264,6 @@ class Scheduler:
             with obs.trace_context(live.trace_id):
                 with obs.traced_span("serve.kv_export",
                                      request_id=request_id) as sp:
-                    if not self.engine.paged:
-                        raise migrate.MigrationError(
-                            "kv_layout 'dense' has no blocks to export "
-                            "— migration requires the paged pool")
                     tokens = [int(t) for t in live.req.prompt]
                     nfull = min(len(tokens) // pool.block_size,
                                 int(pool._bound[slot]))
@@ -1335,12 +1326,8 @@ class Scheduler:
         ordinary admission path. Counts committed installs into the
         schema-pinned ``serve.kv.migrations_total`` /
         ``serve.kv.migration_bytes``."""
-        from nezha_tpu.serve import migrate
         faults.point("replica.kv_install")
         with self._lock:
-            if not self.engine.paged:
-                raise migrate.MigrationError(
-                    "kv_layout 'dense' cannot install migrated blocks")
             installed = self.engine.pool.install_block_payload(tokens,
                                                                layers)
             if installed > 0:
@@ -1359,14 +1346,9 @@ class Scheduler:
                      max_entries: int = 256) -> dict:
         """The ``/healthz`` digest payload (PR 17): a bounded
         prefix-hash summary of what this replica's pool holds, rebuilt
-        at most once per ``interval_s``. Dense pools (nothing
-        block-indexed to advertise) report ``digest_size = 0`` and no
-        ``fleet_digest`` key — the Router simply never scores this
-        replica above zero coverage."""
+        at most once per ``interval_s``."""
         from nezha_tpu.serve import fleetcache
         with self._lock:
-            if not self.engine.paged:
-                return {"digest_size": 0, "digest_age_s": 0.0}
             dc = self._digest_cache
             if (dc is None or dc.interval_s != float(interval_s)
                     or dc.max_entries != int(max_entries)):
@@ -1389,11 +1371,6 @@ class Scheduler:
         from nezha_tpu.serve import migrate
         with self._lock:
             pool = self.engine.pool
-            if not self.engine.paged:
-                raise migrate.MigrationError(
-                    "kv_layout 'dense' has no blocks to export — "
-                    "peer pull requires the paged pool",
-                    kind="kv_pull_failed")
             covered, layers, _ = pool.export_prefix_payload(tokens)
             return migrate.encode_wire(covered, layers, pool.block_size)
 
@@ -1405,12 +1382,7 @@ class Scheduler:
         hit, and account the wire bytes into the schema-pinned
         ``serve.kv.pull_bytes`` (NOT the migration ledgers — a peer
         pull is a cache transfer, not a request handoff)."""
-        from nezha_tpu.serve import migrate
         with self._lock:
-            if not self.engine.paged:
-                raise migrate.MigrationError(
-                    "kv_layout 'dense' cannot install pulled blocks",
-                    kind="kv_pull_failed")
             installed = self.engine.pool.install_block_payload(
                 tokens, layers, origin="peer")
             if installed > 0:

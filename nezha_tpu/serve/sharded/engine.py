@@ -45,7 +45,6 @@ into whatever mesh the destination runs.
 
 from __future__ import annotations
 
-import dataclasses
 from typing import Optional, Sequence
 
 import numpy as np
@@ -81,18 +80,10 @@ class ShardedEngine(Engine):
         m = int(mesh_devices)
         if m < 1:
             raise ValueError(f"mesh_devices must be >= 1, got {m}")
-        if cfg.kv_layout != "paged":
-            raise ValueError(
-                "the sharded engine requires kv_layout='paged' — the "
-                "dense layout has no head-sharded pool")
-        # Sequence-sharded prefill (PR 20). NEZHA_NO_SEQ_PREFILL=1 is
-        # the runtime escape hatch: fall back to the replicated prefill
-        # path — the long buckets keep serving the same prompts, only
-        # the chunk attention stops sharding over the sequence axis.
-        import os
-        if (cfg.prefill_mode == "sequence"
-                and os.environ.get("NEZHA_NO_SEQ_PREFILL")):
-            cfg = dataclasses.replace(cfg, prefill_mode="replicated")
+        # Sequence-sharded prefill (PR 20); prefill_mode="replicated"
+        # is the way back: the long buckets keep serving the same
+        # prompts, only the chunk attention stops sharding over the
+        # sequence axis.
         self._seq_active = cfg.prefill_mode == "sequence"
         self._seq_variant = None
         if self._seq_active:
@@ -188,9 +179,6 @@ class ShardedEngine(Engine):
             num_blocks=num_blocks, prefix_cache=prefix_cache,
             eviction=eviction, quantized=quantized,
             host_blocks=host_blocks)
-
-    def _make_dense_pool(self, model):
-        raise ValueError("the sharded engine has no dense pool")
 
     def _wrap_program(self, fn):
         """Every frozen program traces under the auto-partitioner scope

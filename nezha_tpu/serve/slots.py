@@ -1,27 +1,21 @@
-"""KV pools for the serving engine: dense slots and ref-counted pages.
+"""The KV pool for the serving engine: ref-counted pages.
 
-Two layouts share one slot-level contract (``alloc``/``free``/
-``num_free``/``occupancy`` — the scheduler's whole view):
-
-- :class:`SlotPool` — the dense layout: per-layer K/V buffers shaped
-  ``[B_max, H, L_max, D]``, one worst-case ``max_len`` reservation per
-  admitted request. Simple, but memory occupancy (not compute) caps
-  concurrency: a 10-token request holds the same rows as a full one.
-- :class:`PagedSlotPool` — the block-paged layout
-  (``ServeConfig.kv_layout="paged"``, the default): per-layer K/V
-  buffers shaped ``[num_blocks, block_size, H*D]`` (lane-dense rows:
-  one row a position, its heads side by side in lanes, which is the
-  device's own row-major layout, so no program copies a pool between
-  layouts; PERF.md section 6, PR 27), a host-side free
-  list of blocks with REF COUNTS, and a per-slot block table
-  (``[max_blocks_per_row]`` int32) threaded into the compiled programs.
-  Admission binds only the blocks the prompt needs and decode binds
-  further blocks lazily as positions advance, so resident memory tracks
-  tokens actually written, not ``B_max * max_len``. Block 0 is a
-  reserved SCRATCH block: freed slots' table rows reset to it and
-  non-emitting rows' pad writes are routed to it in-program, so a
-  retired slot can never scribble on a block that was rebound to a new
-  request.
+:class:`PagedSlotPool` is the one layout serving has. The scheduler sees
+its slot-level surface (``alloc``/``free``/``num_free``/``occupancy``);
+the engine sees blocks: per-layer K/V buffers shaped
+``[num_blocks, block_size, H*D]`` (lane-dense rows: one row a position,
+its heads side by side in lanes, which is the device's own row-major
+layout, so no program copies a pool between layouts; PERF.md section 6,
+PR 27), a host-side free list of blocks with REF COUNTS, and a per-slot
+block table
+(``[max_blocks_per_row]`` int32) threaded into the compiled programs.
+Admission binds only the blocks the prompt needs and decode binds
+further blocks lazily as positions advance, so resident memory tracks
+tokens actually written, not ``B_max * max_len``. Block 0 is a
+reserved SCRATCH block: freed slots' table rows reset to it and
+non-emitting rows' pad writes are routed to it in-program, so a
+retired slot can never scribble on a block that was rebound to a new
+request.
 
 On top of the ref counts the paged pool keeps a **prefix-reuse trie**
 (:class:`PrefixTrie`) keyed on full blocks of prompt tokens: a request
@@ -68,18 +62,18 @@ At int8, host RAM holds ~100x the device's resident conversations —
 this is what makes shared-prefix reuse survive real multi-tenant
 churn instead of only back-to-back templated bursts.
 
-Stale-KV reuse invariant (regression-tested for both layouts): freeing
-a slot/block is bookkeeping only — stale K/V stays in the buffers, and
-that is safe by construction because a new occupant's prefill
+Stale-KV reuse invariant (regression-tested on the float and the int8
+pool): freeing a slot/block is bookkeeping only — stale K/V stays in
+the buffers, and that is safe by construction because a new occupant's prefill
 overwrites ``[0, prompt_len)`` (or takes references to blocks holding
 EXACTLY the tokens it would have written) before attention ever covers
 those positions, and the decode path (mask or flash-decode ``lengths``)
 stops at ``pos``. Bucket pads beyond the prompt write garbage K/V above
 ``prompt_len`` that the first decode writes overwrite before any mask
 reaches them. Non-emitting rows in a decode block write one pad token's
-K/V at their FROZEN position each scan step (dense: their own slot row;
-paged: their own bound block, or scratch when inactive) — never
-attended, because the row's own ``lengths`` stop at its content.
+K/V at their FROZEN position each scan step (their own bound block,
+or scratch when inactive) — never attended, because the row's own
+``lengths`` stop at its content.
 """
 
 from __future__ import annotations
@@ -108,122 +102,6 @@ class KVBlocksExhausted(RuntimeError):
     def __init__(self, msg: str, slot: Optional[int] = None):
         super().__init__(msg)
         self.slot = slot
-
-
-class SlotPool:
-    """Host-side slot bookkeeping + the pooled dense cache buffers.
-
-    ``caches`` is the per-layer list of ``{"k", "v"}`` dicts the model's
-    cache path consumes. The pool hands out slot INDICES; the engine
-    threads the cache pytree through its jitted programs (functional
-    updates — the pool re-binds ``caches`` to each program's output).
-    """
-
-    paged = False
-    quantized = False
-    # Host-tier accounting, layout-invariant (the serve.kv.host_*
-    # gauges report 0 for dense pools, never go missing).
-    host_blocks = 0
-    host_blocks_used = 0
-    host_bytes_resident = 0
-    demotions = 0
-    promotions = 0
-    promote_failures = 0
-
-    def __init__(self, model, capacity: int, max_len: int,
-                 dtype=jnp.bfloat16):
-        from nezha_tpu.models.generate import init_cache
-        if capacity < 1:
-            raise ValueError(f"capacity must be >= 1, got {capacity}")
-        if max_len < 1:
-            raise ValueError(f"max_len must be >= 1, got {max_len}")
-        self.capacity = capacity
-        self.max_len = max_len
-        self.dtype = dtype
-        cfg = model.cfg
-        self._slot_bytes = (2 * cfg.num_layers * cfg.num_heads * max_len
-                            * (cfg.hidden_size // cfg.num_heads)
-                            * jnp.dtype(dtype).itemsize)
-        self.caches = init_cache(model, capacity, max_len, dtype)
-        # LIFO free list: the most-recently-freed slot is re-used first,
-        # keeping the active rows clustered low (cheap occupancy reads).
-        self._free: List[int] = list(range(capacity - 1, -1, -1))
-        # A second pool shadowing this one's slot lifecycle (the
-        # speculative engine's DRAFT KV pool): alloc/free mirror by slot
-        # INDEX, so the draft model's cache rows for request R always
-        # live at the same slot as the target's, and freeing the target
-        # slot can never leak the draft's blocks.
-        self.mirror = None
-
-    # ----------------------------------------------------------- alloc
-    def alloc(self) -> Optional[int]:
-        """-> a free slot index, or None when the pool is fully occupied."""
-        slot = self._free.pop() if self._free else None
-        if slot is not None and self.mirror is not None:
-            self.mirror.claim(slot)
-        return slot
-
-    def claim(self, slot: int) -> None:
-        """Take a SPECIFIC free slot (the mirror path: the leader pool
-        chose the index). Raises if the slot is not free — lifecycle
-        drift between the pools must surface, not corrupt."""
-        self._free.remove(slot)
-
-    def free(self, slot: int) -> None:
-        if not 0 <= slot < self.capacity:
-            raise ValueError(f"slot {slot} out of range [0, {self.capacity})")
-        if slot in self._free:
-            raise ValueError(f"slot {slot} is already free (double free)")
-        self._free.append(slot)
-        if self.mirror is not None:
-            self.mirror.free(slot)
-
-    @property
-    def num_free(self) -> int:
-        return len(self._free)
-
-    @property
-    def num_active(self) -> int:
-        return self.capacity - len(self._free)
-
-    @property
-    def occupancy(self) -> float:
-        """Active fraction in [0, 1] — the batch-occupancy gauge value."""
-        return self.num_active / self.capacity
-
-    @property
-    def blocks_used(self) -> int:
-        """Dense pools have no block granularity; report reserved rows
-        in slot units so the ``serve.kv.blocks_used`` gauge stays
-        meaningful across layouts."""
-        return self.num_active
-
-    @property
-    def bytes_resident(self) -> int:
-        """Device bytes the active reservations hold (the
-        ``serve.kv.bytes_resident`` gauge): dense reserves a worst-case
-        ``max_len`` K/V row pair per active slot, whatever was actually
-        written."""
-        return self.num_active * self._slot_bytes
-
-
-def read_slot(pool_leaf, slot):
-    """Slice one slot's rows out of a pooled cache leaf:
-    ``pool_leaf [B_max, H, L_max, D]`` -> ``[1, H, L_max, D]``, ``slot``
-    a traced int32 scalar. Pure — call under jit (the engine's bucket
-    prefill programs run each prompt chunk against this view)."""
-    return lax.dynamic_slice_in_dim(pool_leaf, slot, 1, axis=0)
-
-
-def write_slot(pool_leaf, chunk_leaf, slot):
-    """Write rows back into a slot of a pooled cache leaf:
-    ``pool_leaf [B_max, H, L_max, D]``, ``chunk_leaf [1, H, P, D]``
-    (P <= L_max), ``slot`` a traced int32 scalar. Pure — returns the
-    updated leaf; call under jit (engine prefill program)."""
-    zero = jnp.zeros((), jnp.int32)
-    return lax.dynamic_update_slice(
-        pool_leaf, chunk_leaf.astype(pool_leaf.dtype),
-        (slot, zero, zero, zero))
 
 
 # --------------------------------------------------------------- paged
@@ -529,8 +407,6 @@ class PagedSlotPool:
     - freeing the last reference returns the block to the free list.
     """
 
-    paged = True
-
     def __init__(self, model, capacity: int, max_len: int,
                  dtype=jnp.bfloat16, *, block_size: int = 16,
                  num_blocks: Optional[int] = None,
@@ -568,8 +444,8 @@ class PagedSlotPool:
         # Table width: every slot must be able to reach max_len.
         self.blocks_per_slot = math.ceil(max_len / block_size)
         if num_blocks is None:
-            # Dense-equivalent capacity by default (+1 for scratch):
-            # paged-by-default must never serve LESS than dense did.
+            # Every slot can reach max_len by default (+1 for scratch):
+            # then slots, not blocks, are what admission runs out of.
             num_blocks = 1 + capacity * self.blocks_per_slot
         if num_blocks < 2:
             raise ValueError(
@@ -646,10 +522,13 @@ class PagedSlotPool:
         # once per tier it touched.
         self._peer_blocks: set = set()
         self.fleet_hits = {"device": 0, "host": 0, "peer": 0}
-        # Mirror pool (speculative draft KV — see SlotPool.mirror):
-        # slot lifecycle is mirrored by INDEX; block bookkeeping stays
-        # per-pool (the draft binds its own blocks lazily, sized by the
-        # draft model's geometry). leak_check recurses into it, so the
+        # Mirror pool: a second pool shadowing this one's slot lifecycle
+        # (the speculative engine's DRAFT KV pool). alloc/free mirror by
+        # slot INDEX, so the draft's cache for request R always lives at
+        # the target's slot and freeing the target slot can never leak
+        # the draft's blocks; block bookkeeping stays per-pool (the
+        # draft binds its own blocks lazily, sized by the draft model's
+        # geometry). leak_check recurses into it, so the
         # chaos oracles cover both pools in one call.
         self.mirror = None
 
@@ -664,8 +543,9 @@ class PagedSlotPool:
         return slot
 
     def claim(self, slot: int) -> None:
-        """Take a SPECIFIC free slot (the mirror path — see
-        :meth:`SlotPool.claim`). Raises when the slot is not free."""
+        """Take a SPECIFIC free slot (the mirror path: the leader pool
+        chose the index). Raises when the slot is not free: lifecycle
+        drift between the pools must surface, not corrupt."""
         self._free_slots.remove(slot)
 
     def free(self, slot: int) -> None:

@@ -109,16 +109,12 @@ def test_generate_greedy_parity_kernel_vs_composed():
     assert (a == b).all()
 
 
-def test_decode_impl_env_escape_hatch(monkeypatch):
-    """NEZHA_NO_DECODE_KERNEL=1 forces the composed path even when the
-    config demands the kernel — the day-1 hardware escape hatch."""
+def test_decode_impl_resolution():
+    """``decode_impl`` alone decides: "kernel" forces the kernel,
+    "xla" is the one way to the composed path."""
     from nezha_tpu.models.gpt2 import GPT2Config, _decode_flash_ok
 
-    cfg = GPT2Config(decode_impl="kernel")
-    assert _decode_flash_ok(cfg)
-    monkeypatch.setenv("NEZHA_NO_DECODE_KERNEL", "1")
-    assert not _decode_flash_ok(cfg)
-    monkeypatch.delenv("NEZHA_NO_DECODE_KERNEL")
+    assert _decode_flash_ok(GPT2Config(decode_impl="kernel"))
     assert not _decode_flash_ok(GPT2Config(decode_impl="xla"))
     # auto follows the shared attn_impl resolution: composed on CPU.
     assert not _decode_flash_ok(GPT2Config(decode_impl="auto"))

@@ -56,6 +56,18 @@ def test_prefill_logits_match_plain_forward(model_and_vars):
                                atol=1e-5, rtol=1e-5)
 
 
+def test_per_row_positions_need_the_paged_cache(model_and_vars):
+    """A whole-batch cache takes ONE scalar position; a ``[B]`` vector
+    (every row at its own depth) is the paged cache's and is refused
+    here, not run through the scalar code."""
+    model, variables = model_and_vars
+    cache = init_cache(model, 2, 16, jnp.float32)
+    with pytest.raises(ValueError, match="paged"):
+        model.apply(variables, jnp.asarray([[5], [7]], jnp.int32),
+                    training=False, cache=cache,
+                    pos=jnp.asarray([3, 1], jnp.int32))
+
+
 def test_sampling_is_rng_deterministic(model_and_vars):
     model, variables = model_and_vars
     prompt = np.array([[1, 2, 3]], np.int32)

@@ -90,8 +90,8 @@ def test_host_tier_config_validation():
     # int8-only: the demoted payload is the wire-format bytes verbatim.
     with pytest.raises(ValueError, match="int8"):
         ServeConfig(kv_host_blocks=8)
-    with pytest.raises(ValueError, match="paged"):
-        ServeConfig(kv_layout="dense", kv_host_blocks=8)
+    with pytest.raises(TypeError, match="kv_layout"):
+        ServeConfig(kv_layout="paged", kv_dtype="int8", kv_host_blocks=8)
     with pytest.raises(ValueError, match="prefix_cache"):
         ServeConfig(kv_dtype="int8", prefix_cache=False,
                     kv_host_blocks=8)

@@ -560,5 +560,5 @@ def test_serving_benchmark_kv_dtype_record(tmp_path):
 def test_serveconfig_kv_dtype_validation():
     with pytest.raises(ValueError, match="kv_dtype"):
         ServeConfig(kv_dtype="fp4")
-    with pytest.raises(ValueError, match="paged"):
-        ServeConfig(kv_layout="dense", kv_dtype="int8")
+    with pytest.raises(TypeError, match="kv_layout"):
+        ServeConfig(kv_layout="paged", kv_dtype="int8")

@@ -253,7 +253,6 @@ def test_yarn_frequencies_and_interleave():
 # refusals: typed, at start-up
 @pytest.mark.parametrize("kw, match", [
     ({"kv_dtype": "int8"}, "no block quantizer"),
-    ({"kv_layout": "dense"}, "kv_layout='dense'"),
     ({"speculative": "on"}, "speculative"),
 ])
 def test_typed_refusals(tiny, kw, match):
@@ -263,6 +262,18 @@ def test_typed_refusals(tiny, kw, match):
         kw = {"speculative": SpeculativeConfig(draft_k=2, draft_layers=1)}
     with pytest.raises(ValueError, match=match):
         _engine(model, variables, **kw)
+
+
+def test_latent_cache_without_tables_is_refused(tiny):
+    """The model's own guard: its attention takes the paged cache (a
+    latent pool and the block tables) and nothing else."""
+    model, variables = tiny
+    (name, (shape, dtype)), = model.cache_leaves(8, jnp.float32).items()
+    rows = [{name: jnp.zeros((3,) + tuple(shape), dtype)}
+            for _ in range(model.cfg.num_layers)]
+    with pytest.raises(ValueError, match="block-paged only"):
+        model.apply(variables, jnp.zeros((1, 4), jnp.int32),
+                    cache=rows, pos=jnp.zeros((), jnp.int32))
 
 
 def test_cli_builds_the_stack_and_refuses_what_it_cannot_serve():

@@ -3,13 +3,15 @@ shared-prefix prefill reuse.
 
 Covers the pool/trie bookkeeping (alloc/free/ref counts/COW/eviction,
 zero-leak accounting), the block-table operand of the flash-decode
-kernel, engine parity against the dense layout and one-shot generate(),
+kernel, engine parity against one-shot generate(),
 prefix-hit reuse (a templated request takes block references instead of
 re-prefilling — and still decodes bit-identically), the stale-KV reuse
-invariant for BOTH layouts (a freed block/slot rebound to a new request
-is never attendable before that request overwrites it — proven by
-poisoning freed storage with NaN), typed block-exhaustion backpressure
-(victim retired, batch survives), the ``serve.kv.bind`` fault point,
+invariant on float32 and bfloat16 pools (a freed block rebound to a new
+request is never attendable before that request overwrites it — proven by
+poisoning freed storage with a huge sentinel; the int8 pool's case, scale
+rows included, is tests/test_kv_quant.py's), typed block-exhaustion
+backpressure (victim retired, batch survives), the ``serve.kv.bind`` fault
+point,
 and a seeded chaos run asserting zero slot AND block leaks with the
 frozen program count and schema-valid artifacts.
 """
@@ -44,7 +46,6 @@ CFG = dict(vocab_size=97, max_positions=64, num_layers=2, num_heads=4,
 PCFG = ServeConfig(max_batch_size=3, max_len=48, max_prefill_len=8,
                    prefill_buckets=(4, 8), k_max=16, queue_capacity=8,
                    cache_dtype=jnp.float32, kv_block_size=4)
-DCFG = dataclasses.replace(PCFG, kv_layout="dense")
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 for sub in ("tools", "benchmarks"):
@@ -76,7 +77,7 @@ def test_paged_pool_alloc_bind_free_refcounts(model_and_vars):
     model, _ = model_and_vars
     pool = PagedSlotPool(model, capacity=2, max_len=16,
                          dtype=jnp.float32, block_size=4)
-    # Dense-equivalent default: 1 scratch + 2 slots * 4 blocks.
+    # Default: every slot can reach max_len: 1 scratch + 2 slots * 4.
     assert pool.num_blocks == 9 and pool.blocks_per_slot == 4
     assert pool.blocks_used == 0
     s = pool.alloc()
@@ -90,6 +91,15 @@ def test_paged_pool_alloc_bind_free_refcounts(model_and_vars):
     pool.free(s)
     assert pool.blocks_used == 0 and pool.num_free == 2
     assert (pool.tables_host[s] == 0).all()   # table reset to scratch
+    # The slot layer (the scheduler's whole view of the pool): slots run
+    # out before blocks do here, and the last slot freed is the next out.
+    a, b = pool.alloc(), pool.alloc()
+    assert {a, b} == {0, 1} and pool.alloc() is None
+    assert pool.num_active == 2 and pool.occupancy == 1.0
+    pool.free(a)
+    assert pool.num_free == 1 and pool.alloc() == a
+    pool.free(a)
+    pool.free(b)
     with pytest.raises(ValueError, match="double free"):
         pool.free(s)
     with pytest.raises(ValueError, match="out of range"):
@@ -273,34 +283,29 @@ def test_paged_decode_kernel_reads_no_block_a_row_does_not_own(kind):
 
 
 # --------------------------------------------------------- engine parity
-def test_paged_engine_matches_dense_and_generate(model_and_vars):
-    """Greedy, sampled, and chunked-prompt requests decode identically
-    on the paged and dense layouts, and greedy matches one-shot
-    generate() — the block indirection is a memory layout, never a
-    semantic. The frozen program count holds for both."""
+def test_paged_engine_matches_generate(model_and_vars):
+    """Greedy requests (one of them a chunked prompt), batched with a
+    sampled one, match one-shot generate() token for token — the block
+    indirection is a memory layout, never a semantic — and the sampled
+    request fills its budget. The frozen program count holds."""
     model, variables = model_and_vars
     reqs = [dict(prompt=[5, 17, 3, 42], max_new_tokens=10),
             dict(prompt=[7, 7], max_new_tokens=9, temperature=0.9,
                  top_k=10, seed=7),
             dict(prompt=[(7 * i + 3) % 97 for i in range(20)],
                  max_new_tokens=6)]
-    outs = {}
-    for name, cfg in (("paged", PCFG), ("dense", DCFG)):
-        eng = Engine(model, variables, cfg)
-        sched = Scheduler(eng)
-        rids = [sched.submit(Request(**kw)) for kw in reqs]
-        _drain(sched)
-        outs[name] = [sched.results[r].tokens for r in rids]
-        stats = eng.compile_stats()
-        assert stats["entries"] == stats["misses"] == \
-            1 + len(cfg.prefill_buckets)
-        if name == "paged":
-            eng.pool.leak_check()
-    assert outs["paged"] == outs["dense"]
-    assert outs["paged"][0] == _greedy_ref(model, variables,
-                                           reqs[0]["prompt"], 10)
-    assert outs["paged"][2] == _greedy_ref(model, variables,
-                                           reqs[2]["prompt"], 6)
+    eng = Engine(model, variables, PCFG)
+    sched = Scheduler(eng)
+    rids = [sched.submit(Request(**kw)) for kw in reqs]
+    _drain(sched)
+    outs = [sched.results[r].tokens for r in rids]
+    stats = eng.compile_stats()
+    assert stats["entries"] == stats["misses"] == \
+        1 + len(PCFG.prefill_buckets)
+    eng.pool.leak_check()
+    assert outs[0] == _greedy_ref(model, variables, reqs[0]["prompt"], 10)
+    assert len(outs[1]) == 9
+    assert outs[2] == _greedy_ref(model, variables, reqs[2]["prompt"], 6)
 
 
 def test_prefix_hit_skips_prefill_and_decodes_identically(
@@ -381,7 +386,7 @@ def test_cow_on_shared_block_write_with_live_donor(model_and_vars):
 _POISON = 1.0e3   # finite but logit-wrecking if a single stale
                   # position ever gets nonzero attention weight
                   # (NaN would ALSO poison legitimately-masked scores
-                  # through the additive -inf mask — the layouts'
+                  # through the additive -inf mask — the pool's
                   # guarantee is zero WEIGHT on stale positions, which
                   # only a finite sentinel tests honestly; the flash
                   # kernel path additionally never loads them)
@@ -389,40 +394,34 @@ _POISON = 1.0e3   # finite but logit-wrecking if a single stale
 
 def _poison_free_storage(eng):
     """Overwrite every cache position a retired request left behind
-    (paged: all free blocks; dense: the whole pool — every slot is free
-    after drain) with a huge sentinel. If ANY stale position were
+    (all free blocks) with a huge sentinel. If ANY stale position were
     attendable before its new owner overwrites it, the sentinel would
     visibly skew the logits and the token-for-token reference
     comparison below would fail."""
-    if eng.paged:
-        idx = jnp.asarray(sorted(eng.pool._free_blocks), jnp.int32)
-        eng.pool.caches = [
-            {kv: leaf.at[idx].set(_POISON)
-             for kv, leaf in layer.items()}
-            for layer in eng.pool.caches]
-    else:
-        eng.pool.caches = [
-            {kv: jnp.full_like(leaf, _POISON)
-             for kv, leaf in layer.items()}
-            for layer in eng.pool.caches]
+    idx = jnp.asarray(sorted(eng.pool._free_blocks), jnp.int32)
+    eng.pool.caches = [
+        {kv: leaf.at[idx].set(_POISON)
+         for kv, leaf in layer.items()}
+        for layer in eng.pool.caches]
 
 
-@pytest.mark.parametrize("layout", ["paged", "dense"])
-def test_stale_kv_never_attendable_after_rebind(model_and_vars, layout):
-    """THE reuse invariant slots.py documents: a freed block (or slot
-    row) rebound to a new request must never be attendable before that
+@pytest.mark.parametrize("storage", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_stale_kv_never_attendable_after_rebind(model_and_vars, storage):
+    """THE reuse invariant slots.py documents: a freed block rebound
+    to a new request must never be attendable before that
     request overwrites it. Serve a request, retire it, poison all freed
-    storage with NaN, then serve a different request through the same
-    storage — its tokens must match a clean-engine reference exactly
-    (any attention over stale positions would surface as a NaN logit
-    burst and an ERROR retirement)."""
+    storage, then serve a different request through the same
+    storage — its tokens must match a clean reference exactly
+    (generate() over a whole-batch cache of the same dtype). The int8
+    pool's case, scale rows included, is test_kv_quant.py's
+    ``test_int8_stale_kv_and_stale_scales_never_attendable``."""
     model, variables = model_and_vars
-    cfg = PCFG if layout == "paged" else DCFG
-    if layout == "paged":
-        # prefix_cache off: every block the first request bound is
-        # genuinely FREED at retirement (no trie refs), so the poison
-        # covers the exact storage the second request rebinds.
-        cfg = dataclasses.replace(cfg, prefix_cache=False)
+    # prefix_cache off: every block the first request bound is
+    # genuinely FREED at retirement (no trie refs), so the poison
+    # covers the exact storage the second request rebinds.
+    cfg = dataclasses.replace(PCFG, prefix_cache=False,
+                              cache_dtype=storage)
     eng = Engine(model, variables, cfg)
     sched = Scheduler(eng)
     first = sched.submit(Request(
@@ -435,44 +434,41 @@ def test_stale_kv_never_attendable_after_rebind(model_and_vars, layout):
     _drain(sched)
     res = sched.results[second]
     assert res.finish_reason == "length", res.error
-    assert res.tokens == _greedy_ref(model, variables, prompt2, 8)
-    if layout == "paged":
-        eng.pool.leak_check()
+    ref = np.asarray(generate(
+        model, variables, np.asarray([prompt2], np.int32),
+        max_new_tokens=8, temperature=0.0,
+        cache_dtype=storage))[0, len(prompt2):].tolist()
+    assert res.tokens == ref
+    eng.pool.leak_check()
 
 
 # ------------------------------------------------ occupancy + exhaustion
 def test_paged_admits_more_residents_than_dense_at_equal_memory(
         model_and_vars):
-    """The tentpole's occupancy claim at engine level: with the SAME
-    device KV budget (96 token-positions), the dense layout caps at 2
-    resident requests (2 slots x worst-case 48), while the paged pool
-    runs 4 short requests concurrently — because blocks bind for
-    tokens actually written, not for max_len."""
+    """The paged pool's occupancy claim at engine level: with a
+    device KV budget of 96 token-positions, a worst-case reservation of
+    max_len positions a request admits 96 // 48 = 2 residents, while the
+    paged pool runs 4 short requests concurrently — because blocks bind
+    for tokens actually written, not for max_len."""
     model, variables = model_and_vars
-    dense = Engine(model, variables, dataclasses.replace(
-        DCFG, max_batch_size=2))                       # 2 * 48 = 96
+    budget = 96
     paged = Engine(model, variables, dataclasses.replace(
         PCFG, max_batch_size=4, kv_block_size=8,
-        kv_num_blocks=13))                             # 12 * 8 = 96
-    reqs = [Request(prompt=[3 + i, 1, 4, 1], max_new_tokens=8,
-                    request_id=f"r{i}") for i in range(6)]
-    peaks = {}
-    for name, eng in (("dense", dense), ("paged", paged)):
-        sched = Scheduler(eng)
-        for r in reqs:
-            sched.submit(dataclasses.replace(r))
-        peak = 0
-        for _ in range(400):
-            if not sched.has_work():
-                break
-            sched.step()
-            peak = max(peak, len(sched._live))
-        assert not sched.has_work()
-        assert all(sched.results[f"r{i}"].finish_reason == "length"
-                   for i in range(6))
-        peaks[name] = peak
-    assert peaks["dense"] == 2
-    assert peaks["paged"] == 4           # strictly more, equal memory
+        kv_num_blocks=1 + budget // 8))                # 12 * 8 = 96
+    sched = Scheduler(paged)
+    for i in range(6):
+        sched.submit(Request(prompt=[3 + i, 1, 4, 1], max_new_tokens=8,
+                             request_id=f"r{i}"))
+    peak = 0
+    for _ in range(400):
+        if not sched.has_work():
+            break
+        sched.step()
+        peak = max(peak, len(sched._live))
+    assert not sched.has_work()
+    assert all(sched.results[f"r{i}"].finish_reason == "length"
+               for i in range(6))
+    assert peak == 4 > budget // PCFG.max_len   # more, at equal memory
     paged.pool.leak_check()
 
 
@@ -556,8 +552,8 @@ def test_prefix_hit_falls_back_to_cold_prefill_in_tight_pool(
     only reclaimable block is the one the hit just referenced). The
     engine must fall back to a COLD prefill — releasing the hit's
     references makes the block evictable again — and serve the
-    request, not retire it with a deterministic error a dense pool
-    would never produce."""
+    request, not retire it with a deterministic error a pool with no
+    prefix cache would never produce."""
     model, variables = model_and_vars
     # 3 usable blocks, blocks_per_slot 3 (max_len 12, bs 4).
     eng = Engine(model, variables, dataclasses.replace(
@@ -760,14 +756,26 @@ def test_chaos_paged_zero_block_leaks(model_and_vars, tmp_path):
 
 # ------------------------------------------------- config + bench + CLI
 def test_serveconfig_kv_validation():
-    with pytest.raises(ValueError, match="kv_layout"):
-        ServeConfig(kv_layout="sparse")
+    with pytest.raises(TypeError, match="kv_layout"):
+        ServeConfig(kv_layout="paged")   # the paged pool is the one layout
     with pytest.raises(ValueError, match="kv_block_size"):
         ServeConfig(kv_block_size=0)
     with pytest.raises(ValueError, match="kv_num_blocks"):
         ServeConfig(kv_num_blocks=1)
     with pytest.raises(ValueError, match="kv_eviction"):
         ServeConfig(kv_eviction="fifo")
+
+
+def test_cli_refuses_the_removed_kv_layout_flag(capsys):
+    """An old command line that still passes ``--kv-layout`` is told so
+    by argparse (exit 2, the argument named), not served in silence."""
+    from nezha_tpu.cli import serve as cli
+
+    with pytest.raises(SystemExit) as e:
+        cli.main(["--random-init", "--model-preset", "tiny",
+                  "--kv-layout", "dense"])
+    assert e.value.code == 2
+    assert "--kv-layout" in capsys.readouterr().err
 
 
 def test_serving_benchmark_shared_prefix_record(tmp_path):
@@ -793,14 +801,6 @@ def test_serving_benchmark_shared_prefix_record(tmp_path):
     assert sp["ttft_hit_s"]["p50"] > 0 and sp["ttft_miss_s"]["p50"] > 0
     from check_telemetry_schema import check_run_dir
     assert check_run_dir(run_dir) == []
-
-    # The dense before/after knob still runs (and reports no hits).
-    rec_d = bench.run(bench.build_parser().parse_args(
-        ["--requests", "4", "--concurrency", "2", "--max-new-tokens",
-         "2", "--max-batch-size", "2", "--max-len", "32",
-         "--max-prefill-len", "8", "--kv-layout", "dense"]))
-    assert rec_d["kv"]["layout"] == "dense"
-    assert rec_d["kv"]["prefix_hits"] == 0
 
 
 def test_nezha_bench_gates_against_committed_baseline(tmp_path):

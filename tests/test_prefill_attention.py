@@ -553,17 +553,6 @@ def test_chaos_kernel_prefill_zero_leaks_and_telemetry(model_and_vars,
                for e in check_run_dir(run_dir))
 
 
-def test_env_escape_hatch_kills_kernel(model_and_vars, monkeypatch):
-    """``NEZHA_NO_PREFILL_KERNEL=1`` beats even an explicit
-    ``prefill_impl="kernel"`` — the day-1 rollback needs no config
-    push — and the gauge reports the fallback."""
-    model, variables = model_and_vars
-    monkeypatch.setenv("NEZHA_NO_PREFILL_KERNEL", "1")
-    cfg = dataclasses.replace(PCFG, prefill_impl="kernel")
-    eng = Engine(model, variables, cfg)
-    assert not eng.prefill_kernel_active
-
-
 def test_serve_config_validates_prefill_impl():
     with pytest.raises(ValueError, match="prefill_impl"):
         ServeConfig(prefill_impl="mosaic")

@@ -57,7 +57,6 @@ PCFG = ServeConfig(max_batch_size=2, max_len=48, max_prefill_len=8,
                    prefill_buckets=(4, 8), k_max=16, queue_capacity=8,
                    cache_dtype=jnp.float32, kv_block_size=4,
                    preemption=True, preemption_budget=2)
-DCFG = dataclasses.replace(PCFG, kv_layout="dense")
 
 
 @pytest.fixture(scope="module")
@@ -70,12 +69,6 @@ def model_and_vars():
 def paged_engine(model_and_vars):
     model, variables = model_and_vars
     return Engine(model, variables, PCFG)
-
-
-@pytest.fixture(scope="module")
-def dense_engine(model_and_vars):
-    model, variables = model_and_vars
-    return Engine(model, variables, DCFG)
 
 
 @pytest.fixture(autouse=True)
@@ -201,9 +194,11 @@ def _run_reference(engine, rid, prompt, max_new):
 def _preempt_resume_case(engine):
     """Shared body of the bit-identical preempt -> resume check: a
     background decode is suspended mid-stream by two interactive
-    arrivals, demoted (blocks -> trie -> host tier on the paged
-    layout; a cold re-prefill on dense), resumed, and must emit
-    exactly the uninterrupted stream."""
+    arrivals, demoted (its blocks go to the trie; with the prefix
+    cache off nothing is indexed and resume is a cold re-prefill),
+    resumed, and must emit exactly the uninterrupted stream. Returns
+    the prefix hits the resume took (the interactive prompts are
+    shorter than a block, so they can take none)."""
     prompt = [5, 9, 14, 20, 27, 35]
     ref = _run_reference(engine, "ref", prompt, max_new=12)
 
@@ -219,6 +214,7 @@ def _preempt_resume_case(engine):
     # The second interactive could only get its slot by suspending the
     # strictly-lower-priority background decode.
     assert sched.preempted_count == 1
+    hits_before = engine.pool.prefix_hits
     _drain(sched)
     assert sched.preempted_count == 0
     for rid in ("i0", "i1"):
@@ -227,15 +223,31 @@ def _preempt_resume_case(engine):
     assert res.finish_reason == FinishReason.LENGTH
     assert res.tokens == ref, "resume is not bit-identical"
     assert engine.pool.num_free == engine.cfg.max_batch_size
+    return engine.pool.prefix_hits - hits_before
 
 
-def test_preempt_resume_bit_identical_paged(paged_engine):
-    _preempt_resume_case(paged_engine)
-    paged_engine.pool.leak_check()
+@pytest.mark.parametrize("storage", ["float32", "int8"])
+def test_preempt_resume_bit_identical_paged(model_and_vars, paged_engine,
+                                            storage):
+    """Resume through the trie: the victim's full blocks (on the int8
+    pool its scale rows with them) are referenced again, not
+    recomputed."""
+    engine = paged_engine
+    if storage == "int8":
+        engine = Engine(*model_and_vars,
+                        dataclasses.replace(PCFG, kv_dtype="int8"))
+    assert _preempt_resume_case(engine) == 1
+    engine.pool.leak_check()
 
 
-def test_preempt_resume_bit_identical_dense(dense_engine):
-    _preempt_resume_case(dense_engine)
+def test_preempt_resume_bit_identical_cold(model_and_vars):
+    """The other arm of ``_preempt``: with the prefix cache off nothing
+    is indexed, the victim's blocks are freed and resume pays a cold
+    re-prefill of prompt + emitted tokens."""
+    engine = Engine(*model_and_vars,
+                    dataclasses.replace(PCFG, prefix_cache=False))
+    assert _preempt_resume_case(engine) == 0
+    engine.pool.leak_check()
 
 
 def test_deadline_while_preempted(paged_engine):

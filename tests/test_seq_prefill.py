@@ -19,8 +19,7 @@ Pins, per the acceptance list:
   ``long_prefill_buckets=()``;
 - config/CLI validation is typed and early (mode and variant names,
   long-bucket monotonicity and range, bucket divisibility by the mesh,
-  the single-device refusal) and ``NEZHA_NO_SEQ_PREFILL=1`` is the
-  no-config-push rollback;
+  the single-device refusal);
 - the ``serve.prefill.seq`` chaos point: an injected error retires
   ONLY the victim request with zero slot/block/scale leaks per shard;
 - the telemetry (``serve.prefill.seq_shards`` gauge,
@@ -233,19 +232,7 @@ def test_plan_chunks_long_buckets_and_classic_reduction(model_and_vars):
     assert classic._plan_chunks(3) == [(0, 3, 4)]
 
 
-# -------------------------------------------------- validation + hatch
-def test_env_escape_hatch_kills_seq_prefill(model_and_vars, ref_tokens,
-                                            monkeypatch):
-    """``NEZHA_NO_SEQ_PREFILL=1`` beats an explicit
-    ``prefill_mode="sequence"`` — the engine silently serves the
-    replicated path (same tokens, no config push needed)."""
-    model, variables = model_and_vars
-    monkeypatch.setenv("NEZHA_NO_SEQ_PREFILL", "1")
-    eng = ShardedEngine(model, variables, _seq(SCFG), mesh_devices=2)
-    assert not eng._seq_active
-    assert _greedy(eng, PROMPTS) == ref_tokens
-
-
+# ------------------------------------------------------- validation
 def test_single_device_engine_rejects_sequence_mode(model_and_vars):
     model, variables = model_and_vars
     with pytest.raises(ValueError, match="mesh"):

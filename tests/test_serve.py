@@ -23,7 +23,6 @@ from nezha_tpu.serve import (
     Request,
     Scheduler,
     ServeConfig,
-    SlotPool,
     sample_tokens,
 )
 
@@ -53,25 +52,6 @@ def _drain(sched, max_iters=200):
     iters = sched.run_until_idle(max_iters=max_iters)
     assert not sched.has_work(), "scheduler did not drain"
     return iters
-
-
-# ------------------------------------------------------------- slot pool
-def test_slot_pool_alloc_free(model_and_vars):
-    model, _ = model_and_vars
-    pool = SlotPool(model, capacity=2, max_len=8, dtype=jnp.float32)
-    a, b = pool.alloc(), pool.alloc()
-    assert {a, b} == {0, 1} and pool.alloc() is None
-    assert pool.num_active == 2 and pool.occupancy == 1.0
-    pool.free(a)
-    assert pool.num_free == 1 and pool.alloc() == a
-    with pytest.raises(ValueError, match="double free"):
-        pool.free(b)
-        pool.free(b)
-    with pytest.raises(ValueError, match="out of range"):
-        pool.free(7)
-    assert pool.caches[0]["k"].shape == (2, CFG["num_heads"], 8,
-                                         CFG["hidden_size"]
-                                         // CFG["num_heads"])
 
 
 # ------------------------------------------------------ per-row sampling

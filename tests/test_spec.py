@@ -2,8 +2,8 @@
 device-resident horizon scan.
 
 Covers the parity gates (greedy outputs bit-identical speculative vs
-classic on both KV layouts, h=1 and h=8, chunked prefill included; the
-lossless rejection-sampling law on the sampling kernels; sampled spec
+classic with the prefix trie on and off, h=1 and h=8, chunked prefill
+included; the lossless rejection-sampling law on the sampling kernels; sampled spec
 outputs horizon-invariant), the on-device completion semantics (EOS
 inside an accepted prefix freezes the row mid-window — overshoot never
 reaches the client), the frozen TWO-ENGINE program-count contract
@@ -86,26 +86,28 @@ def _run(model, variables, cfg):
 
 
 # ------------------------------------------------------------ parity
-@pytest.mark.parametrize("layout", ["paged", "dense"])
+@pytest.mark.parametrize("prefix_cache", [True, False],
+                         ids=["trie", "no-trie"])
 def test_greedy_parity_spec_vs_classic_bit_identical(model_and_vars,
-                                                     layout):
+                                                     prefix_cache):
     """The ISSUE 13 parity gate: with speculative ON every request's
     output (greedy AND sampled-within-spec across horizons) matches —
     greedy rows bit-identical to the CLASSIC engine and to one-shot
     generate(), at h=1 and h=8, chunked prompts included. Every
     accepted draft token is verified against the target, so the draft
-    (a 1-layer early-exit) can only change speed, never tokens."""
+    (a 1-layer early-exit) can only change speed, never tokens. The
+    draft pool never has a prefix trie; the target's may."""
     model, variables = model_and_vars
     outs = {}
     for h in (1, 8):
-        base = dataclasses.replace(SCFG, kv_layout=layout,
+        base = dataclasses.replace(SCFG, prefix_cache=prefix_cache,
                                    decode_horizon=h)
         _, classic = _run(model, variables, base)
         eng, spec = _run(model, variables,
                          dataclasses.replace(base, speculative=SPEC))
         # Greedy rows: bit-identical to classic, reason and all.
         for rid in ("g0", "g1"):
-            assert spec[rid] == classic[rid], (layout, h, rid)
+            assert spec[rid] == classic[rid], (prefix_cache, h, rid)
         # The speculation actually ran and accepted draft tokens.
         assert eng.spec_verifies > 0
         assert eng.spec_accepted > 0

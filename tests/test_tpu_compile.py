@@ -296,12 +296,12 @@ def _serve_programs(model, quantized, v5e, *, slots, table, block, chunk,
              spec((b,), jnp.float32), spec((b,), jnp.int32),
              spec((b,), jnp.int32))
     last, pos, keys, temps, top_ks, top_ps, eos, budgets = state
-    step = jax.jit(_build_step(model, 64, 0, 1, paged=True),
+    step = jax.jit(_build_step(model, 64, 0, 1),
                    donate_argnums=(1,)).lower(
         variables, caches, tables, last, pos, spec((b,), jnp.bool_), keys,
         temps, top_ks, top_ps, eos, budgets).compile()
     i32, f32 = spec((), jnp.int32), spec((), jnp.float32)
-    prefill = jax.jit(_build_prefill(model, chunk, paged=True,
+    prefill = jax.jit(_build_prefill(model, chunk,
                                      quantized=quantized),
                       donate_argnums=(1,)).lower(
         variables, caches, tables, spec((1, chunk), jnp.int32), i32, i32, i32,
