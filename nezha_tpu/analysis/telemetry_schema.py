@@ -67,6 +67,12 @@ _SERVE_COUNTERS = {"serve.admitted_total", "serve.rejected_total",
                    # a dense model reports 0s, never omits them.
                    "serve.moe.pairs_total",
                    "serve.moe.held_pairs_total",
+                   # Sampling (PR 29): decode steps (speculative:
+                   # windows) in which some row's nucleus was wider
+                   # than the k_max head, so the vocabulary was sorted.
+                   # Traffic-invariant: top-k and peaked top-p traffic
+                   # report 0, never omit it.
+                   "serve.sampling.full_sort_steps_total",
                    # Cross-replica KV migration (PR 11, disaggregated
                    # prefill/decode tiers): committed installs and
                    # their int8-wire bytes. Topology-invariant: a

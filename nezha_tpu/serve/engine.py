@@ -91,9 +91,10 @@ from jax import lax
 from nezha_tpu import faults, obs
 from nezha_tpu.runtime.executor import Executor
 from nezha_tpu.serve.sampling import (accept_mask, categorical_rows,
-                                      filter_logits, filtered_probs,
+                                      filter_logits_and_flag,
                                       finite_rows, residual_logits,
-                                      sample_tokens, split_and_sample)
+                                      sample_tokens_and_flag,
+                                      split_and_sample)
 from nezha_tpu.serve.slots import KVBlocksExhausted, PagedSlotPool
 
 
@@ -1050,14 +1051,14 @@ class Engine:
                 jnp.asarray(active, bool), self.keys,
                 self.temps, self.top_ks, self.top_ps,
                 self.eos_ids, self.budgets)
-            (tok, emitted, ok, caches, last, pos, keys, budgets,
-             *load) = out
+            (tok, emitted, ok, full_sorts, caches, last, pos, keys,
+             budgets, *load) = out
             # Start the block's device->host transfers NOW, before any
             # host bookkeeping (state rebinds here, retire/admit/stream
             # in the scheduler): the fetches below then find bytes
             # already in flight instead of paying the full sync
             # serially.
-            _start_host_copies(tok, emitted, ok, *load)
+            _start_host_copies(tok, emitted, ok, full_sorts, *load)
         self.pool.caches = caches
         if faults.enabled():
             last = faults.corrupt(
@@ -1069,8 +1070,10 @@ class Engine:
             # The host blocked on the device: the block's fetches.
             self.step_ok = np.asarray(ok)
             tok_h, emitted_h = np.asarray(tok), np.asarray(emitted)
+            full_sorts_h = int(np.asarray(full_sorts))
             if load:
                 self.last_expert_load = np.asarray(load[0])
+        obs.counter("serve.sampling.full_sort_steps_total").inc(full_sorts_h)
         if load:
             self._record_expert_load(int(np.count_nonzero(active)))
         # Advance the host position/budget mirrors by the block's
@@ -1125,9 +1128,9 @@ class Engine:
                 jnp.asarray(active, bool), self.keys,
                 self.temps, self.top_ks, self.top_ps,
                 self.eos_ids, self.budgets, self.residual)
-            (tok, emitted, ok, win_emitted, caches_all, last, pos, keys,
-             budgets, residual) = out
-            _start_host_copies(tok, emitted, ok, win_emitted)
+            (tok, emitted, ok, win_emitted, full_sorts, caches_all, last,
+             pos, keys, budgets, residual) = out
+            _start_host_copies(tok, emitted, ok, win_emitted, full_sorts)
         self.pool.caches, self.draft_pool.caches = caches_all
         if faults.enabled():
             # The pinned verify-step fault point: a nan/inf rule
@@ -1145,6 +1148,8 @@ class Engine:
             self.step_ok = np.asarray(ok)
             tok_h, emitted_h = np.asarray(tok), np.asarray(emitted)
             win_h = np.asarray(win_emitted)
+            full_sorts_h = int(np.asarray(full_sorts))
+        obs.counter("serve.sampling.full_sort_steps_total").inc(full_sorts_h)
         # Speculation ledger: every window that emitted >= 1 token ran
         # one verify forward; its accepted-prefix length is (e_w - 1)
         # draft tokens (the t0 column is the classic carried-logits
@@ -1320,8 +1325,12 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int):
         # budget >= 1) — it guards the degenerate budget-0 row a direct
         # engine caller could create, which must emit nothing.
         emit = active & ~done & ok & (emitted < budgets)
-        next_keys, tok = split_and_sample(keys, last_logits, temps,
-                                          top_ks, top_ps, k_max)
+        # A row that emits nothing has its token replaced by the pad, so
+        # it gets no nucleus: an empty or retired slot's stale top_p over
+        # stale logits must not send the step into the vocabulary sort.
+        next_keys, tok, full_sort = split_and_sample(
+            keys, last_logits, temps, top_ks,
+            jnp.where(emit, top_ps, 1.0), k_max)
         tok = jnp.where(emit, tok, pad_id)
         rows = _with_tables(caches, tables)
         logits, states = model.apply(variables, tok[:, None],
@@ -1344,7 +1353,7 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int):
                 jnp.where(act, row_logits, last_logits),
                 jnp.where(emit, positions + 1, positions),
                 jnp.where(act, next_keys, keys),
-                done, ok, emitted), (tok, load)
+                done, ok, emitted), (tok, full_sort, load)
 
     def step(variables, caches, tables, last_logits, positions, active,
              keys, temps, top_ks, top_ps, eos_ids, budgets):
@@ -1361,16 +1370,19 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int):
         if horizon == 1:
             # Inline, not a length-1 scan: the default must stay
             # bit-identical to the classic single-token step program.
-            carry, (tok, load) = scan_body(init, None)
+            carry, (tok, full_sort, load) = scan_body(init, None)
             tok_block = tok[:, None]
         else:
-            carry, (toks, loads) = lax.scan(scan_body, init, None,
-                                            length=horizon)
+            carry, (toks, full_sort, loads) = lax.scan(
+                scan_body, init, None, length=horizon)
             tok_block = jnp.transpose(toks, (1, 0))        # [H,B]->[B,H]
             load = None if loads is None else loads.sum(axis=0)
         caches, last_logits, positions, keys, done, ok, emitted = carry
-        out = (tok_block, emitted, ok, caches, last_logits, positions,
-               keys, jnp.maximum(budgets - emitted, 0))
+        # How many of the block's steps sorted the vocabulary (sampling's
+        # wide-nucleus branch): 0 or 1 at horizon 1.
+        full_sorts = jnp.sum(full_sort, dtype=jnp.int32)
+        out = (tok_block, emitted, ok, full_sorts, caches, last_logits,
+               positions, keys, jnp.maximum(budgets - emitted, 0))
         return out if load is None else out + (load,)
 
     return step
@@ -1449,8 +1461,11 @@ def _build_spec_step(model, draft_model, k_max: int, pad_id: int,
         # spec streams stay aligned with the classic stream at every
         # window boundary. Residual rows draw a RAW categorical: their
         # carried logits are already-filtered log-probs.
-        t_cls = sample_tokens(last_logits, sub0, temps, top_ks, top_ps,
-                              k_max)
+        # Rows that emit nothing this window get no nucleus (as in the
+        # classic step): their draws and distributions are never used.
+        top_ps = jnp.where(emit0, top_ps, 1.0)
+        t_cls, sorted0 = sample_tokens_and_flag(
+            last_logits, sub0, temps, top_ks, top_ps, k_max)
         t_res = categorical_rows(sub0, last_logits)
         t0 = jnp.where(residual, t_res, t_cls)
         t0 = jnp.where(emit0, t0, pad_id).astype(jnp.int32)
@@ -1471,15 +1486,16 @@ def _build_spec_step(model, draft_model, k_max: int, pad_id: int,
             # p = 0 and always rejects — matching the support is what
             # keeps sampled accept rates near the draft's actual
             # fidelity.
-            fl = filter_logits(row, temps, top_ks, top_ps, k_max)
+            fl, sorted_d = filter_logits_and_flag(row, temps, top_ks,
+                                                  top_ps, k_max)
             dkey = jax.vmap(
                 lambda kk: jax.random.fold_in(kk, 1 + j))(keys)
             d = jnp.where(greedy, jnp.argmax(row, axis=-1),
                           categorical_rows(dkey, fl)).astype(jnp.int32)
             d = jnp.where(emit0, d, pad_id)
-            return (dc2, d), (d, jax.nn.softmax(fl, axis=-1))
+            return (dc2, d), (d, jax.nn.softmax(fl, axis=-1), sorted_d)
 
-        (dcaches, _), (d_all, q_all) = lax.scan(
+        (dcaches, _), (d_all, q_all, sorted_d) = lax.scan(
             dstep, (dcaches, t0), jnp.arange(w))
         win = jnp.concatenate(
             [t0[:, None], jnp.transpose(d_all[:k], (1, 0))], axis=1)
@@ -1498,9 +1514,15 @@ def _build_spec_step(model, draft_model, k_max: int, pad_id: int,
         ok = jnp.where(emit0, ok & okrow, ok)
 
         tmax = jnp.argmax(vlog, axis=-1).astype(jnp.int32)    # [B, w]
-        pf = jax.vmap(
-            lambda l: filtered_probs(l, temps, top_ks, top_ps, k_max),
-            in_axes=1, out_axes=1)(vlog[:, :k, :])            # [B, k, V]
+        # The k verify positions of a row as k rows with the row's
+        # params (row-major, so a reshape splits them again): a vmap
+        # would batch the filter's "any row needs the sort" predicate
+        # and turn its cond into a select that always sorts.
+        per_pos = lambda a: jnp.repeat(a, k)
+        pf_logits, sorted_v = filter_logits_and_flag(
+            vlog[:, :k, :].reshape(b * k, -1), per_pos(temps),
+            per_pos(top_ks), per_pos(top_ps), k_max)
+        pf = jax.nn.softmax(pf_logits, axis=-1).reshape(b, k, -1)
         qf = jnp.transpose(q_all[:k], (1, 0, 2))              # [B, k, V]
         u = jax.vmap(lambda kk: jax.random.uniform(
             jax.random.fold_in(kk, w + 1), (k,)))(keys)       # [B, k]
@@ -1559,7 +1581,7 @@ def _build_spec_step(model, draft_model, k_max: int, pad_id: int,
 
         return ((new_caches, dcaches, last_new, positions + e, keys_new,
                  done, ok, emitted_new, residual_new),
-                (tok_out, emit_w, e))
+                (tok_out, emit_w, e, sorted0 | sorted_d.any() | sorted_v))
 
     def spec_step(variables, caches_all, dvariables, tables, dtables,
                   last_logits, positions, active, keys, temps, top_ks,
@@ -1578,13 +1600,13 @@ def _build_spec_step(model, draft_model, k_max: int, pad_id: int,
                           dtables, carry)
 
         if horizon == 1:
-            carry, (tok_w, emit_m, e_w) = scan_body(init, None)
+            carry, (tok_w, emit_m, e_w, full_sort) = scan_body(init, None)
             toks = tok_w[:, None, :]
             mask = emit_m[:, None, :]
             win_emitted = e_w[:, None]
         else:
-            carry, (tok_s, emit_s, e_s) = lax.scan(scan_body, init,
-                                                   None, length=horizon)
+            carry, (tok_s, emit_s, e_s, full_sort) = lax.scan(
+                scan_body, init, None, length=horizon)
             toks = jnp.transpose(tok_s, (1, 0, 2))     # [B, H, w]
             mask = jnp.transpose(emit_s, (1, 0, 2))
             win_emitted = jnp.transpose(e_s, (1, 0))   # [B, H]
@@ -1603,7 +1625,9 @@ def _build_spec_step(model, draft_model, k_max: int, pad_id: int,
             stable=True)
         tok_block = jnp.take_along_axis(
             jnp.where(mask_flat, tok_flat, pad_id), order, axis=1)
-        return (tok_block, emitted, ok, win_emitted,
+        # Windows of the block in which some filter sorted the vocabulary.
+        full_sorts = jnp.sum(full_sort, dtype=jnp.int32)
+        return (tok_block, emitted, ok, win_emitted, full_sorts,
                 (caches, dcaches), last_logits, positions, keys,
                 jnp.maximum(budgets - emitted, 0), residual)
 

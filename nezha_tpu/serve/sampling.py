@@ -16,7 +16,10 @@ mix of requests:
   clamped to ``[1, k_max]``;
 - top-p is the same exclusive-cumsum nucleus as ``_sample`` with p
   broadcast per row (``p >= 1`` keeps everything, ``p <= 0`` degrades to
-  argmax via the rank-0 term — never an empty nucleus);
+  argmax via the rank-0 term — never an empty nucleus), read off the
+  ``k_max`` head top-k already extracted: the vocabulary is sorted only
+  in a step where some row's nucleus is wider than the head
+  (:func:`filter_logits_and_flag`);
 - rows draw from their OWN PRNG key (vmapped categorical), so sampling
   rows are also isolated: a request's token sequence depends only on its
   seed and its step count, never on who shares the batch.
@@ -64,6 +67,74 @@ def finite_rows(logits) -> jax.Array:
     return jnp.isfinite(logits).all(axis=-1)
 
 
+def _sorted_nucleus_threshold(scaled, top_p) -> jax.Array:
+    """``[B, V]`` top-k survivors (the rest at ``-inf``) -> ``[B, 1]``
+    nucleus threshold by a sort of the whole row: the smallest value
+    whose exclusive cumulative mass is under ``top_p``. What a row costs
+    when its nucleus is wider than the ``k_max`` head (the second branch
+    of :func:`filter_logits_and_flag`'s ``cond``)."""
+    sorted_logits = jnp.flip(jnp.sort(scaled, axis=-1), axis=-1)
+    probs = jax.nn.softmax(sorted_logits, axis=-1)
+    exclusive_cum = jnp.cumsum(probs, axis=-1) - probs
+    rank = lax.broadcasted_iota(jnp.int32, sorted_logits.shape, 1)
+    keep = (exclusive_cum < top_p[:, None]) | (rank == 0)
+    return jnp.min(jnp.where(keep, sorted_logits, jnp.inf), axis=-1,
+                   keepdims=True)
+
+
+def filter_logits_and_flag(logits, temperature, top_k, top_p,
+                           k_max: int):
+    """:func:`filter_logits` plus a scalar bool: whether this call ran
+    the full-vocabulary sort (the step program returns it; the engine
+    counts ``serve.sampling.full_sort_steps_total``).
+
+    The ``k_max`` largest scaled logits ``lax.top_k`` returns (the
+    "head", in descending order) are, once the row's top-k rule is
+    applied to them, the first ``k_max`` entries of the sorted
+    survivors, so the nucleus threshold is read off their exclusive
+    cumulative mass; the normaliser is one ``exp``-sum over ALL the
+    row's survivors, which counts ties at the k-th value that lie
+    outside the head. That is exact unless every head entry is inside
+    the nucleus AND survivors lie below the head's last value (top-k
+    off, a flat distribution): only such a row needs the sort, and one
+    ``lax.cond`` on "any row needs it" runs it. ``top_p >= 1`` is no
+    nucleus at all."""
+    b, v = logits.shape
+    if not 1 <= k_max <= v:
+        raise ValueError(f"k_max must be in [1, {v}], got {k_max}")
+    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+
+    # Per-row top-k under the static cap: the k_max'th-largest values are
+    # computed once; each row thresholds at its own (clamped) k-th value.
+    head = lax.top_k(scaled, k_max)[0]                        # [B, k_max]
+    k_eff = jnp.clip(top_k, 1, k_max)
+    kth = jnp.take_along_axis(head, (k_eff - 1)[:, None], axis=1)
+    apply_k = (top_k > 0)[:, None]
+    scaled = jnp.where(apply_k & (scaled < kth), -jnp.inf, scaled)
+    head = jnp.where(apply_k & (head < kth), -jnp.inf, head)
+
+    # Per-row nucleus from the head (same construction as
+    # generate._sample over the sorted row, p per row).
+    norm = jnp.sum(jnp.exp(scaled - head[:, :1]), axis=-1, keepdims=True)
+    probs = jnp.exp(head - head[:, :1]) / norm
+    exclusive_cum = jnp.cumsum(probs, axis=-1) - probs
+    rank = lax.broadcasted_iota(jnp.int32, head.shape, 1)
+    keep = (exclusive_cum < top_p[:, None]) | (rank == 0)
+    threshold = jnp.min(jnp.where(keep, head, jnp.inf), axis=-1,
+                        keepdims=True)
+
+    nucleus = top_p < 1.0
+    below_head = ((scaled < head[:, -1:]) & (scaled > -jnp.inf)).any(axis=-1)
+    wide = nucleus & keep[:, -1] & below_head
+    full_sort = wide.any()
+    sorted_threshold = lax.cond(
+        full_sort, _sorted_nucleus_threshold,
+        lambda s, p: jnp.full((b, 1), -jnp.inf, s.dtype), scaled, top_p)
+    threshold = jnp.where(wide[:, None], sorted_threshold, threshold)
+    threshold = jnp.where(nucleus[:, None], threshold, -jnp.inf)
+    return jnp.where(scaled < threshold, -jnp.inf, scaled), full_sort
+
+
 def filter_logits(logits, temperature, top_k, top_p,
                   k_max: int) -> jax.Array:
     """The per-row temperature/top-k/top-p truncation, factored out of
@@ -72,36 +143,20 @@ def filter_logits(logits, temperature, top_k, top_p,
     ``[B, V]`` scaled logits with truncated entries at ``-inf``.
     Sampling from the result (``categorical``) is exactly what
     :func:`sample_tokens` does for non-greedy rows."""
-    b, v = logits.shape
-    if not 1 <= k_max <= v:
-        raise ValueError(f"k_max must be in [1, {v}], got {k_max}")
-    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
-
-    # Per-row top-k under the static cap: the k_max'th-largest values are
-    # computed once; each row thresholds at its own (clamped) k-th value.
-    kth_vals = lax.top_k(scaled, k_max)[0]                    # [B, k_max]
-    k_eff = jnp.clip(top_k, 1, k_max)
-    kth = jnp.take_along_axis(kth_vals, (k_eff - 1)[:, None], axis=1)
-    apply_k = (top_k > 0)[:, None]
-    scaled = jnp.where(apply_k & (scaled < kth), -jnp.inf, scaled)
-
-    # Per-row nucleus (same construction as generate._sample, p per row).
-    sorted_logits = jnp.flip(jnp.sort(scaled, axis=-1), axis=-1)
-    probs = jax.nn.softmax(sorted_logits, axis=-1)
-    exclusive_cum = jnp.cumsum(probs, axis=-1) - probs
-    rank = lax.broadcasted_iota(jnp.int32, sorted_logits.shape, 1)
-    keep = (exclusive_cum < top_p[:, None]) | (rank == 0)
-    threshold = jnp.min(
-        jnp.where(keep, sorted_logits, jnp.inf), axis=-1, keepdims=True)
-    return jnp.where(scaled < threshold, -jnp.inf, scaled)
+    return filter_logits_and_flag(logits, temperature, top_k, top_p,
+                                  k_max)[0]
 
 
-def filtered_probs(logits, temperature, top_k, top_p,
-                   k_max: int) -> jax.Array:
-    """``softmax(filter_logits(...))`` — the probability vector the
-    speculative rejection test and residual are computed over."""
-    return jax.nn.softmax(filter_logits(logits, temperature, top_k,
-                                        top_p, k_max), axis=-1)
+def sample_tokens_and_flag(logits, keys, temperature, top_k, top_p,
+                           k_max: int):
+    """:func:`sample_tokens` plus :func:`filter_logits_and_flag`'s
+    scalar: ``(token ids [B], full_sort)``."""
+    greedy = temperature <= 0.0
+    scaled, full_sort = filter_logits_and_flag(logits, temperature, top_k,
+                                               top_p, k_max)
+    sampled = jax.vmap(jax.random.categorical)(keys, scaled)
+    tok = jnp.where(greedy, jnp.argmax(logits, axis=-1), sampled)
+    return tok.astype(jnp.int32), full_sort
 
 
 def sample_tokens(logits, keys, temperature, top_k, top_p,
@@ -110,26 +165,24 @@ def sample_tokens(logits, keys, temperature, top_k, top_p,
     temperature/top_p ``[B]`` float, top_k ``[B]`` int (``<= 0`` = off),
     ``k_max`` static int (``1 <= k_max <= V``) -> token ids ``[B]``.
     """
-    greedy = temperature <= 0.0
-    scaled = filter_logits(logits, temperature, top_k, top_p, k_max)
-    sampled = jax.vmap(jax.random.categorical)(keys, scaled)
-    return jnp.where(greedy, jnp.argmax(logits, axis=-1),
-                     sampled).astype(jnp.int32)
+    return sample_tokens_and_flag(logits, keys, temperature, top_k, top_p,
+                                  k_max)[0]
 
 
 def split_and_sample(keys, logits, temperature, top_k, top_p,
                      k_max: int):
     """One decode step's sampling move: split every row's PRNG key and
     sample from the carried logits. ``keys`` ``[B, 2]`` -> ``(next_keys
-    [B, 2], tokens [B])``. The caller commits ``next_keys`` only for
-    rows whose token is actually emitted — that is what keeps a
-    request's RNG stream a function of (seed, emitted count) alone, so
-    the same request samples bit-identical tokens at any decode horizon
-    and next to any batch mix."""
+    [B, 2], tokens [B], full_sort)``, the last as
+    :func:`filter_logits_and_flag` gives it. The caller commits
+    ``next_keys`` only for rows whose token is actually emitted — that
+    is what keeps a request's RNG stream a function of (seed, emitted
+    count) alone, so the same request samples bit-identical tokens at
+    any decode horizon and next to any batch mix."""
     splits = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
-    tok = sample_tokens(logits, splits[:, 1], temperature, top_k, top_p,
-                        k_max)
-    return splits[:, 0], tok
+    tok, full_sort = sample_tokens_and_flag(
+        logits, splits[:, 1], temperature, top_k, top_p, k_max)
+    return splits[:, 0], tok, full_sort
 
 
 # ------------------------------------------------- speculative decoding

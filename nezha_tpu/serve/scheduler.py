@@ -227,6 +227,10 @@ def register_serve_instruments() -> None:
     obs.counter("serve.moe.pairs_total")
     obs.counter("serve.moe.held_pairs_total")
     obs.gauge("serve.moe.load_max_over_mean")
+    # Decode steps whose sampling sorted the whole vocabulary (a row's
+    # nucleus wider than the k_max head; serve/sampling.py). 0 for
+    # top-k traffic and for peaked top-p traffic.
+    obs.counter("serve.sampling.full_sort_steps_total")
     # Cross-replica migration (disaggregated prefill/decode tiers,
     # serve/migrate.py): committed installs and their wire bytes —
     # migration GB/s is bytes / the router.migrate span durations.

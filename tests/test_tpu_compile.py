@@ -401,3 +401,55 @@ def test_mistral4_step_program_has_no_pool_shaped_copy(mistral4_programs):
     assert not re.findall(r" = " + pool + r"\S* copy\(", text)
     # and the program's fetch carries the expert-load counter
     assert re.search(r"s32\[1,32\]", text.split("ENTRY", 1)[1])
+
+
+# ---- Sampling sorts the vocabulary only inside a conditional (PR 29): the
+# nucleus threshold comes from the k_max head the native ``TopK`` returns,
+# and the sort is the second branch of one ``lax.cond`` on "some row's
+# nucleus is wider than the head". Before, ``sort f32[256,50257]`` ran every
+# decode step: 18.5 ms of a 51 ms step in the GPT-2 cell.
+def _sorts_outside_conditional_branches(text):
+    """The ``sort`` instructions of an HLO module that run whenever the
+    program does: those not in a conditional's branch computation, nor in
+    anything only such a branch calls."""
+    bodies = {m[1]: m[2] for m in re.finditer(
+        r"^(?:ENTRY )?%(\S+) \(.*?\) -> [^\n]*\{\n(.*?)^\}", text,
+        re.M | re.S)}
+    branch_only = set()
+    for m in re.finditer(r"branch_computations=\{([^}]*)\}"
+                         r"|(?:true|false)_computation=(%\S+)", text):
+        branch_only.update(
+            n.strip().strip("%,") for n in (m[1] or m[2]).split(","))
+    frontier = list(branch_only)
+    while frontier:
+        for name in re.findall(r"%([\w.\-]+)", bodies.get(frontier.pop(), "")):
+            if name in bodies and name not in branch_only:
+                branch_only.add(name)
+                frontier.append(name)
+    assert any(re.search(r" sort\(", bodies[n]) for n in branch_only
+               if n in bodies)              # the wide-nucleus branch is there
+    return [line.strip()[:160] for name, body in bodies.items()
+            if name not in branch_only
+            for line in body.splitlines() if re.search(r" sort\(", line)]
+
+
+@pytest.mark.parametrize("served", ["gpt2", "mistral4"])
+def test_serve_step_program_sorts_the_vocabulary_only_under_a_conditional(
+        request, served):
+    """At the cells' deployments (GPT-2: 256 slots, vocabulary 50,257;
+    Mistral-Small-4: 128 slots, 32,768 held): the native ``TopK`` is in
+    the step program and no vocabulary-wide ``sort`` outside the branch
+    computations of a ``conditional``. GPT-2's step has no other sort
+    at all; Mistral's dropless experts sort their token-expert pairs."""
+    if served == "gpt2":
+        text = request.getfixturevalue("gpt2_programs")("bf16")[
+            "step"].as_text()
+        vocab = f"[{CELL_BATCH},50257]"
+    else:
+        text = request.getfixturevalue("mistral4_programs")["step"]
+        vocab = f"[{M4_SLOTS},32768]"
+    assert re.search(r'custom_call_target="TopK"', text)
+    always = _sorts_outside_conditional_branches(text)
+    assert not [line for line in always if vocab in line]
+    if served == "gpt2":
+        assert always == []
