@@ -130,6 +130,9 @@ _SERVE_COUNTERS = {"serve.admitted_total", "serve.rejected_total",
                    "serve.tenant_over_limit_total"}
 _SERVE_GAUGES = {"serve.queue_depth", "serve.batch_occupancy",
                  "serve.kv.blocks_used",
+                 # Ring blocks the taken slots hold for window layers
+                 # (PR 30; 0 for a model without window layers).
+                 "serve.kv.window_blocks_used",
                  # KV quantization (PR 9): device bytes the resident KV
                  # holds and the storage width in bits (8 = int8 blocks
                  # + per-block scales, 16/32 = plain bf16/f32 pools).

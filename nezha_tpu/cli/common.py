@@ -181,8 +181,9 @@ def load_gpt2_for_inference(args):
 
 def load_model_for_inference(args):
     """(model, variables) for ``nezha-serve --model``: GPT-2 from any of
-    its three weight sources, or Mistral-Small-4 with random weights
-    (the one source it has: no checkpoint converter exists for it)."""
+    its three weight sources, or Mistral-Small-4 / K-EXAONE with random
+    weights (the one source they have: no checkpoint converter exists
+    for them)."""
     if getattr(args, "model", "gpt2") == "gpt2":
         return load_gpt2_for_inference(args)
     if not getattr(args, "random_init", False):
@@ -191,9 +192,11 @@ def load_model_for_inference(args):
             f"checkpoint or Hugging Face converter exists for it)")
     import jax
 
-    from nezha_tpu.models.mistral4 import mistral_small4
-
-    model = mistral_small4(args.model_preset)
+    if args.model == "k_exaone":
+        from nezha_tpu.models.exaone_moe import k_exaone as build
+    else:
+        from nezha_tpu.models.mistral4 import mistral_small4 as build
+    model = build(args.model_preset)
     # model.init builds the tree leaf by leaf in the policy's parameter
     # dtype (bf16 at the full preset: 2 bytes a parameter on the device).
     return model, model.init(jax.random.PRNGKey(args.seed))

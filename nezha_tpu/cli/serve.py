@@ -79,13 +79,21 @@ def build_parser() -> argparse.ArgumentParser:
                      help="Hugging Face GPT2LMHeadModel directory")
     src.add_argument("--random-init", action="store_true",
                      help="fresh random weights (smoke/benchmark runs)")
-    p.add_argument("--model", choices=["gpt2", "mistral_small4"],
+    p.add_argument("--model",
+                   choices=["gpt2", "mistral_small4", "k_exaone"],
                    default="gpt2",
-                   help="the architecture served: gpt2, or "
+                   help="the architecture served: gpt2 (every option); "
                         "mistral_small4 (Mistral-Small-4: latent "
-                        "attention, dropless experts; --random-init "
-                        "only, --model-preset full = one chip's share "
-                        "at the published widths, bf16 parameters)")
+                        "attention, dropless experts) or k_exaone "
+                        "(K-EXAONE: grouped-query heads, window layers "
+                        "in a ring of blocks beside global layers, "
+                        "sigmoid-routed experts). The last two: "
+                        "--random-init only, --model-preset full = one "
+                        "chip's share at the published widths, bf16 "
+                        "parameters; refused with --mesh, --kv-dtype "
+                        "int8, --kv-host-blocks, --speculative, KV "
+                        "migration and peer pulls; k_exaone also with "
+                        "--prefix-cache on")
     p.add_argument("--model-preset", choices=["full", "tiny"],
                    default="full")
     p.add_argument("--tokenizer", default=None,
@@ -419,7 +427,10 @@ def _build_stack(args):
             raise SystemExit(
                 f"--model {args.model}: not supported with "
                 f"{', '.join(unsupported)} (its cache is a latent row a "
-                f"token, not per-head K/V)")
+                f"token, or window layers in a ring of blocks, not "
+                f"per-head K/V in one growing table)")
+        # (--prefix-cache on with window layers is the pool's own typed
+        # refusal, from the model's declaration: "serve engine: ...")
     if mesh_m > 1 and getattr(args, "ckpt_dir", None):
         # The implicit nezha-reshard: build the serve mesh first, then
         # stream the training checkpoint straight into the head-sharded

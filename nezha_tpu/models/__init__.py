@@ -12,6 +12,8 @@ _LAZY = {
     "Bert": "bert", "BertConfig": "bert", "bert_base": "bert",
     "Mistral4": "mistral4", "Mistral4Config": "mistral4",
     "mistral_small4": "mistral4",
+    "ExaoneMoe": "exaone_moe", "ExaoneMoeConfig": "exaone_moe",
+    "k_exaone": "exaone_moe",
     "generate": "generate", "init_cache": "generate",
     "gpt2_from_hf": "convert", "bert_from_hf": "convert",
     "gpt2_params_from_hf": "convert", "gpt2_params_to_hf": "convert",

@@ -1056,10 +1056,12 @@ class GPT2(Module):
 
     # ------------------------------------- what serve.Engine asks a model
     def cache_leaves(self, block_size: int, dtype, quantized: bool = False
-                     ) -> dict:
-        """The per-layer cache leaves of one pool block: name -> (trailing
-        shape, dtype); ``PagedSlotPool`` allocates ``[num_blocks, ...]`` of
-        each. K and V are LANE-DENSE rows: ``(block_size, H*D)``, a
+                     ) -> list:
+        """One entry a layer, ``(group, window, leaves)``: the cache
+        group the layer's blocks live in (every layer here: the growing
+        group, no window) and the leaves of one pool block, name ->
+        (trailing shape, dtype); ``PagedSlotPool`` allocates
+        ``[num_blocks, ...]`` of each. K and V are LANE-DENSE rows: ``(block_size, H*D)``, a
         position's heads side by side in lanes (head ``h`` in lanes
         ``h*D .. (h+1)*D``), so the pool ``[N, bs, H*D]`` is whole
         128-lane tiles in the device's own row-major layout and no
@@ -1068,10 +1070,12 @@ class GPT2(Module):
         cfg = self.cfg
         kv = (block_size, cfg.hidden_size)
         if quantized:
-            return {"k": (kv, jnp.int8), "v": (kv, jnp.int8),
-                    "k_scale": ((cfg.num_heads,), jnp.float32),
-                    "v_scale": ((cfg.num_heads,), jnp.float32)}
-        return {"k": (kv, dtype), "v": (kv, dtype)}
+            leaves = {"k": (kv, jnp.int8), "v": (kv, jnp.int8),
+                      "k_scale": ((cfg.num_heads,), jnp.float32),
+                      "v_scale": ((cfg.num_heads,), jnp.float32)}
+        else:
+            leaves = {"k": (kv, dtype), "v": (kv, dtype)}
+        return [("global", None, leaves)] * cfg.num_layers
 
     def caches_from_states(self, states: dict, prev: list) -> list:
         return [states.get(f"h{i}", {}).get("attn", {}).get("cache", prev[i])

@@ -402,16 +402,18 @@ class Mistral4(Module):
 
     # ------------------------------------- what serve.Engine asks a model
     def cache_leaves(self, block_size: int, dtype, quantized: bool = False
-                     ) -> dict:
-        """The per-layer cache leaves of one pool block: name -> (trailing
-        shape, dtype). One latent row a token, no head axis, the row (in
-        whole 128-lane tiles) as the minor dimension."""
+                     ) -> list:
+        """One entry a layer, ``(group, window, leaves)``: every layer in
+        the growing group, and the leaves of one pool block, name ->
+        (trailing shape, dtype). One latent row a token, no head axis,
+        the row (in whole 128-lane tiles) as the minor dimension."""
         if quantized:
             raise ValueError(
                 "kv_dtype='int8': the latent cache has no block quantizer "
                 "(its rows are a normed latent and a rotated key, not "
                 "per-head K/V)")
-        return {"latent": ((block_size, self.cfg.latent_row_width), dtype)}
+        leaves = {"latent": ((block_size, self.cfg.latent_row_width), dtype)}
+        return [("global", None, leaves)] * self.cfg.num_hidden_layers
 
     def caches_from_states(self, states: dict, prev: list) -> list:
         return [states.get(f"h{i}", {}).get("attn", {}).get("cache", prev[i])

@@ -20,6 +20,8 @@ run in interpret mode on CPU (tests) and compile on TPU.
 from nezha_tpu.ops.pallas.decode_attention import (
     flash_decode_attention,
     flash_decode_attention_sharded,
+    paged_attention_composed,
+    ring_entries,
 )
 from nezha_tpu.ops.pallas.flash_attention import flash_attention
 from nezha_tpu.ops.pallas.layer_norm import fused_layer_norm
@@ -30,4 +32,5 @@ from nezha_tpu.ops.pallas.prefill_attention import (
 
 __all__ = ["flash_attention", "flash_decode_attention",
            "flash_decode_attention_sharded", "flash_prefill_attention",
-           "flash_prefill_attention_sharded", "fused_layer_norm"]
+           "flash_prefill_attention_sharded", "fused_layer_norm",
+           "paged_attention_composed", "ring_entries"]

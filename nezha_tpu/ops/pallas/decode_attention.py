@@ -113,7 +113,8 @@ _ENTRIES_PER_STEP = 8
 
 
 def _paged_decode_kernel(tab_ref, len_ref, q_ref, *refs, scale: float,
-                         block_size: int, entries: int, quant: bool):
+                         block_size: int, entries: int, quant: bool,
+                         group: int = 1, window: int = 0, ring: int = 0):
     """One grid step = ``entries`` consecutive table entries of one row,
     ALL heads. The pool is lane-dense: each of the ``entries`` K and V
     refs is one pool block ``(1, bs, H*D)`` (the index maps of
@@ -141,7 +142,21 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, *refs, scale: float,
     and V's scale multiplies ``p`` before its dot: the same quantity as
     ``ops.quant.dequantize_kv_rows`` followed by the dots, without
     rounding the dequantized tile to bf16. Softmax statistics and the
-    accumulator stay fp32."""
+    accumulator stay fp32.
+
+    GROUPED QUERY HEADS (``group`` query heads read one K/V head): the
+    pool's rows are ``KVH*D`` lanes and ``q_ref`` is ``(1, H, KVH*D)``,
+    row ``h`` holding query head ``h`` in the lanes of K/V head
+    ``h // group``: the same two matmuls give all ``H`` heads' scores
+    and outputs, and head ``h``'s output is the ``h // group``-th
+    ``D``-lane block of its row. ``group == 1`` is the case above.
+
+    A WINDOW call (``window`` > 0) reads a RING of ``ring`` table
+    entries: position ``p`` lives in entry ``(p // bs) % ring``, so
+    entry ``e`` holds block ``cur - (cur - e) % ring`` of the row's
+    positions (``cur`` the block of its last position), a key is
+    visible iff ``length - window <= kpos < length``, and the row's
+    walk is ``ring / entries`` steps whatever its length."""
     c = entries
     k_refs, v_refs, refs = refs[:c], refs[c:2 * c], refs[2 * c:]
     ks_ref = vs_ref = None
@@ -178,9 +193,23 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, *refs, scale: float,
     # repeat the blocks of the row's last live step, so the pipeline
     # issues no DMA for them either. A row with length == 0 (inactive
     # slot) runs no step at all and finalizes to an all-zero output.
-    @pl.when(ki * span < length)
+    def key_positions(shape):
+        """The position of each of the step's ``span`` keys."""
+        lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+        if not ring:
+            return ki * span + lane
+        cur = (length - 1) // block_size
+        entry = lane // block_size
+        blk = jnp.zeros(shape, jnp.int32)
+        for j in range(c):
+            e = ki * c + j
+            blk = jnp.where(entry == j,
+                            cur - lax.rem(cur - e + ring, ring), blk)
+        return (blk - entry) * block_size + lane
+
+    @pl.when((length > 0) if ring else (ki * span < length))
     def _block():
-        q = q_ref[0]                                         # [H, H*D]
+        q = q_ref[0]                                         # [H, KVH*D]
         k = jnp.concatenate([r[0] for r in k_refs], axis=0)  # [span, H*D]
         v = jnp.concatenate([r[0] for r in v_refs], axis=0)
         s = lax.dot_general(q, k.astype(q.dtype),
@@ -188,8 +217,11 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, *refs, scale: float,
                             preferred_element_type=jnp.float32) * scale
         if quant:
             s = s * entry_scales(ks_ref)
-        kpos = ki * span + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < length, s, NEG_BIG)             # [H, span]
+        kpos = key_positions(s.shape)
+        visible = kpos < length
+        if window:
+            visible &= kpos >= jnp.maximum(length - window, 0)
+        s = jnp.where(visible, s, NEG_BIG)                   # [H, span]
         m_prev = m_scr[:, :1]
         l_prev = l_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -209,10 +241,11 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, *refs, scale: float,
     def _final():
         # common.softmax_finalize over every head's row (no lse:
         # inference only); a row that folded nothing stays exactly zero.
-        # Head h's output is the h-th diagonal D-lane block of its row.
+        # Head h's output is the D-lane block of its K/V head in its row.
         out = acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)
         for h in range(heads):
-            o_ref[0, h] = out[h:h + 1, h * d:(h + 1) * d].astype(
+            g = h // group
+            o_ref[0, h] = out[h:h + 1, g * d:(g + 1) * d].astype(
                 o_ref.dtype)
 
 
@@ -235,19 +268,20 @@ def _visited_entries(tab, lens, block_size: int, entries: int):
 
 
 def _paged_call(q, k, v, lengths, block_tables, scale, interpret,
-                block_scales=None):
-    """Paged layout: k/v are LANE-DENSE BLOCK POOLS ``[N, bs, H*D]`` and
-    ``block_tables [B, M]`` maps row b's KV block ki to pool block
+                block_scales=None, window=None):
+    """Paged layout: k/v are LANE-DENSE BLOCK POOLS ``[N, bs, KVH*D]``
+    and ``block_tables [B, M]`` maps row b's KV block ki to pool block
     ``block_tables[b, ki]``. Table and lengths ride as SCALAR-PREFETCH
     operands (pltpu.PrefetchScalarGridSpec); the grid is ``(B, M / c)``
     and each pool is passed ``c`` times, operand ``j`` gathering table
     entry ``ki * c + j`` in its index map (XLA feeds all of them from
-    one buffer). A pool block is ``bs`` whole rows of ``H*D`` lanes,
-    contiguous in HBM, so one DMA brings all ``H`` heads of an entry
-    and no lane of it is padding. The table is first rewritten by
+    one buffer). A pool block is ``bs`` whole rows of ``KVH*D`` lanes,
+    contiguous in HBM, so one DMA brings all heads of an entry and no
+    lane of it is padding. A full table is first rewritten by
     :func:`_visited_entries` (one index load is then all an index map
     costs the scalar core, which is what a skipped step's time is made
-    of). The query goes in block-diagonally (``[B, H, H*D]``, built
+    of); a ring (``window``) is walked as it stands, ``M / c`` steps a
+    row. The query goes in block-diagonally (``[B, H, KVH*D]``, built
     here by one small XLA fusion; see :func:`_paged_decode_kernel`).
     With ``block_scales`` (int8 pools) the row's per-block fp32 scales
     are pre-gathered through the same rewritten table
@@ -260,18 +294,27 @@ def _paged_call(q, k, v, lengths, block_tables, scale, interpret,
     tiles) and which would stop the scalar core walking the steps that
     move nothing (1.44 of 1.85 ms a call: PERF.md section 6, PR 27)."""
     b, h, _, d = q.shape
-    bs = k.shape[1]
-    hd = h * d
+    bs, hd = k.shape[1], k.shape[2]
+    kvh = hd // d
+    group = h // kvh
     m = block_tables.shape[1]
     c = _pick_block(m, _ENTRIES_PER_STEP)
     quant = block_scales is not None
-    kernel = functools.partial(_paged_decode_kernel, scale=scale,
-                               block_size=bs, entries=c, quant=quant)
-    lens = jnp.clip(jnp.asarray(lengths, jnp.int32), 0, m * bs)
-    tab = _visited_entries(jnp.asarray(block_tables, jnp.int32), lens,
-                           bs, c)
-    q_bd = (q[:, :, 0, None, :]
-            * jnp.eye(h, dtype=q.dtype)[None, :, :, None]).reshape(b, h, hd)
+    kernel = functools.partial(
+        _paged_decode_kernel, scale=scale, block_size=bs, entries=c,
+        quant=quant, group=group, window=window or 0,
+        ring=m if window else 0)
+    tab = jnp.asarray(block_tables, jnp.int32)
+    if window:
+        lens = jnp.maximum(jnp.asarray(lengths, jnp.int32), 0)
+    else:
+        lens = jnp.clip(jnp.asarray(lengths, jnp.int32), 0, m * bs)
+        tab = _visited_entries(tab, lens, bs, c)
+    # row h: head h's values in the lanes of K/V head h // group
+    lanes_of = (jnp.arange(h)[:, None] // group
+                == jnp.arange(kvh)[None, :]).astype(q.dtype)
+    q_bd = (q[:, :, 0, None, :] * lanes_of[None, :, :, None]).reshape(
+        b, h, hd)
     q_spec = pl.BlockSpec((1, h, hd), lambda b_, ki, tab, lens: (b_, 0, 0))
     out_spec = pl.BlockSpec((1, h, 1, d),
                             lambda b_, ki, tab, lens: (b_, 0, 0, 0))
@@ -303,15 +346,64 @@ def _paged_call(q, k, v, lengths, block_tables, scale, interpret,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=("nezha_decode_attention_paged_int8" if quant
+              else "nezha_decode_attention_window" if window
               else "nezha_decode_attention_paged"),
     )(tab, lens, *operands)
+
+
+def ring_entries(window: int, block_size: int) -> int:
+    """Table entries of a window layer's ring: the blocks a window can
+    straddle plus the one being written, ``ceil(window / bs) + 1``."""
+    return -(-window // block_size) + 1
+
+
+def paged_attention_composed(q, k, v, lengths, block_tables,
+                             scale: Optional[float] = None,
+                             window: Optional[int] = None):
+    """What the paged kernels compute, composed from ``jax.numpy`` over
+    the gathered view of each row's table (``[B, M*bs, KVH*D]``): the
+    path a model takes where no kernel runs, and the other side of the
+    kernels' interpret-mode tests. Same operands and conventions as
+    :func:`flash_decode_attention` with ``block_tables`` (grouped query
+    heads, a ring with ``window``); ``q`` may hold several queries a
+    row (``[B, H, S, D]``, query ``i`` at position ``lengths - S + i``).
+    -> ``[B, H, S, D]``."""
+    b, h, s, d = q.shape
+    bs, hd = k.shape[1], k.shape[2]
+    kvh, m = hd // d, block_tables.shape[1]
+    scale = scale if scale is not None else 1.0 / (d ** 0.5)
+    lens = jnp.asarray(lengths, jnp.int32)
+    entry = jnp.arange(m, dtype=jnp.int32)[None, :]
+    if window:
+        cur = (jnp.maximum(lens, 1) - 1)[:, None] // bs
+        blk = cur - jnp.remainder(cur - entry, m)        # [B, M]
+    else:
+        blk = jnp.broadcast_to(entry, (b, m))
+    kpos = (blk[:, :, None] * bs + jnp.arange(bs)[None, None, :]).reshape(
+        b, 1, m * bs)
+    qpos = (lens[:, None] - s + jnp.arange(s)[None, :])[:, :, None]
+    visible = (kpos >= 0) & (kpos <= qpos) & (qpos >= 0)
+    if window:
+        visible &= qpos - kpos < window
+    kk = k[block_tables].reshape(b, m * bs, kvh, d).astype(q.dtype)
+    vv = v[block_tables].reshape(b, m * bs, kvh, d).astype(q.dtype)
+    qg = q.reshape(b, kvh, h // kvh, s, d)
+    sc = jnp.einsum("bkgsd,blkd->bkgsl", qg, kk,
+                    preferred_element_type=jnp.float32) * scale
+    sc = jnp.where(visible[:, None, None], sc, NEG_BIG)
+    p = jnp.exp(sc - sc.max(axis=-1, keepdims=True))
+    p = jnp.where(visible[:, None, None], p, 0.0)
+    p = p / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
+    out = jnp.einsum("bkgsl,blkd->bkgsd", p.astype(vv.dtype), vv)
+    return out.reshape(b, h, s, d)
 
 
 def flash_decode_attention(q, k, v, lengths,
                            scale: Optional[float] = None,
                            block_k: Optional[int] = None,
                            interpret: Optional[bool] = None,
-                           block_tables=None, block_scales=None):
+                           block_tables=None, block_scales=None,
+                           window: Optional[int] = None):
     """q ``[B, H, 1, D]``, k/v ``[B, H, L, D]``, lengths ``[B]`` int32
     -> ``[B, H, 1, D]``.
 
@@ -342,6 +434,14 @@ def flash_decode_attention(q, k, v, lengths,
     (exact in bf16), softmax statistics and the accumulator stay
     fp32.
 
+    GROUPED QUERY HEADS (paged only): pools of ``KVH*D`` lanes with
+    ``H`` a multiple of ``KVH``; query head ``h`` reads K/V head
+    ``h // (H / KVH)``. With ``window`` (paged only) the table is a
+    RING (:func:`ring_entries` entries or more): position ``p`` lives
+    in entry ``(p // block_size) % M``, ``lengths`` is not bounded by
+    the table, and query ``lengths - 1`` sees the ``window`` keys that
+    end with itself.
+
     ``block_k`` defaults to the largest divisor of ``L`` that is <= 256
     (KV pools are padded to power-of-two-ish capacities, so real shapes
     get real blocks). ``interpret=None`` auto-selects: compiled on TPU,
@@ -356,11 +456,27 @@ def flash_decode_attention(q, k, v, lengths,
     if block_scales is not None and block_tables is None:
         raise ValueError("block_scales requires block_tables (int8 is "
                          "a paged-pool format)")
+    if window is not None and block_tables is None:
+        raise ValueError("window requires block_tables (a ring of blocks)")
     if block_tables is not None:
-        if k.shape != v.shape or k.ndim != 3 or k.shape[2] != h * d:
+        if (k.shape != v.shape or k.ndim != 3 or k.shape[2] % d
+                or h % (k.shape[2] // d)):
             raise ValueError(
                 f"paged k/v pools {k.shape}/{v.shape} do not match q "
-                f"{q.shape}: want [num_blocks, block_size, H*D]")
+                f"{q.shape}: want [num_blocks, block_size, KVH*D] with "
+                f"H a multiple of KVH")
+        kvh = k.shape[2] // d
+        if window is not None and not (
+                window >= 1 and block_tables.shape[1]
+                >= ring_entries(window, k.shape[1])):
+            raise ValueError(
+                f"a window of {window} over blocks of {k.shape[1]} needs "
+                f"a ring of {ring_entries(max(window, 1), k.shape[1])} "
+                f"entries, got a table of {block_tables.shape[1]}")
+        if block_scales is not None and (kvh != h or window is not None):
+            raise ValueError(
+                "int8 pools (block_scales) have no grouped-query or "
+                "window form: the scales are per (block, query head)")
         if block_tables.shape[0] != b:
             raise ValueError(
                 f"block_tables {block_tables.shape} does not match "
@@ -374,7 +490,8 @@ def flash_decode_attention(q, k, v, lengths,
                     f"[num_blocks, H] = {want}")
         scale = scale if scale is not None else 1.0 / (d ** 0.5)
         return _paged_call(q, k, v, lengths, block_tables, scale,
-                           interpret, block_scales=block_scales)
+                           interpret, block_scales=block_scales,
+                           window=window)
     if k.shape != v.shape or k.shape[:2] != (b, h) or k.shape[3] != d:
         raise ValueError(f"k/v {k.shape}/{v.shape} do not match q {q.shape}")
     L = k.shape[2]

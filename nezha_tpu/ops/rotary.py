@@ -12,6 +12,10 @@ interpolation), and blends linearly in between, by pair index.
 layout of a projection whose checkpoint is stored ``rope_interleave``. A
 dot product of two vectors rotated this way equals that of the
 de-interleaved half-split form other stacks use, so nothing is permuted.
+
+``apply_half_split`` is that other form (``x * cos + rotate_half(x) *
+sin``): pair ``i`` is ``(x[i], x[i + dim/2])``, the layout of a checkpoint
+stored without ``rope_interleave``.
 """
 
 from __future__ import annotations
@@ -65,3 +69,17 @@ def apply_interleaved(x, positions, inv_freq):
     a, b = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
     return out.reshape(xf.shape).astype(x.dtype)
+
+
+def apply_half_split(x, positions, inv_freq):
+    """Rotate the pairs ``(x[..., i], x[..., i + dim/2])`` by
+    ``positions * inv_freq[i]``: ``x * cos + rotate_half(x) * sin`` with
+    ``rotate_half(x) = [-x2 | x1]``. Shapes and dtypes as
+    :func:`apply_interleaved`."""
+    ang = positions[..., None].astype(jnp.float32) * inv_freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    half = xf.shape[-1] // 2
+    a, b = xf[..., :half], xf[..., half:]
+    out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return out.astype(x.dtype)

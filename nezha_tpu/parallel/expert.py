@@ -159,16 +159,38 @@ class DroplessMoEConfig:
     experts_held: Tuple[int, int]
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    # How the router scores an expert (:func:`route_top_k`): "softmax"
+    # over all experts, or "sigmoid" of each logit with a learned
+    # per-expert selection bias (``router/bias``, float32).
+    score_func: str = "softmax"
+
+
+SCORE_FUNCS = ("softmax", "sigmoid")
 
 
 def route_top_k(router_logits: jax.Array, top_k: int, norm_topk_prob: bool,
-                scaling: float) -> Tuple[jax.Array, jax.Array]:
-    """Softmax over ALL experts, then the ``top_k`` largest:
-    -> (ids [T, k] int32, weights [T, k] float32). With
-    ``norm_topk_prob`` the weights are renormalised over the k chosen
-    (held here or not), then scaled."""
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    weights, ids = jax.lax.top_k(probs, top_k)
+                scaling: float, score_func: str = "softmax",
+                bias=None) -> Tuple[jax.Array, jax.Array]:
+    """Scores over ALL experts, then the ``top_k`` largest:
+    -> (ids [T, k] int32, weights [T, k] float32). Two score functions
+    exist (``SCORE_FUNCS``). ``"softmax"``: the scores are the softmax
+    of the logits, and choose and weigh. ``"sigmoid"`` (the DeepSeek-V3
+    line's ``scoring_func``): ``s = sigmoid(logits)``; the chosen are
+    the largest of ``s + bias`` (``bias`` [E]: the per-expert selection
+    bias that balances load without a loss term), and the weights are
+    ``s`` of the chosen, WITHOUT the bias. With ``norm_topk_prob`` the
+    weights are renormalised over the k chosen (held here or not), then
+    scaled."""
+    logits = router_logits.astype(jnp.float32)
+    if score_func == "softmax":
+        weights, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    elif score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, ids = jax.lax.top_k(
+            scores if bias is None else scores + bias, top_k)
+        weights = jnp.take_along_axis(scores, ids, axis=-1)
+    else:
+        raise ValueError(f"score_func {score_func!r} not in {SCORE_FUNCS}")
     if norm_topk_prob:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return ids.astype(jnp.int32), weights * scaling
@@ -198,15 +220,23 @@ class DroplessMoE(Module):
             raise ValueError(
                 f"experts_held {cfg.experts_held} outside the "
                 f"{cfg.num_experts} routed experts")
+        if cfg.score_func not in SCORE_FUNCS:
+            raise ValueError(
+                f"score_func {cfg.score_func!r} not in {SCORE_FUNCS}")
 
     def init(self, rng: jax.Array) -> Variables:
         cfg, dtype = self.cfg, self.policy.param_dtype
         held = cfg.experts_held[1]
         r_router, r_gate, r_up, r_down = jax.random.split(rng, 4)
         normal = init_lib.normal(0.02)
+        router = {"w": normal(r_router, (cfg.d_model, cfg.num_experts),
+                              dtype)}
+        if cfg.score_func == "sigmoid":
+            # drawn, not zero: selection and weights then differ
+            router["bias"] = normal(jax.random.fold_in(r_router, 1),
+                                    (cfg.num_experts,), jnp.float32)
         return make_variables({
-            "router": {"w": normal(r_router, (cfg.d_model, cfg.num_experts),
-                                   dtype)},
+            "router": router,
             "w_gate": normal(r_gate, (held, cfg.d_model, cfg.d_ff), dtype),
             "w_up": normal(r_up, (held, cfg.d_model, cfg.d_ff), dtype),
             "w_down": normal(r_down, (held, cfg.d_ff, cfg.d_model), dtype),
@@ -227,7 +257,8 @@ class DroplessMoE(Module):
         logits = jnp.dot(x, p["router"]["w"].astype(cdt),
                          preferred_element_type=jnp.float32)
         ids, weights = route_top_k(logits, k, cfg.norm_topk_prob,
-                                   cfg.routed_scaling_factor)
+                                   cfg.routed_scaling_factor,
+                                   cfg.score_func, p["router"].get("bias"))
         with jax.named_scope("nezha_moe_experts"):
             local = ids - first
             is_held = (local >= 0) & (local < held)

@@ -505,7 +505,7 @@ class Engine:
                 and cfg.prefill_impl != getattr(model.cfg, "prefill_impl",
                                                 None)):
             impl_overrides["prefill_impl"] = cfg.prefill_impl
-        if impl_overrides and not hasattr(model.cfg, "decode_impl"):
+        if any(not hasattr(model.cfg, k) for k in impl_overrides):
             raise ValueError(
                 f"decode_impl / prefill_impl {impl_overrides} given, but "
                 f"{type(model).__name__} has one attention implementation "
@@ -528,10 +528,12 @@ class Engine:
         # model's own declaration refuses an int8 pool; the draft pool
         # of speculative decoding and the mesh's head sharding derive
         # their shapes from K/V heads.
-        leaves = sorted(model.cache_leaves(
-            cfg.kv_block_size, cfg.cache_dtype, self.kv_quant))
+        layers = model.cache_leaves(
+            cfg.kv_block_size, cfg.cache_dtype, self.kv_quant)
+        leaves = sorted({name for _, _, lv in layers for name in lv})
+        windowed = any(w is not None for _, w, _ in layers)
         self.kv_heads_cache = {"k", "v"} <= set(leaves)
-        if not self.kv_heads_cache:
+        if not self.kv_heads_cache or windowed:
             unsupported = [what for what, on in (
                 ("speculative decoding", cfg.speculative is not None),
                 # only the mesh-sharded engine sets this
@@ -539,9 +541,10 @@ class Engine:
                 if on]
             if unsupported:
                 raise ValueError(
-                    f"{type(model).__name__} caches {leaves}, not "
-                    f"per-head K/V: {', '.join(unsupported)} not supported "
-                    f"with it")
+                    f"{type(model).__name__} caches {leaves}"
+                    f"{' with window layers in a ring' if windowed else ''}"
+                    f", not per-head K/V in one growing table: "
+                    f"{', '.join(unsupported)} not supported with it")
         # Whether prefill chunks dispatch through the flash-prefill
         # kernel (the model's own resolution, asked once). It drives
         # telemetry only: the pinned ``serve.prefill.kernel_active`` gauge
@@ -612,9 +615,11 @@ class Engine:
         # a prompt lands in its bucket). Prefill programs route through the
         # dedicated _wrap_prefill_program hook: the sharded engine in
         # sequence mode nests the seq-prefill scope around the trace.
+        groups = self.pool.layer_groups
         self._prefill_fns = {w: self._wrap_prefill_program(
                                     _build_prefill(self.model, w,
-                                                   quantized=self.kv_quant))
+                                                   quantized=self.kv_quant,
+                                                   groups=groups))
                              for w in cfg.all_prefill_buckets}
         # Speculative decoding: a DRAFT engine rides along — its own
         # model (explicit, or an early-exit self-draft sharing the
@@ -683,7 +688,7 @@ class Engine:
         else:
             self._step_fn = self._wrap_program(
                 _build_step(self.model, self.k_max, cfg.pad_id,
-                            cfg.decode_horizon))
+                            cfg.decode_horizon, groups=groups))
 
     # ----------------------------------------------- subsystem hooks
     # The tensor-sharded engine (serve/sharded/engine.py) specializes
@@ -773,7 +778,10 @@ class Engine:
         rem = n - off
         if width is None:
             width = next(w for w in buckets if w >= rem)
-        if off + width > cfg.max_len:
+        if off + width > cfg.max_len and not self.pool.window:
+            # (A model with window layers writes no pad at all: it is
+            # told the chunk's real length, and a slide would ask its
+            # ring for keys it has already overwritten.)
             # A padded tail would spill past the slot's KV capacity
             # (max_len not a multiple of the stride, prompt near
             # capacity) — and dynamic_update_slice would CLAMP the write
@@ -929,8 +937,7 @@ class Engine:
                       else contextlib.nullcontext()):
                     out = self.executor.run(
                         self._prefill_fns[width], self.variables,
-                        self.pool.caches,
-                        jnp.asarray(self.pool.tables_host),
+                        self.pool.caches, self.pool.device_tables(),
                         jnp.asarray(padded), *scalars, *state)
                 if self.prefill_kernel_active and self.kv_quant:
                     obs.counter("serve.prefill.fused_writes_total").inc(
@@ -971,7 +978,7 @@ class Engine:
                 self.draft_pool.caches = self.draft_executor.run(
                     self._draft_prefill_fns[width],
                     self.draft_variables, self.draft_pool.caches,
-                    jnp.asarray(self.draft_pool.tables_host),
+                    self.draft_pool.device_tables(),
                     jnp.asarray(padded), *dscalars)
             # Fresh request: its carried logits are real target logits,
             # not a residual distribution.
@@ -1013,16 +1020,21 @@ class Engine:
 
     def _dispatch_attrs(self, active: np.ndarray) -> dict:
         """What the ``serve.engine.dispatch`` span says of a step: the
-        active ``rows`` and the table entries they hold going in
-        (``blocks``; ``latent_blocks`` on a latent pool).
-        ``blocks / (rows * M)`` is the share of the block table the
-        paged decode kernel visits: it skips, without a DMA, every entry
-        past a row's length."""
+        active ``rows`` and the table entries they hold going in, a
+        group (``blocks``, ``latent_blocks`` on a latent pool: the
+        growing table; ``window_blocks``: the ring, where the model has
+        window layers). ``blocks / (rows * M)`` is the share of the
+        block table the paged decode kernel visits: it skips, without a
+        DMA, every entry past a row's length."""
         active = np.asarray(active, bool)
-        blocks = int(np.sum(
-            self.host_positions[active] // self.cfg.kv_block_size + 1))
-        return {"rows": int(np.count_nonzero(active)),
-                "blocks" if self.kv_heads_cache else "latent_blocks": blocks}
+        held = self.host_positions[active] // self.cfg.kv_block_size + 1
+        attrs = {"rows": int(np.count_nonzero(active)),
+                 "blocks" if self.kv_heads_cache else "latent_blocks":
+                 int(held.sum())}
+        if self.pool.window:
+            attrs["window_blocks"] = int(
+                np.minimum(held, self.pool.window_entries).sum())
+        return attrs
 
     def step(self, active: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """Decode one BLOCK of up to ``decode_horizon`` tokens for every
@@ -1046,7 +1058,7 @@ class Engine:
                 active, self.cfg.decode_horizon, (self.pool,))
             out = self.executor.run(
                 self._step_fn, self.variables, self.pool.caches,
-                jnp.asarray(self.pool.tables_host),
+                self.pool.device_tables(),
                 self.last_logits, self.positions,
                 jnp.asarray(active, bool), self.keys,
                 self.temps, self.top_ks, self.top_ps,
@@ -1122,8 +1134,8 @@ class Engine:
                 self._step_fn, self.variables,
                 (self.pool.caches, self.draft_pool.caches),
                 self.draft_variables,
-                jnp.asarray(self.pool.tables_host),
-                jnp.asarray(self.draft_pool.tables_host),
+                self.pool.device_tables(),
+                self.draft_pool.device_tables(),
                 self.last_logits, self.positions,
                 jnp.asarray(active, bool), self.keys,
                 self.temps, self.top_ks, self.top_ps,
@@ -1212,22 +1224,37 @@ def _start_host_copies(*arrays) -> None:
             copy_async()
 
 
-def _with_tables(caches, tables):
+def _with_tables(caches, tables, groups=None, valid=None):
     """The model's paged cache rows: each layer's pool leaves plus the
-    block ``tables``. The dict-merge keeps every leaf riding into the
-    model (int8 pools carry k_scale/v_scale beside k/v: the scales are
-    cache state like any other)."""
-    return [{**pool, "tables": tables} for pool in caches]
+    block table of the layer's group (``tables``: group -> table;
+    ``groups``: the group a layer, all ``"global"`` when None). The
+    dict-merge keeps every leaf riding into the model (int8 pools carry
+    k_scale/v_scale beside k/v: the scales are cache state like any
+    other). A model with window layers is also told how many of a
+    prefill chunk's tokens are real (``valid``): a pad written into a
+    ring would overwrite keys the next queries still see."""
+    groups = groups or ("global",) * len(caches)
+    extra = {} if valid is None or "window" not in tables else {
+        "valid": valid}
+    return [{**pool, "tables": tables[g], **extra}
+            for pool, g in zip(caches, groups)]
+
+
+def _table_rows(tables, slot):
+    """One slot's row of every group's table, ``[1, M]`` each."""
+    zero = jnp.zeros((), jnp.int32)
+    return {g: lax.dynamic_slice(t, (slot, zero), (1, t.shape[1]))
+            for g, t in tables.items()}
 
 
 def _pool_leaves(new_rows, caches):
     """The pool's own leaves back out of the rows the model returned
     (which also hold the tables and, on an int8 prefill, ``qerr``)."""
-    kept = tuple(caches[0])
-    return [{kk: r[kk] for kk in kept} for r in new_rows]
+    return [{kk: r[kk] for kk in pool} for r, pool in zip(new_rows, caches)]
 
 
-def _build_prefill(model, width: int, quantized: bool = False):
+def _build_prefill(model, width: int, quantized: bool = False,
+                   groups=None):
     def prefill(variables, caches, tables, tokens, length, slot, pos,
                 seed, temperature, top_k, top_p, eos_id, budget,
                 last_logits, positions, keys, temps, top_ks, top_ps,
@@ -1244,10 +1271,8 @@ def _build_prefill(model, width: int, quantized: bool = False):
         # attends the gathered prefix, so a shared-prefix request
         # starting at a nonzero `pos` sees the cached blocks it
         # referenced instead of recomputing them.
-        zero = jnp.zeros((), jnp.int32)
-        tab_row = lax.dynamic_slice(
-            tables, (slot, zero), (1, tables.shape[1]))
-        rows = _with_tables(caches, tab_row)
+        rows = _with_tables(caches, _table_rows(tables, slot), groups,
+                            valid=length)
         logits, states = model.apply(variables, tokens, training=False,
                                      cache=rows, pos=pos)
         new_rows = model.caches_from_states(states, rows)
@@ -1288,7 +1313,7 @@ def _build_prefill(model, width: int, quantized: bool = False):
     return prefill
 
 
-def _build_step(model, k_max: int, pad_id: int, horizon: int):
+def _build_step(model, k_max: int, pad_id: int, horizon: int, groups=None):
     def body(active, temps, top_ks, top_ps, eos_ids, budgets,
              variables, tables, carry):
         """One fused decode step: the single-token body the horizon scan
@@ -1332,7 +1357,7 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int):
             keys, last_logits, temps, top_ks,
             jnp.where(emit, top_ps, 1.0), k_max)
         tok = jnp.where(emit, tok, pad_id)
-        rows = _with_tables(caches, tables)
+        rows = _with_tables(caches, tables, groups)
         logits, states = model.apply(variables, tok[:, None],
                                      training=False, cache=rows,
                                      pos=positions, active=emit)
@@ -1398,10 +1423,7 @@ def _build_draft_prefill(model, width: int):
     end)."""
     def prefill(variables, caches, tables, tokens, length, slot, pos):
         del length
-        zero = jnp.zeros((), jnp.int32)
-        tab_row = lax.dynamic_slice(
-            tables, (slot, zero), (1, tables.shape[1]))
-        rows = _with_tables(caches, tab_row)
+        rows = _with_tables(caches, _table_rows(tables, slot))
         _, states = model.apply(variables, tokens, training=False,
                                 cache=rows, pos=pos)
         return _pool_leaves(model.caches_from_states(states, rows), caches)

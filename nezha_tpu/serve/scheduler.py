@@ -238,6 +238,9 @@ def register_serve_instruments() -> None:
     obs.counter("serve.kv.migrations_total")
     obs.counter("serve.kv.migration_bytes")
     obs.gauge("serve.kv.blocks_used")
+    # The window layers' ring (0 for a model without window layers):
+    # every taken slot holds its whole ring.
+    obs.gauge("serve.kv.window_blocks_used")
     # Tiered KV host spill (PR 15): trie blocks demoted to host RAM on
     # eviction instead of discarded, and blocks promoted back on a
     # returning prefix hit; occupancy gauges for the host-side LRU.
@@ -528,8 +531,11 @@ class Scheduler:
                 self.engine.pool.occupancy)
             obs.gauge("serve.kv.blocks_used").set(
                 self.engine.pool.blocks_used)
+            obs.gauge("serve.kv.window_blocks_used").set(
+                self.engine.pool.window_blocks_used)
             obs.gauge("serve.kv.bytes_resident").set(
-                self.engine.pool.bytes_resident)
+                self.engine.pool.bytes_resident
+                + self.engine.pool.window_bytes_resident)
             obs.gauge("serve.kv.host_blocks_used").set(
                 self.engine.pool.host_blocks_used)
             obs.gauge("serve.kv.host_bytes_resident").set(

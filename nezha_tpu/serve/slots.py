@@ -1,7 +1,17 @@
 """The KV pool for the serving engine: ref-counted pages.
 
-:class:`PagedSlotPool` is the one layout serving has. The scheduler sees
-its slot-level surface (``alloc``/``free``/``num_free``/``occupancy``);
+:class:`PagedSlotPool` is the one layout serving has. A model declares,
+layer by layer, what it caches and in which GROUP the layer's blocks
+live: the growing group (a table of ``max_len / block_size`` entries a
+slot, blocks bound as positions advance; everything below describes
+it) and, for layers that attend a sliding window only, a window group
+(a RING of ``ceil(window / block_size) + 1`` entries a slot, bound once
+when the slot is taken and released with it; position ``p`` lives in
+entry ``(p // block_size) % ring``, so a block is overwritten once its
+tokens are out of every later query's window). Both groups keep their
+blocks in one implementation of free list and ref counts
+(:class:`_BlockBooks`). The scheduler sees
+the slot-level surface (``alloc``/``free``/``num_free``/``occupancy``);
 the engine sees blocks: per-layer K/V buffers shaped
 ``[num_blocks, block_size, H*D]`` (lane-dense rows: one row a position,
 its heads side by side in lanes, which is the device's own row-major
@@ -91,6 +101,7 @@ from jax import lax
 
 from nezha_tpu import faults, obs
 from nezha_tpu.ops import quant
+from nezha_tpu.ops.pallas.decode_attention import ring_entries
 
 
 class KVBlocksExhausted(RuntimeError):
@@ -259,6 +270,55 @@ class PrefixTrie:
             self._leaves.add(node.parent)
 
 
+class _BlockBooks:
+    """One cache group's blocks: a LIFO free list over ``1 .. n-1``
+    (block 0 is the group's scratch block: never allocated, never
+    ref-counted) and per-block ref counts. The growing group and the
+    window ring each have one; the lifecycle is this class's."""
+
+    def __init__(self, num_blocks: int):
+        self.num_blocks = num_blocks
+        self.free: List[int] = list(range(num_blocks - 1, 0, -1))
+        self.refs = np.zeros((num_blocks,), np.int64)
+
+    @property
+    def used(self) -> int:
+        return self.num_blocks - 1 - len(self.free)
+
+    def take(self) -> int:
+        """Pop a free block at ref count 1 (the caller saw one free)."""
+        b = self.free.pop()
+        self.refs[b] = 1
+        return b
+
+    def release(self, block: int) -> bool:
+        """Drop one reference; -> True when that freed the block."""
+        if block == 0:
+            return False
+        self.refs[block] -= 1
+        if self.refs[block] < 0:
+            raise AssertionError(
+                f"block {block} ref count went negative (double release)")
+        if self.refs[block] == 0:
+            self.free.append(block)
+            return True
+        return False
+
+    def check(self, expect: np.ndarray, what: str) -> None:
+        """Assert the books balance against ``expect``ed ref counts."""
+        if not np.array_equal(expect, self.refs):
+            bad = np.flatnonzero(expect != self.refs)
+            raise AssertionError(
+                f"{what} ref-count leak at blocks {bad.tolist()}: "
+                f"expected {expect[bad].tolist()}, "
+                f"recorded {self.refs[bad].tolist()}")
+        n_held = int(np.count_nonzero(self.refs))
+        if len(self.free) + n_held != self.num_blocks - 1:
+            raise AssertionError(
+                f"{what} leak: {len(self.free)} free + {n_held} held != "
+                f"{self.num_blocks - 1} allocatable")
+
+
 def _copy_block(caches: list, src, dst) -> list:
     """Device-side block copy across every layer's K and V pool:
     ``caches[l][kv] [N, bs, H*D]`` with block ``src`` copied over
@@ -373,15 +433,23 @@ _scatter_blocks_dequant_jit = jax.jit(_scatter_blocks_dequant,
 class PagedSlotPool:
     """Block-paged KV pool: ref-counted blocks + per-slot block tables.
 
-    Device state: ``caches`` (per-layer ``{"k", "v"}`` pools shaped
-    ``[num_blocks, block_size, H*D]``: lane-dense rows, a position's
-    heads side by side, head ``h`` in lanes ``h*D .. (h+1)*D``) and —
-    uploaded per dispatch from
-    the host mirror — ``tables_host`` (``[capacity, blocks_per_slot]``
-    int32; entry ``[s, i]`` is the pool block holding slot ``s``'s
-    positions ``[i*bs, (i+1)*bs)``, or 0/scratch when unbound). Host
-    state: the block free list, per-block ref counts, per-slot bound
-    counts, and the prefix trie.
+    Device state: ``caches``, one dict of leaves a layer as the model
+    declared them (``model.cache_leaves``: a group and the leaves of one
+    block), each leaf ``[blocks of the layer's group, ...]``: K/V rows
+    ``[N, block_size, KVH*D]`` (lane-dense: a position's heads side by
+    side, head ``h`` in lanes ``h*D .. (h+1)*D``), or a latent row. And,
+    uploaded per dispatch from the host mirrors (:meth:`device_tables`),
+    one table a group: ``tables_host`` (``[capacity, blocks_per_slot]``
+    int32; entry ``[s, i]`` is the block of the GROWING group holding
+    slot ``s``'s positions ``[i*bs, (i+1)*bs)``, or 0/scratch when
+    unbound) and, where the model has window layers,
+    ``window_tables_host`` (``[capacity, window_entries]``: the slot's
+    ring, every entry bound from :meth:`alloc` to :meth:`free`). Host
+    state: a free list and ref counts a group, per-slot bound counts,
+    and the prefix trie. ``num_blocks``, ``blocks_used``,
+    ``bytes_resident`` and ``available_blocks`` are the growing group's
+    (admission runs out of those); the ring's are ``window_blocks_used``
+    and ``window_bytes_resident``.
 
     With ``quantized=True`` (``ServeConfig.kv_dtype="int8"``) the K/V
     pools store int8 and each layer carries ``k_scale``/``v_scale``
@@ -455,48 +523,84 @@ class PagedSlotPool:
         self.prefix_cache_enabled = prefix_cache
         self.eviction = eviction
         self.quantized = quantized
-        # The model DECLARES its per-layer cache leaves (name -> trailing
-        # shape and dtype of one block); the pool allocates
-        # ``[num_blocks, ...]`` of each and does its byte accounting from
-        # the same declaration. GPT-2: lane-dense ``k`` / ``v`` rows
-        # ``[N, bs, H*D]`` and, with ``ServeConfig.kv_dtype="int8"``,
-        # int8 blocks plus one fp32 absmax scale per (block, head) — the
-        # ``[num_blocks, H]`` scale buffers ride IN the caches pytree, so
-        # everything that moves a block (program donation, COW copy,
-        # checkpoint of the tree structure) moves its scale row with it
-        # by construction. A latent-attention model: one ``latent``
-        # ``[N, bs, width]`` leaf, no head axis. Zero-init: q = 0 with
-        # scale 0 dequantizes to exact zeros, same as a float pool's zero
-        # init. Block lifecycle, ref counts, COW and the trie below are
-        # one implementation over any leaf set.
-        leaves = model.cache_leaves(block_size, dtype, quantized)
-        self.caches = [{name: jnp.zeros((num_blocks,) + tuple(shape), dt)
-                        for name, (shape, dt) in leaves.items()}
-                       for _ in range(model.cfg.num_layers)]
-        # Per-block device footprint (every leaf, all layers) — the
-        # serve.kv.bytes_resident gauge's unit and the equal-memory
-        # bench's conversion rate between int8 and bf16 block budgets.
-        self.bytes_per_block = model.cfg.num_layers * sum(
-            math.prod(shape) * jnp.dtype(dt).itemsize
-            for shape, dt in leaves.values())
+        # The model DECLARES, layer by layer, the group its cache lives in
+        # and the leaves of one block (name -> trailing shape and dtype);
+        # the pool allocates ``[blocks of the group, ...]`` of each and
+        # does its byte accounting from the same declaration. GPT-2:
+        # lane-dense ``k`` / ``v`` rows ``[N, bs, H*D]`` and, with
+        # ``ServeConfig.kv_dtype="int8"``, int8 blocks plus one fp32
+        # absmax scale per (block, head): the ``[num_blocks, H]`` scale
+        # buffers ride IN the caches pytree, so everything that moves a
+        # block (program donation, COW copy, checkpoint of the tree
+        # structure) moves its scale row with it by construction. A
+        # latent-attention model: one ``latent`` ``[N, bs, width]`` leaf,
+        # no head axis. Zero-init: q = 0 with scale 0 dequantizes to
+        # exact zeros, same as a float pool's zero init. A layer with a
+        # ``window`` lives in the ring group: its leaves hold
+        # ``1 + capacity * window_entries`` blocks whatever ``max_len``
+        # is. Block lifecycle and ref counts are one implementation over
+        # any leaf set and either group; COW and the trie are the growing
+        # group's.
+        layers = model.cache_leaves(block_size, dtype, quantized)
+        windows = {w for _, w, _ in layers if w is not None}
+        if len(windows) > 1:
+            raise ValueError(
+                f"one window group a pool: layers declare windows "
+                f"{sorted(windows)}")
+        self.window: Optional[int] = windows.pop() if windows else None
+        self.window_entries = (ring_entries(self.window, block_size)
+                               if self.window else 0)
+        if self.window and prefix_cache:
+            raise ValueError(
+                "prefix_cache with window layers: a ring overwrites the "
+                "blocks a trie hit would have to re-bind (a hit would "
+                "need the window layers' last tokens as a snapshot)")
+        self.layer_groups: Tuple[str, ...] = tuple(g for g, _, _ in layers)
+        ring_blocks = 1 + capacity * self.window_entries
+        self.caches = [
+            {name: jnp.zeros(
+                (num_blocks if w is None else ring_blocks,) + tuple(shape),
+                dt) for name, (shape, dt) in leaves.items()}
+            for _, w, leaves in layers]
+
+        def block_bytes(ring: bool) -> int:
+            return sum(math.prod(shape) * jnp.dtype(dt).itemsize
+                       for _, w, leaves in layers if (w is not None) == ring
+                       for shape, dt in leaves.values())
+
+        # Per-block device footprint (every leaf, all the group's
+        # layers): the serve.kv.bytes_resident gauge's unit and the
+        # equal-memory bench's conversion rate between int8 and bf16
+        # block budgets.
+        self.bytes_per_block = block_bytes(False)
+        self.window_bytes_per_block = block_bytes(True)
         # Migration, peer pulls and the host tier speak one wire format:
         # int8 K/V blocks as per-head tiles ``[n, H, bs, D]`` +
-        # per-(block, head) scales ``[n, H]``. A pool of other leaves
-        # has none.
-        self.kv_wire = {"k", "v"} <= set(leaves)
+        # per-(block, head) scales ``[n, H]``. A pool of other leaves,
+        # or one with a ring (whose blocks no table row spells in
+        # order), has none.
+        self.kv_wire = not self.window and all(
+            {"k", "v"} <= set(leaves) for _, _, leaves in layers)
         self.wire_block_shape: Optional[Tuple[int, int, int]] = None
         if self.kv_wire:
             heads = model.cfg.num_heads
             self.wire_block_shape = (
-                heads, block_size, leaves["k"][0][-1] // heads)
+                heads, block_size, layers[0][2]["k"][0][-1] // heads)
         self.tables_host = np.zeros((capacity, self.blocks_per_slot),
                                     np.int32)
         self._free_slots: List[int] = list(range(capacity - 1, -1, -1))
         # Block 0 reserved as scratch (pad-write sink for non-emitting
-        # rows) — LIFO free list over the rest.
-        self._free_blocks: List[int] = list(range(num_blocks - 1, 0, -1))
-        self._refs = np.zeros((num_blocks,), np.int64)
+        # rows), a LIFO free list over the rest. ``_free_blocks`` and
+        # ``_refs`` ARE the growing group's books (the same list and
+        # array), under the names the code below has always used.
+        self._books = _BlockBooks(num_blocks)
+        self._free_blocks: List[int] = self._books.free
+        self._refs = self._books.refs
         self._bound = np.zeros((capacity,), np.int32)   # per-slot entries
+        # The window group's ring: every entry of a taken slot is bound.
+        self._ring_books = _BlockBooks(ring_blocks)
+        self.window_tables_host = np.zeros(
+            (capacity, self.window_entries), np.int32)
         self.trie = PrefixTrie(block_size)
         self.cow_copies = 0
         self.prefix_hits = 0
@@ -538,15 +642,25 @@ class PagedSlotPool:
         Blocks are bound separately (:meth:`bind_for_prompt` /
         :meth:`prepare_write`) — a fresh slot holds none."""
         slot = self._free_slots.pop() if self._free_slots else None
-        if slot is not None and self.mirror is not None:
-            self.mirror.claim(slot)
+        if slot is not None:
+            self._bind_ring(slot)
+            if self.mirror is not None:
+                self.mirror.claim(slot)
         return slot
+
+    def _bind_ring(self, slot: int) -> None:
+        """A taken slot holds its whole ring until :meth:`free` (the
+        ring group reserves ``window_entries`` blocks a slot, so this
+        never runs dry)."""
+        for i in range(self.window_entries):
+            self.window_tables_host[slot, i] = self._ring_books.take()
 
     def claim(self, slot: int) -> None:
         """Take a SPECIFIC free slot (the mirror path: the leader pool
         chose the index). Raises when the slot is not free: lifecycle
         drift between the pools must surface, not corrupt."""
         self._free_slots.remove(slot)
+        self._bind_ring(slot)
 
     def free(self, slot: int) -> None:
         """Release the slot and DROP ITS BLOCK REFERENCES in the same
@@ -560,12 +674,16 @@ class PagedSlotPool:
         if slot in self._free_slots:
             raise ValueError(f"slot {slot} is already free (double free)")
         self.release_blocks(slot)
+        for b in self.window_tables_host[slot]:
+            self._ring_books.release(int(b))
+        self.window_tables_host[slot, :] = 0
         self._free_slots.append(slot)
         if self.mirror is not None:
             self.mirror.free(slot)
 
     def release_blocks(self, slot: int) -> None:
-        """Drop the slot's block references (without freeing the slot):
+        """Drop the slot's references in the growing group (without
+        freeing the slot; its ring stays bound):
         the table row resets to scratch and blocks nobody else holds
         return to the free list. Used by :meth:`free` and by the
         engine's cold-prefill fallback when a prefix hit pinned the
@@ -602,6 +720,25 @@ class PagedSlotPool:
         pool holds ~2x the blocks of a bf16 pool (scale overhead is
         ``4 / (block_size * D)`` per element)."""
         return self.blocks_used * self.bytes_per_block
+
+    @property
+    def window_blocks_used(self) -> int:
+        """Ring blocks the taken slots hold (``window_entries`` each):
+        the ``serve.kv.window_blocks_used`` gauge value."""
+        return self._ring_books.used
+
+    @property
+    def window_bytes_resident(self) -> int:
+        """Device bytes of the bound ring blocks, all window layers."""
+        return self.window_blocks_used * self.window_bytes_per_block
+
+    def device_tables(self) -> Dict[str, jax.Array]:
+        """The host tables as a dispatch uploads them: one a group,
+        keyed as ``layer_groups`` names them."""
+        tables = {"global": jnp.asarray(self.tables_host)}
+        if self.window:
+            tables["window"] = jnp.asarray(self.window_tables_host)
+        return tables
 
     @property
     def trie_only_blocks(self) -> int:
@@ -650,22 +787,13 @@ class PagedSlotPool:
                 f"no free KV blocks ({self.blocks_used}/"
                 f"{self.num_blocks - 1} in use, "
                 f"{len(self.trie)} cached)", slot=slot)
-        b = self._free_blocks.pop()
-        self._refs[b] = 1
-        return b
+        return self._books.take()
 
     def _release(self, block: int) -> None:
-        if block == 0:
-            return
-        self._refs[block] -= 1
-        if self._refs[block] == 0:
-            self._free_blocks.append(block)
+        if self._books.release(block):
             # A freed block's peer tag dies with it: the index will be
             # rebound to unrelated content, which must count as local.
             self._peer_blocks.discard(block)
-        elif self._refs[block] < 0:
-            raise AssertionError(
-                f"block {block} ref count went negative (double release)")
 
     # ------------------------------------------------------- host tier
     @property
@@ -975,8 +1103,13 @@ class PagedSlotPool:
                 b = int(self.tables_host[slot, bi])
                 if self._refs[b] > 1:
                     nb = self._alloc_block(slot)
-                    self.caches = _copy_block_jit(
-                        self.caches, np.int32(b), np.int32(nb))
+                    grown = [i for i, g in enumerate(self.layer_groups)
+                             if g == "global"]
+                    copied = _copy_block_jit(
+                        [self.caches[i] for i in grown],
+                        np.int32(b), np.int32(nb))
+                    for i, layer in zip(grown, copied):
+                        self.caches[i] = layer
                     self.tables_host[slot, bi] = nb
                     self._release(b)
                     self.cow_copies += 1
@@ -1243,12 +1376,24 @@ class PagedSlotPool:
         for b in self.trie.blocks:
             expect[b] += 1
         expect[0] = 0
-        if not np.array_equal(expect, self._refs):
-            bad = np.flatnonzero(expect != self._refs)
-            raise AssertionError(
-                f"KV block ref-count leak at blocks {bad.tolist()}: "
-                f"expected {expect[bad].tolist()}, "
-                f"recorded {self._refs[bad].tolist()}")
+        self._books.check(expect, "KV block")
+        # The window group: a taken slot holds exactly its ring, a free
+        # slot's row is scratch, and nothing else references a ring block.
+        ring = np.zeros((self._ring_books.num_blocks,), np.int64)
+        for slot in range(self.capacity):
+            row = self.window_tables_host[slot]
+            if slot in self._free_slots:
+                if row.any():
+                    raise AssertionError(
+                        f"free slot {slot} still names ring blocks "
+                        f"{row.tolist()}")
+            elif self.window_entries and not row.all():
+                raise AssertionError(
+                    f"slot {slot}'s ring has unbound entries: "
+                    f"{row.tolist()}")
+            np.add.at(ring, row, 1)
+        ring[0] = 0
+        self._ring_books.check(ring, "window ring block")
         # Fleet peer tags (PR 17) may only name blocks somebody still
         # holds: a tag on a freed block would mis-count an unrelated
         # future binding as a peer hit.
@@ -1257,12 +1402,6 @@ class PagedSlotPool:
             raise AssertionError(
                 f"peer tier tags leaked past release: blocks "
                 f"{sorted(untagged)} are tagged but free")
-        n_free = len(self._free_blocks)
-        n_held = int(np.count_nonzero(self._refs))
-        if n_free + n_held != self.num_blocks - 1:
-            raise AssertionError(
-                f"KV block leak: {n_free} free + {n_held} held != "
-                f"{self.num_blocks - 1} allocatable")
         if self.mirror is not None:
             # Draft-pool extension of the oracle: the mirror's slot
             # free-list must agree with ours slot for slot (lifecycle

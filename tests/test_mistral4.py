@@ -268,7 +268,7 @@ def test_latent_cache_without_tables_is_refused(tiny):
     """The model's own guard: its attention takes the paged cache (a
     latent pool and the block tables) and nothing else."""
     model, variables = tiny
-    (name, (shape, dtype)), = model.cache_leaves(8, jnp.float32).items()
+    (name, (shape, dtype)), = model.cache_leaves(8, jnp.float32)[0][2].items()
     rows = [{name: jnp.zeros((3,) + tuple(shape), dtype)}
             for _ in range(model.cfg.num_layers)]
     with pytest.raises(ValueError, match="block-paged only"):

@@ -478,8 +478,7 @@ def test_int8_kernel_strictly_fewer_scatters(engines):
         state = (eng.last_logits, eng.positions, eng.keys, eng.temps,
                  eng.top_ks, eng.top_ps, eng.eos_ids, eng.budgets)
         lowered = jax.jit(eng._prefill_fns[width]).lower(
-            eng.variables, eng.pool.caches,
-            jnp.asarray(eng.pool.tables_host),
+            eng.variables, eng.pool.caches, eng.pool.device_tables(),
             jnp.zeros((1, width), jnp.int32), *scalars, *state)
         counts[impl] = lowered.as_text().count("scatter")
     assert counts["kernel"] < counts["xla"], counts

@@ -276,6 +276,7 @@ def test_nested_shard_map_kernel_compiles_for_v5e_mesh4(v5e_devices, case):
 def _serve_programs(model, quantized, v5e, *, slots, table, block, chunk,
                     logits):
     """{"step" | "prefill": compiled program} of ``model`` for one v5e."""
+    from nezha_tpu.ops.pallas import ring_entries
     from nezha_tpu.serve.engine import _build_prefill, _build_step
 
     def spec(shape, dtype):
@@ -284,11 +285,14 @@ def _serve_programs(model, quantized, v5e, *, slots, table, block, chunk,
     variables = jax.tree_util.tree_map(
         lambda a: spec(a.shape, a.dtype),
         jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    caches = [{name: spec((1 + slots * table,) + tuple(shape), dt)
-               for name, (shape, dt) in
-               model.cache_leaves(block, BF16, quantized).items()}
-              for _ in range(model.cfg.num_layers)]
-    tables = spec((slots, table), jnp.int32)
+    layers = model.cache_leaves(block, BF16, quantized)
+    ring = {w: ring_entries(w, block) for _, w, _ in layers if w}
+    caches = [{name: spec((1 + slots * ring.get(w, table),) + tuple(shape),
+                          dt) for name, (shape, dt) in leaves.items()}
+              for _, w, leaves in layers]
+    groups = tuple(g for g, _, _ in layers)
+    tables = {g: spec((slots, ring.get(w, table)), jnp.int32)
+              for g, w, _ in layers}
     b = slots
     state = (spec((b, logits), jnp.float32),
              spec((b,), jnp.int32), spec((b, 2), jnp.uint32),
@@ -296,13 +300,13 @@ def _serve_programs(model, quantized, v5e, *, slots, table, block, chunk,
              spec((b,), jnp.float32), spec((b,), jnp.int32),
              spec((b,), jnp.int32))
     last, pos, keys, temps, top_ks, top_ps, eos, budgets = state
-    step = jax.jit(_build_step(model, 64, 0, 1),
+    step = jax.jit(_build_step(model, 64, 0, 1, groups=groups),
                    donate_argnums=(1,)).lower(
         variables, caches, tables, last, pos, spec((b,), jnp.bool_), keys,
         temps, top_ks, top_ps, eos, budgets).compile()
     i32, f32 = spec((), jnp.int32), spec((), jnp.float32)
-    prefill = jax.jit(_build_prefill(model, chunk,
-                                     quantized=quantized),
+    prefill = jax.jit(_build_prefill(model, chunk, quantized=quantized,
+                                     groups=groups),
                       donate_argnums=(1,)).lower(
         variables, caches, tables, spec((1, chunk), jnp.int32), i32, i32, i32,
         i32, f32, i32, f32, i32, i32, *state).compile()
