@@ -728,11 +728,12 @@ class Attention(Module):
             # attention (and, on int8 pools, the fused write above).
             out = out_pf
         elif use_decode_kernel or shmap_mesh is not None:
-            # The kernel takes the POOLS + table directly (block-table
-            # gather operand): its grid is rows x table entries / c
-            # whatever the rows hold, but it DMAs and folds only the
-            # entries below a row's own length (all heads of an entry
-            # at once), and an inactive row folds nothing. Int8 pools
+            # The kernel takes the POOLS + table directly: it copies
+            # and folds only the table entries below a row's own length
+            # (all heads of an entry at once; a loop over the row's own
+            # entries where the pool's rows are whole 128-lane tiles, a
+            # grid of rows x table entries / c on a head shard), and an
+            # inactive row folds nothing. Int8 pools
             # add the [N, H] scale operands and the kernel dequantizes
             # inside its block loop — the int8 cache never round-trips
             # through a dense bf16 view.

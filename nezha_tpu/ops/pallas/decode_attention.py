@@ -9,24 +9,28 @@ kernel is built for the actual access pattern (the "Harnessing HPC
 Kernels" argument from PAPERS.md: shape-specialized hot loops deserve a
 kernel, not a generic lowering):
 
-- the KV dimension of the grid is sequential ("arbitrary" semantics) —
-  the split-K layout: each program folds one KV block into VMEM running
-  ``(max, sum, acc)`` scratch via online softmax, merged at the final
-  block (no score matrix, no mask tensor). The dense layout runs
-  ``(B, H, L / block_k)``; the paged layout runs ``(B, M / c)`` — all
-  heads of ``c`` table entries a step (the paged pool is lane-dense,
-  ``[N, bs, H*D]``: a block is ``bs`` whole rows of all heads), see
-  :func:`_paged_call`;
+- the KV walk is sequential — the split-K layout: each step folds one
+  KV block into VMEM running ``(max, sum, acc)`` scratch via online
+  softmax, merged at the row's end (no score matrix, no mask tensor).
+  The dense layout runs a grid ``(B, H, L / block_k)``. The paged
+  layout (a lane-dense pool ``[N, bs, KVH*D]``: a block is ``bs`` whole
+  rows of all heads) folds all heads of ``c`` table entries at a time
+  and has TWO iteration spaces, chosen by the pool's lane width in
+  :func:`_paged_call`: a grid of ONE step a row with a LOOP over the
+  row's own live entries inside it, fed by manual DMA (whole 128-lane
+  tiles: the serving cells), or a grid ``(B, M / c)`` (any other
+  width);
 - per-row ``lengths`` ride as a SCALAR-PREFETCH operand (SMEM — the TPU
-  lowering refuses a ``(1, 128)`` VMEM block over ``[B, 128]``): a
-  program whose block starts at or past its row's length SKIPS the block
-  (``@pl.when``). The grid does not shrink with the rows: the paged
-  kernel takes ``B * M / c`` steps whatever they hold, but DMA and
-  arithmetic happen only for a row's live entries (entries past its
-  length repeat a block index already held, which the pipeline does not
-  fetch again), so short rows and inactive rows (``length == 0``) cost
-  the scalar core's step bookkeeping only (about 0.7 us a step of 8
-  entries on a v5e: PERF.md section 6, PR 25);
+  lowering refuses a ``(1, 128)`` VMEM block over ``[B, 128]``). The
+  dense and the grid forms SKIP a block that starts at or past its
+  row's length (``@pl.when``), but their grids do not shrink with the
+  rows: every step costs the scalar core its bookkeeping whether it
+  moves bytes or not (about 0.7 us a step of 8 entries on a v5e: a
+  paged call of 256 rows x 64 entries read 1.45 ms with every row
+  EMPTY, 1.86 ms at the serving cell's ~25 live entries a row). The
+  loop form's trip count is the row's own ``ceil(length / span)``: a
+  call costs what its rows hold, and short rows and inactive rows
+  (``length == 0``) cost one grid step (PERF.md section 6, PR 31);
 - Q·Kᵀ and P·V accumulate fp32 over the caches' native dtype (bf16 pool
   dots run at the doubled MXU rate; the softmax statistics and the
   accumulator stay fp32 throughout);
@@ -103,98 +107,84 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         _finalize(o_ref, l_scr, acc_scr)
 
 
-# Table entries folded into one grid step of the paged kernels: the
-# pool rides as this many K and V operands, and their tiles are one
-# ``c * block_size``-position block of the online softmax. Chosen on the
-# chip from {4, 8} by gpt2-124m.batch-gen's out_tok_s (8: 642.7 twice,
-# 4: 636.1 tokens/s on one seed; PERF.md section 6, PR 25); a table
-# whose length it does not divide takes the largest divisor below it.
-_ENTRIES_PER_STEP = 8
+# Table entries folded into one iteration of the paged kernels (one
+# ``c * block_size``-position block of the online softmax): a loop
+# iteration's ``2 c`` copies, or the grid form's ``c`` K and ``c`` V
+# operands. A table whose length it does not divide takes the largest
+# divisor below it. Chosen on the chip with the loop alone at
+# gpt2-124m.batch-gen's shapes (256 rows, ~6,400 live entries; PERF.md
+# section 6, PR 31): c = 4 / 8 / 16 / 32 read 1.16 / 0.81 / 0.69 / 0.74
+# ms a call (the grid form: 1.86; `out_tok_s` 6,855-6,918 -> 10,807-
+# 10,937 at 16). An iteration costs ~36-41 ns a copy and a 0.6-0.75 us
+# chain of matmul, reductions and exp that does not shorten with the
+# span (copies alone 0.59 ms at 8 and at 16, folds alone 0.54 / 0.38):
+# fewer, larger iterations win until the copies past a row's last live
+# entry (they repeat it: half an iteration a row on average, a quarter
+# of all copies at 16) cost more than the iterations saved. K-EXAONE's
+# global call (128 rows, 128 KB entries, bound by its bytes) read 2.63 /
+# 2.66 / 2.84 ms at c = 4 / 8 / 16 against the grid's 3.53: 0.2 ms of a
+# 29 ms step, left for the sake of one constant.
+_ENTRIES_PER_STEP = 16
 
 
-def _paged_decode_kernel(tab_ref, len_ref, q_ref, *refs, scale: float,
-                         block_size: int, entries: int, quant: bool,
-                         group: int = 1, window: int = 0, ring: int = 0):
-    """One grid step = ``entries`` consecutive table entries of one row,
-    ALL heads. The pool is lane-dense: each of the ``entries`` K and V
-    refs is one pool block ``(1, bs, H*D)`` (the index maps of
-    :func:`_paged_call` gathered them through the table), a position a
-    row, head ``h`` in lanes ``h*D .. (h+1)*D``; stacked they are one
-    ``[span, H*D]`` block of the online softmax, ``span = entries * bs``.
+def _fold_entries(q, k, v, length, ki, m_scr, l_scr, acc_scr, *,
+                  scale: float, block_size: int, entries: int,
+                  window: int = 0, ring: int = 0, scales=None, last=None):
+    """Fold iteration ``ki`` of a row, table entries ``ki * c ..
+    ki * c + c - 1`` stacked as ``k`` / ``v`` ``[span, KVH*D]`` (``span
+    = c * bs``), into the row's running ``(max, sum, acc)``: THE body of
+    both iteration spaces of the paged decode kernel.
 
-    No lane is sliced per head in the block loop. ``q_ref`` is the
-    query laid out BLOCK-DIAGONALLY, ``(1, H, H*D)``: row ``h`` holds
-    head ``h``'s ``D`` values in that head's lanes and zeros elsewhere,
-    so ONE matmul ``q_bd @ K^T`` gives every head's scores ``[H, span]``
-    (the zeros add nothing: exact), and ``p @ V`` gives ``[H, H*D]``
-    whose diagonal ``D``-lane blocks are the heads' outputs (the
-    off-diagonal blocks are finite junk that is never read). The MXU
-    takes each K/V byte once, as the per-head ``M = 1`` matmuls did;
-    on a v5e this form read 1.85 ms a call at 256 rows x ~6,470 live
-    entries against 3.04 for per-head 64-lane slices and 3.09 for a
-    ``(k * q) @ E`` head-sum on the VPU (PERF.md section 6, PR 27).
+    No lane is sliced per head. ``q`` is the query laid out
+    BLOCK-DIAGONALLY, ``[H, KVH*D]``: row ``h`` holds head ``h``'s ``D``
+    values in the lanes of its K/V head and zeros elsewhere, so ONE
+    matmul ``q_bd @ K^T`` gives every head's scores ``[H, span]`` (the
+    zeros add nothing: exact), and ``p @ V`` gives ``[H, KVH*D]`` whose
+    diagonal ``D``-lane blocks are the heads' outputs (the off-diagonal
+    blocks are finite junk that is never read). The MXU takes each K/V
+    byte once, as per-head ``M = 1`` matmuls did; on a v5e this form
+    read 1.85 ms a call at 256 rows x ~6,470 live entries against 3.04
+    for per-head 64-lane slices and 3.09 for a ``(k * q) @ E`` head-sum
+    on the VPU (PERF.md section 6, PR 27).
 
-    On an INT8 pool the row's per-block fp32 scales ride as
-    ``(1, 1, H, M)`` (see :func:`gather_row_scales`). A head's scale is
-    constant over its own lanes, and row ``h`` of the two products
-    reads only head ``h``'s lanes, so K's scale multiplies the SCORES
+    ``scales`` (an INT8 pool): the row's per-block fp32 K and V scales,
+    two ``[H, M]`` tiles (:func:`gather_row_scales`). A head's scale is
+    constant over its own lanes, and row ``h`` of the two products reads
+    only head ``h``'s lanes, so K's scale multiplies the SCORES
     (``[H, span]``, after the int8 x bf16 dot: int8 is exact in bf16)
     and V's scale multiplies ``p`` before its dot: the same quantity as
     ``ops.quant.dequantize_kv_rows`` followed by the dots, without
-    rounding the dequantized tile to bf16. Softmax statistics and the
-    accumulator stay fp32.
-
-    GROUPED QUERY HEADS (``group`` query heads read one K/V head): the
-    pool's rows are ``KVH*D`` lanes and ``q_ref`` is ``(1, H, KVH*D)``,
-    row ``h`` holding query head ``h`` in the lanes of K/V head
-    ``h // group``: the same two matmuls give all ``H`` heads' scores
-    and outputs, and head ``h``'s output is the ``h // group``-th
-    ``D``-lane block of its row. ``group == 1`` is the case above.
+    rounding the dequantized tile to bf16. ``last`` (the loop form) is
+    the row's last live entry: a slot past it holds that entry again
+    and takes its scale. Softmax statistics and the accumulator stay
+    fp32.
 
     A WINDOW call (``window`` > 0) reads a RING of ``ring`` table
     entries: position ``p`` lives in entry ``(p // bs) % ring``, so
     entry ``e`` holds block ``cur - (cur - e) % ring`` of the row's
-    positions (``cur`` the block of its last position), a key is
-    visible iff ``length - window <= kpos < length``, and the row's
-    walk is ``ring / entries`` steps whatever its length."""
-    c = entries
-    k_refs, v_refs, refs = refs[:c], refs[c:2 * c], refs[2 * c:]
-    ks_ref = vs_ref = None
-    if quant:
-        ks_ref, vs_ref, *refs = refs
-    o_ref, m_scr, l_scr, acc_scr = refs
-    ki = pl.program_id(1)
-    span = c * block_size
-    heads, d = o_ref.shape[1], o_ref.shape[3]
+    positions (``cur`` the block of its last position) and a key is
+    visible iff ``length - window <= kpos < length``."""
+    c, span = entries, entries * block_size
+    heads = q.shape[0]
 
-    @pl.when(ki == 0)
-    def _init():
-        _scratch_init(m_scr, l_scr, acc_scr)
-
-    length = len_ref[pl.program_id(0)]
-
-    def entry_scales(scale_ref):
+    def entry_scales(rows):
         """``[H, span]``: the scale of the entry each position sits in.
         Entry ``ki * c + j`` is lane ``ki * c + j`` of the row's scale
-        block: a masked lane reduction (Mosaic has no dynamic lane
-        index into VMEM), one selected element plus zeros."""
-        rows = scale_ref[0, 0]                               # [H, M]
+        tile: a masked lane reduction (Mosaic has no dynamic lane index
+        into VMEM), one selected element plus zeros."""
         lane = lax.broadcasted_iota(jnp.int32, rows.shape, 1)
         entry = lax.broadcasted_iota(
             jnp.int32, (heads, span), 1) // block_size
         out = jnp.zeros((heads, span), jnp.float32)
         for j in range(c):
-            sj = jnp.sum(jnp.where(lane == ki * c + j, rows, 0.0),
+            e = ki * c + j if last is None else jnp.minimum(ki * c + j, last)
+            sj = jnp.sum(jnp.where(lane == e, rows, 0.0),
                          axis=-1, keepdims=True)             # [H, 1]
             out = jnp.where(entry == j, sj, out)
         return out
 
-    # Steps at or past the row's length do nothing: their index maps
-    # repeat the blocks of the row's last live step, so the pipeline
-    # issues no DMA for them either. A row with length == 0 (inactive
-    # slot) runs no step at all and finalizes to an all-zero output.
     def key_positions(shape):
-        """The position of each of the step's ``span`` keys."""
+        """The position of each of the iteration's ``span`` keys."""
         lane = lax.broadcasted_iota(jnp.int32, shape, 1)
         if not ring:
             return ki * span + lane
@@ -207,50 +197,189 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, *refs, scale: float,
                             cur - lax.rem(cur - e + ring, ring), blk)
         return (blk - entry) * block_size + lane
 
-    @pl.when((length > 0) if ring else (ki * span < length))
+    s = lax.dot_general(q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+    if scales is not None:
+        s = s * entry_scales(scales[0])
+    kpos = key_positions(s.shape)
+    visible = kpos < length
+    if window:
+        visible &= kpos >= jnp.maximum(length - window, 0)
+    s = jnp.where(visible, s, NEG_BIG)                       # [H, span]
+    m_prev = m_scr[:, :1]
+    l_prev = l_scr[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = corr * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    if scales is not None:
+        p = p * entry_scales(scales[1])
+    acc_scr[:] = acc_scr[:] * corr + lax.dot_general(
+        p.astype(q.dtype), v.astype(q.dtype), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                  # [H, KVH*D]
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+
+def _write_heads(o_ref, l_scr, acc_scr, group: int):
+    """common.softmax_finalize over every head's row (no lse: inference
+    only); a row that folded nothing stays exactly zero. GROUPED QUERY
+    HEADS (``group`` query heads read one K/V head): head ``h``'s output
+    is the ``D``-lane block of K/V head ``h // group`` in its row."""
+    heads, d = o_ref.shape[1], o_ref.shape[3]
+    out = acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)
+    for h in range(heads):
+        g = h // group
+        o_ref[0, h] = out[h:h + 1, g * d:(g + 1) * d].astype(o_ref.dtype)
+
+
+def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_hbm, v_hbm, *refs,
+                         scale: float, block_size: int, entries: int,
+                         quant: bool, group: int = 1):
+    """THE LOOP FORM (a full table): one grid step = one row, ALL
+    heads, and inside it a loop over the row's own live table entries,
+    ``entries`` of them an iteration: ``ceil(length / span)`` iterations
+    (``span = entries * bs``; none for an inactive row, which finalizes
+    to exact zeros).
+
+    Both pools stay in HBM (``pl.ANY``). An iteration's K and V blocks
+    come by ``2 * entries`` copies of one pool block each (``(bs,
+    KVH*D)``: whole rows of whole 128-lane tiles, contiguous in HBM)
+    into one of two VMEM slots, gathered through the RAW table in SMEM;
+    iteration ``i + 1``'s copies start before iteration ``i``'s are
+    waited on, and a row's LAST iteration starts the NEXT row's first
+    (scratch, semaphores and the slot counter persist across grid
+    steps, so the row axis is ``"arbitrary"``): no row waits a DMA
+    latency at its start, as the grid form's pipeline arranged for
+    free. Every one of an iteration's slots is filled from a block the
+    row owns: past the row's last live entry the copy repeats that
+    entry (its positions lie at or past ``length`` and are masked), so
+    no buffer row is ever left as VMEM found it or as another row left
+    it (``0 * NaN`` in ``p @ V``) and no block the row does not own is
+    read. Guarding each copy past the last live entry instead read
+    0.81 against 0.69 ms a call: the guards cost the scalar core more
+    than the repeated copies cost the DMA engine (PERF.md section 6,
+    PR 31)."""
+    ks_ref = vs_ref = None
+    if quant:
+        ks_ref, vs_ref, *refs = refs
+    o_ref, k_buf, v_buf, sem, slot_ref, m_scr, l_scr, acc_scr = refs
+    c, bs = entries, block_size
+    span = c * bs
+    b, rows = pl.program_id(0), pl.num_programs(0)
+
+    def iterations(row):
+        return (len_ref[row] + span - 1) // span
+
+    def last_entry(row):
+        return (len_ref[row] + bs - 1) // bs - 1
+
+    def start(row, it, slot):
+        """Start the copies of iteration ``it`` of ``row`` into ``slot``."""
+        last = last_entry(row)
+        for j in range(c):
+            blk = tab_ref[row, jnp.minimum(it * c + j, last)]
+            for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                pltpu.make_async_copy(
+                    hbm.at[blk], buf.at[slot, pl.ds(j * bs, bs)],
+                    sem.at[slot]).start()
+
+    def wait(slot):
+        """Wait for all ``2 c`` copies into ``slot``: a DMA semaphore
+        counts bytes, so one descriptor the shape of a pool's whole
+        slot waits for that pool's ``c`` copies."""
+        for buf in (k_buf, v_buf):
+            pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                  sem.at[slot]).wait()
+
+    n = iterations(b)
+    nxt = jnp.minimum(b + 1, rows - 1)
+    next_row_is_live = (b + 1 < rows) & (iterations(nxt) > 0)
+
+    @pl.when(b == 0)
+    def _first_row():
+        slot_ref[0] = 0
+
+    slot0 = slot_ref[0]
+    length = len_ref[b]
+    _scratch_init(m_scr, l_scr, acc_scr)
+
+    # Nobody has started this row's first iteration (it is the first
+    # row), or this row has none and the next row's must start here.
+    @pl.when(((b == 0) & (n > 0)) | ((n == 0) & next_row_is_live))
+    def _():
+        start(jnp.where(n > 0, b, nxt), 0, slot0)
+
+    def iteration(i, carry):
+        slot = lax.rem(slot0 + i, 2)
+        more = i + 1 < n
+
+        @pl.when(more | next_row_is_live)
+        def _():
+            start(jnp.where(more, b, nxt), jnp.where(more, i + 1, 0),
+                  1 - slot)
+
+        wait(slot)
+        _fold_entries(
+            q_ref[0], k_buf[slot], v_buf[slot], length, i, m_scr, l_scr,
+            acc_scr, scale=scale, block_size=bs, entries=c,
+            last=last_entry(b),
+            scales=(ks_ref[0, 0], vs_ref[0, 0]) if quant else None)
+        return carry
+
+    lax.fori_loop(0, n, iteration, 0)
+    slot_ref[0] = lax.rem(slot0 + n, 2)
+    _write_heads(o_ref, l_scr, acc_scr, group)
+
+
+def _paged_decode_grid_kernel(tab_ref, len_ref, q_ref, *refs, scale: float,
+                              block_size: int, entries: int, quant: bool,
+                              group: int = 1, window: int = 0,
+                              ring: int = 0):
+    """THE GRID FORM: one grid step = ``entries`` consecutive table
+    entries of one row, ALL heads. Each of the ``entries`` K and V refs
+    is one pool block ``(1, bs, KVH*D)`` (the index maps of
+    :func:`_paged_call` gathered them through the table); stacked they
+    are one block of :func:`_fold_entries`. Steps at or past the row's
+    length do nothing: their index maps repeat the blocks of the row's
+    last live step, so the pipeline issues no DMA for them either, but
+    the scalar core still walks them (about 0.7 us a step on a v5e). A
+    ring (``window``) is walked as it stands, ``ring / entries`` steps
+    a row whatever the length and every one of them live (K-EXAONE's
+    rings are 3 entries: one step a row)."""
+    c = entries
+    k_refs, v_refs, refs = refs[:c], refs[c:2 * c], refs[2 * c:]
+    ks_ref = vs_ref = None
+    if quant:
+        ks_ref, vs_ref, *refs = refs
+    o_ref, m_scr, l_scr, acc_scr = refs
+    ki = pl.program_id(1)
+
+    @pl.when(ki == 0)
+    def _init():
+        _scratch_init(m_scr, l_scr, acc_scr)
+
+    length = len_ref[pl.program_id(0)]
+
+    # A row with length == 0 (inactive slot) runs no step at all and
+    # finalizes to an all-zero output.
+    @pl.when((length > 0) if ring else (ki * c * block_size < length))
     def _block():
-        q = q_ref[0]                                         # [H, KVH*D]
-        k = jnp.concatenate([r[0] for r in k_refs], axis=0)  # [span, H*D]
-        v = jnp.concatenate([r[0] for r in v_refs], axis=0)
-        s = lax.dot_general(q, k.astype(q.dtype),
-                            (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        if quant:
-            s = s * entry_scales(ks_ref)
-        kpos = key_positions(s.shape)
-        visible = kpos < length
-        if window:
-            visible &= kpos >= jnp.maximum(length - window, 0)
-        s = jnp.where(visible, s, NEG_BIG)                   # [H, span]
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = corr * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        if quant:
-            p = p * entry_scales(vs_ref)
-        acc_scr[:] = acc_scr[:] * corr + lax.dot_general(
-            p.astype(q.dtype), v.astype(q.dtype),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [H, H*D]
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        _fold_entries(
+            q_ref[0],
+            jnp.concatenate([r[0] for r in k_refs], axis=0),
+            jnp.concatenate([r[0] for r in v_refs], axis=0),
+            length, ki, m_scr, l_scr, acc_scr, scale=scale,
+            block_size=block_size, entries=c, window=window, ring=ring,
+            scales=(ks_ref[0, 0], vs_ref[0, 0]) if quant else None)
 
     @pl.when(ki == pl.num_programs(1) - 1)
     def _final():
-        # common.softmax_finalize over every head's row (no lse:
-        # inference only); a row that folded nothing stays exactly zero.
-        # Head h's output is the D-lane block of its K/V head in its row.
-        out = acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)
-        for h in range(heads):
-            g = h // group
-            o_ref[0, h] = out[h:h + 1, g * d:(g + 1) * d].astype(
-                o_ref.dtype)
+        _write_heads(o_ref, l_scr, acc_scr, group)
 
 
 def _visited_entries(tab, lens, block_size: int, entries: int):
-    """``block_tables [B, M]`` as the paged kernel walks it: entry ``e``
+    """``block_tables [B, M]`` as the GRID form walks it: entry ``e``
     of a row is operand ``e % entries`` of grid step ``e // entries``.
     Up to the row's last live entry the table stands as it is; every
     entry past it names a block the row's last live step already holds
@@ -267,32 +396,48 @@ def _visited_entries(tab, lens, block_size: int, entries: int):
     return jnp.take_along_axis(tab, ent, axis=1)
 
 
+# Jitted so that a model's layers share ONE trace and ONE lowering of the
+# kernel body: traced inline, a 12-layer step program traced it 12 times on
+# every process start, before any compile cache is consulted, and the loop
+# form's unrolled copies made that 20 s of a serving cell's set-up (PERF.md
+# section 6, PR 31).
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "window"))
 def _paged_call(q, k, v, lengths, block_tables, scale, interpret,
                 block_scales=None, window=None):
     """Paged layout: k/v are LANE-DENSE BLOCK POOLS ``[N, bs, KVH*D]``
     and ``block_tables [B, M]`` maps row b's KV block ki to pool block
     ``block_tables[b, ki]``. Table and lengths ride as SCALAR-PREFETCH
-    operands (pltpu.PrefetchScalarGridSpec); the grid is ``(B, M / c)``
-    and each pool is passed ``c`` times, operand ``j`` gathering table
-    entry ``ki * c + j`` in its index map (XLA feeds all of them from
-    one buffer). A pool block is ``bs`` whole rows of ``KVH*D`` lanes,
-    contiguous in HBM, so one DMA brings all heads of an entry and no
-    lane of it is padding. A full table is first rewritten by
-    :func:`_visited_entries` (one index load is then all an index map
-    costs the scalar core, which is what a skipped step's time is made
-    of); a ring (``window``) is walked as it stands, ``M / c`` steps a
-    row. The query goes in block-diagonally (``[B, H, KVH*D]``, built
-    here by one small XLA fusion; see :func:`_paged_decode_kernel`).
-    With ``block_scales`` (int8 pools) the row's per-block fp32 scales
-    are pre-gathered through the same rewritten table
-    (:func:`gather_row_scales`) and the kernel applies them in the
-    block loop.
+    operands (pltpu.PrefetchScalarGridSpec). A pool block is ``bs``
+    whole rows of ``KVH*D`` lanes, contiguous in HBM, so one DMA brings
+    all heads of an entry. The query goes in block-diagonally
+    (``[B, H, KVH*D]``, built here by one small XLA fusion; see
+    :func:`_fold_entries`). With ``block_scales`` (int8 pools) the
+    row's per-block fp32 scales are pre-gathered through the table
+    (:func:`gather_row_scales`) and ride as a per-row VMEM block.
 
-    Not here yet: a manual-DMA loop over a row's own entries (pools in
-    ``pl.ANY``, ``make_async_copy`` per live entry), which the
-    lane-dense pool admits (a ``(bs, H*D)`` block is whole 128-lane
-    tiles) and which would stop the scalar core walking the steps that
-    move nothing (1.44 of 1.85 ms a call: PERF.md section 6, PR 27)."""
+    WHICH ITERATION SPACE a call gets is decided here, by what the
+    operands show (the same rule compiled and interpreted):
+
+    - a full table over a pool whose ``KVH*D`` is a multiple of 128
+      (GPT-2's 768, K-EXAONE's 1,024): the LOOP form
+      (:func:`_paged_decode_kernel`), grid ``(B,)``, the pools in
+      ``pl.ANY`` and the RAW table: a call costs what its rows' live
+      entries cost. It keeps the names the benchmark and the compile
+      tests read: ``nezha_decode_attention_paged`` / ``_paged_int8``.
+    - any other width (a four-way head shard of GPT-2's pool is 192
+      lanes; the tests' 48), and a ring (``window``): the GRID form
+      (:func:`_paged_decode_grid_kernel`), grid ``(B, M / c)``, each
+      pool passed ``c`` times with operand ``j`` gathering table entry
+      ``ki * c + j`` in its index map (a full table first rewritten by
+      :func:`_visited_entries`), named with a ``_grid`` suffix
+      (``nezha_decode_attention_window_grid``). Mosaic refuses the
+      loop's copies at such a width: "Slice shape along dimension 2
+      must be aligned to tiling (128), but is 192". A ring has no step
+      that moves nothing for the loop to save (K-EXAONE's: 3 entries,
+      one live step a row, bound by the MXU taking K and V as weights):
+      on the loop its call read 0.271 against 0.262 ms alone and 4.6%
+      more in the cell's trace, so it stays as it was (PERF.md section
+      6, PR 31)."""
     b, h, _, d = q.shape
     bs, hd = k.shape[1], k.shape[2]
     kvh = hd // d
@@ -300,54 +445,70 @@ def _paged_call(q, k, v, lengths, block_tables, scale, interpret,
     m = block_tables.shape[1]
     c = _pick_block(m, _ENTRIES_PER_STEP)
     quant = block_scales is not None
-    kernel = functools.partial(
-        _paged_decode_kernel, scale=scale, block_size=bs, entries=c,
-        quant=quant, group=group, window=window or 0,
-        ring=m if window else 0)
+    loop = hd % _LANES == 0 and not window
+    if loop:
+        kernel = functools.partial(
+            _paged_decode_kernel, scale=scale, block_size=bs, entries=c,
+            quant=quant, group=group)
+    else:
+        kernel = functools.partial(
+            _paged_decode_grid_kernel, scale=scale, block_size=bs,
+            entries=c, quant=quant, group=group, window=window or 0,
+            ring=m if window else 0)
     tab = jnp.asarray(block_tables, jnp.int32)
     if window:
         lens = jnp.maximum(jnp.asarray(lengths, jnp.int32), 0)
     else:
         lens = jnp.clip(jnp.asarray(lengths, jnp.int32), 0, m * bs)
-        tab = _visited_entries(tab, lens, bs, c)
+        if not loop:
+            tab = _visited_entries(tab, lens, bs, c)
     # row h: head h's values in the lanes of K/V head h // group
     lanes_of = (jnp.arange(h)[:, None] // group
                 == jnp.arange(kvh)[None, :]).astype(q.dtype)
     q_bd = (q[:, :, 0, None, :] * lanes_of[None, :, :, None]).reshape(
         b, h, hd)
-    q_spec = pl.BlockSpec((1, h, hd), lambda b_, ki, tab, lens: (b_, 0, 0))
-    out_spec = pl.BlockSpec((1, h, 1, d),
-                            lambda b_, ki, tab, lens: (b_, 0, 0, 0))
-    kv_specs = [pl.BlockSpec((1, bs, hd),
-                             lambda b_, ki, tab, lens, j=j:
-                             (tab[b_, ki * c + j], 0, 0))
-                for j in range(c)]
-    in_specs = [q_spec] + kv_specs * 2
-    operands = [q_bd] + [k] * c + [v] * c
+    row = lambda b_, *_: (b_, 0, 0)                          # noqa: E731
+    q_spec = pl.BlockSpec((1, h, hd), row)
+    out_spec = pl.BlockSpec((1, h, 1, d), lambda b_, *_: (b_, 0, 0, 0))
+    softmax_scratch = [pltpu.VMEM((h, _LANES), jnp.float32),
+                       pltpu.VMEM((h, _LANES), jnp.float32),
+                       pltpu.VMEM((h, hd), jnp.float32)]
+    if loop:
+        grid = (b,)
+        in_specs = [q_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        operands = [q_bd, k, v]
+        scratch = [pltpu.VMEM((2, c * bs, hd), k.dtype),
+                   pltpu.VMEM((2, c * bs, hd), v.dtype),
+                   pltpu.SemaphoreType.DMA((2,)),
+                   pltpu.SMEM((1,), jnp.int32)] + softmax_scratch
+        semantics = ("arbitrary",)
+    else:
+        grid = (b, m // c)
+        kv_specs = [pl.BlockSpec((1, bs, hd),
+                                 lambda b_, ki, tab, lens, j=j:
+                                 (tab[b_, ki * c + j], 0, 0))
+                    for j in range(c)]
+        in_specs = [q_spec] + kv_specs * 2
+        operands = [q_bd] + [k] * c + [v] * c
+        scratch = softmax_scratch
+        semantics = ("parallel", "arbitrary")
     if quant:
         in_specs += [pl.BlockSpec((1, 1, h, m),
-                                  lambda b_, ki, tab, lens:
-                                  (b_, 0, 0, 0))] * 2
+                                  lambda b_, *_: (b_, 0, 0, 0))] * 2
         operands += [gather_row_scales(sc, tab, h) for sc in block_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, m // c),
-        in_specs=in_specs,
-        out_specs=out_spec,
-        scratch_shapes=[pltpu.VMEM((h, _LANES), jnp.float32),
-                        pltpu.VMEM((h, _LANES), jnp.float32),
-                        pltpu.VMEM((h, hd), jnp.float32)],
-    )
+        num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+        out_specs=out_spec, scratch_shapes=scratch)
+    name = ("nezha_decode_attention_paged_int8" if quant
+            else "nezha_decode_attention_window" if window
+            else "nezha_decode_attention_paged")
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
-        name=("nezha_decode_attention_paged_int8" if quant
-              else "nezha_decode_attention_window" if window
-              else "nezha_decode_attention_paged"),
+        name=name if loop else name + "_grid",
     )(tab, lens, *operands)
 
 

@@ -180,108 +180,6 @@ def test_flash_decode_block_table_operand_parity():
         np.asarray(dense), atol=1e-5)
 
 
-def _paged_case(kind, m, seed=0, b=8, h=3, d=16, bs=8):
-    """A batch over a PERMUTED table (no two entries share a block, no
-    row's blocks are in order) whose lengths sit on every edge of the
-    kernel's iteration space: empty, one position, one block, one block
-    and one, one grid step (c entries), one step and one, the full
-    table less one, the full table. Returns the kernel's operands (the
-    pools as lane-dense rows ``[n, bs, H*D]``; the reference below
-    thinks in per-head tiles and ``merge_heads`` converts, in this one
-    place) and the composed path's answer (gather, dequantize, masked
-    dense attention in float32 over the same stored values)."""
-    from nezha_tpu import ops
-    from nezha_tpu.ops.pallas import decode_attention as da
-    from nezha_tpu.ops.pallas.common import pick_block
-    from nezha_tpu.ops.quant import (
-        dequantize_kv_block,
-        merge_heads,
-        quantize_kv_block,
-    )
-
-    c = pick_block(m, da._ENTRIES_PER_STEP)
-    lengths = np.minimum([0, 1, bs, bs + 1, c * bs, c * bs + 1,
-                          m * bs - 1, m * bs], m * bs).astype(np.int32)
-    assert len(lengths) == b
-    rng = np.random.default_rng(seed)
-    n = b * m + 1                                   # block 0: scratch
-    tables = (rng.permutation(n - 1) + 1).reshape(b, m).astype(np.int32)
-    dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
-    q = jnp.asarray(rng.normal(size=(b, h, 1, d)), dtype)
-    kp = jnp.asarray(rng.normal(size=(n, h, bs, d)), dtype)
-    vp = jnp.asarray(rng.normal(size=(n, h, bs, d)), dtype)
-    scales = None
-    if kind == "int8":
-        (kp, ksc), (vp, vsc) = quantize_kv_block(kp), quantize_kv_block(vp)
-        scales = (ksc, vsc)
-        k_all = dequantize_kv_block(kp[tables], ksc[tables])
-        v_all = dequantize_kv_block(vp[tables], vsc[tables])
-    else:
-        k_all = kp[tables].astype(jnp.float32)
-        v_all = vp[tables].astype(jnp.float32)
-    k_all = k_all.transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
-    v_all = v_all.transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
-    mask = jnp.where(jnp.arange(m * bs)[None, :] < lengths[:, None],
-                     0.0, -jnp.inf).astype(jnp.float32)
-    ref = np.array(ops.dot_product_attention(
-        q.astype(jnp.float32), k_all, v_all, mask=mask[:, None, None, :]))
-    ref[lengths == 0] = 0.0          # the kernel's answer for no position
-    owned = np.zeros(n, bool)
-    for row, length in zip(tables, lengths):
-        owned[row[:-(-int(length) // bs)]] = True
-    return (q, merge_heads(kp), merge_heads(vp), jnp.asarray(lengths),
-            jnp.asarray(tables), scales, ref, owned)
-
-
-# bf16 tiles dot in bf16 (scores and P both rounded to 8 bits of
-# mantissa); float32 and dequantized-int8-in-float32 tiles stay exact.
-_PAGED_TOL = {"f32": 2e-6, "int8": 2e-6, "bf16": 3e-2}
-
-
-@pytest.mark.parametrize("m,h,d", [(16, 3, 16), (12, 3, 16), (3, 3, 16),
-                                   (16, 12, 64)],
-                         ids=["m16", "m12-not-a-multiple", "m3-one-step",
-                              "m16-hd768"])
-@pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
-def test_paged_decode_kernel_matches_composed_on_every_edge(kind, m, h, d):
-    """The paged kernel (interpret mode) against the composed path, all
-    edge lengths mixed in one batch: M a multiple of the module's
-    entries-per-step constant, M that is not (the largest divisor
-    steps it: 12 -> 6 or 4), and a table shorter than one step, at a
-    pool width under one 128-lane tile (48) and at GPT-2's (768)."""
-    from nezha_tpu.ops.pallas import flash_decode_attention
-
-    q, kp, vp, lengths, tables, scales, ref, _ = _paged_case(kind, m, h=h,
-                                                             d=d)
-    out = np.asarray(flash_decode_attention(
-        q, kp, vp, lengths, block_tables=tables, block_scales=scales,
-        interpret=True), np.float32)
-    assert np.abs(out - ref).max() <= _PAGED_TOL[kind]
-    assert (out[0] == 0).all()                    # length 0: exact zeros
-
-
-@pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
-def test_paged_decode_kernel_reads_no_block_a_row_does_not_own(kind):
-    """Every block no row owns (scratch block 0, and each row's table
-    entries at or past ceil(length / bs)) is NaN — on an int8 pool its
-    scale is. The outputs stay finite and equal the clean pool's: an
-    entry past a row's length is never folded, whatever it names."""
-    from nezha_tpu.ops.pallas import flash_decode_attention
-
-    q, kp, vp, lengths, tables, scales, ref, owned = _paged_case(kind, 12)
-    if kind == "int8":
-        scales = tuple(jnp.where(owned[:, None], sc, jnp.nan)
-                       for sc in scales)
-    else:
-        kp, vp = (jnp.where(owned[:, None, None], pool, jnp.nan)
-                  for pool in (kp, vp))
-    out = np.asarray(flash_decode_attention(
-        q, kp, vp, lengths, block_tables=tables, block_scales=scales,
-        interpret=True), np.float32)
-    assert np.isfinite(out).all()
-    assert np.abs(out - ref).max() <= _PAGED_TOL[kind]
-
-
 # --------------------------------------------------------- engine parity
 def test_paged_engine_matches_generate(model_and_vars):
     """Greedy requests (one of them a chunked prompt), batched with a
@@ -848,3 +746,123 @@ def test_nezha_bench_gates_against_committed_baseline(tmp_path):
     base2 = json.load(open(sb))
     assert base2["by_platform"]["tpu"]["closed_loop_horizon_sweep"][
         "by_horizon"]["1"]["tokens_per_sec"] == 123456.0
+
+
+# ------------------------------------- the paged decode kernel, every edge
+# Last in the file: the timing-gated test above compares two back-to-back
+# ms-scale timings, and this file starts with the run (`--dist loadfile`
+# schedules the files with the most items first), so what stands before it
+# decides what it runs beside. These cases grew from 15 to 33 in PR 31.
+def _paged_case(kind, m, seed=0, b=8, h=3, d=16, bs=8):
+    """A batch over a PERMUTED table (no two entries share a block, no
+    row's blocks are in order) whose lengths sit on every edge of the
+    kernel's iteration space: empty, one position, one block, one block
+    and one, one grid step (c entries), one step and one, the full
+    table less one, the full table. Returns the kernel's operands (the
+    pools as lane-dense rows ``[n, bs, H*D]``; the reference below
+    thinks in per-head tiles and ``merge_heads`` converts, in this one
+    place) and the composed path's answer (gather, dequantize, masked
+    dense attention in float32 over the same stored values)."""
+    from nezha_tpu import ops
+    from nezha_tpu.ops.pallas import decode_attention as da
+    from nezha_tpu.ops.pallas.common import pick_block
+    from nezha_tpu.ops.quant import (
+        dequantize_kv_block,
+        merge_heads,
+        quantize_kv_block,
+    )
+
+    c = pick_block(m, da._ENTRIES_PER_STEP)
+    lengths = np.minimum([0, 1, bs, bs + 1, c * bs, c * bs + 1,
+                          m * bs - 1, m * bs], m * bs).astype(np.int32)
+    assert len(lengths) == b
+    rng = np.random.default_rng(seed)
+    n = b * m + 1                                   # block 0: scratch
+    tables = (rng.permutation(n - 1) + 1).reshape(b, m).astype(np.int32)
+    dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
+    q = jnp.asarray(rng.normal(size=(b, h, 1, d)), dtype)
+    kp = jnp.asarray(rng.normal(size=(n, h, bs, d)), dtype)
+    vp = jnp.asarray(rng.normal(size=(n, h, bs, d)), dtype)
+    scales = None
+    if kind == "int8":
+        (kp, ksc), (vp, vsc) = quantize_kv_block(kp), quantize_kv_block(vp)
+        scales = (ksc, vsc)
+        k_all = dequantize_kv_block(kp[tables], ksc[tables])
+        v_all = dequantize_kv_block(vp[tables], vsc[tables])
+    else:
+        k_all = kp[tables].astype(jnp.float32)
+        v_all = vp[tables].astype(jnp.float32)
+    k_all = k_all.transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
+    v_all = v_all.transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
+    mask = jnp.where(jnp.arange(m * bs)[None, :] < lengths[:, None],
+                     0.0, -jnp.inf).astype(jnp.float32)
+    ref = np.array(ops.dot_product_attention(
+        q.astype(jnp.float32), k_all, v_all, mask=mask[:, None, None, :]))
+    ref[lengths == 0] = 0.0          # the kernel's answer for no position
+    owned = np.zeros(n, bool)
+    for row, length in zip(tables, lengths):
+        owned[row[:-(-int(length) // bs)]] = True
+    return (q, merge_heads(kp), merge_heads(vp), jnp.asarray(lengths),
+            jnp.asarray(tables), scales, ref, owned)
+
+
+# bf16 tiles dot in bf16 (scores and P both rounded to 8 bits of
+# mantissa); float32 and dequantized-int8-in-float32 tiles stay exact.
+_PAGED_TOL = {"f32": 2e-6, "int8": 2e-6, "bf16": 3e-2}
+
+
+@pytest.mark.parametrize("m,h,d", [(32, 3, 16), (24, 3, 16), (3, 3, 16),
+                                   (32, 12, 64), (32, 2, 64), (24, 2, 64),
+                                   (3, 2, 64), (24, 12, 64)],
+                         ids=["m32", "m24-not-a-multiple", "m3-one-step",
+                              "m32-hd768", "m32-hd128",
+                              "m24-not-a-multiple-hd128", "m3-one-step-hd128",
+                              "m24-not-a-multiple-hd768"])
+@pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+def test_paged_decode_kernel_matches_composed_on_every_edge(kind, m, h, d):
+    """The paged kernel (interpret mode) against the composed path, all
+    edge lengths mixed in one batch: M a multiple of the module's
+    entries-per-step constant, M that is not (the largest divisor
+    steps it: 24 -> 12), and a table shorter than one step. The pool
+    width decides the kernel's iteration space (``_paged_call``): under
+    one 128-lane tile (48) the GRID form walks the table; whole tiles
+    (128, and GPT-2's 768) take the per-row LOOP, whose last iteration
+    of a row is partly live at every length that is not a multiple of
+    an iteration's span."""
+    from nezha_tpu.ops.pallas import flash_decode_attention
+
+    q, kp, vp, lengths, tables, scales, ref, _ = _paged_case(kind, m, h=h,
+                                                             d=d)
+    out = np.asarray(flash_decode_attention(
+        q, kp, vp, lengths, block_tables=tables, block_scales=scales,
+        interpret=True), np.float32)
+    assert np.abs(out - ref).max() <= _PAGED_TOL[kind]
+    assert (out[0] == 0).all()                    # length 0: exact zeros
+
+
+@pytest.mark.parametrize("h,d", [(3, 16), (2, 64), (12, 64)],
+                         ids=["hd48-grid", "hd128-loop", "hd768-loop"])
+@pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+def test_paged_decode_kernel_reads_no_block_a_row_does_not_own(kind, h, d):
+    """Every block no row owns (scratch block 0, and each row's table
+    entries at or past ceil(length / bs)) is NaN — on an int8 pool its
+    scale is. The outputs stay finite and equal the clean pool's: an
+    entry past a row's length is never folded, whatever it names, by
+    the grid form (48 lanes) and by the loop (whole 128-lane tiles),
+    whose every copy must name a block the row owns: a buffer row left
+    unfilled, or filled from a NaN block, is ``0 * NaN`` in ``p @ V``."""
+    from nezha_tpu.ops.pallas import flash_decode_attention
+
+    q, kp, vp, lengths, tables, scales, ref, owned = _paged_case(
+        kind, 24, h=h, d=d)
+    if kind == "int8":
+        scales = tuple(jnp.where(owned[:, None], sc, jnp.nan)
+                       for sc in scales)
+    else:
+        kp, vp = (jnp.where(owned[:, None, None], pool, jnp.nan)
+                  for pool in (kp, vp))
+    out = np.asarray(flash_decode_attention(
+        q, kp, vp, lengths, block_tables=tables, block_scales=scales,
+        interpret=True), np.float32)
+    assert np.isfinite(out).all()
+    assert np.abs(out - ref).max() <= _PAGED_TOL[kind]

@@ -84,6 +84,10 @@ def test_k_exaone_step_decodes_through_one_kernel_body_twice_named(
     window = [c for c in calls if re.match(
         r"(ROOT )?%?nezha_decode_attention_window\S* = " + shape, c)]
     assert (len(paged), len(window), len(calls)) == (1, 4, 5), calls
+    # 1,024-lane pools: the full table takes the per-row loop, a ring
+    # (every step of it live) keeps the grid form and says so in its name
+    assert "_grid" not in paged[0].split(" = ")[0], paged
+    assert all("_grid" in c.split(" = ")[0] for c in window), window
     assert re.search(r"s32\[4,16\]", text.split("ENTRY", 1)[1])
 
 

@@ -37,36 +37,46 @@ def _case(group, m, lens, *, window=None, bs=8, kvh=2, d=16, seed=0):
     return np.asarray(got), np.asarray(want), (q, k, v, tab, lens)
 
 
+# Two K/V heads of 16 are a 32-lane pool (the GRID form walks the table),
+# of 64 a whole 128-lane tile (the per-row LOOP walks a full table; a ring
+# keeps the grid form at every width): `_paged_call`.
+WIDTHS = pytest.mark.parametrize("d", [16, 64], ids=["hd32", "hd128"])
+
+
+@WIDTHS
 @pytest.mark.parametrize("group", [1, 2, 4, 8])
-@pytest.mark.parametrize("m", [3, 6, 16])
-def test_grouped_heads_over_a_full_table(group, m):
-    """Tables of 3, 6 and 16 entries (1, 1 and 2 grid steps a row at 8
-    entries a step); rows empty, inside a block, at a block's edge and at
-    the table's end."""
+@pytest.mark.parametrize("m", [3, 6, 32])
+def test_grouped_heads_over_a_full_table(group, m, d):
+    """Tables of 3, 6 and 32 entries (1, 1 and 2 iterations a row at 16
+    entries each); rows empty, inside a block, at a block's edge and at
+    the table's end, an empty row between live ones and one last."""
     cap = m * 8
-    got, want, _ = _case(group, m, (0, 5, 16, cap))
-    assert got.shape == (4, 2 * group, 1, 16)
+    got, want, _ = _case(group, m, (0, 5, 0, 16, cap, 0), d=d)
+    assert got.shape == (6, 2 * group, 1, d)
     assert np.abs(got - want).max() < TOL
     assert not got[0].any()                       # the empty row
 
 
+@WIDTHS
 @pytest.mark.parametrize("group", [1, 2, 4, 8])
 @pytest.mark.parametrize("window, bs", [(12, 8), (16, 8), (8, 4), (5, 8)])
-def test_window_over_a_ring(group, window, bs):
+def test_window_over_a_ring(group, window, bs, d):
     """A ring of ``ceil(window / bs) + 1`` entries: rows that have not
     filled their window, that have just filled it, that have wrapped the
     ring once and many times, and an empty row."""
     m = ring_entries(window, bs)
     lens = (0, 3, window, window + 1, m * bs + 3, 4001)
-    got, want, _ = _case(group, m, lens, window=window, bs=bs)
+    got, want, _ = _case(group, m, lens, window=window, bs=bs, d=d)
     assert np.abs(got - want).max() < TOL
     assert not got[0].any()
 
 
-def test_a_ring_wider_than_it_must_be_and_walked_in_two_steps():
-    """16 entries for a window that needs 3: two grid steps a row, a
-    step of which may hold no visible key."""
-    got, want, _ = _case(4, 16, (1, 9, 130, 1000), window=12)
+@WIDTHS
+def test_a_ring_wider_than_it_must_be_and_walked_in_two_steps(d):
+    """32 entries for a window that needs 3: two iterations a row, one
+    of which may hold no visible key; an empty row between two live
+    ones."""
+    got, want, _ = _case(4, 32, (1, 0, 9, 130, 0, 1000), window=12, d=d)
     assert np.abs(got - want).max() < TOL
 
 
