@@ -73,6 +73,11 @@ _SERVE_COUNTERS = {"serve.admitted_total", "serve.rejected_total",
                    # Traffic-invariant: top-k and peaked top-p traffic
                    # report 0, never omit it.
                    "serve.sampling.full_sort_steps_total",
+                   # Recurrent-state layers (PR 32): admissions whose
+                   # prefill reset a state entry (started it from
+                   # zeros). Model-invariant: a model without state
+                   # layers reports 0, never omits it.
+                   "serve.state.resets_total",
                    # Cross-replica KV migration (PR 11, disaggregated
                    # prefill/decode tiers): committed installs and
                    # their int8-wire bytes. Topology-invariant: a
@@ -133,6 +138,10 @@ _SERVE_GAUGES = {"serve.queue_depth", "serve.batch_occupancy",
                  # Ring blocks the taken slots hold for window layers
                  # (PR 30; 0 for a model without window layers).
                  "serve.kv.window_blocks_used",
+                 # State entries the taken slots hold for recurrent-
+                 # state layers, and their device bytes (PR 32; 0s for
+                 # a model without state layers).
+                 "serve.state.slots_bound", "serve.state.bytes_resident",
                  # KV quantization (PR 9): device bytes the resident KV
                  # holds and the storage width in bits (8 = int8 blocks
                  # + per-block scales, 16/32 = plain bf16/f32 pools).

@@ -181,8 +181,8 @@ def load_gpt2_for_inference(args):
 
 def load_model_for_inference(args):
     """(model, variables) for ``nezha-serve --model``: GPT-2 from any of
-    its three weight sources, or Mistral-Small-4 / K-EXAONE with random
-    weights (the one source they have: no checkpoint converter exists
+    its three weight sources, or Mistral-Small-4 / K-EXAONE / Kimi-Linear
+    with random weights (the one source they have: no checkpoint converter exists
     for them)."""
     if getattr(args, "model", "gpt2") == "gpt2":
         return load_gpt2_for_inference(args)
@@ -194,6 +194,8 @@ def load_model_for_inference(args):
 
     if args.model == "k_exaone":
         from nezha_tpu.models.exaone_moe import k_exaone as build
+    elif args.model == "kimi_linear":
+        from nezha_tpu.models.kimi_linear import kimi_linear as build
     else:
         from nezha_tpu.models.mistral4 import mistral_small4 as build
     model = build(args.model_preset)

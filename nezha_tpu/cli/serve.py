@@ -80,20 +80,27 @@ def build_parser() -> argparse.ArgumentParser:
     src.add_argument("--random-init", action="store_true",
                      help="fresh random weights (smoke/benchmark runs)")
     p.add_argument("--model",
-                   choices=["gpt2", "mistral_small4", "k_exaone"],
+                   choices=["gpt2", "mistral_small4", "k_exaone",
+                            "kimi_linear"],
                    default="gpt2",
                    help="the architecture served: gpt2 (every option); "
                         "mistral_small4 (Mistral-Small-4: latent "
-                        "attention, dropless experts) or k_exaone "
+                        "attention, dropless experts); k_exaone "
                         "(K-EXAONE: grouped-query heads, window layers "
                         "in a ring of blocks beside global layers, "
-                        "sigmoid-routed experts). The last two: "
-                        "--random-init only, --model-preset full = one "
-                        "chip's share at the published widths, bf16 "
-                        "parameters; refused with --mesh, --kv-dtype "
-                        "int8, --kv-host-blocks, --speculative, KV "
-                        "migration and peer pulls; k_exaone also with "
-                        "--prefix-cache on")
+                        "sigmoid-routed experts); kimi_linear "
+                        "(Kimi-Linear: linear-attention layers with a "
+                        "recurrent state a slot beside latent-attention "
+                        "layers, sigmoid-routed experts). The last "
+                        "three: --random-init only, --model-preset full "
+                        "= one chip's share at the published widths, "
+                        "bf16 parameters; refused with --mesh, "
+                        "--kv-dtype int8, --kv-host-blocks, "
+                        "--speculative, KV migration and peer pulls; "
+                        "k_exaone and kimi_linear also with "
+                        "--prefix-cache on (a trie hit would need the "
+                        "window layers' last tokens, or the recurrent "
+                        "state at the hit's boundary, as a snapshot)")
     p.add_argument("--model-preset", choices=["full", "tiny"],
                    default="full")
     p.add_argument("--tokenizer", default=None,
@@ -427,10 +434,12 @@ def _build_stack(args):
             raise SystemExit(
                 f"--model {args.model}: not supported with "
                 f"{', '.join(unsupported)} (its cache is a latent row a "
-                f"token, or window layers in a ring of blocks, not "
-                f"per-head K/V in one growing table)")
-        # (--prefix-cache on with window layers is the pool's own typed
-        # refusal, from the model's declaration: "serve engine: ...")
+                f"token, window layers in a ring of blocks, or a "
+                f"recurrent state a slot, not per-head K/V in one "
+                f"growing table)")
+        # (--prefix-cache on with window or state layers is the pool's
+        # own typed refusal, from the model's declaration: "serve
+        # engine: ...")
     if mesh_m > 1 and getattr(args, "ckpt_dir", None):
         # The implicit nezha-reshard: build the serve mesh first, then
         # stream the training checkpoint straight into the head-sharded

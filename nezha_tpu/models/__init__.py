@@ -14,6 +14,8 @@ _LAZY = {
     "mistral_small4": "mistral4",
     "ExaoneMoe": "exaone_moe", "ExaoneMoeConfig": "exaone_moe",
     "k_exaone": "exaone_moe",
+    "KimiLinear": "kimi_linear", "KimiLinearConfig": "kimi_linear",
+    "kimi_linear": "kimi_linear",
     "generate": "generate", "init_cache": "generate",
     "gpt2_from_hf": "convert", "bert_from_hf": "convert",
     "gpt2_params_from_hf": "convert", "gpt2_params_to_hf": "convert",

@@ -43,11 +43,14 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from nezha_tpu import nn
 from nezha_tpu.nn import initializers as init_lib
 from nezha_tpu.nn.module import Module, Variables, child_vars, run_child
 from nezha_tpu.ops import rotary
+from nezha_tpu.ops.pallas import latent_decode_attention
+from nezha_tpu.ops.pallas.common import NEG_BIG, pick_block
 from nezha_tpu.parallel.expert import DroplessMoE, DroplessMoEConfig
 from nezha_tpu.tensor.policy import DEFAULT_POLICY, Policy
 
@@ -85,6 +88,12 @@ class Mistral4Config:
     # whose weights are here. The defaults are the whole model.
     experts_held: Tuple[int, int] = (0, 128)
     vocab_held: int = 131072
+    # How :class:`MLAttention` reads the paged latent cache at decode:
+    # "xla" gathers each row's table as one view and attends it composed
+    # (what this model's cell runs); "kernel" is the paged decode
+    # kernel's latent form, "auto" that kernel on a TPU backend
+    # (ServeConfig.decode_impl overrides it, as for GPT-2).
+    decode_impl: str = "xla"
 
     # What serve.Engine and the pools read of any model's config.
     @property
@@ -135,6 +144,16 @@ TINY_KW = dict(
     rope_beta_slow=1.0)
 
 
+# A prefill chunk attends a table of up to GATHERED_KEYS_MAX keys as ONE
+# gathered view (scores ``f32[H, S, keys]``: 0.5 GB at 32 heads, 1,024
+# queries and the 4,096 keys of Mistral-Small-4's deployment) and folds a
+# longer table PREFILL_KEY_BLOCK keys at a time with an online softmax
+# (scores ``f32[H, S, 512]`` whatever the length: the 16,384 keys of
+# Kimi-Linear's deployment would be 2.1 GB gathered).
+GATHERED_KEYS_MAX = 4096
+PREFILL_KEY_BLOCK = 512
+
+
 def _linear(n_in: int, n_out: int, policy: Policy) -> nn.Linear:
     return nn.Linear(n_in, n_out, use_bias=False,
                      kernel_init=init_lib.normal(0.02), policy=policy)
@@ -168,14 +187,35 @@ class GatedMLP(Module):
 
 
 class MLAttention(Module):
-    def __init__(self, cfg: Mistral4Config, policy: Policy):
+    """Multi-head latent attention over a paged latent cache, for any
+    config with this module's keys (``Mistral4Config``,
+    ``kimi_linear.KimiLinearConfig``). Two things are optional:
+
+    - the low-rank query: ``q_lora_rank=None`` projects ``x`` straight to
+      the heads (one ``q`` matrix; no ``q_a``, ``q_a_norm``, ``q_b``);
+    - rotation: with ``mla_use_nope`` the ``qk_rope_head_dim`` dimensions
+      of the query and of the shared key are kept and NOT rotated (and
+      the position-dependent query scaling is off), so nothing here
+      reads a position.
+
+    ``kv_a`` / ``kv_a_norm`` / ``kv_b`` / ``o`` are always there. How a
+    decode step reads the cache is the config's (``decode_impl``: the
+    composed view or the paged kernel's latent form); how a prefill chunk
+    does follows the table's length (``GATHERED_KEYS_MAX``)."""
+
+    def __init__(self, cfg, policy: Policy):
         self.cfg = cfg
         self.policy = policy
         h, heads = cfg.hidden_size, cfg.num_attention_heads
         qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-        self.q_a = _linear(h, cfg.q_lora_rank, policy)
-        self.q_a_norm = nn.RMSNorm(cfg.q_lora_rank, cfg.rms_norm_eps, policy)
-        self.q_b = _linear(cfg.q_lora_rank, heads * qk, policy)
+        self.rotates = not getattr(cfg, "mla_use_nope", False)
+        if cfg.q_lora_rank is None:
+            self.q = _linear(h, heads * qk, policy)
+        else:
+            self.q_a = _linear(h, cfg.q_lora_rank, policy)
+            self.q_a_norm = nn.RMSNorm(cfg.q_lora_rank, cfg.rms_norm_eps,
+                                       policy)
+            self.q_b = _linear(cfg.q_lora_rank, heads * qk, policy)
         self.kv_a = _linear(h, cfg.latent_width, policy)
         self.kv_a_norm = nn.RMSNorm(cfg.kv_lora_rank, cfg.rms_norm_eps, policy)
         self.kv_b = _linear(cfg.kv_lora_rank,
@@ -198,11 +238,22 @@ class MLAttention(Module):
         heads, n, r = (c.num_attention_heads, c.qk_nope_head_dim,
                        c.qk_rope_head_dim)
         st: dict = {}
-        inv_freq = self._inv_freq()
-        c_q = run_child(self.q_a_norm, "q_a_norm", variables, st,
-                        run_child(self.q_a, "q_a", variables, st, x))
-        q = run_child(self.q_b, "q_b", variables, st, c_q)
+        if c.q_lora_rank is None:
+            q = run_child(self.q, "q", variables, st, x)
+        else:
+            c_q = run_child(self.q_a_norm, "q_a_norm", variables, st,
+                            run_child(self.q_a, "q_a", variables, st, x))
+            q = run_child(self.q_b, "q_b", variables, st, c_q)
         q = q.reshape(b, s, heads, n + r)
+        kv = run_child(self.kv_a, "kv_a", variables, st, x)
+        c_kv = run_child(self.kv_a_norm, "kv_a_norm", variables, st,
+                         kv[..., :c.kv_lora_rank])
+        pad = jnp.zeros(kv.shape[:-1] + (c.latent_row_width
+                                         - c.latent_width,), kv.dtype)
+        if not self.rotates:
+            return q[..., :n], q[..., n:], jnp.concatenate(
+                [c_kv, kv[..., c.kv_lora_rank:], pad], axis=-1)
+        inv_freq = self._inv_freq()
         if c.llama_4_scaling_beta:
             # 1 below the original context; grows with the logarithm of
             # how many original contexts deep the position lies.
@@ -214,13 +265,8 @@ class MLAttention(Module):
         q_nope, q_rope = q[..., :n], q[..., n:]
         q_rope = rotary.apply_interleaved(q_rope, positions[..., None],
                                           inv_freq)
-        kv = run_child(self.kv_a, "kv_a", variables, st, x)
-        c_kv = run_child(self.kv_a_norm, "kv_a_norm", variables, st,
-                         kv[..., :c.kv_lora_rank])
         k_r = rotary.apply_interleaved(kv[..., c.kv_lora_rank:], positions,
                                        inv_freq)
-        pad = jnp.zeros(k_r.shape[:-1] + (c.latent_row_width
-                                          - c.latent_width,), k_r.dtype)
         return q_nope, q_rope, jnp.concatenate([c_kv, k_r, pad], axis=-1)
 
     def _w_kvb(self, variables: Variables):
@@ -252,20 +298,146 @@ class MLAttention(Module):
         """One query a row (``q_*`` [B,1,H,*]) against latent rows ``ctx``
         [B,L,w] as they lie; ``attendable`` [B, L]. -> [B, 1, H*v]."""
         c = self.cfg
-        w = self._w_kvb(variables)
-        w_uk, w_uv = w[..., :c.qk_nope_head_dim], w[..., c.qk_nope_head_dim:]
-        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk)
-        # the stored row's zero lanes meet zeros in the query
-        pad = jnp.zeros(q_lat.shape[:-1] + (c.latent_row_width
-                                            - c.latent_width,), q_lat.dtype)
-        q_cat = jnp.concatenate([q_lat, q_rope[:, 0], pad], axis=-1)
+        q_cat = self.absorbed_query(variables, q_nope, q_rope)
         s = jnp.einsum("bhw,blw->bhl", q_cat, ctx,
                        preferred_element_type=jnp.float32)
         s = jnp.where(attendable[:, None], s * c.softmax_scale, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1).astype(ctx.dtype)
         o_lat = jnp.einsum("bhl,blr->bhr", p, ctx[..., :c.kv_lora_rank])
+        return self.expanded_values(variables, o_lat)
+
+    def absorbed_query(self, variables, q_nope, q_rope):
+        """``[q_nope,h W_uk,h^T | q_rope,h | 0..]`` ``[B, H, row]``: each
+        head's query as it meets a cached row (``q_*`` [B,1,H,*])."""
+        c = self.cfg
+        w_uk = self._w_kvb(variables)[..., :c.qk_nope_head_dim]
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk)
+        # the stored row's zero lanes meet zeros in the query
+        pad = jnp.zeros(q_lat.shape[:-1] + (c.latent_row_width
+                                            - c.latent_width,), q_lat.dtype)
+        return jnp.concatenate([q_lat, q_rope[:, 0], pad], axis=-1)
+
+    def expanded_values(self, variables, o_lat):
+        """``o_lat`` [B, H, kv_lora] (``sum_t p_t c_kv,t`` a head) through
+        ``W_uv,h`` -> [B, 1, H*v]."""
+        w_uv = self._w_kvb(variables)[..., self.cfg.qk_nope_head_dim:]
         o = jnp.einsum("bhr,rhv->bhv", o_lat, w_uv)
         return o.reshape(o.shape[0], 1, -1)
+
+    def _gathered(self, pool, tab, dtype):
+        """The rows' blocks as one ``[b, L, w]`` view (unbound entries
+        gather scratch: always masked, at or past the length)."""
+        b, m = tab.shape
+        return pool[tab].reshape(b, m * pool.shape[1],
+                                 pool.shape[-1]).astype(dtype)
+
+    def _decode(self, variables, q_nope, q_rope, latent, cache, pos, active):
+        """One token a row at its own depth: write its latent row
+        (inactive rows write the scratch block, block 0, as for GPT-2),
+        then attend absorbed: through the paged kernel's latent form,
+        which reads the table as it lies, or composed over the gathered
+        view (``decode_impl``). -> ([B, 1, H*v], the pool)."""
+        c = self.cfg
+        pool, tab = cache["latent"], cache["tables"]
+        bs_kv, m = pool.shape[1], tab.shape[1]
+        L = m * bs_kv
+        pos_w = jnp.minimum(pos, L - 1)
+        blk = jnp.take_along_axis(
+            tab, jnp.clip(pos_w // bs_kv, 0, m - 1)[:, None], axis=1)[:, 0]
+        off = pos_w % bs_kv
+        if active is not None:
+            blk = jnp.where(active, blk, 0)
+            off = jnp.where(active, off, 0)
+        pool = pool.at[blk, off, :].set(latent[:, 0, :].astype(pool.dtype))
+        impl = c.decode_impl
+        if impl == "kernel" or (impl == "auto"
+                                and jax.default_backend() == "tpu"):
+            # inactive rows attend nothing
+            lengths = pos + 1 if active is None else jnp.where(
+                active, pos + 1, 0)
+            o_lat = latent_decode_attention(
+                self.absorbed_query(variables, q_nope, q_rope), pool,
+                lengths, tab, c.kv_lora_rank, c.softmax_scale)
+            return self.expanded_values(variables, o_lat[:, :, 0]), pool
+        with jax.named_scope("nezha_mla_decode"):
+            return self.absorbed(
+                variables, q_nope, q_rope,
+                self._gathered(pool, tab, latent.dtype),
+                jnp.arange(L)[None, :] <= pos[:, None]), pool
+
+    def _prefill_gathered(self, variables, q_nope, q_rope, latent, cache,
+                          pos):
+        """A chunk at a traced scalar offset, expanded over the gathered
+        view of the whole table: pads past the prompt land in the row's
+        own bound blocks and are overwritten by decode before any mask
+        reaches them. -> ([1, S, H*v], the pool)."""
+        pool, tab = cache["latent"], cache["tables"]
+        bs_kv, m = pool.shape[1], tab.shape[1]
+        L, s = m * bs_kv, latent.shape[1]
+        ppos = jnp.minimum(pos + jnp.arange(s), L - 1)
+        blk = tab[:, jnp.clip(ppos // bs_kv, 0, m - 1)]             # [b, s]
+        pool = pool.at[blk, (ppos % bs_kv)[None, :], :].set(
+            latent.astype(pool.dtype))
+        attendable = (jnp.arange(L)[None, :]
+                      <= (pos + jnp.arange(s))[:, None])[None]
+        with jax.named_scope("nezha_mla_prefill"):
+            return self.expanded(variables, q_nope, q_rope,
+                                 self._gathered(pool, tab, latent.dtype),
+                                 attendable), pool
+
+    def _prefill_blocked(self, variables, q_nope, q_rope, latent, cache, pos):
+        """A chunk of ``S`` tokens of one row at the traced offset
+        ``pos``, of which the first ``cache["valid"]`` are real: write
+        their rows (pads land on the scratch block), then fold the
+        table's entries ``PREFILL_KEY_BLOCK`` keys at a time, expanded,
+        up to the chunk's last query, with an online softmax: the scores
+        of one fold are ``[H, S, key block]`` float32, whatever the
+        table's length. -> ([1, S, H*v], the pool)."""
+        c = self.cfg
+        pool, tab = cache["latent"], cache["tables"]
+        b, s = latent.shape[:2]
+        bs_kv, m = pool.shape[1], tab.shape[1]
+        local = jnp.arange(s)
+        p_abs = pos + local
+        keep = (local < cache.get("valid", s)) & (p_abs < m * bs_kv)
+        blk = jnp.where(keep[None, :],
+                        tab[:, jnp.clip(p_abs // bs_kv, 0, m - 1)], 0)
+        off = jnp.where(keep, p_abs % bs_kv, 0)[None, :]
+        pool = pool.at[blk, off, :].set(latent.astype(pool.dtype))
+        e = pick_block(m, max(1, PREFILL_KEY_BLOCK // bs_kv))
+        span = e * bs_kv
+        w = self._w_kvb(variables)
+        n, r = c.qk_nope_head_dim, c.kv_lora_rank
+
+        def fold(i, carry):
+            m_run, l_run, acc = carry
+            ent = lax.dynamic_slice_in_dim(tab, i * e, e, axis=1)
+            ctx = pool[ent].reshape(b, span, -1).astype(latent.dtype)
+            kv = jnp.einsum("blr,rhd->blhd", ctx[..., :r], w)
+            sc = (jnp.einsum("bshn,blhn->bhsl", q_nope, kv[..., :n],
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bshr,blr->bhsl", q_rope,
+                               ctx[..., r:c.latent_width],
+                               preferred_element_type=jnp.float32))
+            visible = (i * span + jnp.arange(span))[None, :] \
+                <= p_abs[:, None]
+            sc = jnp.where(visible, sc * c.softmax_scale, NEG_BIG)
+            m_new = jnp.maximum(m_run, sc.max(axis=-1, keepdims=True))
+            p = jnp.where(visible, jnp.exp(sc - m_new), 0.0)
+            corr = jnp.exp(m_run - m_new)
+            acc = acc * corr + jnp.einsum(
+                "bhsl,blhv->bhsv", p.astype(kv.dtype), kv[..., n:],
+                preferred_element_type=jnp.float32)
+            return m_new, l_run * corr + p.sum(axis=-1, keepdims=True), acc
+
+        heads = c.num_attention_heads
+        init = (jnp.full((b, heads, s, 1), NEG_BIG, jnp.float32),
+                jnp.zeros((b, heads, s, 1), jnp.float32),
+                jnp.zeros((b, heads, s, c.v_head_dim), jnp.float32))
+        blocks = jnp.minimum((pos + s + span - 1) // span, m // e)
+        _, l_run, acc = lax.fori_loop(0, blocks, fold, init)
+        out = (acc / jnp.maximum(l_run, 1e-30)).astype(latent.dtype)
+        return out.transpose(0, 2, 1, 3).reshape(b, s, -1), pool
 
     def apply(self, variables: Variables, x, training: bool = False, rng=None,
               cache=None, pos=None, prefill: bool = False, active=None):
@@ -291,45 +463,18 @@ class MLAttention(Module):
                 raise ValueError(
                     "multi-token steps at per-row positions (speculative "
                     "verify) are not implemented for the latent cache")
-            pool, tab = cache["latent"], cache["tables"]
-            bs_kv, m = pool.shape[1], tab.shape[1]
-            L = m * bs_kv
             if per_row:
-                # Decode: one row a request at its own depth; inactive
-                # rows write the scratch block (block 0), as for GPT-2.
-                pos_w = jnp.minimum(pos, L - 1)
-                blk = jnp.take_along_axis(
-                    tab, jnp.clip(pos_w // bs_kv, 0, m - 1)[:, None],
-                    axis=1)[:, 0]
-                off = pos_w % bs_kv
-                if active is not None:
-                    blk = jnp.where(active, blk, 0)
-                    off = jnp.where(active, off, 0)
-                pool = pool.at[blk, off, :].set(
-                    latent[:, 0, :].astype(pool.dtype))
-                attendable = jnp.arange(L)[None, :] <= pos[:, None]
-            else:
-                # Prefill chunk at a traced scalar offset: pads past the
-                # prompt land in the row's own bound blocks and are
-                # overwritten by decode before any mask reaches them.
-                ppos = jnp.minimum(pos + jnp.arange(s), L - 1)
-                blk = tab[:, jnp.clip(ppos // bs_kv, 0, m - 1)]     # [b, s]
-                pool = pool.at[blk, (ppos % bs_kv)[None, :], :].set(
-                    latent.astype(pool.dtype))
-                attendable = (jnp.arange(L)[None, :]
-                              <= (pos + jnp.arange(s))[:, None])[None]
-            # The row's blocks as one [b, L, w] view (unbound entries
-            # gather scratch: always masked, at or past the length).
-            ctx = pool[tab].reshape(b, L, pool.shape[-1]).astype(latent.dtype)
-            if per_row:
-                with jax.named_scope("nezha_mla_decode"):
-                    out = self.absorbed(variables, q_nope, q_rope, ctx,
-                                        attendable)
-            else:
+                out, pool = self._decode(variables, q_nope, q_rope, latent,
+                                         cache, pos, active)
+            elif (cache["tables"].shape[1] * cache["latent"].shape[1]
+                  > GATHERED_KEYS_MAX):
                 with jax.named_scope("nezha_mla_prefill"):
-                    out = self.expanded(variables, q_nope, q_rope, ctx,
-                                        attendable)
-            states["cache"] = {"latent": pool, "tables": tab}
+                    out, pool = self._prefill_blocked(
+                        variables, q_nope, q_rope, latent, cache, pos)
+            else:
+                out, pool = self._prefill_gathered(
+                    variables, q_nope, q_rope, latent, cache, pos)
+            states["cache"] = {"latent": pool, "tables": cache["tables"]}
         return _project_f32(self.o, variables, "o", out), states
 
 

@@ -11,6 +11,10 @@ are Mosaic/Pallas kernels tiled for MXU/VPU and VMEM:
 - `flash_prefill_attention`: chunked prefill attention through the block
   table, with the int8 block write fused into the kernel epilogue (the
   TTFT hot path).
+- `latent_decode_attention`: the paged decode kernel over ONE pool of
+  latent rows (multi-head latent attention, absorbed).
+- `kda_decode`: the one-pass state update of a gated delta-rule linear
+  attention layer (`kda.py`, with its chunked and recurrent forms).
 - `fused_layer_norm`: single-pass normalization on VMEM rows.
 
 The shared online-softmax scratch core lives in `common.py`. All kernels
@@ -20,8 +24,18 @@ run in interpret mode on CPU (tests) and compile on TPU.
 from nezha_tpu.ops.pallas.decode_attention import (
     flash_decode_attention,
     flash_decode_attention_sharded,
+    latent_attention_composed,
+    latent_decode_attention,
     paged_attention_composed,
     ring_entries,
+)
+from nezha_tpu.ops.pallas.kda import (
+    kda_chunked,
+    kda_conv_step,
+    kda_conv_step_reference,
+    kda_decode,
+    kda_decode_reference,
+    kda_recurrent,
 )
 from nezha_tpu.ops.pallas.flash_attention import flash_attention
 from nezha_tpu.ops.pallas.layer_norm import fused_layer_norm
@@ -33,4 +47,8 @@ from nezha_tpu.ops.pallas.prefill_attention import (
 __all__ = ["flash_attention", "flash_decode_attention",
            "flash_decode_attention_sharded", "flash_prefill_attention",
            "flash_prefill_attention_sharded", "fused_layer_norm",
-           "paged_attention_composed", "ring_entries"]
+           "kda_chunked", "kda_conv_step", "kda_conv_step_reference",
+           "kda_decode", "kda_decode_reference",
+           "kda_recurrent", "latent_attention_composed",
+           "latent_decode_attention", "paged_attention_composed",
+           "ring_entries"]

@@ -233,9 +233,9 @@ def _write_heads(o_ref, l_scr, acc_scr, group: int):
         o_ref[0, h] = out[h:h + 1, g * d:(g + 1) * d].astype(o_ref.dtype)
 
 
-def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_hbm, v_hbm, *refs,
+def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_hbm, *refs,
                          scale: float, block_size: int, entries: int,
-                         quant: bool, group: int = 1):
+                         quant: bool, group: int = 1, latent: int = 0):
     """THE LOOP FORM (a full table): one grid step = one row, ALL
     heads, and inside it a loop over the row's own live table entries,
     ``entries`` of them an iteration: ``ceil(length / span)`` iterations
@@ -259,11 +259,27 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_hbm, v_hbm, *refs,
     read. Guarding each copy past the last live entry instead read
     0.81 against 0.69 ms a call: the guards cost the scalar core more
     than the repeated copies cost the DMA engine (PERF.md section 6,
-    PR 31)."""
+    PR 31).
+
+    THE LATENT FORM (``latent`` > 0: the width of a cached row's value
+    part): ONE pool of latent rows (``(bs, W)``, ``[c_kv | k_r | 0..]``)
+    and no V pool. An entry is copied once and serves as keys over all
+    ``W`` lanes (against the absorbed query ``[q~ | q_r | 0..]``) and as
+    values over its first ``latent`` lanes; every query head reads the
+    one row (``group = H`` over one "head" of ``latent`` lanes), so the
+    accumulator and the result are ``latent`` wide."""
+    v_hbm = None
+    if not latent:
+        v_hbm, *refs = refs
     ks_ref = vs_ref = None
     if quant:
         ks_ref, vs_ref, *refs = refs
-    o_ref, k_buf, v_buf, sem, slot_ref, m_scr, l_scr, acc_scr = refs
+    o_ref, k_buf, *refs = refs
+    v_buf = None
+    if not latent:
+        v_buf, *refs = refs
+    sem, slot_ref, m_scr, l_scr, acc_scr = refs
+    pools = ((k_hbm, k_buf),) if latent else ((k_hbm, k_buf), (v_hbm, v_buf))
     c, bs = entries, block_size
     span = c * bs
     b, rows = pl.program_id(0), pl.num_programs(0)
@@ -279,7 +295,7 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_hbm, v_hbm, *refs,
         last = last_entry(row)
         for j in range(c):
             blk = tab_ref[row, jnp.minimum(it * c + j, last)]
-            for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+            for hbm, buf in pools:
                 pltpu.make_async_copy(
                     hbm.at[blk], buf.at[slot, pl.ds(j * bs, bs)],
                     sem.at[slot]).start()
@@ -288,7 +304,7 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_hbm, v_hbm, *refs,
         """Wait for all ``2 c`` copies into ``slot``: a DMA semaphore
         counts bytes, so one descriptor the shape of a pool's whole
         slot waits for that pool's ``c`` copies."""
-        for buf in (k_buf, v_buf):
+        for _, buf in pools:
             pltpu.make_async_copy(buf.at[slot], buf.at[slot],
                                   sem.at[slot]).wait()
 
@@ -321,7 +337,9 @@ def _paged_decode_kernel(tab_ref, len_ref, q_ref, k_hbm, v_hbm, *refs,
 
         wait(slot)
         _fold_entries(
-            q_ref[0], k_buf[slot], v_buf[slot], length, i, m_scr, l_scr,
+            q_ref[0], k_buf[slot],
+            k_buf[slot, :, :latent] if latent else v_buf[slot],
+            length, i, m_scr, l_scr,
             acc_scr, scale=scale, block_size=bs, entries=c,
             last=last_entry(b),
             scales=(ks_ref[0, 0], vs_ref[0, 0]) if quant else None)
@@ -510,6 +528,89 @@ def _paged_call(q, k, v, lengths, block_tables, scale, interpret,
         interpret=interpret,
         name=name if loop else name + "_grid",
     )(tab, lens, *operands)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "value_width", "interpret"))
+def _latent_call(q, pool, lengths, block_tables, scale, value_width,
+                 interpret):
+    """The loop form over ONE leaf (see :func:`_paged_decode_kernel`):
+    ``q`` ``[B, H, W]`` is the absorbed query as it meets a cached row,
+    ``pool`` ``[N, bs, W]`` the latent rows, ``W`` a whole number of
+    128-lane tiles. -> ``[B, H, 1, value_width]``."""
+    b, h, w = q.shape
+    bs, m = pool.shape[1], block_tables.shape[1]
+    c = _pick_block(m, _ENTRIES_PER_STEP)
+    kernel = functools.partial(
+        _paged_decode_kernel, scale=scale, block_size=bs, entries=c,
+        quant=False, group=h, latent=value_width)
+    lens = jnp.clip(jnp.asarray(lengths, jnp.int32), 0, m * bs)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(b,),
+        in_specs=[pl.BlockSpec((1, h, w), lambda b_, *_: (b_, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, h, 1, value_width),
+                               lambda b_, *_: (b_, 0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, c * bs, w), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.VMEM((h, _LANES), jnp.float32),
+                        pltpu.VMEM((h, _LANES), jnp.float32),
+                        pltpu.VMEM((h, value_width), jnp.float32)])
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, value_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="nezha_decode_attention_latent",
+    )(jnp.asarray(block_tables, jnp.int32), lens, q, pool)
+
+
+def latent_attention_composed(q, pool, lengths, block_tables, value_width,
+                              scale: float):
+    """What :func:`latent_decode_attention` computes, composed from
+    ``jax.numpy`` over the gathered view of each row's table (``[B,
+    M*bs, W]``): the path where no kernel runs and the other side of the
+    kernel's interpret-mode tests."""
+    b, h, w = q.shape
+    bs, m = pool.shape[1], block_tables.shape[1]
+    ctx = pool[block_tables].reshape(b, m * bs, w).astype(q.dtype)
+    visible = (jnp.arange(m * bs)[None, :]
+               < jnp.asarray(lengths, jnp.int32)[:, None])[:, None]
+    sc = jnp.einsum("bhw,blw->bhl", q, ctx,
+                    preferred_element_type=jnp.float32) * scale
+    sc = jnp.where(visible, sc, NEG_BIG)
+    p = jnp.where(visible, jnp.exp(sc - sc.max(axis=-1, keepdims=True)), 0.0)
+    p = p / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
+    out = jnp.einsum("bhl,blr->bhr", p.astype(ctx.dtype),
+                     ctx[..., :value_width])
+    return out[:, :, None, :]
+
+
+def latent_decode_attention(q, pool, lengths, block_tables, value_width: int,
+                            scale: float, interpret: Optional[bool] = None):
+    """Single-token decode over a paged LATENT cache (multi-head latent
+    attention, absorbed): ``q`` ``[B, H, W]`` is every head's query
+    already in the cached row's space (``[q_nope W_uk | q_rope | 0..]``),
+    ``pool`` ``[N, bs, W]`` holds one row a token (``[c_kv | k_r |
+    0..]``, ``W`` a multiple of 128), ``block_tables`` ``[B, M]`` and
+    ``lengths`` as for :func:`flash_decode_attention`. Scores run over
+    all ``W`` lanes; the values are the rows' first ``value_width`` lanes.
+    -> ``[B, H, 1, value_width]`` (the caller expands it through
+    ``W_uv``). A row with ``length == 0`` reads nothing and comes back
+    exactly zero. The kernel is the paged decode kernel's loop form with
+    one pool (``nezha_decode_attention_latent``)."""
+    b, h, w = q.shape
+    if (pool.ndim != 3 or pool.shape[2] != w or w % _LANES
+            or not 0 < value_width <= w or block_tables.shape[0] != b):
+        raise ValueError(
+            f"latent pool {pool.shape} / tables {block_tables.shape} do "
+            f"not match q {q.shape}: want [num_blocks, block_size, W] with "
+            f"W a multiple of {_LANES} and value_width <= W")
+    return _latent_call(q, pool, lengths, block_tables, float(scale),
+                        int(value_width), resolve_interpret(interpret))
 
 
 def ring_entries(window: int, block_size: int) -> int:

@@ -531,9 +531,14 @@ class Engine:
         layers = model.cache_leaves(
             cfg.kv_block_size, cfg.cache_dtype, self.kv_quant)
         leaves = sorted({name for _, _, lv in layers for name in lv})
-        windowed = any(w is not None for _, w, _ in layers)
-        self.kv_heads_cache = {"k", "v"} <= set(leaves)
-        if not self.kv_heads_cache or windowed:
+        groups = sorted({g for g, _, _ in layers})
+        self.kv_heads_cache = all({"k", "v"} <= set(lv)
+                                  for g, _, lv in layers if g == "global")
+        # THE predicate: every layer in the growing table of per-head
+        # K/V. A ring cannot take back a rejected token's write and a
+        # state has already folded it in; neither has a head axis the
+        # mesh's sharding could split as it splits K/V.
+        if not (self.kv_heads_cache and groups == ["global"]):
             unsupported = [what for what, on in (
                 ("speculative decoding", cfg.speculative is not None),
                 # only the mesh-sharded engine sets this
@@ -541,9 +546,8 @@ class Engine:
                 if on]
             if unsupported:
                 raise ValueError(
-                    f"{type(model).__name__} caches {leaves}"
-                    f"{' with window layers in a ring' if windowed else ''}"
-                    f", not per-head K/V in one growing table: "
+                    f"{type(model).__name__} caches {leaves} in groups "
+                    f"{groups}, not per-head K/V in one growing table: "
                     f"{', '.join(unsupported)} not supported with it")
         # Whether prefill chunks dispatch through the flash-prefill
         # kernel (the model's own resolution, asked once). It drives
@@ -778,10 +782,12 @@ class Engine:
         rem = n - off
         if width is None:
             width = next(w for w in buckets if w >= rem)
-        if off + width > cfg.max_len and not self.pool.window:
-            # (A model with window layers writes no pad at all: it is
-            # told the chunk's real length, and a slide would ask its
-            # ring for keys it has already overwritten.)
+        if off + width > cfg.max_len and not (self.pool.window
+                                              or self.pool.state_entries):
+            # (A model with window or state layers writes no pad at all:
+            # it is told the chunk's real length, and a slide would ask
+            # its ring for keys it has already overwritten, or fold
+            # tokens into a state that already holds them.)
             # A padded tail would spill past the slot's KV capacity
             # (max_len not a multiple of the stride, prompt near
             # capacity) — and dynamic_update_slice would CLAMP the write
@@ -902,6 +908,13 @@ class Engine:
         self.last_prefill_tokens = sum(w for _, _, w in chunks)
         self.last_prefill_chunks = len(chunks)
         ann.set(cached=start, chunks=len(chunks))
+        if self.pool.state_entries:
+            # chunks a state layer's prefill scan walks (a layer), and
+            # the reset: the chunk at offset 0 starts from zeros
+            ann.set(state_chunks=sum(
+                self.model.prefill_scan_chunks(w) for _, _, w in chunks))
+            if start == 0:
+                obs.counter("serve.state.resets_total").inc()
         qerrs: List[Any] = []
         for off, ln, width in chunks:
             obs.histogram("serve.prefill.bucket_len").observe(width)
@@ -1023,9 +1036,10 @@ class Engine:
         active ``rows`` and the table entries they hold going in, a
         group (``blocks``, ``latent_blocks`` on a latent pool: the
         growing table; ``window_blocks``: the ring, where the model has
-        window layers). ``blocks / (rows * M)`` is the share of the
-        block table the paged decode kernel visits: it skips, without a
-        DMA, every entry past a row's length."""
+        window layers; a state layer updates the state of every one of
+        the ``rows``, so it adds no attr). ``blocks / (rows * M)`` is the
+        share of the block table the paged decode kernel visits: it
+        skips, without a DMA, every entry past a row's length."""
         active = np.asarray(active, bool)
         held = self.host_positions[active] // self.cfg.kv_block_size + 1
         attrs = {"rows": int(np.count_nonzero(active)),
@@ -1230,12 +1244,13 @@ def _with_tables(caches, tables, groups=None, valid=None):
     ``groups``: the group a layer, all ``"global"`` when None). The
     dict-merge keeps every leaf riding into the model (int8 pools carry
     k_scale/v_scale beside k/v: the scales are cache state like any
-    other). A model with window layers is also told how many of a
-    prefill chunk's tokens are real (``valid``): a pad written into a
-    ring would overwrite keys the next queries still see."""
+    other). A model with window or state layers is also told how many
+    of a prefill chunk's tokens are real (``valid``): a pad written into
+    a ring would overwrite keys the next queries still see, and a pad
+    folded into a state could not be taken out again."""
     groups = groups or ("global",) * len(caches)
-    extra = {} if valid is None or "window" not in tables else {
-        "valid": valid}
+    extra = {} if valid is None or not (
+        {"window", "state"} & set(tables)) else {"valid": valid}
     return [{**pool, "tables": tables[g], **extra}
             for pool, g in zip(caches, groups)]
 

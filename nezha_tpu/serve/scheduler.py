@@ -241,6 +241,11 @@ def register_serve_instruments() -> None:
     # The window layers' ring (0 for a model without window layers):
     # every taken slot holds its whole ring.
     obs.gauge("serve.kv.window_blocks_used")
+    # The state group (0s for a model without recurrent-state layers):
+    # every taken slot holds one entry; a prefill from offset 0 resets it.
+    obs.gauge("serve.state.slots_bound")
+    obs.gauge("serve.state.bytes_resident")
+    obs.counter("serve.state.resets_total")
     # Tiered KV host spill (PR 15): trie blocks demoted to host RAM on
     # eviction instead of discarded, and blocks promoted back on a
     # returning prefix hit; occupancy gauges for the host-side LRU.
@@ -533,9 +538,14 @@ class Scheduler:
                 self.engine.pool.blocks_used)
             obs.gauge("serve.kv.window_blocks_used").set(
                 self.engine.pool.window_blocks_used)
+            obs.gauge("serve.state.slots_bound").set(
+                self.engine.pool.state_slots_bound)
+            obs.gauge("serve.state.bytes_resident").set(
+                self.engine.pool.state_bytes_resident)
             obs.gauge("serve.kv.bytes_resident").set(
                 self.engine.pool.bytes_resident
-                + self.engine.pool.window_bytes_resident)
+                + self.engine.pool.window_bytes_resident
+                + self.engine.pool.state_bytes_resident)
             obs.gauge("serve.kv.host_blocks_used").set(
                 self.engine.pool.host_blocks_used)
             obs.gauge("serve.kv.host_bytes_resident").set(

@@ -1,16 +1,29 @@
 """The KV pool for the serving engine: ref-counted pages.
 
 :class:`PagedSlotPool` is the one layout serving has. A model declares,
-layer by layer, what it caches and in which GROUP the layer's blocks
-live: the growing group (a table of ``max_len / block_size`` entries a
-slot, blocks bound as positions advance; everything below describes
-it) and, for layers that attend a sliding window only, a window group
-(a RING of ``ceil(window / block_size) + 1`` entries a slot, bound once
-when the slot is taken and released with it; position ``p`` lives in
-entry ``(p // block_size) % ring``, so a block is overwritten once its
-tokens are out of every later query's window). Both groups keep their
-blocks in one implementation of free list and ref counts
-(:class:`_BlockBooks`). The scheduler sees
+layer by layer, what it caches and in which GROUP the layer's entries
+live. There are three kinds of group:
+
+- the GROWING group (``"global"``): a table of ``max_len / block_size``
+  entries a slot, blocks of tokens bound as positions advance;
+  everything below describes it;
+- a WINDOW group (``"window"``), for layers that attend a sliding window
+  only: a RING of ``ceil(window / block_size) + 1`` entries a slot,
+  bound once when the slot is taken and released with it; position
+  ``p`` lives in entry ``(p // block_size) % ring``, so a block is
+  overwritten once its tokens are out of every later query's window;
+- a STATE group (``"state"``), for layers whose cache is per SEQUENCE
+  and not per token (a linear-attention layer's recurrent state and its
+  convolutions' tails): ONE entry a slot, bound and released with the
+  slot like a ring, never indexed by position, never shared. A ring
+  needs no reset because its mask hides stale rows; a state has no
+  mask, so it is RESET AT ADMISSION: the prefill program's chunk at
+  offset 0 starts from zeros whatever the entry held (no maintenance
+  program runs; ``serve.state.resets_total`` counts them).
+
+All three keep their entries in one implementation of free list and ref
+counts (:class:`_BlockBooks`; entry 0 of each is its scratch entry). The
+scheduler sees
 the slot-level surface (``alloc``/``free``/``num_free``/``occupancy``);
 the engine sees blocks: per-layer K/V buffers shaped
 ``[num_blocks, block_size, H*D]`` (lane-dense rows: one row a position,
@@ -273,8 +286,9 @@ class PrefixTrie:
 class _BlockBooks:
     """One cache group's blocks: a LIFO free list over ``1 .. n-1``
     (block 0 is the group's scratch block: never allocated, never
-    ref-counted) and per-block ref counts. The growing group and the
-    window ring each have one; the lifecycle is this class's."""
+    ref-counted) and per-block ref counts. The growing group, the
+    window ring and the state group each have one; the lifecycle is
+    this class's."""
 
     def __init__(self, num_blocks: int):
         self.num_blocks = num_blocks
@@ -444,12 +458,15 @@ class PagedSlotPool:
     slot ``s``'s positions ``[i*bs, (i+1)*bs)``, or 0/scratch when
     unbound) and, where the model has window layers,
     ``window_tables_host`` (``[capacity, window_entries]``: the slot's
-    ring, every entry bound from :meth:`alloc` to :meth:`free`). Host
+    ring, every entry bound from :meth:`alloc` to :meth:`free`) and,
+    where it has state layers, ``state_tables_host`` (``[capacity, 1]``:
+    the slot's one state entry, bound likewise). Host
     state: a free list and ref counts a group, per-slot bound counts,
     and the prefix trie. ``num_blocks``, ``blocks_used``,
     ``bytes_resident`` and ``available_blocks`` are the growing group's
     (admission runs out of those); the ring's are ``window_blocks_used``
-    and ``window_bytes_resident``.
+    and ``window_bytes_resident``, the state group's
+    ``state_slots_bound`` and ``state_bytes_resident``.
 
     With ``quantized=True`` (``ServeConfig.kv_dtype="int8"``) the K/V
     pools store int8 and each layer carries ``k_scale``/``v_scale``
@@ -556,30 +573,43 @@ class PagedSlotPool:
                 "blocks a trie hit would have to re-bind (a hit would "
                 "need the window layers' last tokens as a snapshot)")
         self.layer_groups: Tuple[str, ...] = tuple(g for g, _, _ in layers)
+        unknown = set(self.layer_groups) - {"global", "window", "state"}
+        if unknown:
+            raise ValueError(f"unknown cache groups {sorted(unknown)}")
+        # A state layer's leaves are per slot: one entry each.
+        self.state_entries = int("state" in self.layer_groups)
+        if self.state_entries and prefix_cache:
+            raise ValueError(
+                "prefix_cache with state layers: a trie hit at a block "
+                "boundary would need the recurrent state AT that boundary "
+                "as a snapshot, and the pool keeps only the latest")
         ring_blocks = 1 + capacity * self.window_entries
+        state_blocks = 1 + capacity * self.state_entries
+        entries = {"global": num_blocks, "window": ring_blocks,
+                   "state": state_blocks}
         self.caches = [
-            {name: jnp.zeros(
-                (num_blocks if w is None else ring_blocks,) + tuple(shape),
-                dt) for name, (shape, dt) in leaves.items()}
-            for _, w, leaves in layers]
+            {name: jnp.zeros((entries[g],) + tuple(shape), dt)
+             for name, (shape, dt) in leaves.items()}
+            for g, _, leaves in layers]
 
-        def block_bytes(ring: bool) -> int:
+        def block_bytes(group: str) -> int:
             return sum(math.prod(shape) * jnp.dtype(dt).itemsize
-                       for _, w, leaves in layers if (w is not None) == ring
+                       for g, _, leaves in layers if g == group
                        for shape, dt in leaves.values())
 
         # Per-block device footprint (every leaf, all the group's
         # layers): the serve.kv.bytes_resident gauge's unit and the
         # equal-memory bench's conversion rate between int8 and bf16
         # block budgets.
-        self.bytes_per_block = block_bytes(False)
-        self.window_bytes_per_block = block_bytes(True)
+        self.bytes_per_block = block_bytes("global")
+        self.window_bytes_per_block = block_bytes("window")
+        self.state_bytes_per_entry = block_bytes("state")
         # Migration, peer pulls and the host tier speak one wire format:
         # int8 K/V blocks as per-head tiles ``[n, H, bs, D]`` +
         # per-(block, head) scales ``[n, H]``. A pool of other leaves,
         # or one with a ring (whose blocks no table row spells in
-        # order), has none.
-        self.kv_wire = not self.window and all(
+        # order) or a state (which is no block of tokens), has none.
+        self.kv_wire = set(self.layer_groups) == {"global"} and all(
             {"k", "v"} <= set(leaves) for _, _, leaves in layers)
         self.wire_block_shape: Optional[Tuple[int, int, int]] = None
         if self.kv_wire:
@@ -601,6 +631,10 @@ class PagedSlotPool:
         self._ring_books = _BlockBooks(ring_blocks)
         self.window_tables_host = np.zeros(
             (capacity, self.window_entries), np.int32)
+        # The state group: a taken slot's one entry.
+        self._state_books = _BlockBooks(state_blocks)
+        self.state_tables_host = np.zeros(
+            (capacity, self.state_entries), np.int32)
         self.trie = PrefixTrie(block_size)
         self.cow_copies = 0
         self.prefix_hits = 0
@@ -643,24 +677,26 @@ class PagedSlotPool:
         :meth:`prepare_write`) — a fresh slot holds none."""
         slot = self._free_slots.pop() if self._free_slots else None
         if slot is not None:
-            self._bind_ring(slot)
+            self._bind_slot_entries(slot)
             if self.mirror is not None:
                 self.mirror.claim(slot)
         return slot
 
-    def _bind_ring(self, slot: int) -> None:
-        """A taken slot holds its whole ring until :meth:`free` (the
-        ring group reserves ``window_entries`` blocks a slot, so this
+    def _bind_slot_entries(self, slot: int) -> None:
+        """A taken slot holds its whole ring and its state entry until
+        :meth:`free` (each group reserves a slot's entries, so this
         never runs dry)."""
         for i in range(self.window_entries):
             self.window_tables_host[slot, i] = self._ring_books.take()
+        for i in range(self.state_entries):
+            self.state_tables_host[slot, i] = self._state_books.take()
 
     def claim(self, slot: int) -> None:
         """Take a SPECIFIC free slot (the mirror path: the leader pool
         chose the index). Raises when the slot is not free: lifecycle
         drift between the pools must surface, not corrupt."""
         self._free_slots.remove(slot)
-        self._bind_ring(slot)
+        self._bind_slot_entries(slot)
 
     def free(self, slot: int) -> None:
         """Release the slot and DROP ITS BLOCK REFERENCES in the same
@@ -674,9 +710,11 @@ class PagedSlotPool:
         if slot in self._free_slots:
             raise ValueError(f"slot {slot} is already free (double free)")
         self.release_blocks(slot)
-        for b in self.window_tables_host[slot]:
-            self._ring_books.release(int(b))
-        self.window_tables_host[slot, :] = 0
+        for books, table in ((self._ring_books, self.window_tables_host),
+                             (self._state_books, self.state_tables_host)):
+            for b in table[slot]:
+                books.release(int(b))
+            table[slot, :] = 0
         self._free_slots.append(slot)
         if self.mirror is not None:
             self.mirror.free(slot)
@@ -732,12 +770,25 @@ class PagedSlotPool:
         """Device bytes of the bound ring blocks, all window layers."""
         return self.window_blocks_used * self.window_bytes_per_block
 
+    @property
+    def state_slots_bound(self) -> int:
+        """State entries the taken slots hold (one each): the
+        ``serve.state.slots_bound`` gauge value."""
+        return self._state_books.used
+
+    @property
+    def state_bytes_resident(self) -> int:
+        """Device bytes of the bound state entries, all state layers."""
+        return self.state_slots_bound * self.state_bytes_per_entry
+
     def device_tables(self) -> Dict[str, jax.Array]:
         """The host tables as a dispatch uploads them: one a group,
         keyed as ``layer_groups`` names them."""
         tables = {"global": jnp.asarray(self.tables_host)}
         if self.window:
             tables["window"] = jnp.asarray(self.window_tables_host)
+        if self.state_entries:
+            tables["state"] = jnp.asarray(self.state_tables_host)
         return tables
 
     @property
@@ -1377,23 +1428,27 @@ class PagedSlotPool:
             expect[b] += 1
         expect[0] = 0
         self._books.check(expect, "KV block")
-        # The window group: a taken slot holds exactly its ring, a free
-        # slot's row is scratch, and nothing else references a ring block.
-        ring = np.zeros((self._ring_books.num_blocks,), np.int64)
-        for slot in range(self.capacity):
-            row = self.window_tables_host[slot]
-            if slot in self._free_slots:
-                if row.any():
+        # The window and state groups: a taken slot holds exactly its
+        # ring and its state entry, a free slot's rows are scratch, and
+        # nothing else references an entry of either.
+        for books, table, what in (
+                (self._ring_books, self.window_tables_host,
+                 "window ring block"),
+                (self._state_books, self.state_tables_host, "state entry")):
+            held = np.zeros((books.num_blocks,), np.int64)
+            for slot in range(self.capacity):
+                row = table[slot]
+                if slot in self._free_slots:
+                    if row.any():
+                        raise AssertionError(
+                            f"free slot {slot} still names {what}s "
+                            f"{row.tolist()}")
+                elif row.size and not row.all():
                     raise AssertionError(
-                        f"free slot {slot} still names ring blocks "
-                        f"{row.tolist()}")
-            elif self.window_entries and not row.all():
-                raise AssertionError(
-                    f"slot {slot}'s ring has unbound entries: "
-                    f"{row.tolist()}")
-            np.add.at(ring, row, 1)
-        ring[0] = 0
-        self._ring_books.check(ring, "window ring block")
+                        f"slot {slot} has unbound {what}s: {row.tolist()}")
+                np.add.at(held, row, 1)
+            held[0] = 0
+            books.check(held, what)
         # Fleet peer tags (PR 17) may only name blocks somebody still
         # holds: a tag on a freed block would mis-count an unrelated
         # future binding as a peer hit.

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from chipbench.reference import mistral4 as ref
+from nezha_tpu.models import mistral4
 from nezha_tpu.models.gpt2 import GPT2, GPT2Config
 from nezha_tpu.models.mistral4 import (TINY_KW, Mistral4, Mistral4Config,
                                        mistral_small4)
@@ -73,11 +74,23 @@ def _reference_rows(model, variables, seq, first, count):
         jnp.asarray(pos), ref_cfg(model.cfg)))[0]
 
 
-# (a) program vs reference through the real Engine and paged latent pool
+# (a) program vs reference through the real Engine and paged latent pool:
+# as this model is served (the composed decode view, the gathered prefill
+# view of a table of up to 4,096 keys), and on the two paths Kimi-Linear's
+# deployment takes through the same class (the paged kernel's latent form
+# in interpret mode; a table folded a key block at a time)
+@pytest.mark.parametrize("paths", ["composed-gathered", "kernel-folded"])
 @pytest.mark.parametrize("n_prompt", [13, 21, 37])
-def test_engine_prefill_and_decode_match_reference(tiny, n_prompt):
+def test_engine_prefill_and_decode_match_reference(tiny, n_prompt, paths,
+                                                   monkeypatch):
     model, variables = tiny
-    eng = _engine(model, variables)
+    kw = {}
+    if paths == "kernel-folded":
+        monkeypatch.setattr(mistral4, "GATHERED_KEYS_MAX", 0)
+        monkeypatch.setattr(mistral4, "PREFILL_KEY_BLOCK", 16)
+        kw["decode_impl"] = "kernel"
+    eng = _engine(model, variables, **kw)
+    assert eng.model.cfg.decode_impl == kw.get("decode_impl", "xla")
     rng = np.random.default_rng(n_prompt)
     prompt = rng.integers(0, model.cfg.vocab_held, n_prompt).tolist()
     slot = eng.pool.alloc()

@@ -297,11 +297,15 @@ def _serve_programs(model, quantized, v5e, *, slots, table, block, chunk,
         jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     layers = model.cache_leaves(block, BF16, quantized)
     ring = {w: ring_entries(w, block) for _, w, _ in layers if w}
-    caches = [{name: spec((1 + slots * ring.get(w, table),) + tuple(shape),
-                          dt) for name, (shape, dt) in leaves.items()}
-              for _, w, leaves in layers]
+
+    def entries(group, w):      # table entries a slot, by kind of group
+        return 1 if group == "state" else ring.get(w, table)
+
+    caches = [{name: spec((1 + slots * entries(g, w),) + tuple(shape), dt)
+               for name, (shape, dt) in leaves.items()}
+              for g, w, leaves in layers]
     groups = tuple(g for g, _, _ in layers)
-    tables = {g: spec((slots, ring.get(w, table)), jnp.int32)
+    tables = {g: spec((slots, entries(g, w)), jnp.int32)
               for g, w, _ in layers}
     b = slots
     state = (spec((b, logits), jnp.float32),
