@@ -67,6 +67,13 @@ _SERVE_COUNTERS = {"serve.admitted_total", "serve.rejected_total",
                    # a dense model reports 0s, never omits them.
                    "serve.moe.pairs_total",
                    "serve.moe.held_pairs_total",
+                   # The experts' kernel (PR 33): the (row tile, expert)
+                   # visits its tiling made in the decode steps and the
+                   # held experts those steps touched; visits / touched
+                   # is how many times a touched expert's weights were
+                   # read a layer call (1.0: once). Model-invariant 0s.
+                   "serve.moe.expert_visits_total",
+                   "serve.moe.experts_touched_total",
                    # Sampling (PR 29): decode steps (speculative:
                    # windows) in which some row's nucleus was wider
                    # than the k_max head, so the vocabulary was sorted.

@@ -462,6 +462,13 @@ class KimiLinear(Module):
         return jnp.stack([states[f"h{i}"]["moe"]["load"]
                           for i in self.cfg.sparse_layers])
 
+    def expert_visits(self, states: dict):
+        """[sparse layers, 2] int32: the (row tile, expert) visits the
+        experts' kernel made in this forward pass and the held experts
+        it touched (``ops/pallas/moe_experts.py::visit_plan``)."""
+        return jnp.stack([states[f"h{i}"]["moe"]["visits"]
+                          for i in self.cfg.sparse_layers])
+
     def paged_prefill_uses_kernel(self) -> bool:
         return False
 

@@ -570,6 +570,13 @@ class Mistral4(Module):
         return jnp.stack([states[f"h{i}"]["moe"]["load"]
                           for i in range(self.cfg.num_hidden_layers)])
 
+    def expert_visits(self, states: dict):
+        """[sparse layers, 2] int32: the (row tile, expert) visits the
+        experts' kernel made in this forward pass and the held experts
+        it touched (``ops/pallas/moe_experts.py::visit_plan``)."""
+        return jnp.stack([states[f"h{i}"]["moe"]["visits"]
+                          for i in range(self.cfg.num_hidden_layers)])
+
     def paged_prefill_uses_kernel(self) -> bool:
         return False
 

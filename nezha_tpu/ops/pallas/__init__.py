@@ -15,6 +15,12 @@ are Mosaic/Pallas kernels tiled for MXU/VPU and VMEM:
   latent rows (multi-head latent attention, absorbed).
 - `kda_decode`: the one-pass state update of a gated delta-rule linear
   attention layer (`kda.py`, with its chunked and recurrent forms).
+- `moe_experts`: the routed experts of a dropless layer as one grouped
+  matmul (`nezha_moe_experts`: gate, up, SiLU and down over the pair rows
+  sorted by held expert; a touched expert's weights cross HBM once a call,
+  in `d_ff` tiles; row tile, `d_ff` tile and window from the static shapes
+  by `tile_sizes`; float32 accumulation, `h` rounded to the compute dtype
+  before the down projection).
 - `fused_layer_norm`: single-pass normalization on VMEM rows.
 
 The shared online-softmax scratch core lives in `common.py`. All kernels
@@ -39,6 +45,10 @@ from nezha_tpu.ops.pallas.kda import (
 )
 from nezha_tpu.ops.pallas.flash_attention import flash_attention
 from nezha_tpu.ops.pallas.layer_norm import fused_layer_norm
+from nezha_tpu.ops.pallas.moe_experts import (
+    moe_experts,
+    moe_experts_reference,
+)
 from nezha_tpu.ops.pallas.prefill_attention import (
     flash_prefill_attention,
     flash_prefill_attention_sharded,
@@ -50,5 +60,6 @@ __all__ = ["flash_attention", "flash_decode_attention",
            "kda_chunked", "kda_conv_step", "kda_conv_step_reference",
            "kda_decode", "kda_decode_reference",
            "kda_recurrent", "latent_attention_composed",
-           "latent_decode_attention", "paged_attention_composed",
+           "latent_decode_attention", "moe_experts",
+           "moe_experts_reference", "paged_attention_composed",
            "ring_entries"]

@@ -35,6 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from nezha_tpu import nn
 from nezha_tpu.nn import initializers as init_lib
 from nezha_tpu.nn.module import Module, Variables, make_variables
+from nezha_tpu.ops.pallas.moe_experts import moe_experts
 from nezha_tpu.tensor.policy import DEFAULT_POLICY, Policy
 
 
@@ -204,12 +205,22 @@ class DroplessMoE(Module):
     out what the absent ones would add (on an expert-parallel host their
     chips compute it; alone, that partial sum is the result). Token-
     expert pairs are sorted by expert, pairs of absent experts last, and
-    three grouped matmuls (``jax.lax.ragged_dot``: one weight matrix per
-    group of rows) run over the held groups only; shapes are static (T x
-    top_k pair rows), so one program serves a prefill chunk (hundreds of
-    rows an expert) and a decode step (a few). ``apply`` returns
-    ``(y, {"load": [count] int32})``: the pairs computed per held expert,
-    rows with ``active`` False not counted."""
+    ONE kernel call runs gate, up, the activation and down over the held
+    groups only (``ops/pallas/moe_experts.py``, ``nezha_moe_experts``: a
+    grouped matmul whose row tile, ``d_ff`` tile and window follow the
+    static shapes by its ``tile_sizes``; an expert with no row costs no
+    weight read and a touched one's weights are read once a call unless a
+    row tile's edge cuts its group). Its rounding points are those of the
+    three ``jax.lax.ragged_dot`` calls it replaced: ``gate`` and ``up``
+    accumulate in float32, ``silu(gate) * up`` is rounded to the compute
+    dtype, the down projection accumulates in float32. Shapes are static
+    (T x top_k pair rows), so one program serves a prefill chunk (tens of
+    rows an expert) and a decode step (a few); no shape keeps
+    ``ragged_dot`` (measured: PERF.md section 6, PR 33). ``apply`` returns
+    ``(y, {"load": [count] int32, "visits": [2] int32})``: the pairs
+    computed per held expert, rows with ``active`` False not counted, and
+    the kernel's (row tile, expert) visits beside the held experts it
+    touched."""
 
     def __init__(self, cfg: DroplessMoEConfig, policy: Policy = DEFAULT_POLICY):
         self.cfg = cfg
@@ -270,11 +281,9 @@ class DroplessMoE(Module):
                 jnp.where(jnp.repeat(active, k), key, held),
                 length=held + 1)[:held].astype(jnp.int32)
             xs = x[token_of]                                     # [T*k, d]
-            f32 = dict(preferred_element_type=jnp.float32)
-            gate = jax.lax.ragged_dot(xs, p["w_gate"].astype(cdt), sizes, **f32)
-            up = jax.lax.ragged_dot(xs, p["w_up"].astype(cdt), sizes, **f32)
-            h = (jax.nn.silu(gate) * up).astype(cdt)
-            out = jax.lax.ragged_dot(h, p["w_down"].astype(cdt), sizes, **f32)
+            out, visits = moe_experts(
+                xs, sizes, p["w_gate"].astype(cdt), p["w_up"].astype(cdt),
+                p["w_down"].astype(cdt))
             # Back to token order by the inverse permutation (a gather,
             # not a scatter-add); rows of absent experts weigh nothing.
             w_sorted = jnp.where(key[order] < held,
@@ -283,7 +292,7 @@ class DroplessMoE(Module):
             inverse = jnp.zeros((t * k,), jnp.int32).at[order].set(
                 jnp.arange(t * k, dtype=jnp.int32))
             y = out[inverse].reshape(t, k, cfg.d_model).sum(axis=1)
-        return y, {"load": load}        # y is float32
+        return y, {"load": load, "visits": visits}      # y is float32
 
 
 def moe_ep_rules(ep_axis: str = "ep"):

@@ -1099,9 +1099,10 @@ class Engine:
             full_sorts_h = int(np.asarray(full_sorts))
             if load:
                 self.last_expert_load = np.asarray(load[0])
+                visits_h = np.asarray(load[1])
         obs.counter("serve.sampling.full_sort_steps_total").inc(full_sorts_h)
         if load:
-            self._record_expert_load(int(np.count_nonzero(active)))
+            self._record_expert_load(int(np.count_nonzero(active)), visits_h)
         # Advance the host position/budget mirrors by the block's
         # emitted counts (positions advance and budgets decay on
         # device exactly once per emitted token; a NaN-frozen row
@@ -1111,16 +1112,24 @@ class Engine:
         self.host_budgets -= emitted_h.astype(np.int64)
         return tok_h, emitted_h
 
-    def _record_expert_load(self, rows: int) -> None:
+    def _record_expert_load(self, rows: int, visits: np.ndarray) -> None:
         """The expert layer's counters for one step (registry
         instruments: no-ops without a run dir). ``pairs`` is what the
         router chose over all experts (rows x top-k a layer); ``held``
-        what this chip's experts computed of it."""
+        what this chip's experts computed of it; ``visits`` ``[layers,
+        2]`` the (row tile, expert) visits of the experts' kernel beside
+        the held experts it touched: their ratio is how many times a
+        touched expert's weights were read a layer call, 1.0 where no
+        group is cut by a row tile's edge."""
         load = self.last_expert_load
         layers, held = load.shape
         top_k = self.model.cfg.num_experts_per_tok
         obs.counter("serve.moe.pairs_total").inc(rows * top_k * layers)
         obs.counter("serve.moe.held_pairs_total").inc(int(load.sum()))
+        obs.counter("serve.moe.expert_visits_total").inc(
+            int(visits[:, 0].sum()))
+        obs.counter("serve.moe.experts_touched_total").inc(
+            int(visits[:, 1].sum()))
         mean = load.mean(axis=1)
         if (mean > 0).all():
             obs.gauge("serve.moe.load_max_over_mean").set(
@@ -1381,6 +1390,8 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int, groups=None):
         # (a model with no serving-side expert layer: nothing is added
         # to its program).
         load = model.expert_load(states)
+        if load is not None:    # and the experts' kernel's (visits, touched)
+            load = (load, model.expert_visits(states))
         new_caches = _pool_leaves(new_rows, caches)
         row_logits = logits[:, -1, :]
         ok = jnp.where(emit, ok & finite_rows(row_logits), ok)
@@ -1416,14 +1427,15 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int, groups=None):
             carry, (toks, full_sort, loads) = lax.scan(
                 scan_body, init, None, length=horizon)
             tok_block = jnp.transpose(toks, (1, 0))        # [H,B]->[B,H]
-            load = None if loads is None else loads.sum(axis=0)
+            load = None if loads is None else tuple(
+                x.sum(axis=0) for x in loads)
         caches, last_logits, positions, keys, done, ok, emitted = carry
         # How many of the block's steps sorted the vocabulary (sampling's
         # wide-nucleus branch): 0 or 1 at horizon 1.
         full_sorts = jnp.sum(full_sort, dtype=jnp.int32)
         out = (tok_block, emitted, ok, full_sorts, caches, last_logits,
                positions, keys, jnp.maximum(budgets - emitted, 0))
-        return out if load is None else out + (load,)
+        return out if load is None else out + load
 
     return step
 

@@ -226,6 +226,10 @@ def register_serve_instruments() -> None:
     # only; the engine records them a step). Model-invariant 0s.
     obs.counter("serve.moe.pairs_total")
     obs.counter("serve.moe.held_pairs_total")
+    # The experts' kernel (ops/pallas/moe_experts.py): (row tile, expert)
+    # visits of the decode steps and the held experts they touched.
+    obs.counter("serve.moe.expert_visits_total")
+    obs.counter("serve.moe.experts_touched_total")
     obs.gauge("serve.moe.load_max_over_mean")
     # Decode steps whose sampling sorted the whole vocabulary (a row's
     # nucleus wider than the k_max head; serve/sampling.py). 0 for

@@ -377,11 +377,11 @@ def test_gpt2_serve_program_has_no_pool_shaped_copy(gpt2_programs, kind,
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
-# ---- Mistral-Small-4's serve programs (PR 26): no Pallas kernel of their
-# own yet (composed latent attention, the compiler's grouped matmul for
-# the experts), so what is compiled is the ENGINE's step and 1,024-token
-# prefill programs at the published widths, one layer deep, with the
-# serving cell's slots, table and pool.
+# ---- Mistral-Small-4's serve programs (PR 26): composed latent attention
+# and, since PR 33, the experts' grouped matmuls as ONE Pallas call a layer
+# (``nezha_moe_experts``, which Mosaic compiles here at the published
+# widths), so what is compiled is the ENGINE's step and 1,024-token prefill
+# programs, one layer deep, with the serving cell's slots, table and pool.
 M4_SLOTS, M4_MAX_LEN, M4_BLOCK, M4_CHUNK = 128, 4096, 64, 1024
 
 
@@ -391,17 +391,35 @@ def mistral4_programs(v5e):
     from nezha_tpu.models.mistral4 import mistral_small4
 
     model = mistral_small4("full", num_hidden_layers=1)
-    programs = _serve_programs(
-        model, False, v5e, slots=M4_SLOTS, table=M4_MAX_LEN // M4_BLOCK,
-        block=M4_BLOCK, chunk=M4_CHUNK, logits=model.cfg.vocab_held)
+    with pytest.MonkeyPatch.context() as mp:
+        # a kernel is compiled on a TPU backend only (see gpt2_programs)
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        programs = _serve_programs(
+            model, False, v5e, slots=M4_SLOTS, table=M4_MAX_LEN // M4_BLOCK,
+            block=M4_BLOCK, chunk=M4_CHUNK, logits=model.cfg.vocab_held)
     return {name: compiled.as_text() for name, compiled in programs.items()}
+
+
+def moe_expert_calls(text):
+    """The ``nezha_moe_experts*`` custom calls of an HLO module (what the
+    benchmark's ``kernel.moe_*`` patterns count), after asserting that the
+    compiler's own grouped matmul is in it nowhere."""
+    assert not re.findall(r"%ragged-dot\S* = ", text)
+    return [line.strip() for line in text.splitlines()
+            if "tpu_custom_call" in line and re.match(
+                r"(ROOT )?%?nezha_moe_experts\S* = ", line.strip())]
 
 
 @pytest.mark.parametrize("program", ["step", "prefill"])
 def test_mistral4_serve_programs_compile_for_v5e(mistral4_programs, program):
     text = mistral4_programs[program]
-    # the experts run through the compiler's own grouped matmul
-    assert len(re.findall(r"%ragged-dot\S* = ", text)) >= 3
+    # the routed experts of the one layer are ONE kernel call and no
+    # ``ragged-dot``, in the step (512 pair rows) and in the 1,024-token
+    # chunk (4,096: no shape keeps the compiler's grouped matmul)
+    rows = M4_SLOTS * 4 if program == "step" else M4_CHUNK * 4
+    (call,) = moe_expert_calls(text)
+    assert re.match(rf"(ROOT )?%?nezha_moe_experts\S* = \(?f32\[{rows},4096\]",
+                    call), call
     pool = re.escape(f"bf16[{1 + M4_SLOTS * (M4_MAX_LEN // M4_BLOCK)},"
                      f"{M4_BLOCK},384]")
     assert re.search(pool, text)            # the latent pool, 384-lane rows

@@ -21,6 +21,7 @@ import pytest
 
 from test_tpu_compile import (  # noqa: F401  (fixtures, by name)
     _serve_programs,
+    moe_expert_calls,
     _sorts_outside_conditional_branches,
     v5e,
     v5e_devices,
@@ -49,16 +50,21 @@ def k_exaone_programs(v5e):
 def test_k_exaone_serve_programs_fit_and_copy_no_pool(k_exaone_programs,
                                                       program):
     """Both pools are in the program as lane-dense rows of 8 x 128 lanes,
-    no ``copy`` has either pool's shape, the sparse layers' experts run
-    through the compiler's grouped matmul, and arguments + temporaries
-    stay under 90% of the chip's 16 GB."""
+    no ``copy`` has either pool's shape, each of the four sparse layers'
+    experts is ONE ``nezha_moe_experts`` call and no ``ragged-dot`` (the
+    step's 1,024 pair rows and the chunk's 8,192 alike: no shape keeps the
+    compiler's grouped matmul), and arguments + temporaries stay under 90%
+    of the chip's 16 GB."""
     compiled = k_exaone_programs[program]
     text = compiled.as_text()
     for n in KX_POOLS.values():
         pool = re.escape(f"bf16[{n},{KX_BLOCK},1024]")
         assert re.search(pool, text)
         assert not re.findall(r" = " + pool + r"\S* copy\(", text)
-    assert len(re.findall(r"%ragged-dot\S* = ", text)) >= 4 * 3
+    rows = KX_SLOTS * 8 if program == "step" else KX_CHUNK * 8
+    calls = moe_expert_calls(text)
+    assert len(calls) == 4 and all(
+        re.search(rf" = \(?f32\[{rows},6144\]", c) for c in calls), calls
     ma = compiled.memory_analysis()
     live = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
             + ma.output_size_in_bytes - ma.alias_size_in_bytes)
@@ -72,8 +78,10 @@ def test_k_exaone_step_decodes_through_one_kernel_body_twice_named(
     ring call on each of the four window layers, all with 64 query heads
     (``bf16[128,64,1,128]``: the shape the cell's
     ``kernel.gqa_decode_roofline`` and ``kernel.window_decode_time_share``
-    patterns anchor on), and the
-    step's fetch carries the four sparse layers' expert-load counter."""
+    patterns anchor on), the only other kernels of this repo's in the step
+    the four sparse layers' ``nezha_moe_experts``, and the
+    step's fetch carries the four sparse layers' expert-load counter and
+    the experts' kernel's (visits, touched) beside it."""
     text = k_exaone_programs["step"].as_text()
     calls = [line.strip() for line in text.splitlines()
              if "tpu_custom_call" in line and " = " in line
@@ -83,12 +91,14 @@ def test_k_exaone_step_decodes_through_one_kernel_body_twice_named(
         r"(ROOT )?%?nezha_decode_attention_paged\S* = " + shape, c)]
     window = [c for c in calls if re.match(
         r"(ROOT )?%?nezha_decode_attention_window\S* = " + shape, c)]
-    assert (len(paged), len(window), len(calls)) == (1, 4, 5), calls
+    assert (len(paged), len(window), len(calls)) == (1, 4, 5 + 4), calls
+    assert len(moe_expert_calls(text)) == 4
     # 1,024-lane pools: the full table takes the per-row loop, a ring
     # (every step of it live) keeps the grid form and says so in its name
     assert "_grid" not in paged[0].split(" = ")[0], paged
     assert all("_grid" in c.split(" = ")[0] for c in window), window
     assert re.search(r"s32\[4,16\]", text.split("ENTRY", 1)[1])
+    assert re.search(r"s32\[4,2\]", text.split("ENTRY", 1)[1])
 
 
 def test_k_exaone_step_program_sorts_the_vocabulary_only_under_a_conditional(

@@ -18,6 +18,7 @@ import pytest
 
 from test_tpu_compile import (  # noqa: F401  (fixtures, by name)
     _serve_programs,
+    moe_expert_calls,
     _sorts_outside_conditional_branches,
     v5e,
     v5e_devices,
@@ -50,14 +51,19 @@ def test_kimi_serve_programs_fit_and_copy_no_pool_or_state(kimi_programs,
                                                            program):
     """Every leaf of both groups is in the program as declared, no ``copy``
     has a leaf's shape (the state is rewritten in place: 0.54 GB a layer),
-    the sparse layers' experts run through the compiler's grouped matmul,
-    and arguments + temporaries stay under 90% of the chip's 16 GB."""
+    each of the four sparse layers' experts is ONE ``nezha_moe_experts``
+    call and no ``ragged-dot`` (the step's 2,048 pair rows and the chunk's
+    8,192 alike: no shape keeps the compiler's grouped matmul), and
+    arguments + temporaries stay under 90% of the chip's 16 GB."""
     compiled = kimi_programs[program]
     text = compiled.as_text()
     for leaf in KL_LEAVES.values():
         assert re.search(re.escape(leaf), text), leaf
         assert not re.findall(r" = " + re.escape(leaf) + r"\S* copy\(", text)
-    assert len(re.findall(r"%ragged-dot\S* = ", text)) >= 4 * 3
+    rows = KL_SLOTS * 8 if program == "step" else KL_CHUNK * 8
+    calls = moe_expert_calls(text)
+    assert len(calls) == 4 and all(
+        re.search(rf" = \(?f32\[{rows},2304\]", c) for c in calls), calls
     ma = compiled.memory_analysis()
     live = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
             + ma.output_size_in_bytes - ma.alias_size_in_bytes)
@@ -72,8 +78,10 @@ def test_kimi_step_updates_the_state_in_place_and_decodes_in_the_paged_kernel(
     with its pool aliased to its second result, and ONE ``nezha_decode_attention_latent`` on the MLA
     layer with a ``bf16[256,32,1,512]`` result (the shape
     ``kernel.decode_time_share``'s accepted pattern takes; the state
-    update's tuple result it does not); the step's fetch carries the four
-    sparse layers' expert-load counter."""
+    update's tuple result it does not); the four sparse layers'
+    ``nezha_moe_experts`` calls are the step's only other kernels of this
+    repo's; the step's fetch carries the four sparse layers' expert-load
+    counter and the experts' kernel's (visits, touched) beside it."""
     text = kimi_programs["step"].as_text()
     calls = [line.strip() for line in text.splitlines()
              if "tpu_custom_call" in line and " = " in line
@@ -87,7 +95,9 @@ def test_kimi_step_updates_the_state_in_place_and_decodes_in_the_paged_kernel(
     latent = [c for c in calls if re.match(
         r"(ROOT )?%?nezha_decode_attention_latent\S* = "
         + re.escape("bf16[256,32,1,512]"), c)]
-    assert (len(kda), len(conv), len(latent), len(calls)) == (4, 4, 1, 9), calls
+    assert (len(kda), len(conv), len(latent), len(calls)) == (
+        4, 4, 1, 9 + 4), calls
+    assert len(moe_expert_calls(text)) == 4
     assert all("output_to_operand_aliasing={{1}: (3, {})}" in c
                for c in kda + conv)
     accepted = re.compile(r"^%?\S+ = bf16\[\d+,\d+,1,\d+\]\S* custom-call\("
@@ -96,16 +106,19 @@ def test_kimi_step_updates_the_state_in_place_and_decodes_in_the_paged_kernel(
     assert not any(accepted.search(c.removeprefix("ROOT "))
                    for c in kda + conv)
     assert re.search(r"s32\[4,64\]", text.split("ENTRY", 1)[1])
+    assert re.search(r"s32\[4,2\]", text.split("ENTRY", 1)[1])
 
 
 def test_kimi_prefill_runs_no_kernel_and_scans_sixteen_chunks(kimi_programs):
-    """The chunked KDA form is ``jax.numpy`` in this PR: the 1,024-token
-    prefill program holds no kernel of this repo's (the experts'
-    ``ragged-dot`` custom calls are the compiler's), and one ``while`` a
-    KDA layer over the bucket's 16 chunks of 64 tokens x 32 heads x
-    128."""
+    """The chunked KDA form is ``jax.numpy``: the 1,024-token prefill
+    program holds no kernel of this repo's but the four sparse layers'
+    ``nezha_moe_experts`` (PR 33: a chunk's 8,192 pair rows go through the
+    same kernel as a step's, no ``ragged-dot`` custom call is left), and
+    one ``while`` a KDA layer over the bucket's 16 chunks of 64 tokens x
+    32 heads x 128."""
     text = kimi_programs["prefill"].as_text()
-    assert not re.findall(r"%?nezha_\w+\S* = .*tpu_custom_call", text)
+    kernels = re.findall(r"%?(nezha_\w+?)(?:\.\d+)? = .*tpu_custom_call", text)
+    assert sorted(kernels) == ["nezha_moe_experts"] * 4, kernels
     scans = [line for line in text.splitlines()
              if " while(" in line and "f32[16,32,64,128]" in line]
     assert len(scans) == 4
