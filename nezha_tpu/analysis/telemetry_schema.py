@@ -34,10 +34,11 @@ This module also pins the LIVE ``GET /stats`` payload
 
 from __future__ import annotations
 
+import ast
 import json
 import os
 import re
-from typing import List
+from typing import Dict, List, Optional
 
 SCHEMA_VERSION = 1
 _HIST_KEYS = {"count", "sum", "min", "max", "mean", "p50", "p90", "p99"}
@@ -251,6 +252,22 @@ _OBS_COUNTERS = {"watchdog.checks_total", "watchdog.events_total",
                  "slo.evaluations_total", "slo.violations_total"}
 _OBS_GAUGES = {"slo.burn_rate_max"}
 
+def layer_spans() -> Dict[str, Optional[str]]:
+    """``nezha_tpu/obs/trace.py::LAYER_SPANS`` (layer span -> the span it
+    opens inside), the one list of the names ``obs.annotate`` is called
+    with. Read from the source as a literal: that module imports jax and
+    this one must load without it (tools/check_telemetry_schema.py)."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "obs", "trace.py")
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    for node in tree.body:
+        if (isinstance(node, ast.AnnAssign)
+                and getattr(node.target, "id", None) == "LAYER_SPANS"):
+            return ast.literal_eval(node.value)
+    raise RuntimeError(f"no LAYER_SPANS literal in {path}")
+
+
 # Span-name registry for the namespaces this module owns: spans under
 # serve./checkpoint./dist./router. are an interface (reports and
 # dashboards key on them), so an unknown name in those namespaces is
@@ -258,7 +275,7 @@ _OBS_GAUGES = {"slo.burn_rate_max"}
 # deliberately.
 _PINNED_SPAN_PREFIXES = ("serve.", "checkpoint.", "dist.", "router.")
 _PINNED_SPANS = {
-    "serve.prefill", "serve.decode_step", "serve.drain",
+    "serve.prefill", "serve.drain",
     "checkpoint.save", "checkpoint.verify",
     "dist.join", "dist.barrier", "dist.failure", "dist.leave",
     "router.drain",
@@ -277,8 +294,13 @@ _PINNED_SPANS = {
     "serve.park",            # prefill_only park -> ack/resume/TTL/drain
     "serve.kv_export",       # source side of the migration pull
     "serve.kv_install",      # decode side: export POST+install+ACK
-    "serve.decode_window",   # one per decode dispatch the request rode
     "serve.decode",          # decode residency + first-token milestone
+    # DERIVED by the report, never emitted (PR 34): one per decode pass
+    # the request rode, joined from its serve.decode interval and the
+    # engine's serve.engine.dispatch / serve.engine.wait records
+    # (obs/report.py::decode_windows). Pinned so that a capture from
+    # before PR 34, which holds one record a row a pass, stays valid.
+    "serve.decode_window",
     # Tensor-sharded serving (PR 14): the train->serve checkpoint
     # resharding window (nezha-reshard / nezha-serve --mesh startup) —
     # attrs carry source format, step, and mesh size.
@@ -306,12 +328,14 @@ _PINNED_SPANS = {
     # (attrs carry the victim's request_id, priority, and emitted
     # token count). Absent entirely with preemption off.
     "serve.preempt_s",
-    # Layer boundaries on the profiler's clock (PR 24; obs.annotate):
-    # always a TraceAnnotation, a registry span only under --run-dir.
-    # One scheduler pass and its admission passes and token hand-over;
-    # the engine's prefill, step dispatch and the blocking fetch.
-    "serve.sched.pass", "serve.sched.admit", "serve.sched.emit",
-    "serve.engine.prefill", "serve.engine.dispatch", "serve.engine.wait",
+    # Layer boundaries on the profiler's clock (PR 24, PR 34;
+    # obs.annotate): always a TraceAnnotation, a registry span only
+    # under --run-dir. One scheduler pass, its admission passes and
+    # token hand-over; the engine's prefill, step dispatch and blocking
+    # fetch, and what each of those three is made of. The names and
+    # their nesting live in obs/trace.py::LAYER_SPANS.
+    *(name for name in layer_spans()
+      if name.startswith(_PINNED_SPAN_PREFIXES)),
 }
 
 # Namespaces whose METRIC names (counter/gauge/histogram) the source

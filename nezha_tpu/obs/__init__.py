@@ -86,6 +86,7 @@ from nezha_tpu.obs.timeseries import (
     windows_payload,
 )
 from nezha_tpu.obs.trace import (
+    LAYER_SPANS,
     Tracer,
     annotate,
     annotate_step,
@@ -103,7 +104,7 @@ __all__ = [
     "RunSink", "start_run", "end_run", "current_sink",
     "METRICS_FILE", "SPANS_FILE", "EVENTS_FILE", "SUMMARY_FILE",
     "MetricsLogger", "StepTimer", "read_metrics",
-    "Tracer", "annotate", "annotate_step", "profile_trace",
+    "LAYER_SPANS", "Tracer", "annotate", "annotate_step", "profile_trace",
     "record_event", "windows",
     "LogSketch", "WindowStore", "WINDOW_DURATIONS",
     "install_windows", "uninstall_windows", "current_windows",

@@ -247,29 +247,34 @@ class Histogram:
         # (e.g. two Executor threads timing compiles) would corrupt.
         self._lock = threading.Lock()
 
-    def observe(self, v) -> None:
+    def observe(self, v, n: int = 1) -> None:
+        """``n`` observations of ``v`` at once (the tokens of a decode
+        block whose rows share one per-token latency): count, sum and
+        the reservoir are what ``n`` calls leave, under one lock."""
         if not _state.enabled:
             return
         v = float(v)
         with self._lock:
-            self.count += 1
-            self.total += v
+            self.total += v * n
             if self.min is None or v < self.min:
                 self.min = v
             if self.max is None or v > self.max:
                 self.max = v
-            if len(self._samples) < self._cap:
-                self._samples.append(v)
-            else:
-                j = self._rng.randrange(self.count)
-                if j < self._cap:
-                    self._samples[j] = v
+            random, cap = self._rng.random, self._cap
+            room = min(n, cap - len(self._samples))
+            self._samples.extend([v] * room)
+            # Algorithm R for the rest: observation number c takes a
+            # uniformly drawn slot with probability cap/c.
+            for c in range(self.count + room + 1, self.count + n + 1):
+                if random() * c < cap:
+                    self._samples[int(random() * cap)] = v
+            self.count += n
         # Window tap outside the reservoir lock: the store has its own
         # lock, and nesting them would couple every histogram's hot path
         # to the rotation critical section.
         w = _state.windows
         if w is not None:
-            w.record_histogram(self.name, v)
+            w.record_histogram(self.name, v, n)
 
     def percentile(self, q: float) -> Optional[float]:
         with self._lock:

@@ -18,6 +18,7 @@ replica took with it.
 
 from __future__ import annotations
 
+import bisect
 import json
 import os
 from typing import Any, Dict, List, Optional
@@ -379,17 +380,84 @@ def load_fleet_spans(run_dir: str) -> List[dict]:
     return out
 
 
+def _attrs(rec: dict) -> dict:
+    a = rec.get("attrs")
+    return a if isinstance(a, dict) else {}
+
+
+def decode_passes(spans: List[dict]) -> Dict[tuple, List[tuple]]:
+    """The decode passes of a capture: ``(source dir, engine) -> [(t0,
+    t1, rows)]`` sorted by start, each a ``serve.engine.dispatch`` record
+    through the end of the same engine's next ``serve.engine.wait`` (the
+    block's fetch): the window a request that rode the pass waited
+    through. A dispatch no wait follows (the step raised) is no pass."""
+    by_engine: Dict[tuple, List[dict]] = {}
+    for rec in spans:
+        if rec.get("name") in ("serve.engine.dispatch",
+                               "serve.engine.wait"):
+            key = (rec.get("_src", "."), _attrs(rec).get("engine"))
+            by_engine.setdefault(key, []).append(rec)
+    out: Dict[tuple, List[tuple]] = {}
+    for key, recs in by_engine.items():
+        recs.sort(key=lambda r: r.get("t0", 0.0))
+        passes, opened = [], None
+        for rec in recs:
+            if rec["name"] == "serve.engine.dispatch":
+                opened = rec
+            elif opened is not None:
+                passes.append((opened.get("t0", 0.0), rec.get("t1", 0.0),
+                               _attrs(opened).get("rows")))
+                opened = None
+        out[key] = passes
+    return out
+
+
+def decode_windows(frags: List[dict],
+                   passes: Dict[tuple, List[tuple]]) -> List[dict]:
+    """One ``serve.decode_window`` fragment for each decode pass a traced
+    request rode: the passes of its engine that lie inside one of its
+    ``serve.decode`` residencies. The scheduler wrote one such record a
+    row a pass until PR 34; a pass's own records say the same with a
+    count that does not grow with the rows. (A request preempted and
+    resumed keeps only its last residency's ``serve.decode``, so only
+    that one's windows.)"""
+    out = []
+    for dec in frags:
+        if dec.get("name") != "serve.decode":
+            continue
+        a = _attrs(dec)
+        key = (dec.get("_src", "."), a.get("engine"))
+        lo, hi = dec.get("t0", 0.0), dec.get("t1", 0.0)
+        own = passes.get(key, [])
+        first = bisect.bisect_left(own, (lo,))
+        for t0, t1, rows in own[first:]:
+            if t0 > hi:
+                break
+            if t1 <= hi:
+                out.append({
+                    "name": "serve.decode_window", "t0": t0, "t1": t1,
+                    "dur_s": t1 - t0, "trace_id": dec.get("trace_id"),
+                    "attrs": {"request_id": a.get("request_id"),
+                              "rows": rows},
+                    "_src": dec.get("_src", "."), "derived": True})
+    return out
+
+
 def stitch_traces(spans: List[dict]) -> Dict[str, List[dict]]:
     """Group span fragments by ``trace_id`` (records without one are
     not part of any request timeline), each trace's fragments sorted by
     start time — all fragments carry epoch wall clocks, so one host's
-    replicas order correctly across processes."""
+    replicas order correctly across processes. A trace's decode windows
+    are joined in from the capture's pass records
+    (:func:`decode_windows`)."""
     traces: Dict[str, List[dict]] = {}
     for rec in spans:
         tid = rec.get("trace_id")
         if isinstance(tid, str) and tid:
             traces.setdefault(tid, []).append(rec)
+    passes = decode_passes(spans) if traces else {}
     for frags in traces.values():
+        frags.extend(decode_windows(frags, passes))
         frags.sort(key=lambda r: (r.get("t0", 0.0), r.get("t1", 0.0)))
     return traces
 
@@ -408,10 +476,6 @@ def trace_timeline(trace_id: str, frags: List[dict]) -> dict:
     for f in frags:
         by_name.setdefault(str(f.get("name")), []).append(f)
 
-    def attrs_of(f) -> dict:
-        a = f.get("attrs")
-        return a if isinstance(a, dict) else {}
-
     root = (by_name.get("router.request") or [None])[0]
     qws = by_name.get("serve.queue_wait", [])
     prefills = by_name.get("serve.prefill", [])
@@ -423,7 +487,7 @@ def trace_timeline(trace_id: str, frags: List[dict]) -> dict:
     # transfer segment for a migration that never delivered, masking
     # exactly the degradation this report exists to surface.
     pulls = [p for p in by_name.get("serve.kv_install", [])
-             if "error" not in attrs_of(p)]
+             if "error" not in _attrs(p)]
     # The LAST decode fragment wins: a resumed (local-decode fallback)
     # request parks one aborted residency behind the real one.
     decodes = by_name.get("serve.decode", [])
@@ -431,7 +495,7 @@ def trace_timeline(trace_id: str, frags: List[dict]) -> dict:
 
     request_id = None
     for f in frags:
-        rid = attrs_of(f).get("request_id")
+        rid = _attrs(f).get("request_id")
         if rid:
             request_id = rid
             break
@@ -440,7 +504,7 @@ def trace_timeline(trace_id: str, frags: List[dict]) -> dict:
     pull_t0 = pulls[0].get("t0") if pulls else None
     pre = [p for p in prefills
            if pull_t0 is None or p.get("t0", 0.0) <= pull_t0]
-    first_token = attrs_of(decode).get("first_token") if decode else None
+    first_token = _attrs(decode).get("first_token") if decode else None
 
     milestones = [
         ("router.request", root.get("t0") if root else
@@ -470,9 +534,16 @@ def trace_timeline(trace_id: str, frags: List[dict]) -> dict:
         "t0": milestones[0][1],
     }
     if decode is not None:
-        a = attrs_of(decode)
+        a = _attrs(decode)
         out["finish_reason"] = a.get("finish_reason")
         out["tokens"] = a.get("tokens")
+    windows = by_name.get("serve.decode_window", [])
+    if windows:
+        # Where the decode time went: the passes the request rode, the
+        # time inside them, and the slowest one.
+        durs = [w.get("dur_s", 0.0) for w in windows]
+        out["decode_windows"] = {"count": len(durs), "sum_s": sum(durs),
+                                 "slowest_s": max(durs)}
     if missing:
         return out
     # Clamp monotone, then difference: consecutive intervals tile
@@ -578,11 +649,15 @@ def render_trace_report(run_dir: str, top: int = 10) -> str:
         lines.append(f"  {'ttft ms':>10}  {'request':<20}"
                      f"{'replicas':<20}  critical path")
         for t in complete[:top]:
+            w = t.get("decode_windows")
             lines.append(
                 f"  {t['ttft_s'] * 1e3:>10.1f}  "
                 f"{str(t.get('request_id') or t['trace_id']):<20}"
                 f"{','.join(t['replicas']):<20}  "
-                f"{_critical_path(t)}")
+                f"{_critical_path(t)}"
+                + (f"; decode {w['count']} pass(es) "
+                   f"{w['sum_s'] * 1e3:.1f} ms, slowest "
+                   f"{w['slowest_s'] * 1e3:.1f} ms" if w else ""))
     if partial:
         lines.append("")
         lines.append(f"partial traces ({len(partial)} — request still "

@@ -12,7 +12,10 @@ File contract (frozen; tools/check_telemetry_schema.py validates it):
 
     metrics.jsonl   one object per line: {"step": int, "ts": float, ...}
     spans.jsonl     one object per line: {"name", "t0", "t1", "dur_s",
-                    "attrs"}
+                    "attrs"}; buffered: whole lines reach the file when
+                    64 KB have gathered, on the first span written over
+                    a second after the last flush, and at close, so a
+                    live file may trail the process by a second
     events.jsonl    one object per line: {"event_schema_version", "ts",
                     "kind", "severity", "source", "detail"} — the typed
                     watchdog/SLO event stream (PR 16; validated by the
@@ -26,8 +29,9 @@ from __future__ import annotations
 
 import json
 import os
+import threading
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 from nezha_tpu.obs import registry as _registry
 from nezha_tpu.obs.metrics import MetricsLogger
@@ -36,10 +40,20 @@ METRICS_FILE = "metrics.jsonl"
 SPANS_FILE = "spans.jsonl"
 EVENTS_FILE = "events.jsonl"
 SUMMARY_FILE = "summary.json"
+# spans.jsonl's buffer: a decode pass writes ten or so span records, and a
+# flush a record was most of what a pass paid for the registry (PR 34).
+SPAN_FLUSH_BYTES = 64 * 1024
+SPAN_FLUSH_SECONDS = 1.0
 
 
 class RunSink:
     """Writer for one run directory. Create via :func:`start_run`."""
+
+    # Spans arrive from every recording thread — declared for
+    # nezha-lint's lock-discipline rule.
+    _LOCK_GUARDED = {"_span_lines": "_span_lock",
+                     "_span_bytes": "_span_lock",
+                     "_span_flushed_at": "_span_lock"}
 
     def __init__(self, run_dir: str,
                  registry: Optional[_registry.Registry] = None,
@@ -59,6 +73,10 @@ class RunSink:
         self._metrics = MetricsLogger(os.path.join(run_dir, METRICS_FILE),
                                       mode="w")
         self._spans = open(os.path.join(run_dir, SPANS_FILE), "w")
+        self._span_lock = threading.Lock()
+        self._span_lines: List[str] = []
+        self._span_bytes = 0
+        self._span_flushed_at = time.monotonic()
         self._events = open(os.path.join(run_dir, EVENTS_FILE), "w")
         self._t_start = time.time()
         self._closed = False
@@ -68,9 +86,24 @@ class RunSink:
             self._metrics.log(step, metrics)
 
     def write_span(self, rec: dict) -> None:
-        if not self._closed:
-            self._spans.write(json.dumps(rec) + "\n")
-            self._spans.flush()
+        line = json.dumps(rec) + "\n"
+        with self._span_lock:
+            if self._closed:
+                return
+            self._span_lines.append(line)
+            self._span_bytes += len(line)
+            if (self._span_bytes >= SPAN_FLUSH_BYTES
+                    or time.monotonic() - self._span_flushed_at
+                    >= SPAN_FLUSH_SECONDS):
+                self._flush_spans()
+
+    def _flush_spans(self) -> None:
+        """[holds: _span_lock]"""
+        self._spans.write("".join(self._span_lines))
+        self._spans.flush()
+        self._span_lines.clear()
+        self._span_bytes = 0
+        self._span_flushed_at = time.monotonic()
 
     def write_event(self, rec: dict) -> None:
         if not self._closed:
@@ -92,6 +125,8 @@ class RunSink:
         summary = self.summary()
         self._closed = True
         self._metrics.close()
+        with self._span_lock:
+            self._flush_spans()
         self._spans.close()
         self._events.close()
         path = os.path.join(self.run_dir, SUMMARY_FILE)

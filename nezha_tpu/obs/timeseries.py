@@ -86,10 +86,10 @@ class LogSketch:
         # lands every value in the same bucket — merge exactness.
         return math.ceil(math.log(v) / self._ln_gamma - 1e-12)
 
-    def observe(self, v) -> None:
+    def observe(self, v, n: int = 1) -> None:
         v = float(v)
-        self.count += 1
-        self.total += v
+        self.count += n
+        self.total += v * n
         if self.min is None or v < self.min:
             self.min = v
         if self.max is None or v > self.max:
@@ -97,10 +97,10 @@ class LogSketch:
         if v <= 0.0:
             # Telemetry values are durations/sizes; <= 0 collapses into
             # one underflow bucket rather than a log() domain error.
-            self.zero += 1
+            self.zero += n
         else:
             i = self._index(v)
-            self.buckets[i] = self.buckets.get(i, 0) + 1
+            self.buckets[i] = self.buckets.get(i, 0) + n
 
     def merge(self, other: "LogSketch") -> None:
         """Fold ``other`` in: bucket-wise count addition — the merged
@@ -255,13 +255,13 @@ class WindowStore:
                 if v > cur[2]:
                     cur[2] = v
 
-    def record_histogram(self, name: str, v: float) -> None:
+    def record_histogram(self, name: str, v: float, n: int = 1) -> None:
         with self._lock:
             b = self._bucket()
             sk = b.sketches.get(name)
             if sk is None:
                 sk = b.sketches[name] = LogSketch(gamma=self.gamma)
-            sk.observe(v)
+            sk.observe(v, n)
 
     # ----------------------------------------------------- rolled views
     def view(self, duration_s: float, skip: int = 0) -> dict:

@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import contextlib
 import os
-from typing import Iterator, Optional
+from typing import Dict, Iterator, Optional
 
 import jax
 
@@ -41,6 +41,32 @@ from nezha_tpu.obs.registry import (  # noqa: F401 — re-exported API
     trace_sample,
     traced_span,
 )
+
+# THE list of layer spans: every name :func:`annotate` /
+# :func:`annotate_step` is called with, and the span each one opens
+# inside (None: a root). A span's SELF time is its duration minus its
+# children's by this map, which is what an idle gap of the device is
+# charged to (chipbench/pass_spans.py). Read from here by
+# analysis/telemetry_schema.py (the pinned names; from the source, as a
+# literal: keep it one) and by the benchmark's tools and tests.
+LAYER_SPANS: Dict[str, Optional[str]] = {
+    "serve.sched.pass": None,
+    "serve.sched.admit": "serve.sched.pass",
+    "serve.sched.emit": "serve.sched.pass",
+    "serve.engine.prefill": "serve.sched.admit",
+    "serve.engine.dispatch": "serve.sched.pass",
+    "serve.engine.wait": "serve.sched.pass",
+    "train.step": None,
+    "train.data": "train.step",
+    "train.dispatch": "train.step",
+    "train.fetch": "train.step",
+    "serve.engine.bind": "serve.engine.dispatch",
+    "serve.engine.tables": "serve.engine.dispatch",
+    "serve.engine.launch": "serve.engine.dispatch",
+    "serve.engine.fetch": "serve.engine.wait",
+    "serve.engine.prefill.bind": "serve.engine.prefill",
+    "serve.engine.prefill.launch": "serve.engine.prefill",
+}
 
 
 @contextlib.contextmanager
@@ -106,7 +132,7 @@ def annotate(name: str, *, record: bool = True, **attrs) -> _Annotation:
     carry one vocabulary. ``attrs`` are cheap ints: they arrive as the
     host event's stats. ``record=False`` leaves the registry half out:
     a loop that polls while idle passes whether this turn has work, so
-    an idle server writes no span record (each one is a flushed line of
+    an idle server writes no span record (each one is a line of
     ``spans.jsonl`` and a slot of the registry's bounded span list).
     Usable inside jit too (an XLA op annotation).
     """

@@ -80,6 +80,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import itertools
 from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -96,6 +97,11 @@ from nezha_tpu.serve.sampling import (accept_mask, categorical_rows,
                                       sample_tokens_and_flag,
                                       split_and_sample)
 from nezha_tpu.serve.slots import KVBlocksExhausted, PagedSlotPool
+
+# An engine's number within its process: what its decode passes' span
+# records say (``engine``), so that the report can tell the passes of
+# two replicas that share one registry apart (obs/report.py).
+_ENGINE_IDS = itertools.count()
 
 
 def default_prefill_buckets(max_prefill_len: int) -> Tuple[int, ...]:
@@ -485,6 +491,7 @@ class Engine:
             raise ValueError(
                 f"max_len {cfg.max_len} exceeds the model's max_positions "
                 f"{model.cfg.max_positions}")
+        self.engine_id = next(_ENGINE_IDS)
         if (cfg.prefill_mode == "sequence"
                 and not self._seq_prefill_capable):
             raise ValueError(
@@ -868,26 +875,28 @@ class Engine:
         # prefix chunk programs start from the promoted span and
         # queue behind the copy on the device stream (dataflow
         # through pool.caches orders them; no host sync anywhere).
-        start = self.pool.bind_for_prompt(slot, tokens.tolist())
-        chunks = self._plan_chunks(n, start)
-        try:
-            self.pool.prepare_write(
-                slot, min(off for off, _, _ in chunks),
-                max(off + width for off, _, width in chunks))
-        except KVBlocksExhausted:
-            if start == 0:
-                raise
-            # Tight-pool edge: the hit's own references pinned the
-            # evictable blocks its copy-on-write then needed. Fall
-            # back to a COLD prefill — releasing our references
-            # makes those blocks reclaimable again, and admission
-            # sized its budget for exactly this no-hit footprint.
-            self.pool.release_blocks(slot)
-            start = 0
-            chunks = self._plan_chunks(n, 0)
-            self.pool.prepare_write(
-                slot, 0,
-                max(off + width for off, _, width in chunks))
+        with obs.annotate("serve.engine.prefill.bind") as bind:
+            start = self.pool.bind_for_prompt(slot, tokens.tolist())
+            chunks = self._plan_chunks(n, start)
+            try:
+                bound = self.pool.prepare_write(
+                    slot, min(off for off, _, _ in chunks),
+                    max(off + width for off, _, width in chunks))
+            except KVBlocksExhausted:
+                if start == 0:
+                    raise
+                # Tight-pool edge: the hit's own references pinned the
+                # evictable blocks its copy-on-write then needed. Fall
+                # back to a COLD prefill — releasing our references
+                # makes those blocks reclaimable again, and admission
+                # sized its budget for exactly this no-hit footprint.
+                self.pool.release_blocks(slot)
+                start = 0
+                chunks = self._plan_chunks(n, 0)
+                bound = self.pool.prepare_write(
+                    slot, 0,
+                    max(off + width for off, _, width in chunks))
+            bind.set(bound=bound)
         if start > 0:
             # Count the hit only once its binding MATERIALIZED —
             # the cold fallback above must not inflate cache wins.
@@ -945,7 +954,9 @@ class Engine:
                 # kernel epilogue instead of the gather/requant
                 # round-trip — count them so the fused-write rate
                 # is auditable against chunk throughput.
-                with (obs.span("serve.prefill.kernel_s", width=width)
+                with obs.annotate("serve.engine.prefill.launch",
+                                  width=width), \
+                     (obs.span("serve.prefill.kernel_s", width=width)
                       if self.prefill_kernel_active
                       else contextlib.nullcontext()):
                     out = self.executor.run(
@@ -988,11 +999,13 @@ class Engine:
                 padded = np.zeros((1, width), np.int32)
                 padded[0, :ln] = tokens[off:off + ln]
                 dscalars = (np.int32(ln), np.int32(slot), np.int32(off))
-                self.draft_pool.caches = self.draft_executor.run(
-                    self._draft_prefill_fns[width],
-                    self.draft_variables, self.draft_pool.caches,
-                    self.draft_pool.device_tables(),
-                    jnp.asarray(padded), *dscalars)
+                with obs.annotate("serve.engine.prefill.launch",
+                                  width=width):
+                    self.draft_pool.caches = self.draft_executor.run(
+                        self._draft_prefill_fns[width],
+                        self.draft_variables, self.draft_pool.caches,
+                        self.draft_pool.device_tables(),
+                        jnp.asarray(padded), *dscalars)
             # Fresh request: its carried logits are real target logits,
             # not a residual distribution.
             self.residual = self.residual.at[slot].set(False)
@@ -1005,7 +1018,7 @@ class Engine:
                 "serve.prefill.logits", self.last_logits, rows=(slot,))
 
     def _bind_decode_windows(self, active: np.ndarray, cap: int,
-                             pools) -> None:
+                             pools) -> int:
         """Lazy binding: make every active row's write
         window for this block — ``[pos, pos + min(cap, budget))``,
         clamped to capacity — exclusively owned in each of ``pools``
@@ -1017,7 +1030,9 @@ class Engine:
         that finds no block (genuine exhaustion or an injected
         serve.kv.bind fault) surfaces as the typed KVBlocksExhausted
         carrying the victim slot — the scheduler retires that one
-        request and redials; the batch never crashes."""
+        request and redials; the batch never crashes. -> the blocks
+        newly bound."""
+        bound = 0
         for slot in np.flatnonzero(np.asarray(active, bool)):
             pos_h = int(self.host_positions[slot])
             need = min(cap, max(int(self.host_budgets[slot]), 0))
@@ -1027,9 +1042,10 @@ class Engine:
             end = max(min(pos_h + need, self.cfg.max_len), start + 1)
             try:
                 for pool in pools:
-                    pool.prepare_write(int(slot), start, end)
+                    bound += pool.prepare_write(int(slot), start, end)
             except faults.InjectedFault as e:
                 raise KVBlocksExhausted(str(e), slot=int(slot)) from e
+        return bound
 
     def _dispatch_attrs(self, active: np.ndarray) -> dict:
         """What the ``serve.engine.dispatch`` span says of a step: the
@@ -1050,6 +1066,25 @@ class Engine:
                 np.minimum(held, self.pool.window_entries).sum())
         return attrs
 
+    def _stage_step(self, ann, active: np.ndarray, cap: int, pools):
+        """The host's part of a step before its launch, inside the
+        step's ``serve.engine.dispatch`` span ``ann``: that span's attrs
+        (counted here, so in its own self time), the rows' write windows
+        bound in each of ``pools`` (``serve.engine.bind``), and every
+        host-to-device upload of the step, each pool's tables and the
+        active mask (``serve.engine.tables``). -> (the tables a pool, the
+        mask on the device)."""
+        attrs = self._dispatch_attrs(active)
+        ann.set(**attrs)
+        with obs.annotate("serve.engine.bind", rows=attrs["rows"]) as bind:
+            bind.set(bound=self._bind_decode_windows(active, cap, pools))
+        with obs.annotate("serve.engine.tables") as upload:
+            tables = [pool.device_tables() for pool in pools]
+            mask = jnp.asarray(active, bool)
+            upload.set(bytes=mask.nbytes + sum(
+                t.nbytes for group in tables for t in group.values()))
+        return tables, mask
+
     def step(self, active: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """Decode one BLOCK of up to ``decode_horizon`` tokens for every
         row; ``active`` is a ``[B_max]`` bool mask. Returns
@@ -1067,16 +1102,16 @@ class Engine:
         if self.spec is not None:
             return self._spec_step(active)
         with obs.annotate("serve.engine.dispatch",
-                          **self._dispatch_attrs(active)):
-            self._bind_decode_windows(
-                active, self.cfg.decode_horizon, (self.pool,))
-            out = self.executor.run(
-                self._step_fn, self.variables, self.pool.caches,
-                self.pool.device_tables(),
-                self.last_logits, self.positions,
-                jnp.asarray(active, bool), self.keys,
-                self.temps, self.top_ks, self.top_ps,
-                self.eos_ids, self.budgets)
+                          engine=self.engine_id) as ann:
+            (tables,), mask = self._stage_step(
+                ann, active, self.cfg.decode_horizon, (self.pool,))
+            with obs.annotate("serve.engine.launch"):
+                out = self.executor.run(
+                    self._step_fn, self.variables, self.pool.caches,
+                    tables, self.last_logits, self.positions,
+                    mask, self.keys,
+                    self.temps, self.top_ks, self.top_ps,
+                    self.eos_ids, self.budgets)
             (tok, emitted, ok, full_sorts, caches, last, pos, keys,
              budgets, *load) = out
             # Start the block's device->host transfers NOW, before any
@@ -1092,14 +1127,17 @@ class Engine:
                 rows=lambda: np.flatnonzero(active))
         self.last_logits, self.positions, self.keys = last, pos, keys
         self.budgets = budgets
-        with obs.annotate("serve.engine.wait"):
-            # The host blocked on the device: the block's fetches.
+        with obs.annotate("serve.engine.wait", engine=self.engine_id):
+            # The host blocked on the device: the first fetch returns
+            # when the step has run; the others (their copies started
+            # with it) are further round trips.
             self.step_ok = np.asarray(ok)
-            tok_h, emitted_h = np.asarray(tok), np.asarray(emitted)
-            full_sorts_h = int(np.asarray(full_sorts))
-            if load:
-                self.last_expert_load = np.asarray(load[0])
-                visits_h = np.asarray(load[1])
+            with obs.annotate("serve.engine.fetch"):
+                tok_h, emitted_h = np.asarray(tok), np.asarray(emitted)
+                full_sorts_h = int(np.asarray(full_sorts))
+                if load:
+                    self.last_expert_load = np.asarray(load[0])
+                    visits_h = np.asarray(load[1])
         obs.counter("serve.sampling.full_sort_steps_total").inc(full_sorts_h)
         if load:
             self._record_expert_load(int(np.count_nonzero(active)), visits_h)
@@ -1147,22 +1185,21 @@ class Engine:
         k = self.spec.draft_k
         cap = self.cfg.decode_horizon * (k + 1)
         with obs.annotate("serve.engine.dispatch",
-                          **self._dispatch_attrs(active)):
+                          engine=self.engine_id) as ann:
             # Both pools bind the same window: verify/draft writes
             # past it are garbage by construction and route to the
             # scratch block through the unbound table tail.
-            self._bind_decode_windows(active, cap,
-                                      (self.pool, self.draft_pool))
-            out = self.executor.run(
-                self._step_fn, self.variables,
-                (self.pool.caches, self.draft_pool.caches),
-                self.draft_variables,
-                self.pool.device_tables(),
-                self.draft_pool.device_tables(),
-                self.last_logits, self.positions,
-                jnp.asarray(active, bool), self.keys,
-                self.temps, self.top_ks, self.top_ps,
-                self.eos_ids, self.budgets, self.residual)
+            (tables, dtables), mask = self._stage_step(
+                ann, active, cap, (self.pool, self.draft_pool))
+            with obs.annotate("serve.engine.launch"):
+                out = self.executor.run(
+                    self._step_fn, self.variables,
+                    (self.pool.caches, self.draft_pool.caches),
+                    self.draft_variables, tables, dtables,
+                    self.last_logits, self.positions,
+                    mask, self.keys,
+                    self.temps, self.top_ks, self.top_ps,
+                    self.eos_ids, self.budgets, self.residual)
             (tok, emitted, ok, win_emitted, full_sorts, caches_all, last,
              pos, keys, budgets, residual) = out
             _start_host_copies(tok, emitted, ok, win_emitted, full_sorts)
@@ -1179,11 +1216,12 @@ class Engine:
                 rows=lambda: np.flatnonzero(active))
         self.last_logits, self.positions, self.keys = last, pos, keys
         self.budgets, self.residual = budgets, residual
-        with obs.annotate("serve.engine.wait"):
+        with obs.annotate("serve.engine.wait", engine=self.engine_id):
             self.step_ok = np.asarray(ok)
-            tok_h, emitted_h = np.asarray(tok), np.asarray(emitted)
-            win_h = np.asarray(win_emitted)
-            full_sorts_h = int(np.asarray(full_sorts))
+            with obs.annotate("serve.engine.fetch"):
+                tok_h, emitted_h = np.asarray(tok), np.asarray(emitted)
+                win_h = np.asarray(win_emitted)
+                full_sorts_h = int(np.asarray(full_sorts))
         obs.counter("serve.sampling.full_sort_steps_total").inc(full_sorts_h)
         # Speculation ledger: every window that emitted >= 1 token ran
         # one verify forward; its accepted-prefix length is (e_w - 1)

@@ -23,7 +23,7 @@ checked once per block — granularity coarsens to one horizon.
 Telemetry flows through ``nezha_tpu.obs`` at the serving layer's
 metrics of record: ``serve.ttft_s`` (submit -> first token, placed at
 the row's position WITHIN its first block) and ``serve.tpot_s``
-(``block_dt / tokens_emitted`` observed once per emitted token, so
+(``block_dt / tokens_emitted`` counted once per emitted token, so
 percentiles stay comparable across horizon settings) histograms,
 ``serve.host_gap_s`` (host time between one block's fetch and the
 next dispatch, minus the time inside ``Engine.prefill`` during it — the
@@ -34,9 +34,9 @@ bucket-occupancy view), ``serve.queue_depth`` and
 ``serve.batch_occupancy`` gauges,
 ``serve.{admitted,rejected,expired,retired,tokens}_total``,
 ``serve.{errors,step_retries}_total`` and ``serve.prefill.chunks_total``
-counters, ``faults.injected_total`` (the chaos ledger), and a
-``serve.decode_step`` span around every batched decode block's
-dispatch and fetch —
+counters, ``faults.injected_total`` (the chaos ledger), and the
+layer spans of a pass (``obs.LAYER_SPANS``: ``serve.sched.*`` here,
+``serve.engine.*`` around every decode block's dispatch and fetch) —
 the names tools/check_telemetry_schema.py pins. With no run active
 every call site is the registry's branch-only no-op.
 
@@ -46,10 +46,14 @@ Distributed tracing rides the same lifecycle: a request carrying a
 per-request span fragment per lifecycle stage — ``serve.queue_wait``
 (submit -> admit), ``serve.prefill`` (+ the engine's per-chunk
 ``serve.prefill.chunk``), ``serve.park`` / ``serve.kv_export`` (the
-migration handoff), ``serve.decode_window`` (each dispatch the request
-rode) and ``serve.decode`` (residency + the first-token milestone) —
+migration handoff) and ``serve.decode`` (residency + the first-token
+milestone) —
 all stamped with the trace id so ``nezha-telemetry RUN_DIR --trace``
-can stitch the fleet's fragments into one per-request timeline.
+can stitch the fleet's fragments into one per-request timeline. Each
+dispatch a request rode (``serve.decode_window``) is NOT a record of
+its own: the report joins the request's ``serve.decode`` interval
+against the engine's pass records (obs/report.py::decode_windows), so
+what a decode pass writes does not grow with its rows.
 Untraced (or sampled-out) requests emit ZERO extra spans, and with
 telemetry disabled the whole layer stays branch-only no-op.
 
@@ -1035,13 +1039,10 @@ class Scheduler:
         # keeps the final value, which is 0 for any drained server.
         obs.histogram("metric.batch_occupancy").observe(
             len(self._live) / self.engine.cfg.max_batch_size)
-        # Wall-clock twin of the monotonic dispatch window, taken only
-        # when a traced request is in the batch: per-request
-        # serve.decode_window spans and the first-token milestone are
-        # stitched on the epoch clock across processes.
-        traced_batch = obs.enabled() and any(
-            l.trace_id is not None for l in self._live.values())
-        t0_wall = time.time() if traced_batch else None
+        # Wall-clock twin of the dispatch window's monotonic start,
+        # taken only under a run dir: a traced request's first-token
+        # milestone is stitched on the epoch clock across processes.
+        t0_wall = time.time() if obs.enabled() else None
         t0 = time.monotonic()
         if self._host_gap_t is not None:
             # Host time since the previous block came back, MINUS the
@@ -1076,32 +1077,31 @@ class Scheduler:
                     if not self._live:
                         return None
 
-        with obs.span("serve.decode_step", rows=len(self._live)):
-            try:
-                out = _dispatch()
-            except Exception:
-                # One bounded retry with backoff: a transient step crash
-                # (preempted device, injected fault) must not retire
-                # every in-flight request. A second consecutive failure
-                # surfaces to the caller — that is a dead engine, not a
-                # hiccup. (If the first dispatch died AFTER consuming
-                # its donated cache buffers the retry fails fast on the
-                # donation error and surfaces the same way.)
-                obs.counter("serve.step_retries_total").inc()
-                time.sleep(self.step_retry_backoff_s)
-                out = _dispatch()
-            if out is None:
-                self._host_gap_t = None
-                return 0
-            tokens, block_emitted = out
+        try:
+            out = _dispatch()
+        except Exception:
+            # One bounded retry with backoff: a transient step crash
+            # (preempted device, injected fault) must not retire
+            # every in-flight request. A second consecutive failure
+            # surfaces to the caller — that is a dead engine, not a
+            # hiccup. (If the first dispatch died AFTER consuming
+            # its donated cache buffers the retry fails fast on the
+            # donation error and surfaces the same way.)
+            obs.counter("serve.step_retries_total").inc()
+            time.sleep(self.step_retry_backoff_s)
+            out = _dispatch()
+        if out is None:
+            self._host_gap_t = None
+            return 0
+        tokens, block_emitted = out
         now = time.monotonic()
-        now_wall = time.time() if traced_batch else None
         self._host_gap_t = now
         self._gap_prefill_s = 0.0
-        with obs.annotate("serve.sched.emit") as ann:
+        rows = len(self._live)
+        with obs.annotate("serve.sched.emit", rows=rows) as ann:
             emitted = self._emit_block(tokens, block_emitted, t0, now,
-                                       t0_wall, now_wall)
-            ann.set(emitted=emitted)
+                                       t0_wall)
+            ann.set(emitted=emitted, retired=rows - len(self._live))
         if not self._live:
             # The block retired the whole batch: the next decode only
             # happens after new admissions, which may be arbitrarily
@@ -1113,14 +1113,13 @@ class Scheduler:
         return emitted
 
     def _emit_block(self, tokens, block_emitted, t0: float, now: float,
-                    t0_wall: Optional[float],
-                    now_wall: Optional[float]) -> int:
+                    t0_wall: Optional[float]) -> int:
         """[holds: _lock] Hand one decoded block to its requests: per
         row the appends, the first-token and per-token latency
         observations, ``on_token``, and retirement (EOS / length /
         deadline / non-finite logits). ``t0``..``now`` is the dispatch
-        window on the monotonic clock, ``*_wall`` its epoch twin when a
-        traced request rode the block. Returns the tokens emitted."""
+        window on the monotonic clock, ``t0_wall`` its start's epoch
+        twin under a run dir. Returns the tokens emitted."""
         horizon = self.engine.cfg.decode_horizon
         dt = now - t0
         obs.histogram("serve.decode.horizon").observe(
@@ -1128,15 +1127,12 @@ class Scheduler:
         speculative = self.engine.spec is not None
         ok = self.engine.step_ok
         emitted = 0
+        # tokens delivered by the block, keyed by their row's emitted
+        # count: what serve.tpot_s is told once the rows are through
+        delivered: Dict[int, int] = {}
         for slot in list(self._live):
             live = self._live[slot]
             e = int(block_emitted[slot])
-            if live.trace_id is not None and t0_wall is not None and e:
-                # One fragment per traced request per dispatch window:
-                # where a slow request's decode time actually went.
-                obs.emit_span("serve.decode_window", t0_wall, now_wall,
-                              trace_id=live.trace_id,
-                              request_id=live.request_id, tokens=e)
             retired = False
             for i in range(e):
                 tok = int(tokens[slot, i])
@@ -1179,11 +1175,7 @@ class Scheduler:
                               ">=": live.ttft_s >= cfg.threshold,
                               }[cfg.op]
                         self.slo_tracker.observe(ok)
-                # Per-token decode latency: the block cost split over
-                # the tokens it produced, observed once per token —
-                # horizon=1 degenerates to the classic one-dt-per-token
-                # and percentiles stay comparable across horizons.
-                obs.histogram("serve.tpot_s").observe(dt / e)
+                delivered[e] = delivered.get(e, 0) + 1
                 if self.on_token is not None:
                     self.on_token(live.request_id, tok)
                 reason = None
@@ -1225,6 +1217,15 @@ class Scheduler:
                 obs.counter("serve.retired_total").inc()
                 self._finish(live, FinishReason.ERROR,
                              error="non-finite logits")
+        # Per-token decode latency: the block cost split over the
+        # tokens a row produced, counted once per delivered token —
+        # horizon=1 degenerates to the classic one-dt-per-token and
+        # percentiles stay comparable across horizons. Rows that
+        # emitted alike share one value, so a block observes once a
+        # distinct count, not once a token or a row.
+        tpot = obs.histogram("serve.tpot_s")
+        for e, n in delivered.items():
+            tpot.observe(dt / e, n)
         obs.counter("serve.tokens_total").inc(emitted)
         return emitted
 
@@ -1238,7 +1239,8 @@ class Scheduler:
             # milestone the stitcher ends the TTFT decomposition at.
             attrs = {"request_id": live.request_id,
                      "finish_reason": reason,
-                     "tokens": len(live.tokens)}
+                     "tokens": len(live.tokens),
+                     "engine": self.engine.engine_id}
             if live.ttft_s is not None:
                 attrs["ttft_s"] = live.ttft_s
             if live.first_token_wall is not None:

@@ -1138,14 +1138,16 @@ class PagedSlotPool:
             [int(b) for b in self.tables_host[slot, :nfull]], take_ref)
 
     # ------------------------------------------------------ write path
-    def prepare_write(self, slot: int, start: int, end: int) -> None:
+    def prepare_write(self, slot: int, start: int, end: int) -> int:
         """Make positions ``[start, end)`` of ``slot`` writable before a
         dispatch that will write them: bind fresh blocks past the bound
         frontier, and copy-on-write any block in the span whose ref
         count exceeds 1 (shared prefix, or a donor's block the cache /
         another request references). Raises :class:`KVBlocksExhausted`
-        (typed backpressure) when no block can be found."""
+        (typed backpressure) when no block can be found. -> the blocks
+        it allocated (0: the span was bound and exclusively owned)."""
         bs = self.block_size
+        taken = 0
         end = min(end, self.blocks_per_slot * bs)
         first = min(start // bs, int(self._bound[slot]))
         last = math.ceil(end / bs)
@@ -1164,6 +1166,7 @@ class PagedSlotPool:
                     self.tables_host[slot, bi] = nb
                     self._release(b)
                     self.cow_copies += 1
+                    taken += 1
                     obs.counter("serve.kv.cow_copies_total").inc()
             else:
                 if bi != self._bound[slot]:
@@ -1173,6 +1176,8 @@ class PagedSlotPool:
                         f"block {bi}")
                 self.tables_host[slot, bi] = self._alloc_block(slot)
                 self._bound[slot] = bi + 1
+                taken += 1
+        return taken
 
     # ------------------------------------------------------- migration
     def export_block_payload(self, slot: int, nblocks: int

@@ -428,3 +428,33 @@ def test_adopt_trace_header_rule():
     obs.adopt_trace_header({}, p)
     assert "trace_id" not in p
     obs.adopt_trace_header({obs.TRACE_HEADER: "abc"}, [1, 2])  # no-op
+
+
+def test_histogram_weighted_observe_is_n_observations():
+    """observe(v, n) is n observations of v under one lock (a decode
+    block's tokens that share a per-token latency, PR 34): count and sum
+    are those of n calls, the reservoir stays bounded and uniform over
+    the whole stream, and the rolling windows count n too."""
+    from nezha_tpu.obs import timeseries
+    obs.enable()
+    store = timeseries.install_windows()
+    try:
+        one, many = (obs_registry.Histogram(n, cap=256)
+                     for n in ("w.one", "w.many"))
+        for v, n in ((1.0, 300), (100.0, 256 * 20), (3.0, 1)):
+            many.observe(v, n)
+            for _ in range(n):
+                one.observe(v)
+        assert many.count == one.count == 300 + 256 * 20 + 1
+        assert many.total == pytest.approx(one.total)
+        assert (many.min, many.max) == (one.min, one.max) == (1.0, 100.0)
+        assert len(many._samples) == 256
+        late = sum(1 for v in many._samples if v == 100.0) / 256
+        assert late == pytest.approx(256 * 20 / many.count, abs=0.08)
+        assert many.percentile(50) == one.percentile(50) == 100.0
+        view = store.view(60.0)["histograms"]
+        assert view["w.many"]["count"] == view["w.one"]["count"]
+        assert view["w.many"]["sum"] == pytest.approx(view["w.one"]["sum"])
+    finally:
+        timeseries.uninstall_windows()
+        obs.disable()

@@ -321,7 +321,7 @@ def test_serving_smoke_program_count_and_artifacts(model_and_vars,
     # Every batched decode step is labeled with its own span.
     with open(os.path.join(run_dir, "spans.jsonl")) as f:
         span_names = {json.loads(ln)["name"] for ln in f if ln.strip()}
-    assert "serve.decode_step" in span_names
+    assert "serve.engine.dispatch" in span_names
     assert "serve.prefill" in span_names
 
     # The schema checker actually pins the serve names: dropping one

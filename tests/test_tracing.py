@@ -173,7 +173,7 @@ def test_telemetry_disabled_serving_adds_zero_spans(tiny_model):
 
 def test_trace_sampled_out_adds_zero_trace_spans(tiny_model, tmp_path):
     """--trace-sample 0: the run still captures the classic spans
-    (serve.prefill, serve.decode_step) but NOT ONE per-request
+    (serve.prefill, serve.engine.dispatch) but NOT ONE per-request
     trace fragment — tracing cost scales with the sample knob."""
     run_dir = str(tmp_path / "run")
     obs.set_trace_sample(0.0)
@@ -187,7 +187,7 @@ def test_trace_sampled_out_adds_zero_trace_spans(tiny_model, tmp_path):
     with open(os.path.join(run_dir, "spans.jsonl")) as f:
         spans = [json.loads(ln) for ln in f if ln.strip()]
     names = {s["name"] for s in spans}
-    assert "serve.prefill" in names and "serve.decode_step" in names
+    assert "serve.prefill" in names and "serve.engine.dispatch" in names
     assert not any(s.get("trace_id") for s in spans)
     assert not names & {"serve.queue_wait", "serve.decode",
                         "serve.decode_window", "serve.prefill.chunk"}
@@ -920,3 +920,212 @@ def test_host_gap_leaves_out_time_inside_engine_prefill(tiny_model,
         obs.disable()
     assert gap["count"] >= 3
     assert 0.0 <= gap["max"] < 0.3
+
+
+# ---------------------------------------------------------------------
+# PR 34: the children of dispatch / wait / prefill, one span record a
+# pass instead of one a row, and the decode windows the report joins.
+_DATA = os.path.join(_ROOT, "tests", "data")
+
+
+def _read_spans(run_dir):
+    with open(os.path.join(run_dir, "spans.jsonl")) as f:
+        return [json.loads(ln) for ln in f if ln.strip()]
+
+
+def test_layer_spans_is_the_one_list():
+    """obs.LAYER_SPANS names every layer span and the span it opens
+    inside; the schema's pins are read from it (from the source: that
+    module must load without jax), and every parent is itself a span."""
+    from nezha_tpu.analysis import telemetry_schema as ts
+    assert ts.layer_spans() == obs.LAYER_SPANS
+    assert len(obs.LAYER_SPANS) == 16
+    assert set(SERVE_LAYER_SPANS + TRAIN_LAYER_SPANS) <= set(obs.LAYER_SPANS)
+    assert all(parent is None or parent in obs.LAYER_SPANS
+               for parent in obs.LAYER_SPANS.values())
+    assert {n for n in obs.LAYER_SPANS if n.startswith("serve.")} \
+        <= ts.PINNED_SPANS
+    assert "serve.decode_step" not in ts.PINNED_SPANS
+
+
+def test_children_nest_inside_their_parents_in_spans_jsonl(tiny_model,
+                                                           tmp_path):
+    """Under a run dir every child record lies inside a record of the
+    parent LAYER_SPANS gives it, and carries its attrs: the blocks a
+    pass bound, the bytes it uploaded, a chunk's width, what a prefill
+    found cached and bound, and the rows an emit saw and retired."""
+    run_dir = str(tmp_path / "run")
+    obs.start_run(run_dir)
+    sched = Scheduler(_engine(tiny_model))
+    # 21 tokens: two chunks through the 16-wide prefill; 9 + 4 tokens
+    # cross the 8-token block edge at position 16, so a pass binds one
+    rids = [sched.submit(Request(prompt=_prompt(21), max_new_tokens=3)),
+            sched.submit(Request(prompt=_prompt(13, salt=1),
+                                 max_new_tokens=5))]
+    sched.run_until_idle()
+    obs.end_run()
+    assert all(sched.results[r].finish_reason == "length" for r in rids)
+    spans = _read_spans(run_dir)
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    children = [n for n, p in obs.LAYER_SPANS.items()
+                if n.startswith("serve.") and p is not None]
+    for child in children:
+        parents = by_name[obs.LAYER_SPANS[child]]
+        assert by_name.get(child), child
+        for c in by_name[child]:
+            assert any(p["t0"] <= c["t0"] and c["t1"] <= p["t1"]
+                       for p in parents), (child, c)
+    passes = len(by_name["serve.engine.dispatch"])
+    for name in ("serve.engine.bind", "serve.engine.tables",
+                 "serve.engine.launch", "serve.engine.wait",
+                 "serve.engine.fetch", "serve.sched.emit"):
+        assert len(by_name[name]) == passes, name
+    binds = [s["attrs"] for s in by_name["serve.engine.bind"]]
+    assert all(1 <= a["rows"] <= 2 and a["bound"] >= 0 for a in binds)
+    # the 13-token prompt's row writes position 16, the first of a block
+    # its prefill did not bind, in one pass
+    assert sum(a["bound"] for a in binds) == 1
+    # two rows of a 64 / 8 = 8-entry int32 table and the two-row mask
+    assert {s["attrs"]["bytes"] for s in by_name["serve.engine.tables"]} \
+        == {2 * 8 * 4 + 2}
+    assert sorted(s["attrs"]["width"]
+                  for s in by_name["serve.engine.prefill.launch"]) \
+        == [8, 16, 16]         # 21 = 16 + a tail of 5 in the 8-wide bucket
+    assert sorted(s["attrs"]["bound"]
+                  for s in by_name["serve.engine.prefill.bind"]) \
+        == [2, 3]               # blocks of 8 under 16 and 16 + 8 positions
+    emits = [s["attrs"] for s in by_name["serve.sched.emit"]]
+    assert sum(a["emitted"] for a in emits) == 8
+    assert sum(a["retired"] for a in emits) == 2
+    assert all(a["rows"] >= a["retired"] for a in emits)
+    assert {s["attrs"]["engine"] for s in by_name["serve.engine.dispatch"]
+            + by_name["serve.engine.wait"]} == {sched.engine.engine_id}
+    assert check_run_dir(run_dir) == []
+
+
+PASS_RECORDS = ["serve.engine.bind", "serve.engine.tables",
+                "serve.engine.launch", "serve.engine.dispatch",
+                "serve.engine.fetch", "serve.engine.wait",
+                "serve.sched.emit", "serve.sched.pass"]
+
+
+@pytest.mark.parametrize("rows", [8, 256])
+def test_a_decode_pass_writes_the_same_span_records_whatever_its_rows(
+        tiny_model, tmp_path, rows):
+    """Registry and sink on, every request traced (the default sample):
+    a decode pass with 8 live rows and one with 256 write the same eight
+    span records, one a layer span, none a row; and serve.tpot_s still
+    counts one observation a token."""
+    run_dir = str(tmp_path / "run")
+    obs.start_run(run_dir)
+    sched = Scheduler(_engine(tiny_model, max_batch_size=256, max_len=32,
+                              queue_capacity=256))
+    for i in range(rows):
+        sched.submit(Request(prompt=_prompt(5, salt=i), max_new_tokens=8))
+    sched.step()                    # admits every row, decodes once
+    assert len(sched._live) == rows
+    assert all(l.trace_id for l in sched._live.values())
+    tpot0 = obs.histogram("serve.tpot_s").count
+    n0 = len(obs.REGISTRY.spans)
+    assert sched.step() == rows
+    written = [s["name"] for s in obs.REGISTRY.spans[n0:]]
+    assert written == PASS_RECORDS
+    assert obs.histogram("serve.tpot_s").count == tpot0 + rows
+    sched.run_until_idle()
+    n_all = len(obs.REGISTRY.spans)
+    obs.end_run()
+    assert len(_read_spans(run_dir)) == n_all
+    t = stitch_run_dir(run_dir)
+    assert len(t) == rows
+    assert all(x["decode_windows"]["count"] == 8 for x in t)
+
+
+def test_buffered_sink_holds_every_record(tmp_path, monkeypatch):
+    """spans.jsonl is written in whole lines, a buffer at a time: nothing
+    reaches the file for a few records, everything is there after
+    end_run(), a burst past the buffer's size flushes itself, and after
+    a silence longer than the flush interval the next record takes the
+    waiting ones with it. Events still flush one by one."""
+    from nezha_tpu.obs import sink as sink_mod
+    run_dir = str(tmp_path / "run")
+    path = os.path.join(run_dir, "spans.jsonl")
+    lines = lambda: sum(1 for _ in open(path))
+    obs.start_run(run_dir)
+    for i in range(5):
+        obs.emit_span("probe.span", 1.0, 2.0, i=i)
+    obs.record_event("watchdog.stall", severity="warning", idle_s=1.0)
+    assert lines() == 0
+    assert sum(1 for _ in open(os.path.join(run_dir, "events.jsonl"))) == 1
+    for i in range(5, 2000):
+        obs.emit_span("probe.span", 1.0, 2.0, i=i)
+    flushed = lines()
+    assert 0 < flushed < 2000       # 64 KB at a time, whole lines
+    assert all(json.loads(ln)["name"] == "probe.span" for ln in open(path))
+    monkeypatch.setattr(sink_mod, "SPAN_FLUSH_SECONDS", 0.05)
+    time.sleep(0.06)
+    obs.emit_span("probe.span", 1.0, 2.0, i=2000)
+    assert lines() == 2001
+    obs.emit_span("probe.span", 1.0, 2.0, i=2001)
+    obs.end_run()
+    assert [json.loads(ln)["attrs"]["i"] for ln in open(path)] \
+        == list(range(2002))
+
+
+def test_joined_decode_windows_equal_the_recorded_per_row_records():
+    """tests/data/serve_spans_pr33.jsonl is the spans.jsonl of a tiny
+    two-slot run recorded with the parent of PR 34, which wrote one
+    serve.decode_window record a traced row a pass beside the pass's own
+    records. With those 23 records taken out, the report's join gives
+    every request the same windows back: as many, each the pass (dispatch
+    start to fetch end) inside the interval the scheduler had clocked
+    around it."""
+    from nezha_tpu.obs.report import stitch_traces, trace_timeline
+    with open(os.path.join(_DATA, "serve_spans_pr33.jsonl")) as f:
+        recorded = [json.loads(ln) for ln in f if ln.strip()]
+    per_row = {}
+    for s in recorded:
+        if s["name"] == "serve.decode_window":
+            per_row.setdefault(s["trace_id"], []).append(s)
+    assert sum(len(v) for v in per_row.values()) == 23
+    kept = [s for s in recorded if s["name"] != "serve.decode_window"]
+    traces = stitch_traces(kept)
+    assert set(traces) == set(per_row) and len(traces) == 5
+    for tid, frags in traces.items():
+        joined = [f for f in frags if f["name"] == "serve.decode_window"]
+        want = sorted(per_row[tid], key=lambda s: s["t0"])
+        assert len(joined) == len(want), tid
+        for got, rec in zip(joined, want):
+            assert got["derived"] and got["trace_id"] == tid
+            assert got["attrs"]["request_id"] == rec["attrs"]["request_id"]
+            assert rec["t0"] <= got["t0"] <= got["t1"] <= rec["t1"]
+            assert rec["dur_s"] - got["dur_s"] < 2e-3
+        t = trace_timeline(tid, frags)
+        assert "serve.decode_window" in t["span_names"]
+        assert t["decode_windows"]["count"] == len(want) == t["tokens"]
+        assert t["decode_windows"]["slowest_s"] == max(
+            g["dur_s"] for g in joined)
+
+
+def test_joined_windows_keep_two_engines_of_one_registry_apart(tiny_model,
+                                                               tmp_path):
+    """Thread-backend replicas share one registry and one spans.jsonl:
+    the pass records say which engine ran them, so a request's windows
+    are its own engine's passes only, however the two interleave."""
+    run_dir = str(tmp_path / "run")
+    obs.start_run(run_dir)
+    a, b = Scheduler(_engine(tiny_model)), Scheduler(_engine(tiny_model))
+    assert a.engine.engine_id != b.engine.engine_id
+    a.submit(Request(prompt=_prompt(5), max_new_tokens=3, request_id="a"))
+    b.submit(Request(prompt=_prompt(7, salt=1), max_new_tokens=6,
+                     request_id="b"))
+    while a.has_work() or b.has_work():
+        a.step()
+        b.step()
+    obs.end_run()
+    t = {x["request_id"]: x for x in stitch_run_dir(run_dir)}
+    assert t["a"]["decode_windows"]["count"] == 3
+    assert t["b"]["decode_windows"]["count"] == 6
+    report = render_trace_report(run_dir)
+    assert "decode 3 pass(es)" in report and "decode 6 pass(es)" in report
