@@ -89,11 +89,12 @@ class Mistral4Config:
     experts_held: Tuple[int, int] = (0, 128)
     vocab_held: int = 131072
     # How :class:`MLAttention` reads the paged latent cache at decode:
-    # "xla" gathers each row's table as one view and attends it composed
-    # (what this model's cell runs); "kernel" is the paged decode
-    # kernel's latent form, "auto" that kernel on a TPU backend
-    # (ServeConfig.decode_impl overrides it, as for GPT-2).
-    decode_impl: str = "xla"
+    # "kernel" is the paged decode kernel's latent form, which walks each
+    # row's own live table entries once; "xla" gathers each row's whole
+    # table as one view and attends it composed; "auto" is the kernel on
+    # a TPU backend (what this model's cell runs) and the composed view
+    # elsewhere (ServeConfig.decode_impl overrides it, as for GPT-2).
+    decode_impl: str = "auto"
 
     # What serve.Engine and the pools read of any model's config.
     @property
@@ -201,11 +202,16 @@ class MLAttention(Module):
     ``kv_a`` / ``kv_a_norm`` / ``kv_b`` / ``o`` are always there. How a
     decode step reads the cache is the config's (``decode_impl``: the
     composed view or the paged kernel's latent form); how a prefill chunk
-    does follows the table's length (``GATHERED_KEYS_MAX``)."""
+    does follows the table's length (``GATHERED_KEYS_MAX``).
+    ``decode_kernel_name`` is the latent kernel call's name in a trace
+    (``latent_decode_attention``'s ``name``): the block that builds this
+    layer hands it down as it hands ``cfg``; nothing here reads it."""
 
-    def __init__(self, cfg, policy: Policy):
+    def __init__(self, cfg, policy: Policy,
+                 decode_kernel_name: str = "nezha_decode_attention_latent"):
         self.cfg = cfg
         self.policy = policy
+        self.decode_kernel_name = decode_kernel_name
         h, heads = cfg.hidden_size, cfg.num_attention_heads
         qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
         self.rotates = not getattr(cfg, "mla_use_nope", False)
@@ -357,7 +363,8 @@ class MLAttention(Module):
                 active, pos + 1, 0)
             o_lat = latent_decode_attention(
                 self.absorbed_query(variables, q_nope, q_rope), pool,
-                lengths, tab, c.kv_lora_rank, c.softmax_scale)
+                lengths, tab, c.kv_lora_rank, c.softmax_scale,
+                name=self.decode_kernel_name)
             return self.expanded_values(variables, o_lat[:, :, 0]), pool
         with jax.named_scope("nezha_mla_decode"):
             return self.absorbed(
@@ -482,7 +489,10 @@ class Block(Module):
     def __init__(self, cfg: Mistral4Config, policy: Policy):
         h = cfg.hidden_size
         self.attn_norm = nn.RMSNorm(h, cfg.rms_norm_eps, policy)
-        self.attn = MLAttention(cfg, policy)
+        # the decode kernel's name in this model's traces: the one the
+        # benchmark's ``kernel.mla_decode_*`` patterns were written for
+        self.attn = MLAttention(cfg, policy,
+                                decode_kernel_name="nezha_mla_decode_paged")
         self.mlp_norm = nn.RMSNorm(h, cfg.rms_norm_eps, policy)
         self.shared = GatedMLP(
             h, cfg.moe_intermediate_size * cfg.n_shared_experts, policy)

@@ -530,14 +530,15 @@ def _paged_call(q, k, v, lengths, block_tables, scale, interpret,
     )(tab, lens, *operands)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "value_width", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "value_width",
+                                             "interpret", "name"))
 def _latent_call(q, pool, lengths, block_tables, scale, value_width,
-                 interpret):
+                 interpret, name):
     """The loop form over ONE leaf (see :func:`_paged_decode_kernel`):
     ``q`` ``[B, H, W]`` is the absorbed query as it meets a cached row,
     ``pool`` ``[N, bs, W]`` the latent rows, ``W`` a whole number of
-    128-lane tiles. -> ``[B, H, 1, value_width]``."""
+    128-lane tiles. -> ``[B, H, 1, value_width]``. ``name`` is the
+    call's name in a trace and nothing else."""
     b, h, w = q.shape
     bs, m = pool.shape[1], block_tables.shape[1]
     c = _pick_block(m, _ENTRIES_PER_STEP)
@@ -564,7 +565,7 @@ def _latent_call(q, pool, lengths, block_tables, scale, value_width,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="nezha_decode_attention_latent",
+        name=name,
     )(jnp.asarray(block_tables, jnp.int32), lens, q, pool)
 
 
@@ -590,7 +591,8 @@ def latent_attention_composed(q, pool, lengths, block_tables, value_width,
 
 
 def latent_decode_attention(q, pool, lengths, block_tables, value_width: int,
-                            scale: float, interpret: Optional[bool] = None):
+                            scale: float, interpret: Optional[bool] = None,
+                            name: str = "nezha_decode_attention_latent"):
     """Single-token decode over a paged LATENT cache (multi-head latent
     attention, absorbed): ``q`` ``[B, H, W]`` is every head's query
     already in the cached row's space (``[q_nope W_uk | q_rope | 0..]``),
@@ -601,7 +603,11 @@ def latent_decode_attention(q, pool, lengths, block_tables, value_width: int,
     -> ``[B, H, 1, value_width]`` (the caller expands it through
     ``W_uv``). A row with ``length == 0`` reads nothing and comes back
     exactly zero. The kernel is the paged decode kernel's loop form with
-    one pool (``nezha_decode_attention_latent``)."""
+    one pool. ``name`` is the ``pallas_call``'s, the one thing of a model
+    that reaches a v5e trace (a ``jax.named_scope`` does not): a LABEL
+    for whoever reads the trace, by which two models' calls of this one
+    body are told apart there (``nezha_decode_attention_latent`` unless
+    the caller says otherwise). Nothing branches on it."""
     b, h, w = q.shape
     if (pool.ndim != 3 or pool.shape[2] != w or w % _LANES
             or not 0 < value_width <= w or block_tables.shape[0] != b):
@@ -610,7 +616,7 @@ def latent_decode_attention(q, pool, lengths, block_tables, value_width: int,
             f"not match q {q.shape}: want [num_blocks, block_size, W] with "
             f"W a multiple of {_LANES} and value_width <= W")
     return _latent_call(q, pool, lengths, block_tables, float(scale),
-                        int(value_width), resolve_interpret(interpret))
+                        int(value_width), resolve_interpret(interpret), name)
 
 
 def ring_entries(window: int, block_size: int) -> int:

@@ -138,33 +138,66 @@ def test_kda_conv_step_kernel_against_its_twin(lanes, chans):
 # (c) the latent form of the paged kernel in interpret mode against the
 # composed view: an inactive row, rows of one token, a row that ends at an
 # entry's edge, the table's last entry, and a table longer than one
-# iteration of 16 entries
-@pytest.mark.parametrize("table", [6, 40])
-def test_latent_decode_kernel_against_the_composed_view(table):
+# iteration of 16 entries. Kimi-Linear's proportions (a 256-lane row whose
+# values are its first 128 lanes: 640 / 512 halved and halved again) and
+# Mistral-Small-4's (a 384-lane row whose values are its first 256 lanes,
+# as served; its block of 64 scaled down to 8 like the others, its table
+# to 32 entries = two iterations of 16): lengths 0, 1, a block's edge and
+# one whole iteration each +-1, the table's end; in float32 under the
+# float32 tolerance, and over the bf16 pool and query the cell holds,
+# where the kernel rounds ``p`` to bf16 before its float32-accumulated
+# output product and the result to bf16: 7.6e-3 off the float32 view of
+# the same values on results up to 3.2 (the composed view in bf16, which
+# normalises first and takes a bf16 product, reads 1.2e-2), against the
+# 1.45 that lengths off by one read.
+_MISTRAL = dict(table=32, w=384, r=256,
+                lens=[0, 1, 7, 8, 9, 127, 128, 129, 256])
+LATENT_CASES = {
+    "table-6": dict(table=6, w=256, r=128, lens=[0, 1, 8, 9, 45, 48]),
+    "table-40": dict(table=40, w=256, r=128, lens=[0, 1, 8, 9, 317, 320]),
+    "mistral-f32": _MISTRAL,
+    "mistral-bf16": dict(_MISTRAL, dtype=jnp.bfloat16, tol=2e-2),
+    # the call's name in a trace is a label: the same bits under any
+    "mistral-bf16-named": dict(_MISTRAL, dtype=jnp.bfloat16, tol=2e-2,
+                               name="nezha_mla_decode_paged"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LATENT_CASES))
+def test_latent_decode_kernel_against_the_composed_view(case):
+    kw = LATENT_CASES[case]
+    table, w, r, bs = kw["table"], kw["w"], kw["r"], 8
+    dtype, tol = kw.get("dtype", jnp.float32), kw.get("tol", TOL)
+    lens = np.asarray(kw["lens"])
     rng = np.random.default_rng(11)
-    b, h, w, r, bs = 6, 4, 256, 128, 8
+    b, h = len(lens), 4
     n = 1 + b * table
-    pool = jnp.asarray(rng.normal(size=(n, bs, w)), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(n, bs, w)), dtype)
     # unowned blocks hold what a freed slot leaves: anything at all
     pool = pool.at[0].set(jnp.nan)
     tab = jnp.asarray(1 + rng.permutation(b * table).reshape(b, table),
                       jnp.int32)
-    lens = np.asarray([0, 1, bs, bs + 1, table * bs - 3, table * bs])
     # entries past a row's length are unbound: scratch
     bound = np.arange(table)[None, :] * bs < lens[:, None]
     tab = jnp.where(bound, tab, 0)
-    q = jnp.asarray(rng.normal(size=(b, h, w)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(b, h, w)), dtype)
+    # the composed view in float32 over the same (rounded) values
     want = latent_attention_composed(
-        q, jnp.nan_to_num(pool), lens, tab, r, 0.125)
+        q.astype(jnp.float32), jnp.nan_to_num(pool).astype(jnp.float32),
+        lens, tab, r, 0.125)
     got = latent_decode_attention(q, pool, lens, tab, r, 0.125)
-    assert got.shape == (b, h, 1, r)
-    assert np.isfinite(np.asarray(got)).all()
+    assert got.shape == (b, h, 1, r) and got.dtype == dtype
+    assert np.isfinite(np.asarray(got, np.float32)).all()
     assert not np.asarray(got[0]).any()             # the inactive row
     assert float(jnp.abs(want[1:]).max()) > 1e-2
-    assert float(jnp.abs(got - want)[1:].max()) < TOL
+    assert float(jnp.abs(got - want)[1:].max()) < tol
     # one token: the output is that row's first ``r`` lanes, every head
     row = pool[tab[1, 0], 0, :r]
     assert float(jnp.abs(got[1, :, 0] - row[None]).max()) < TOL
+    if "name" in kw:
+        named = latent_decode_attention(q, pool, lens, tab, r, 0.125,
+                                        name=kw["name"])
+        assert bool(jnp.array_equal(named, got))
 
 
 def test_latent_decode_refuses_what_it_cannot_walk():

@@ -75,10 +75,12 @@ def _reference_rows(model, variables, seq, first, count):
 
 
 # (a) program vs reference through the real Engine and paged latent pool:
-# as this model is served (the composed decode view, the gathered prefill
-# view of a table of up to 4,096 keys), and on the two paths Kimi-Linear's
-# deployment takes through the same class (the paged kernel's latent form
-# in interpret mode; a table folded a key block at a time)
+# the composed decode view (what the default, "auto", resolves to off a
+# TPU) with the gathered prefill view of a table of up to 4,096 keys (as
+# this model's chunks are served), and the paged kernel's latent form in
+# interpret mode (as its decode steps are served on a TPU) with a table
+# folded a key block at a time (Kimi-Linear's prefill through the same
+# class)
 @pytest.mark.parametrize("paths", ["composed-gathered", "kernel-folded"])
 @pytest.mark.parametrize("n_prompt", [13, 21, 37])
 def test_engine_prefill_and_decode_match_reference(tiny, n_prompt, paths,
@@ -90,7 +92,7 @@ def test_engine_prefill_and_decode_match_reference(tiny, n_prompt, paths,
         monkeypatch.setattr(mistral4, "PREFILL_KEY_BLOCK", 16)
         kw["decode_impl"] = "kernel"
     eng = _engine(model, variables, **kw)
-    assert eng.model.cfg.decode_impl == kw.get("decode_impl", "xla")
+    assert eng.model.cfg.decode_impl == kw.get("decode_impl", "auto")
     rng = np.random.default_rng(n_prompt)
     prompt = rng.integers(0, model.cfg.vocab_held, n_prompt).tolist()
     slot = eng.pool.alloc()

@@ -377,11 +377,14 @@ def test_gpt2_serve_program_has_no_pool_shaped_copy(gpt2_programs, kind,
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
-# ---- Mistral-Small-4's serve programs (PR 26): composed latent attention
-# and, since PR 33, the experts' grouped matmuls as ONE Pallas call a layer
-# (``nezha_moe_experts``, which Mosaic compiles here at the published
-# widths), so what is compiled is the ENGINE's step and 1,024-token prefill
-# programs, one layer deep, with the serving cell's slots, table and pool.
+# ---- Mistral-Small-4's serve programs (PR 26): decode attention through
+# the paged decode kernel's latent form under this model's name for it
+# (``nezha_mla_decode_paged``, PR 35; the composed view before), prefill
+# attention composed over the gathered table, and, since PR 33, the
+# experts' grouped matmuls as ONE Pallas call a layer (``nezha_moe_experts``,
+# which Mosaic compiles here at the published widths), so what is compiled
+# is the ENGINE's step and 1,024-token prefill programs, one layer deep,
+# with the serving cell's slots, table and pool.
 M4_SLOTS, M4_MAX_LEN, M4_BLOCK, M4_CHUNK = 128, 4096, 64, 1024
 
 
@@ -437,6 +440,29 @@ def test_mistral4_step_program_has_no_pool_shaped_copy(mistral4_programs):
     assert not re.findall(r" = " + pool + r"\S* copy\(", text)
     # and the program's fetch carries the expert-load counter
     assert re.search(r"s32\[1,32\]", text.split("ENTRY", 1)[1])
+
+
+def test_mistral4_step_decodes_in_the_paged_kernel_and_gathers_no_view(
+        mistral4_programs):
+    """The mechanism's "does it engage" reading, at compile time (PR 35):
+    the one layer's step program holds exactly ONE ``nezha_mla_decode_paged``
+    call (the name the benchmark's ``kernel.mla_decode_*`` patterns match)
+    with a ``bf16[128,32,1,256]`` result, and none of the composed view's
+    ops: no gather of every table entry of every row
+    (``bf16[8192,64,384]``), no scores over all 4,096 positions
+    (``f32[128,32,4096]``). The prefill program holds no such call (its
+    table of 4,096 keys stays one gathered view)."""
+    step, prefill = mistral4_programs["step"], mistral4_programs["prefill"]
+    calls = [line.strip() for line in step.splitlines()
+             if "tpu_custom_call" in line and re.match(
+                 r"(ROOT )?%?nezha_mla_decode_paged\S* = ", line.strip())]
+    assert len(calls) == 1, calls
+    assert f" = bf16[{M4_SLOTS},32,1,256]" in calls[0]
+    view = M4_SLOTS * (M4_MAX_LEN // M4_BLOCK)
+    assert not re.search(re.escape(f"bf16[{view},{M4_BLOCK},384]"), step)
+    assert not re.search(re.escape(f"f32[{M4_SLOTS},32,{M4_MAX_LEN}]"), step)
+    assert "nezha_decode_attention_latent" not in step
+    assert not re.search(r"nezha_(mla_decode|decode_attention)", prefill)
 
 
 # ---- Sampling sorts the vocabulary only inside a conditional (PR 29): the
