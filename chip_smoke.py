@@ -26,6 +26,15 @@ a seed) goes through the entry points a user calls, in this one process:
   (random weights give nearly flat, bf16-quantized logits, so exact
   argmax ties are routine and bare token equality would flake).
 
+A whole block of the model with four residual streams (``models/xing4.py``,
+its first dense layer at the published widths: hidden 3,584, four float32
+streams, 20 Sinkhorn rounds) then runs INSIDE one compiled program on the
+kernel path (``nezha_mhc_pre`` / ``nezha_mhc_post`` round each sublayer) and
+on the composed path, at a decode step's 32 rows and at a chunk's 256
+tokens: the new streams must agree within ``MHC_BLOCK_TOL`` of their own
+root mean square. The two kernels were right alone and wrong inside a
+program once (PERF.md section 6, PR 36); this is the check that shows it.
+
 Four chips (``--chips 4``) runs only what exists across chips and what it
 is compared with: ``--parallel dp --mesh dp=4`` and ``--parallel zero1``
 against the same steps on one device of the host (per-step loss within
@@ -70,6 +79,9 @@ PROMPT_LENS = (300, 417, 556, 150)   # three of four span several chunks
 SHARED_PREFIX = (256, 40)       # one more request: r0[:256] + 40 new tokens
 
 LOGIT_TOL_ULPS = 8              # bf16 ulps (2**-8) of the largest |logit|
+MHC_PRESET = "full"             # the four-stream model's published widths
+MHC_TOKENS = (32, 256)          # a step's rows; two token tiles of a chunk
+MHC_BLOCK_TOL = 0.05            # of the new streams' root mean square
 LOSS_RTOL = 5e-3                # dp=4 / zero1 vs one device, per step
 
 
@@ -301,6 +313,39 @@ def compare(tag: str, ref: dict, got: dict, streams: dict) -> dict:
             "ref_margin_at_divergence": excused}
 
 
+def mhc_block() -> dict:
+    """The first block of the four-stream model, kernel path against
+    composed path, each ONE compiled program, on the same streams."""
+    import jax
+    import jax.numpy as jnp
+
+    from nezha_tpu.models.xing4 import xing4
+    from nezha_tpu.nn.module import child_vars
+
+    kw = dict(num_hidden_layers=1, vocab_held=256)
+    models = {impl: xing4(MHC_PRESET, decode_impl=impl, **kw)
+              for impl in ("xla", KERNEL_IMPL)}
+    variables = models["xla"].init(jax.random.PRNGKey(0))
+    block = child_vars(variables, "h0")
+    worst = {}
+    for tokens in MHC_TOKENS:
+        toks = jax.random.randint(jax.random.PRNGKey(tokens), (1, tokens),
+                                  0, 256)
+        e = models["xla"].embed.apply(child_vars(variables, "embed"),
+                                      toks)[0].astype(jnp.float32)
+        x = jnp.concatenate([e] * models["xla"].cfg.hc_mult, axis=-1)
+        ref, got = (jax.jit(lambda v, x, blk=m.h[0]: blk.apply(v, x)[0])(
+            block, x) for m in models.values())
+        worst[tokens] = float(jnp.abs(got - ref).max()
+                              / jnp.sqrt((ref * ref).mean()))
+        if not worst[tokens] <= MHC_BLOCK_TOL:
+            raise RuntimeError(
+                f"mhc_block: at {tokens} tokens the kernel path's streams "
+                f"are {worst[tokens]:.3g} of their rms from the composed "
+                f"path's (limit {MHC_BLOCK_TOL})")
+    return {"streams_diff_over_rms": worst, "tol": MHC_BLOCK_TOL}
+
+
 # ----------------------------------------------------------------- phases
 def one_chip(work: str) -> None:
     impls = resolved_impls()
@@ -332,6 +377,7 @@ def one_chip(work: str) -> None:
                 "wire_kernel": wire["tokens"],
                 "wire_composed": wire_ref["tokens"],
                 "direct_kernel": got["tokens"]}))
+    say("mhc_block", **mhc_block())
 
 
 def four_chips(work: str) -> None:

@@ -75,6 +75,10 @@ _SERVE_COUNTERS = {"serve.admitted_total", "serve.rejected_total",
                    # read a layer call (1.0: once). Model-invariant 0s.
                    "serve.moe.expert_visits_total",
                    "serve.moe.experts_touched_total",
+                   # Hyper-connections (PR 36): the maps a model with a
+                   # multi-stream residual path computed (rows or chunk
+                   # tokens x sublayers a call). Model-invariant 0.
+                   "serve.mhc.maps_total",
                    # Sampling (PR 29): decode steps (speculative:
                    # windows) in which some row's nucleus was wider
                    # than the k_max head, so the vocabulary was sorted.
@@ -180,7 +184,11 @@ _SERVE_GAUGES = {"serve.queue_depth", "serve.batch_occupancy",
                  # Serving-side expert layer (PR 26): the busiest held
                  # expert's pairs over the mean, mean over the layers,
                  # of the latest decode step (0 on a dense model).
-                 "serve.moe.load_max_over_mean"}
+                 "serve.moe.load_max_over_mean",
+                 # Hyper-connections (PR 36): the largest |row sum - 1|
+                 # or |column sum - 1| of any H_res the serve programs
+                 # have produced (0 on a one-stream model).
+                 "serve.mhc.sinkhorn_residual_max"}
 _SERVE_HISTOGRAMS = {"serve.ttft_s", "serve.tpot_s",
                      "serve.prefill.bucket_len",
                      # Decode-horizon instruments (PR 5): host time

@@ -179,26 +179,46 @@ def load_gpt2_for_inference(args):
     return model, variables
 
 
+# ``nezha-serve --model`` beside gpt2: name -> (module of ``nezha_tpu.models``,
+# its builder ``build(preset)``, what ``--help`` says of it). A further
+# model is one line here.
+RANDOM_INIT_MODELS = {
+    "mistral_small4": ("mistral4", "mistral_small4",
+                       "Mistral-Small-4: latent attention, dropless experts"),
+    "k_exaone": ("exaone_moe", "k_exaone",
+                 "K-EXAONE: grouped-query heads, window layers in a ring of "
+                 "blocks beside global layers, sigmoid-routed experts"),
+    "kimi_linear": ("kimi_linear", "kimi_linear",
+                    "Kimi-Linear: linear-attention layers with a recurrent "
+                    "state a slot beside latent-attention layers, "
+                    "sigmoid-routed experts"),
+    "xing4": ("xing4", "xing4",
+              "Xing4.0: four residual streams mixed by manifold-constrained "
+              "hyper-connections round latent attention and sigmoid-routed "
+              "experts"),
+}
+SERVED_MODELS = ("gpt2", *RANDOM_INIT_MODELS)
+
+
 def load_model_for_inference(args):
     """(model, variables) for ``nezha-serve --model``: GPT-2 from any of
-    its three weight sources, or Mistral-Small-4 / K-EXAONE / Kimi-Linear
-    with random weights (the one source they have: no checkpoint converter exists
-    for them)."""
-    if getattr(args, "model", "gpt2") == "gpt2":
+    its three weight sources, or one of ``RANDOM_INIT_MODELS`` with random
+    weights (the one source they have: no checkpoint converter exists for
+    them)."""
+    name = getattr(args, "model", "gpt2")
+    if name == "gpt2":
         return load_gpt2_for_inference(args)
     if not getattr(args, "random_init", False):
         raise SystemExit(
-            f"--model {args.model} takes --random-init only (no "
+            f"--model {name} takes --random-init only (no "
             f"checkpoint or Hugging Face converter exists for it)")
+    import importlib
+
     import jax
 
-    if args.model == "k_exaone":
-        from nezha_tpu.models.exaone_moe import k_exaone as build
-    elif args.model == "kimi_linear":
-        from nezha_tpu.models.kimi_linear import kimi_linear as build
-    else:
-        from nezha_tpu.models.mistral4 import mistral_small4 as build
-    model = build(args.model_preset)
+    module, builder, _ = RANDOM_INIT_MODELS[name]
+    model = getattr(importlib.import_module(f"nezha_tpu.models.{module}"),
+                    builder)(args.model_preset)
     # model.init builds the tree leaf by leaf in the policy's parameter
     # dtype (bf16 at the full preset: 2 bytes a parameter on the device).
     return model, model.init(jax.random.PRNGKey(args.seed))

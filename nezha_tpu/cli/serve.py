@@ -71,6 +71,8 @@ from typing import Optional
 
 
 def build_parser() -> argparse.ArgumentParser:
+    from nezha_tpu.cli.common import RANDOM_INIT_MODELS, SERVED_MODELS
+
     p = argparse.ArgumentParser(prog="nezha-serve", description=__doc__)
     src = p.add_mutually_exclusive_group(required=True)
     src.add_argument("--ckpt-dir",
@@ -79,25 +81,16 @@ def build_parser() -> argparse.ArgumentParser:
                      help="Hugging Face GPT2LMHeadModel directory")
     src.add_argument("--random-init", action="store_true",
                      help="fresh random weights (smoke/benchmark runs)")
-    p.add_argument("--model",
-                   choices=["gpt2", "mistral_small4", "k_exaone",
-                            "kimi_linear"],
-                   default="gpt2",
+    p.add_argument("--model", choices=list(SERVED_MODELS), default="gpt2",
                    help="the architecture served: gpt2 (every option); "
-                        "mistral_small4 (Mistral-Small-4: latent "
-                        "attention, dropless experts); k_exaone "
-                        "(K-EXAONE: grouped-query heads, window layers "
-                        "in a ring of blocks beside global layers, "
-                        "sigmoid-routed experts); kimi_linear "
-                        "(Kimi-Linear: linear-attention layers with a "
-                        "recurrent state a slot beside latent-attention "
-                        "layers, sigmoid-routed experts). The last "
-                        "three: --random-init only, --model-preset full "
-                        "= one chip's share at the published widths, "
-                        "bf16 parameters; refused with --mesh, "
-                        "--kv-dtype int8, --kv-host-blocks, "
-                        "--speculative, KV migration and peer pulls; "
-                        "k_exaone and kimi_linear also with "
+                        + "; ".join(f"{name} ({what})" for name, (_, _, what)
+                                    in RANDOM_INIT_MODELS.items())
+                        + ". All but gpt2: --random-init only, "
+                        "--model-preset full = one chip's share at the "
+                        "published widths, bf16 parameters; refused with "
+                        "--mesh, --kv-dtype int8, --kv-host-blocks, "
+                        "--speculative, KV migration and peer pulls; a "
+                        "model with window or state layers also with "
                         "--prefix-cache on (a trie hit would need the "
                         "window layers' last tokens, or the recurrent "
                         "state at the hit's boundary, as a snapshot)")

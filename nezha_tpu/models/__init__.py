@@ -16,6 +16,7 @@ _LAZY = {
     "k_exaone": "exaone_moe",
     "KimiLinear": "kimi_linear", "KimiLinearConfig": "kimi_linear",
     "kimi_linear": "kimi_linear",
+    "Xing4": "xing4", "Xing4Config": "xing4", "xing4": "xing4",
     "generate": "generate", "init_cache": "generate",
     "gpt2_from_hf": "convert", "bert_from_hf": "convert",
     "gpt2_params_from_hf": "convert", "gpt2_params_to_hf": "convert",

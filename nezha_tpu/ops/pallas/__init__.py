@@ -21,6 +21,11 @@ are Mosaic/Pallas kernels tiled for MXU/VPU and VMEM:
   in `d_ff` tiles; row tile, `d_ff` tile and window from the static shapes
   by `tile_sizes`; float32 accumulation, `h` rounded to the compute dtype
   before the down projection).
+- `mhc_pre` / `mhc_post`: the two halves of a manifold-constrained
+  hyper-connection round a sublayer (`mhc.py`, `nezha_mhc_pre` /
+  `nezha_mhc_post`: a token's streams read once for its three maps, with
+  Sinkhorn's rounds in registers, and the sublayer's input; then the
+  streams rewritten in place).
 - `fused_layer_norm`: single-pass normalization on VMEM rows.
 
 The shared online-softmax scratch core lives in `common.py`. All kernels
@@ -45,6 +50,7 @@ from nezha_tpu.ops.pallas.kda import (
 )
 from nezha_tpu.ops.pallas.flash_attention import flash_attention
 from nezha_tpu.ops.pallas.layer_norm import fused_layer_norm
+from nezha_tpu.ops.pallas.mhc import mhc_post, mhc_pre
 from nezha_tpu.ops.pallas.moe_experts import (
     moe_experts,
     moe_experts_reference,
@@ -60,6 +66,6 @@ __all__ = ["flash_attention", "flash_decode_attention",
            "kda_chunked", "kda_conv_step", "kda_conv_step_reference",
            "kda_decode", "kda_decode_reference",
            "kda_recurrent", "latent_attention_composed",
-           "latent_decode_attention", "moe_experts",
+           "latent_decode_attention", "mhc_post", "mhc_pre", "moe_experts",
            "moe_experts_reference", "paged_attention_composed",
            "ring_entries"]

@@ -607,6 +607,14 @@ class Engine:
         # a serving-side expert layer; None otherwise). It rides the
         # step's existing ok / tok / emitted fetch.
         self.last_expert_load: Optional[np.ndarray] = None
+        # A model whose residual path is mixed by maps declares how many
+        # it computes a token (``mhc_sublayers``) and how far a pass's
+        # worst H_res lay from doubly stochastic (``mhc_residual``): the
+        # serve programs return that scalar last and this is its running
+        # maximum (0.0 for any other model).
+        self._mhc_sublayers = _mhc_sublayers(model)
+        self._mhc_pending: List[Any] = []
+        self.mhc_residual_max = 0.0
         # Tokens the most recent prefill's compiled chunks pushed
         # through the target model (set per prefill call), and how many
         # chunk dispatches it took (the sequence-sharded engine's
@@ -975,6 +983,12 @@ class Engine:
                     # completion.
                     out, err = out[:-1], out[-1]
                     qerrs.append(err)
+                if self._mhc_sublayers:
+                    # the chunk's worst H_res, read at the next step
+                    out, res = out[:-1], out[-1]
+                    self._mhc_pending.append(res)
+                    obs.counter("serve.mhc.maps_total").inc(
+                        width * self._mhc_sublayers)
                 (self.pool.caches, self.last_logits, self.positions,
                  self.keys, self.temps, self.top_ks, self.top_ps,
                  self.eos_ids, self.budgets) = out
@@ -1120,6 +1134,7 @@ class Engine:
             # already in flight instead of paying the full sync
             # serially.
             _start_host_copies(tok, emitted, ok, full_sorts, *load)
+            mhc = load.pop() if self._mhc_sublayers else None
         self.pool.caches = caches
         if faults.enabled():
             last = faults.corrupt(
@@ -1138,6 +1153,8 @@ class Engine:
                 if load:
                     self.last_expert_load = np.asarray(load[0])
                     visits_h = np.asarray(load[1])
+                if mhc is not None:
+                    self._record_mhc(int(np.count_nonzero(active)), mhc)
         obs.counter("serve.sampling.full_sort_steps_total").inc(full_sorts_h)
         if load:
             self._record_expert_load(int(np.count_nonzero(active)), visits_h)
@@ -1149,6 +1166,19 @@ class Engine:
         self.host_positions += emitted_h.astype(np.int64)
         self.host_budgets -= emitted_h.astype(np.int64)
         return tok_h, emitted_h
+
+    def _record_mhc(self, tokens: int, residual) -> None:
+        """The residual path's maps of one step (``tokens`` rows) and of
+        the prefill chunks since the step before it (their tokens were
+        counted at dispatch; their residuals, device scalars that have
+        long been computed by now, are read here so that no prefill
+        waits for one)."""
+        pending, self._mhc_pending = self._mhc_pending, []
+        worst = max(float(np.asarray(r)) for r in (residual, *pending))
+        self.mhc_residual_max = max(self.mhc_residual_max, worst)
+        obs.counter("serve.mhc.maps_total").inc(tokens * self._mhc_sublayers)
+        obs.gauge("serve.mhc.sinkhorn_residual_max").set(
+            self.mhc_residual_max)
 
     def _record_expert_load(self, rows: int, visits: np.ndarray) -> None:
         """The expert layer's counters for one step (registry
@@ -1315,6 +1345,15 @@ def _pool_leaves(new_rows, caches):
     return [{kk: r[kk] for kk in pool} for r, pool in zip(new_rows, caches)]
 
 
+def _mhc_sublayers(model) -> int:
+    """Maps a token passes in a model whose residual path is mixed by
+    hyper-connections (its ``mhc_sublayers``; such a model also has
+    ``mhc_residual(states)``), 0 for any other: the ONE attribute that
+    decides whether the serve programs return the residual and whether
+    the engine reads it."""
+    return int(getattr(model, "mhc_sublayers", 0))
+
+
 def _build_prefill(model, width: int, quantized: bool = False,
                    groups=None):
     def prefill(variables, caches, tables, tokens, length, slot, pos,
@@ -1370,6 +1409,8 @@ def _build_prefill(model, width: int, quantized: bool = False,
                set_row(top_ps, top_p),
                set_row(eos_ids, eos_id),
                set_row(budgets, budget))
+        if _mhc_sublayers(model):       # last: Engine.prefill pops it
+            out += (model.mhc_residual(states),)
         return out + (qerr,) if quantized else out
 
     return prefill
@@ -1430,6 +1471,9 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int, groups=None):
         load = model.expert_load(states)
         if load is not None:    # and the experts' kernel's (visits, touched)
             load = (load, model.expert_visits(states))
+        # ... and, of a model whose residual path is mixed by maps, how
+        # far this pass's worst H_res lay from doubly stochastic
+        mhc = model.mhc_residual(states) if _mhc_sublayers(model) else None
         new_caches = _pool_leaves(new_rows, caches)
         row_logits = logits[:, -1, :]
         ok = jnp.where(emit, ok & finite_rows(row_logits), ok)
@@ -1442,7 +1486,7 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int, groups=None):
                 jnp.where(act, row_logits, last_logits),
                 jnp.where(emit, positions + 1, positions),
                 jnp.where(act, next_keys, keys),
-                done, ok, emitted), (tok, full_sort, load)
+                done, ok, emitted), (tok, full_sort, load, mhc)
 
     def step(variables, caches, tables, last_logits, positions, active,
              keys, temps, top_ks, top_ps, eos_ids, budgets):
@@ -1459,21 +1503,25 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int, groups=None):
         if horizon == 1:
             # Inline, not a length-1 scan: the default must stay
             # bit-identical to the classic single-token step program.
-            carry, (tok, full_sort, load) = scan_body(init, None)
+            carry, (tok, full_sort, load, mhc) = scan_body(init, None)
             tok_block = tok[:, None]
         else:
-            carry, (toks, full_sort, loads) = lax.scan(
+            carry, (toks, full_sort, loads, mhcs) = lax.scan(
                 scan_body, init, None, length=horizon)
             tok_block = jnp.transpose(toks, (1, 0))        # [H,B]->[B,H]
+            # counts add up over the block's steps; the residual is the
+            # worst of them
             load = None if loads is None else tuple(
                 x.sum(axis=0) for x in loads)
+            mhc = None if mhcs is None else mhcs.max(axis=0)
         caches, last_logits, positions, keys, done, ok, emitted = carry
         # How many of the block's steps sorted the vocabulary (sampling's
         # wide-nucleus branch): 0 or 1 at horizon 1.
         full_sorts = jnp.sum(full_sort, dtype=jnp.int32)
         out = (tok_block, emitted, ok, full_sorts, caches, last_logits,
                positions, keys, jnp.maximum(budgets - emitted, 0))
-        return out if load is None else out + load
+        # Engine.step unpacks the tail: the expert counts, then the residual
+        return out + (load or ()) + (() if mhc is None else (mhc,))
 
     return step
 

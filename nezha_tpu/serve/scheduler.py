@@ -235,6 +235,11 @@ def register_serve_instruments() -> None:
     obs.counter("serve.moe.expert_visits_total")
     obs.counter("serve.moe.experts_touched_total")
     obs.gauge("serve.moe.load_max_over_mean")
+    # The residual path's maps (a model with hyper-connections only; the
+    # engine records them a step): maps computed (rows x sublayers), and
+    # how far the worst H_res so far lay from doubly stochastic.
+    obs.counter("serve.mhc.maps_total")
+    obs.gauge("serve.mhc.sinkhorn_residual_max")
     # Decode steps whose sampling sorted the whole vocabulary (a row's
     # nucleus wider than the k_max head; serve/sampling.py). 0 for
     # top-k traffic and for peaked top-p traffic.

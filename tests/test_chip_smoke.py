@@ -94,13 +94,16 @@ def tiny_smoke(monkeypatch):
                             "--kv-block-size", "8",
                             "--kv-num-blocks", "64"),
             "PROMPT_LENS": (20, 27, 37, 10),
-            "SHARED_PREFIX": (16, 4)}.items():
+            "SHARED_PREFIX": (16, 4),
+            "MHC_PRESET": "tiny",
+            "MHC_TOKENS": (8, 24)}.items():
         monkeypatch.setattr(chip_smoke, name, value)
     return chip_smoke
 
 
 @pytest.mark.parametrize("phases,expected", [
-    ("one_chip", ["attention", "train", "serve_bf16", "serve_int8"]),
+    ("one_chip", ["attention", "train", "serve_bf16", "serve_int8",
+                  "mhc_block"]),
     ("four_chips", ["train_4", "serve_bf16_mesh4", "serve_int8_mesh4"]),
 ])
 def test_chip_smoke_phases_rehearse_on_cpu(tiny_smoke, devices8, tmp_path,
@@ -121,6 +124,10 @@ def test_chip_smoke_phases_rehearse_on_cpu(tiny_smoke, devices8, tmp_path,
             agreed = row["tokens_agreeing_of_5"]
             assert all(len(v) == 5 for v in agreed.values())   # 5 requests
             assert max(row["max_logit_diff"].values()) <= row["logit_tol"]
+    if phases == "one_chip":
+        # float32 at the tiny preset, the kernels through the interpreter
+        assert max(facts["mhc_block"]["streams_diff_over_rms"].values()) \
+            < 1e-4
     if phases == "four_chips":
         assert facts["train_4"]["dp4"]["placement"]["batch_devices"] == 4
         assert facts["train_4"]["zero1"]["placement"][

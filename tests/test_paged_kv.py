@@ -733,7 +733,13 @@ def test_nezha_bench_gates_against_committed_baseline(tmp_path):
     base["by_platform"]["tpu"] = {"closed_loop_horizon_sweep": {
         "by_horizon": {"1": {"tokens_per_sec": 123456.0}}}}
     json.dump(base, open(sb, "w"))
-    rec = nb.run(nb.build_parser().parse_args(args))
+    # (up to three tries: two back-to-back ms-scale timings beside a test
+    # that compiles a full-size program for a described chip can differ
+    # fivefold; the mechanism is what is pinned here, see above)
+    for _ in range(3):
+        rec = nb.run(nb.build_parser().parse_args(args))
+        if rec["ok"]:
+            break
     assert rec["ok"] and rec["platform"] == "cpu"
     assert rec["vs_baseline"]["serving"]  # gated something
     # Cook the cpu baseline 10x up -> regression detected, exit 1.
