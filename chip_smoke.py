@@ -237,6 +237,8 @@ def serve_direct(argv: list, prompts: list) -> dict:
 
     scheduler, _, _ = cli._build_stack(cli.build_parser().parse_args(argv))
     engine = scheduler.engine
+    # stepped by hand below: each call returns the block it launched
+    engine.overlap_blocks(False)
     slots = []
     for prompt in prompts:
         slots.append(engine.pool.alloc())

@@ -79,6 +79,15 @@ _SERVE_COUNTERS = {"serve.admitted_total", "serve.rejected_total",
                    # multi-stream residual path computed (rows or chunk
                    # tokens x sublayers a call). Model-invariant 0.
                    "serve.mhc.maps_total",
+                   # One decode block in flight (PR 37): blocks launched
+                   # while the one before them was in flight, forced
+                   # drains of the block in flight (Engine.settle), and
+                   # non-empty row results dropped because the row's
+                   # request had changed meanwhile. Mode-invariant: a
+                   # speculative engine (launch-then-collect) reports 0s.
+                   "serve.engine.blocks_overlapped_total",
+                   "serve.engine.settles_total",
+                   "serve.engine.stale_rows_total",
                    # Sampling (PR 29): decode steps (speculative:
                    # windows) in which some row's nucleus was wider
                    # than the k_max head, so the vocabulary was sorted.

@@ -388,9 +388,13 @@ def _attrs(rec: dict) -> dict:
 def decode_passes(spans: List[dict]) -> Dict[tuple, List[tuple]]:
     """The decode passes of a capture: ``(source dir, engine) -> [(t0,
     t1, rows)]`` sorted by start, each a ``serve.engine.dispatch`` record
-    through the end of the same engine's next ``serve.engine.wait`` (the
-    block's fetch): the window a request that rode the pass waited
-    through. A dispatch no wait follows (the step raised) is no pass."""
+    through the end of the ``serve.engine.wait`` that fetched the block it
+    launched: the window a request that rode the block waited through.
+    The two halves of a block carry its ``seq`` (with one block in
+    flight the wait that FOLLOWS a dispatch fetches the block before
+    it); records without one, a capture from before PR 37 or the
+    speculative step, pair a dispatch with the same engine's next wait.
+    A dispatch no wait follows (the step raised) is no pass."""
     by_engine: Dict[tuple, List[dict]] = {}
     for rec in spans:
         if rec.get("name") in ("serve.engine.dispatch",
@@ -400,15 +404,22 @@ def decode_passes(spans: List[dict]) -> Dict[tuple, List[tuple]]:
     out: Dict[tuple, List[tuple]] = {}
     for key, recs in by_engine.items():
         recs.sort(key=lambda r: r.get("t0", 0.0))
-        passes, opened = [], None
+        passes, opened, by_seq = [], None, {}
         for rec in recs:
+            seq = _attrs(rec).get("seq")
             if rec["name"] == "serve.engine.dispatch":
-                opened = rec
-            elif opened is not None:
-                passes.append((opened.get("t0", 0.0), rec.get("t1", 0.0),
-                               _attrs(opened).get("rows")))
+                if seq is None:
+                    opened = rec
+                else:
+                    by_seq[seq] = rec
+                continue
+            launch = opened if seq is None else by_seq.pop(seq, None)
+            if launch is not None:
+                passes.append((launch.get("t0", 0.0), rec.get("t1", 0.0),
+                               _attrs(launch).get("rows")))
+            if seq is None:
                 opened = None
-        out[key] = passes
+        out[key] = sorted(passes)
     return out
 
 

@@ -47,11 +47,27 @@ serve stack replaces the batch lifecycle with a slot lifecycle:
   outputs (greedy bit-identical; sampled via lossless rejection
   sampling), the draft's KV mirroring the target pool's slot
   lifecycle.
+  ``Engine.step`` has two forms. A direct caller gets
+  launch-then-collect: the call returns the block it launched. The
+  scheduler, the one caller that can hand a block's tokens to whoever
+  held its rows a call earlier, asks for ONE BLOCK IN FLIGHT
+  (``Engine.overlap_blocks``): a call launches block k first and then
+  collects block k-1, so the host's work of a pass runs while the
+  device runs a block (the speculative step, whose window width the
+  host cannot know ahead, stays launch-then-collect).
+  ``Engine.settle()`` brings the block in flight home without
+  launching another, for whoever is about to read or move a live row's
+  state.
 - ``scheduler``: bounded FIFO admission with backpressure, per-request
-  deadlines, and the iteration loop (admit -> decode one block for all
-  active rows -> retire on EOS / max-new-tokens / deadline, freeing
-  slots for waiters; retire/admit and deadline checks run once per
-  horizon). Failure is request-scoped: a prefill exception or
+  deadlines, and the iteration loop. A pass, in order: admit waiters ->
+  LAUNCH block k for the rows held now -> COLLECT block k-1 -> emit its
+  tokens to the requests that held its rows at its launch -> retire on
+  EOS / max-new-tokens / deadline -> admit into the freed slots
+  (retire/admit and deadline checks run once per horizon; a token
+  reaches its client one block after it was sampled). Whatever reads
+  or moves a live row's state outside that order (preemption, the
+  migration calls, a drain, a block-exhaustion victim, the retry after
+  a failed call) settles first. Failure is request-scoped: a prefill exception or
   NaN/inf logit burst retires only the affected request
   (``FinishReason.ERROR``) while the batch keeps decoding, and a step
   crash gets one bounded retry — provable on demand through the

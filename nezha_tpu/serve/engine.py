@@ -66,6 +66,44 @@ would show up as an extra miss, and tests pin the count at
 ``1 + len(prefill_buckets)`` with misses frozen after warmup (a bucket
 program compiles the first time a prompt lands in its bucket).
 
+**One decode block in flight.** Nothing the host does between two
+blocks needs the first one's tokens: sampling, EOS, the budget and the
+health mask are inside the step program, ``positions`` / ``keys`` /
+``budgets`` / ``last_logits`` stay on the device from one block to the
+next, and a prefill dispatches without a sync. The one sync of a pass is
+the fetch of the block's tokens. So ``step`` has two halves,
+:meth:`Engine._launch` (stage the tables and the mask, run the program,
+rebind the engine's device state to its outputs) and
+:meth:`Engine._collect` (the fetch), and two forms:
+
+- *launch-then-collect*, what any direct caller gets: a call returns the
+  block it launched;
+- *overlapped*, after :meth:`Engine.overlap_blocks`: a call launches
+  block k FIRST and then collects block k-1, so the host's turnaround of
+  a pass (emit, retire, admit, bind, upload, launch) runs while the
+  device runs a block instead of beside an idle one. The first call
+  after a drain returns an all-pad block; a call whose rows have no
+  budget left launches nothing and only collects;
+  :meth:`Engine.settle` collects what is in flight without launching,
+  for whoever is about to read or move a live row's state. Only a
+  caller that can hand a block's tokens to whoever held its rows ONE
+  CALL EARLIER can use this form: the :class:`Scheduler`, which asks
+  for it at construction and gets it where the engine can state a row's
+  write window before the block returns, the classic step at any
+  ``decode_horizon`` (the speculative step stays launch-then-collect).
+
+What makes the late block safe: the host mirrors advance at the LAUNCH
+by what a row emits if nothing stops it (``min(horizon, budget)``, the
+window just bound) and are corrected at the collect by the shortfall, so
+block k's windows are bound from mirrors that include block k-1; a row
+that emitted its EOS carries a budget of ZERO out of its block (``done``
+starts from zeros in the next one), so the block launched before the
+host read the EOS emits nothing for it and writes the scratch block, as
+for a row whose budget ran out; device programs run in launch order, so a
+freed slot's blocks may be bound again at once. Device state after k
+launches is what it is after k launch-then-collect steps; only the
+tokens' delivery is one call later.
+
 All per-request scalars cross into the programs as 0-d ARRAYS, never
 Python numbers — the executor's signature (and jax.jit's) would
 otherwise key on the literal value and recompile per request.
@@ -461,6 +499,25 @@ def self_draft(model, variables, num_layers: Optional[int] = None):
     return draft, {"params": dparams, "state": variables.get("state", {})}
 
 
+@dataclasses.dataclass
+class _Block:
+    """A launched decode block, until it is collected: what the host
+    keeps of the launch, and the device arrays its fetch will read."""
+
+    seq: int                # its number among the engine's launches
+    rows: int               # active rows at the launch
+    # [B] bool, the rows whose host mirrors this block still answers
+    # for: the launch's mask, less every slot prefilled since
+    mirrored: np.ndarray
+    bound: np.ndarray       # [B] what the mirrors advanced by at launch
+    tok: Any
+    emitted: Any
+    ok: Any
+    full_sorts: Any
+    load: List[Any]         # (pairs, visits) of an expert layer, or []
+    mhc: Any                # the residual scalar, or None
+
+
 class Engine:
     """Device-side serving state + the frozen program set.
 
@@ -474,9 +531,12 @@ class Engine:
     (however many chunks that takes — including the row's EOS id and
     new-token budget, which become device state) and ``step(active)``
     to decode one BLOCK of up to ``decode_horizon`` tokens for every
-    row and hand the ``[B, H]`` batch back to the host along with
-    per-row emitted counts. ``step_calls`` counts host dispatches of
-    the step program — the denominator of the dispatch-per-token
+    row and hand a ``[B, H]`` batch back to the host along with
+    per-row emitted counts: the block the call launched, or, for the
+    caller that asked for one block in flight
+    (:meth:`overlap_blocks`; module docstring), the block the call
+    BEFORE it launched. ``step_calls`` counts host dispatches of the
+    step program — the denominator of the dispatch-per-token
     amortization this engine exists to improve.
     """
 
@@ -602,6 +662,19 @@ class Engine:
         # decode_horizon tokens for every row) — tests assert the
         # dispatch-per-token amortization against this.
         self.step_calls = 0
+        # One block in flight (module docstring): whether ``step``
+        # returns the block BEFORE the one it launches (the scheduler
+        # asks for it, :meth:`overlap_blocks`), the launched block not
+        # collected yet, and the mechanism's three ledgers beside
+        # ``step_calls`` (the obs counters of the same names only count
+        # inside a run; these always do): blocks launched while another
+        # was in flight, forced drains (:meth:`settle`), and row results
+        # the scheduler dropped because the row's request had changed.
+        self._overlap = False
+        self._in_flight: Optional[_Block] = None
+        self.blocks_overlapped = 0
+        self.settles = 0
+        self.stale_rows = 0
         # [layers, experts held] int32 from the latest step, on the host:
         # the token-expert pairs each held expert computed (models with
         # a serving-side expert layer; None otherwise). It rides the
@@ -911,6 +984,10 @@ class Engine:
             self.pool.count_prefix_hit()
         self.host_positions[slot] = n
         self.host_budgets[slot] = budget
+        if self._in_flight is not None:
+            # the block in flight was launched for the slot's last
+            # holder: its shortfall is not this request's
+            self._in_flight.mirrored[slot] = False
         obs.counter("serve.prefill.chunks_total").inc(len(chunks))
         # Re-pin per call, not just at init: benchmark harnesses reset
         # the registry after warmup, and the impl label must survive
@@ -1107,16 +1184,102 @@ class Engine:
         ``tokens[r, :emitted[r]]``; everything past its count (overshoot
         after EOS / budget / a mid-block NaN freeze, or all H columns of
         an inactive row) is pad and must be ignored. After the call
-        :attr:`step_ok` holds a ``[B_max]`` bool health mask: False
-        where a row's logits went non-finite at any scan step (only
-        meaningful for rows the caller knows are active) — such a row's
-        pre-burst tokens are still counted in ``emitted``."""
+        :attr:`step_ok` holds the returned block's ``[B_max]`` bool
+        health mask: False where a row's logits went non-finite at any
+        scan step (only meaningful for rows the caller knows are
+        active) — such a row's pre-burst tokens are still counted in
+        ``emitted``.
+
+        Launch-then-collect (any direct caller): the block returned is
+        the one this call launched. Overlapped (after
+        :meth:`overlap_blocks`): this call launches block k FIRST and
+        returns block k-1, launched by the call before it with THAT
+        call's mask; with nothing in flight it returns an all-pad block
+        with ``emitted`` zero. A call whose rows can emit nothing, every
+        host budget spent, launches nothing and only collects."""
         faults.point("serve.step")
-        self.step_calls += 1
         if self.spec is not None:
+            self.step_calls += 1
             return self._spec_step(active)
-        with obs.annotate("serve.engine.dispatch",
-                          engine=self.engine_id) as ann:
+        prev = self._in_flight
+        if prev is not None and not (
+                self.host_budgets[np.asarray(active, bool)] > 0).any():
+            # Overlapped, and the block in flight spends the last budget
+            # of every row in the mask: a block launched now could emit
+            # nothing (the device's budget never exceeds the mirror).
+            self._in_flight = None
+            return self._collect(prev)
+        self.step_calls += 1
+        block = self._launch(active)
+        if not self._overlap:
+            return self._collect(block)
+        self._in_flight = block
+        if prev is None:
+            self.step_ok = np.ones((self.cfg.max_batch_size,), bool)
+            return (np.full((self.cfg.max_batch_size,
+                             self.cfg.decode_horizon), self.cfg.pad_id,
+                            np.int32),
+                    np.zeros((self.cfg.max_batch_size,), np.int32))
+        self.blocks_overlapped += 1
+        obs.counter("serve.engine.blocks_overlapped_total").inc()
+        return self._collect(prev)
+
+    def overlap_blocks(self, on: bool = True) -> bool:
+        """Switch :meth:`step` to its overlapped form, where the engine
+        can state a row's write window before the block returns: the
+        classic step at any ``decode_horizon`` (a speculative window's
+        width depends on acceptance, so that engine stays
+        launch-then-collect). For the one caller that can consume a
+        block one call late, the :class:`Scheduler`, which asks at
+        construction; ``on=False`` takes it back for whoever then steps
+        the engine of a built stack by hand (nothing may be in flight).
+        -> whether ``step`` is overlapped now."""
+        if self._in_flight is not None:
+            raise RuntimeError("a block is in flight: settle() first")
+        self._overlap = bool(on) and self.spec is None
+        return self._overlap
+
+    @property
+    def overlapped(self) -> bool:
+        """Whether :meth:`step` returns the block BEFORE the one it
+        launches (:meth:`overlap_blocks`)."""
+        return self._overlap
+
+    @property
+    def in_flight(self) -> bool:
+        """Whether a launched block has not been collected yet."""
+        return self._in_flight is not None
+
+    def settle(self, reason: str = "drain"
+               ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+        """Collect the block in flight WITHOUT launching another, and
+        return its ``(tokens, emitted)``; ``None`` when nothing is in
+        flight. For whoever is about to read or move a live row's state
+        (preemption, migration, a drain, the retry after a failed
+        call): after it the host mirrors, ``step_ok`` and every token
+        are what they are after a launch-then-collect step. ``reason``
+        says why, on the collect's ``serve.engine.wait`` span."""
+        block, self._in_flight = self._in_flight, None
+        if block is None:
+            return None
+        self.settles += 1
+        obs.counter("serve.engine.settles_total").inc()
+        return self._collect(block, settle=reason)
+
+    def _launch(self, active: np.ndarray) -> "_Block":
+        """Stage and launch one block, and rebind the engine's device
+        state to its outputs: after k launches that state is what it is
+        after k steps, whether or not any block has been collected. The
+        host mirrors advance HERE, by what each row emits if nothing
+        stops it (``min(horizon, budget)``, the window just bound), so
+        the next launch binds from mirrors that include this block;
+        :meth:`_collect` takes back what the row fell short by."""
+        active = np.array(active, bool)
+        # ``seq`` on a block's dispatch and on its wait: overlapped, the
+        # wait that follows a dispatch is the block before it's, and the
+        # report joins the two halves of one block by it (obs/report.py).
+        with obs.annotate("serve.engine.dispatch", engine=self.engine_id,
+                          seq=self.step_calls) as ann:
             (tables,), mask = self._stage_step(
                 ann, active, self.cfg.decode_horizon, (self.pool,))
             with obs.annotate("serve.engine.launch"):
@@ -1130,9 +1293,8 @@ class Engine:
              budgets, *load) = out
             # Start the block's device->host transfers NOW, before any
             # host bookkeeping (state rebinds here, retire/admit/stream
-            # in the scheduler): the fetches below then find bytes
-            # already in flight instead of paying the full sync
-            # serially.
+            # in the scheduler): the fetches then find bytes already in
+            # flight instead of paying the full sync serially.
             _start_host_copies(tok, emitted, ok, full_sorts, *load)
             mhc = load.pop() if self._mhc_sublayers else None
         self.pool.caches = caches
@@ -1142,29 +1304,46 @@ class Engine:
                 rows=lambda: np.flatnonzero(active))
         self.last_logits, self.positions, self.keys = last, pos, keys
         self.budgets = budgets
-        with obs.annotate("serve.engine.wait", engine=self.engine_id):
+        bound = np.where(active, np.clip(self.host_budgets, 0,
+                                         self.cfg.decode_horizon), 0)
+        self.host_positions += bound
+        self.host_budgets -= bound
+        return _Block(self.step_calls, int(np.count_nonzero(active)),
+                      active, bound, tok, emitted, ok, full_sorts, load, mhc)
+
+    def _collect(self, block: "_Block", **attrs
+                 ) -> Tuple[np.ndarray, np.ndarray]:
+        """Fetch a launched block: the one sync of a pass. Sets
+        :attr:`step_ok`, the expert and residual counters, and corrects
+        the host mirrors by each row's shortfall ``bound - emitted``
+        (EOS, a NaN freeze): a row prefilled since the launch has
+        mirrors of its own, and is left alone. ``attrs`` go on the
+        ``serve.engine.wait`` span."""
+        with obs.annotate("serve.engine.wait", engine=self.engine_id,
+                          seq=block.seq, **attrs):
             # The host blocked on the device: the first fetch returns
-            # when the step has run; the others (their copies started
+            # when the block has run; the others (their copies started
             # with it) are further round trips.
-            self.step_ok = np.asarray(ok)
+            self.step_ok = np.asarray(block.ok)
             with obs.annotate("serve.engine.fetch"):
-                tok_h, emitted_h = np.asarray(tok), np.asarray(emitted)
-                full_sorts_h = int(np.asarray(full_sorts))
-                if load:
-                    self.last_expert_load = np.asarray(load[0])
-                    visits_h = np.asarray(load[1])
-                if mhc is not None:
-                    self._record_mhc(int(np.count_nonzero(active)), mhc)
+                tok_h = np.asarray(block.tok)
+                emitted_h = np.asarray(block.emitted)
+                full_sorts_h = int(np.asarray(block.full_sorts))
+                if block.load:
+                    self.last_expert_load = np.asarray(block.load[0])
+                    visits_h = np.asarray(block.load[1])
+                if block.mhc is not None:
+                    self._record_mhc(block.rows, block.mhc)
         obs.counter("serve.sampling.full_sort_steps_total").inc(full_sorts_h)
-        if load:
-            self._record_expert_load(int(np.count_nonzero(active)), visits_h)
-        # Advance the host position/budget mirrors by the block's
-        # emitted counts (positions advance and budgets decay on
-        # device exactly once per emitted token; a NaN-frozen row
-        # may lag by one — it is retired this iteration, so its
-        # window is never grown).
-        self.host_positions += emitted_h.astype(np.int64)
-        self.host_budgets -= emitted_h.astype(np.int64)
+        if block.load:
+            self._record_expert_load(block.rows, visits_h)
+        # Positions advance and budgets decay on device exactly once an
+        # emitted token; the mirrors were advanced by ``bound`` at the
+        # launch. (A NaN-frozen row may lag by one: it is retired on
+        # this block's result, so its window is never grown.)
+        short = np.where(block.mirrored, block.bound - emitted_h, 0)
+        self.host_positions -= short
+        self.host_budgets += short
         return tok_h, emitted_h
 
     def _record_mhc(self, tokens: int, residual) -> None:
@@ -1211,7 +1390,11 @@ class Engine:
         SAME ``(tokens, emitted)`` contract as the classic step — the
         emitted tokens are compacted to a left-aligned prefix of the
         ``[B, H*(k+1)]`` block, so the scheduler's slice-at-emitted
-        consumption path is unchanged."""
+        consumption path is unchanged. Always launch-then-collect,
+        whoever calls: a window's width depends on what the verify
+        accepts, so the host cannot advance its mirrors (and bind the
+        next block's write windows) before this block's counts are
+        back; :meth:`overlap_blocks` answers False for this engine."""
         k = self.spec.draft_k
         cap = self.cfg.decode_horizon * (k + 1)
         with obs.annotate("serve.engine.dispatch",
@@ -1518,8 +1701,15 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int, groups=None):
         # How many of the block's steps sorted the vocabulary (sampling's
         # wide-nucleus branch): 0 or 1 at horizon 1.
         full_sorts = jnp.sum(full_sort, dtype=jnp.int32)
+        # The carried budget: what is left, and NOTHING for a row that
+        # finished in this block (``done`` starts from zeros in the
+        # next one: its EOS would be forgotten, and the block launched
+        # before the host has read this one would emit for it again).
+        # Such a row then emits nothing and writes the scratch block,
+        # as one whose budget ran out does.
+        left = jnp.where(done, 0, jnp.maximum(budgets - emitted, 0))
         out = (tok_block, emitted, ok, full_sorts, caches, last_logits,
-               positions, keys, jnp.maximum(budgets - emitted, 0))
+               positions, keys, left)
         # Engine.step unpacks the tail: the expert counts, then the residual
         return out + (load or ()) + (() if mhc is None else (mhc,))
 

@@ -7,34 +7,66 @@ memory; with every request in one lane and one tenant, the default,
 WFQ degenerates to the classic bounded FIFO bit for bit), per-request
 deadlines, and the continuous-batching iteration:
 
-    admit waiters into free slots -> decode one BLOCK (up to
+    admit waiters into free slots -> LAUNCH block k (up to
     ``decode_horizon`` tokens per row, one compiled dispatch) for all
-    active rows -> retire rows on EOS / max-new-tokens / deadline ->
-    admit again (a slot freed by retirement is refilled in the SAME
-    iteration, so capacity never idles while work is queued).
+    rows held now -> COLLECT block k-1 -> emit its tokens -> retire rows
+    on EOS / max-new-tokens / deadline -> admit again (a slot freed by
+    retirement is refilled in the SAME iteration, so capacity never
+    idles while work is queued).
+
+**One block in flight.** The launch and the collect are the two halves
+of ONE ``engine.step(active)`` call a pass (serve/engine.py): the
+scheduler asks the engine for its overlapped form at construction, so
+everything the host does in a pass (emit, retire, admit, bind, upload,
+launch) runs while the device runs a block, instead of beside an idle
+one. The price is that a block comes back one call after its launch, and
+the rows may have changed hands meanwhile. So the scheduler keeps,
+beside each call, what was true at the launch (``_Launched``: which
+request held each row, and when), and ``_emit_block`` serves a row only
+if the SAME request still holds it: a slot retired and granted again
+while its old block was in flight must not have the newcomer handed the
+old row's tokens, nor retired by its ``ok == False``. A row stays in the
+mask until its request retires on the host; the block launched meanwhile
+emits nothing for it (its budget is spent, or its EOS zeroed it on the
+device). ``_settle()`` brings the block in flight home and hands it out
+without launching another: what every path does FIRST that reads or
+moves a live row's device state, or retires rows outside a decode pass
+(``_maybe_preempt``, ``export_parked`` / ``resume_parked`` /
+``install_migrated`` / ``export_prefix`` / ``install_pulled``,
+``cancel_remaining``, a ``KVBlocksExhausted`` victim, the bounded retry
+after a failed call), and the pass in which the last live row retires
+(so ``has_work()`` and ``run_until_idle()`` never leave a block behind).
+Where the engine keeps launch-then-collect (speculative decoding) the
+call returns its own block and none of this engages. A direct caller of
+``Engine.step`` never sees the overlapped form unless it asks.
 
 The decode consumes the engine's ``[B, H]`` token block: each live
 row's tokens are sliced at its device-computed emitted count (overshoot
 past EOS/budget never reaches here — it was dropped on device), events
 stream per token, and retire/admit runs once per horizon, so the host
 cost between dispatches is paid once per H tokens. Deadlines are
-checked once per block — granularity coarsens to one horizon.
+checked once per block, against the block's collect time — granularity
+coarsens to one horizon, and a token streams one block after it was
+sampled.
 
 Telemetry flows through ``nezha_tpu.obs`` at the serving layer's
 metrics of record: ``serve.ttft_s`` (submit -> first token, placed at
 the row's position WITHIN its first block) and ``serve.tpot_s``
 (``block_dt / tokens_emitted`` counted once per emitted token, so
-percentiles stay comparable across horizon settings) histograms,
-``serve.host_gap_s`` (host time between one block's fetch and the
-next dispatch, minus the time inside ``Engine.prefill`` during it — the
-gap the decode horizon amortizes) and ``serve.decode.horizon``
+percentiles stay comparable across horizon settings; a block's window
+runs from its launch, or from the collect before it where that came
+later, to its own collect) histograms,
+``serve.host_gap_s`` (host time between one ``engine.step`` call's
+return and the next one's start, minus the time inside
+``Engine.prefill`` during it — the gap the decode horizon amortizes;
+with a block in flight the device works through it) and ``serve.decode.horizon``
 (tokens-per-dispatch ceiling in effect) histograms,
 ``serve.prefill.bucket_len`` (static pad width per prefill chunk — the
 bucket-occupancy view), ``serve.queue_depth`` and
 ``serve.batch_occupancy`` gauges,
 ``serve.{admitted,rejected,expired,retired,tokens}_total``,
-``serve.{errors,step_retries}_total`` and ``serve.prefill.chunks_total``
-counters, ``faults.injected_total`` (the chaos ledger), and the
+``serve.{errors,step_retries}_total``, ``serve.prefill.chunks_total`` and
+``serve.engine.{blocks_overlapped,settles,stale_rows}_total`` counters, ``faults.injected_total`` (the chaos ledger), and the
 layer spans of a pass (``obs.LAYER_SPANS``: ``serve.sched.*`` here,
 ``serve.engine.*`` around every decode block's dispatch and fetch) —
 the names tools/check_telemetry_schema.py pins. With no run active
@@ -192,6 +224,18 @@ class _Live:
     preempt_count: int = 0
 
 
+@dataclasses.dataclass
+class _Launched:
+    """What was true when a decode block was launched, kept until the
+    block comes back (one ``engine.step`` call later in the overlapped
+    form): which request held each row of its mask, and the launch's
+    start on the monotonic clock with its epoch twin under a run dir."""
+
+    rows: Dict[int, _Live]
+    t0: float
+    t0_wall: Optional[float]
+
+
 def register_serve_instruments() -> None:
     """Pre-register (get-or-create) the full serving instrument set so
     every serving run's summary carries it — a run with zero rejections
@@ -325,6 +369,16 @@ def register_serve_instruments() -> None:
     # tokens_total / count is the realized tokens-per-dispatch).
     obs.histogram("serve.host_gap_s")
     obs.histogram("serve.decode.horizon")
+    # One decode block in flight (PR 37): blocks launched while the one
+    # before them was still in flight (over serve.decode.horizon's count,
+    # the share of a run that was overlapped), forced drains of the block
+    # in flight (Engine.settle; its serve.engine.wait span says why), and
+    # row results dropped because the row's request had changed while
+    # its block was in flight and the result was not empty. 0s under
+    # speculative decoding, which stays launch-then-collect.
+    obs.counter("serve.engine.blocks_overlapped_total")
+    obs.counter("serve.engine.settles_total")
+    obs.counter("serve.engine.stale_rows_total")
 
 
 class Scheduler:
@@ -363,6 +417,8 @@ class Scheduler:
                      "_live": "_lock",
                      "results": "_lock", "_host_gap_t": "_lock",
                      "_gap_prefill_s": "_lock",
+                     "_launched": "_lock", "_collect_t": "_lock",
+                     "_settled_tokens": "_lock",
                      "_parked": "_lock", "_digest_cache": "_lock"}
 
     def __init__(self, engine: Engine,
@@ -420,6 +476,19 @@ class Scheduler:
         # Host seconds inside Engine.prefill since that timestamp
         # (_prefill sums them): prefill time, not gap.
         self._gap_prefill_s = 0.0
+        # One decode block in flight: this scheduler can hand a block's
+        # tokens to the requests that held its rows at the LAUNCH, so it
+        # asks the engine for the overlapped form of step() (granted
+        # where the step is the classic one; _decode reads what the
+        # engine says it does). _launched is the launch the engine's
+        # block in flight came from (None: nothing in flight),
+        # _collect_t when the last block came back, and _settled_tokens
+        # what forced drains delivered since the last pass reported its
+        # count.
+        engine.overlap_blocks()
+        self._launched: Optional[_Launched] = None
+        self._collect_t: Optional[float] = None
+        self._settled_tokens = 0
         register_serve_instruments()
         pool = engine.pool
         obs.gauge("serve.kv.quant_bits").set(
@@ -544,6 +613,8 @@ class Scheduler:
                 emitted = 0
                 self._host_gap_t = None     # idle: no gap to measure
             self._admit()          # refill slots freed by retirement
+            emitted += self._settled_tokens
+            self._settled_tokens = 0
             obs.gauge("serve.queue_depth").set(self._queued_n)
             obs.gauge("serve.batch_occupancy").set(
                 self.engine.pool.occupancy)
@@ -785,18 +856,30 @@ class Scheduler:
         if already >= (len(self._live) if self._slo_burning() else 1):
             return False
         rank = _PRIORITY_RANK[target.req.priority]
-        victim = None
-        for slot, live in self._live.items():
-            if _PRIORITY_RANK[live.req.priority] <= rank:
-                continue
-            if live.preempt_count >= cfg.preemption_budget:
-                continue
-            key = (-_PRIORITY_RANK[live.req.priority],
-                   len(live.tokens), slot)
-            if victim is None or key < victim[0]:
-                victim = (key, slot, live)
-        if victim is None:
+
+        def pick():
+            victim = None
+            for slot, live in self._live.items():
+                if _PRIORITY_RANK[live.req.priority] <= rank:
+                    continue
+                if live.preempt_count >= cfg.preemption_budget:
+                    continue
+                key = (-_PRIORITY_RANK[live.req.priority],
+                       len(live.tokens), slot)
+                if victim is None or key < victim[0]:
+                    victim = (key, slot, live)
+            return victim
+
+        if pick() is None:
             return False
+        # A victim is suspended with every token its KV holds, and chosen
+        # by its progress: first bring the block in flight home.
+        self._settle("preempt")
+        victim = pick()
+        if victim is None:
+            # whoever qualified finished in the settled block: capacity
+            # came free without a suspension
+            return True
         return self._preempt(victim[1], victim[2])
 
     def _preempt(self, slot: int, live: _Live) -> bool:
@@ -1034,10 +1117,11 @@ class Scheduler:
         self._live[slot] = live
 
     def _decode(self) -> int:
-        """[holds: _lock] — step() calls this inside the lock."""
-        active = np.zeros((self.engine.cfg.max_batch_size,), bool)
-        for slot in self._live:
-            active[slot] = True
+        """[holds: _lock] — step() calls this inside the lock. One
+        ``engine.step`` call: in the overlapped form it launches the
+        block of this pass's rows and brings back the block of the pass
+        before, whose tokens go to the requests that held its rows
+        then."""
         # Occupancy OF THIS DECODE, folded into the metric.* histogram
         # the report renders percentiles from (the same name a
         # record_metrics stream would fold into) — the gauge alone only
@@ -1050,8 +1134,8 @@ class Scheduler:
         t0_wall = time.time() if obs.enabled() else None
         t0 = time.monotonic()
         if self._host_gap_t is not None:
-            # Host time since the previous block came back, MINUS the
-            # time spent inside Engine.prefill since then (its
+            # Host time since the previous engine.step call returned,
+            # MINUS the time spent inside Engine.prefill since then (its
             # serve.engine.prefill span): the retire/admit/stream pass
             # alone — the per-dispatch cost a horizon > 1 spreads over
             # H tokens. An admission's prefill is work of its own (4-10
@@ -1059,28 +1143,35 @@ class Scheduler:
             # where its dispatch waits for the device), not gap.
             obs.histogram("serve.host_gap_s").observe(
                 t0 - self._host_gap_t - self._gap_prefill_s)
+
         def _dispatch():
             # KV block exhaustion (genuine, or an injected serve.kv.bind
             # fault) is TYPED BACKPRESSURE, not an engine failure: retire
             # only the victim row — freeing its blocks — and redial with
-            # the survivors. Convergence is guaranteed (every retirement
-            # releases blocks); None means the block retired everyone.
-            while True:
+            # the survivors. The block in flight comes home first: the
+            # victim keeps every token already decoded for it (and may
+            # turn out to have finished there). Convergence is
+            # guaranteed (every retirement releases blocks); None means
+            # nobody is left to decode.
+            while self._live:
+                active = np.zeros((self.engine.cfg.max_batch_size,), bool)
+                active[list(self._live)] = True
                 try:
                     return self.engine.step(active)
                 except KVBlocksExhausted as e:
                     slot = e.slot
                     if slot is None or slot not in self._live:
                         raise
-                    victim = self._live.pop(slot)
+                    self._settle("kv_exhausted")
+                    victim = self._live.pop(slot, None)
+                    if victim is None:      # finished in the settled block
+                        continue
                     self.engine.pool.free(slot)
-                    active[slot] = False
                     obs.counter("serve.errors_total").inc()
                     obs.counter("serve.retired_total").inc()
                     self._finish(victim, FinishReason.ERROR,
                                  error=f"kv blocks exhausted: {e}")
-                    if not self._live:
-                        return None
+            return None
 
         try:
             out = _dispatch()
@@ -1091,9 +1182,12 @@ class Scheduler:
             # surfaces to the caller — that is a dead engine, not a
             # hiccup. (If the first dispatch died AFTER consuming
             # its donated cache buffers the retry fails fast on the
-            # donation error and surfaces the same way.)
+            # donation error and surfaces the same way.) The retry
+            # starts from a settled engine: what was in flight before
+            # the failed call is collected and handed out first.
             obs.counter("serve.step_retries_total").inc()
             time.sleep(self.step_retry_backoff_s)
+            self._settle("retry")
             out = _dispatch()
         if out is None:
             self._host_gap_t = None
@@ -1102,42 +1196,93 @@ class Scheduler:
         now = time.monotonic()
         self._host_gap_t = now
         self._gap_prefill_s = 0.0
-        rows = len(self._live)
-        with obs.annotate("serve.sched.emit", rows=rows) as ann:
-            emitted = self._emit_block(tokens, block_emitted, t0, now,
-                                       t0_wall)
-            ann.set(emitted=emitted, retired=rows - len(self._live))
+        # The call's own launch; overlapped, the block it brought back
+        # is the one the call before it launched.
+        launched = _Launched(dict(self._live), t0, t0_wall)
+        if self.engine.overlapped:
+            launched, self._launched = self._launched, (
+                launched if self.engine.in_flight else None)
+        emitted = 0
+        if launched is not None:
+            emitted = self._emit(tokens, block_emitted, launched, now)
         if not self._live:
-            # The block retired the whole batch: the next decode only
-            # happens after new admissions, which may be arbitrarily
-            # later (open-loop callers gate step() on has_work(), so
-            # the idle reset in step() never runs for them) — a gap
-            # measured across that wait would be idle time, not host
-            # overhead.
+            # The block retired the whole batch. What was launched for
+            # its rows meanwhile holds nothing for anybody: collect it
+            # now, so that has_work() and run_until_idle() never leave a
+            # block behind. And the next decode only happens after new
+            # admissions, which may be arbitrarily later (open-loop
+            # callers gate step() on has_work(), so the idle reset in
+            # step() never runs for them) — a gap measured across that
+            # wait would be idle time, not host overhead.
+            self._settle("idle")
             self._host_gap_t = None
         return emitted
 
-    def _emit_block(self, tokens, block_emitted, t0: float, now: float,
-                    t0_wall: Optional[float]) -> int:
+    def _settle(self, reason: str) -> None:
+        """[holds: _lock] Bring the block in flight home without
+        launching another, and hand its tokens out: what every path
+        does first that reads or moves a live row's state on the device
+        or retires rows from outside a decode pass (preemption, the
+        migration calls, a drain, a block-exhaustion victim, the retry
+        after a failed call) and the pass in which ``_live`` empties.
+        After it the scheduler and the engine stand as after a
+        launch-then-collect step. The tokens it delivers are added to
+        the next pass's count."""
+        out = self.engine.settle(reason)
+        launched, self._launched = self._launched, None
+        if out is not None and launched is not None:
+            self._settled_tokens += self._emit(*out, launched,
+                                               time.monotonic())
+
+    def _emit(self, tokens, block_emitted, launched: _Launched,
+              now: float) -> int:
+        """[holds: _lock] :meth:`_emit_block` under its
+        ``serve.sched.emit`` span."""
+        rows = len(self._live)
+        with obs.annotate("serve.sched.emit", rows=rows) as ann:
+            emitted = self._emit_block(tokens, block_emitted, launched,
+                                       now)
+            ann.set(emitted=emitted, retired=rows - len(self._live))
+        return emitted
+
+    def _emit_block(self, tokens, block_emitted, launched: _Launched,
+                    now: float) -> int:
         """[holds: _lock] Hand one decoded block to its requests: per
         row the appends, the first-token and per-token latency
         observations, ``on_token``, and retirement (EOS / length /
-        deadline / non-finite logits). ``t0``..``now`` is the dispatch
-        window on the monotonic clock, ``t0_wall`` its start's epoch
-        twin under a run dir. Returns the tokens emitted."""
+        deadline / non-finite logits). Only a row still held by the
+        SAME request as at the block's launch is served: a slot retired
+        (and perhaps granted again) while its old block was in flight
+        must not have the newcomer handed the old row's tokens, nor
+        retired by its ``ok == False``; such a result is dropped, and
+        counted in ``serve.engine.stale_rows_total`` where it was not
+        empty. The block's window on the monotonic clock runs to
+        ``now``, its collect, from its launch — or from the collect
+        before it where that came later: with a block in flight the
+        device only turned to this one then, and a launch-to-collect
+        time would read a token's latency as nearly two blocks.
+        Returns the tokens emitted."""
         horizon = self.engine.cfg.decode_horizon
+        t0, t0_wall = launched.t0, launched.t0_wall
+        if self._collect_t is not None and self._collect_t > t0:
+            if t0_wall is not None:
+                t0_wall += self._collect_t - t0
+            t0 = self._collect_t
+        self._collect_t = now
         dt = now - t0
         obs.histogram("serve.decode.horizon").observe(
             self.engine.tokens_per_dispatch)
         speculative = self.engine.spec is not None
         ok = self.engine.step_ok
-        emitted = 0
+        emitted = stale = 0
         # tokens delivered by the block, keyed by their row's emitted
         # count: what serve.tpot_s is told once the rows are through
         delivered: Dict[int, int] = {}
-        for slot in list(self._live):
-            live = self._live[slot]
+        for slot, live in launched.rows.items():
             e = int(block_emitted[slot])
+            if self._live.get(slot) is not live:
+                stale += bool(e or (ok is not None and not ok[slot]))
+                continue
             retired = False
             for i in range(e):
                 tok = int(tokens[slot, i])
@@ -1174,12 +1319,12 @@ class Scheduler:
                         # control signal that widens the preemption
                         # quota in _maybe_preempt.
                         cfg = self.slo_tracker.cfg
-                        ok = {"<": live.ttft_s < cfg.threshold,
-                              "<=": live.ttft_s <= cfg.threshold,
-                              ">": live.ttft_s > cfg.threshold,
-                              ">=": live.ttft_s >= cfg.threshold,
-                              }[cfg.op]
-                        self.slo_tracker.observe(ok)
+                        self.slo_tracker.observe(
+                            {"<": live.ttft_s < cfg.threshold,
+                             "<=": live.ttft_s <= cfg.threshold,
+                             ">": live.ttft_s > cfg.threshold,
+                             ">=": live.ttft_s >= cfg.threshold,
+                             }[cfg.op])
                 delivered[e] = delivered.get(e, 0) + 1
                 if self.on_token is not None:
                     self.on_token(live.request_id, tok)
@@ -1232,6 +1377,9 @@ class Scheduler:
         for e, n in delivered.items():
             tpot.observe(dt / e, n)
         obs.counter("serve.tokens_total").inc(emitted)
+        if stale:
+            self.engine.stale_rows += stale
+            obs.counter("serve.engine.stale_rows_total").inc(stale)
         return emitted
 
     def _finish(self, live: _Live, reason: str,
@@ -1287,6 +1435,7 @@ class Scheduler:
         with self._lock:
             if request_id not in self._parked:
                 raise KeyError(request_id)
+            self._settle("migrate")
             slot, live, _ = self._parked[request_id]
             pool = self.engine.pool
             # The export fragment adopts the PARKED request's trace (the
@@ -1338,6 +1487,7 @@ class Scheduler:
             if parked is None:
                 return False
             slot, live, _ = parked
+            self._settle("migrate")
             self._emit_park_span(live, "resumed")
             if live.trace_id is not None:
                 live.decode_t0_wall = time.time()
@@ -1359,6 +1509,7 @@ class Scheduler:
         ``serve.kv.migration_bytes``."""
         faults.point("replica.kv_install")
         with self._lock:
+            self._settle("migrate")
             installed = self.engine.pool.install_block_payload(tokens,
                                                                layers)
             if installed > 0:
@@ -1401,6 +1552,7 @@ class Scheduler:
         the owner outright."""
         from nezha_tpu.serve import migrate
         with self._lock:
+            self._settle("migrate")
             pool = self.engine.pool
             covered, layers, _ = pool.export_prefix_payload(tokens)
             return migrate.encode_wire(covered, layers, pool.block_size)
@@ -1414,6 +1566,7 @@ class Scheduler:
         ``serve.kv.pull_bytes`` (NOT the migration ledgers — a peer
         pull is a cache transfer, not a request handoff)."""
         with self._lock:
+            self._settle("migrate")
             installed = self.engine.pool.install_block_payload(
                 tokens, layers, origin="peer")
             if installed > 0:
@@ -1438,6 +1591,8 @@ class Scheduler:
                 obs.counter("serve.errors_total").inc()
 
         with self._lock:
+            # what is in flight was decoded: its tokens are delivered
+            self._settle("drain")
             n = 0
             while self._queued_n:
                 live = self._pop_next()

@@ -205,6 +205,7 @@ def _preempt_resume_case(engine):
     sched = Scheduler(engine)
     _submit(sched, "bg", prompt, priority="background", max_new=12)
     sched.step()
+    sched.step()    # one block in flight: the second pass brings the first home
     with sched._lock:
         (bg_live,) = sched._live.values()
         assert len(bg_live.tokens) >= 1    # suspended MID-stream
@@ -254,15 +255,19 @@ def test_deadline_while_preempted(paged_engine):
     """A deadline keeps ticking while a request sits suspended: it
     retires DEADLINE with the tokens it already emitted, never resumes,
     and leaks nothing."""
+    # (every program built first: the block in flight comes home, and
+    # meets the deadline check, when the preemption settles it, after
+    # the first interactive's prefill)
+    _run_reference(paged_engine, "warm", [1, 2, 3], max_new=2)
     sched = Scheduler(paged_engine)
     _submit(sched, "bg", [1, 2, 3, 4, 5, 6], priority="background",
-            max_new=30, deadline_s=0.2)
+            max_new=30, deadline_s=0.5)
     sched.step()
     _submit(sched, "i0", [2, 4, 6], max_new=3)
     _submit(sched, "i1", [3, 5, 7], max_new=3)
     sched.step()
     assert sched.preempted_count == 1
-    time.sleep(0.3)
+    time.sleep(0.6)
     sched.step()            # _expire_preempted runs before admission
     res = sched.results["bg"]
     assert res.finish_reason == FinishReason.DEADLINE

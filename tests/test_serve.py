@@ -167,8 +167,12 @@ def test_eos_retirement_admits_waiter_same_iteration(engine):
                                request_id="eos-req"))
     waiter = sched.submit(Request(prompt=[9, 9], max_new_tokens=2,
                                   request_id="waiter"))
+    passes = 0
     while rid not in sched.results:
-        assert sched.step() > 0
+        # (one block in flight: the first pass launches and brings
+        # nothing back yet)
+        assert sched.step() > 0 or passes == 0
+        passes += 1
         live_ids = {lv.request_id for lv in sched._live.values()}
         if rid not in sched.results:
             assert waiter not in live_ids  # no free slot before EOS
