@@ -203,7 +203,13 @@ _SERVE_GAUGES = {"serve.queue_depth", "serve.batch_occupancy",
                  # Hyper-connections (PR 36): the largest |row sum - 1|
                  # or |column sum - 1| of any H_res the serve programs
                  # have produced (0 on a one-stream model).
-                 "serve.mhc.sinkhorn_residual_max"}
+                 "serve.mhc.sinkhorn_residual_max",
+                 # Sampling: the entries the k_max head's last
+                 # top-k ranking takes a row, beside the counter
+                 # serve.sampling.full_sort_steps_total: the chosen
+                 # tiles' (serve/sampling.py::top_k_values), or the
+                 # vocabulary where the row is ranked whole.
+                 "serve.sampling.topk_candidates"}
 _SERVE_HISTOGRAMS = {"serve.ttft_s", "serve.tpot_s",
                      "serve.prefill.bucket_len",
                      # Decode-horizon instruments (PR 5): host time

@@ -133,7 +133,7 @@ from nezha_tpu.serve.sampling import (accept_mask, categorical_rows,
                                       filter_logits_and_flag,
                                       finite_rows, residual_logits,
                                       sample_tokens_and_flag,
-                                      split_and_sample)
+                                      split_and_sample, topk_candidates)
 from nezha_tpu.serve.slots import (KVBlocksExhausted, PagedSlotPool,
                                    layer_parts)
 
@@ -629,6 +629,13 @@ class Engine:
             model.paged_prefill_uses_kernel())
         obs.gauge("serve.prefill.kernel_active").set(
             1.0 if self.prefill_kernel_active else 0.0)
+        # The entries sampling's last top-k ranking takes a row: which
+        # form of ``sampling.top_k_values`` the programs took (the
+        # vocabulary where the head is ranked whole). Re-pinned with
+        # the gauge above.
+        self.topk_candidates = topk_candidates(self.vocab, self.k_max)
+        obs.gauge("serve.sampling.topk_candidates").set(
+            self.topk_candidates)
         self.pool = self._make_paged_pool(
             model, num_blocks=cfg.kv_num_blocks,
             prefix_cache=cfg.prefix_cache, eviction=cfg.kv_eviction,
@@ -1000,6 +1007,8 @@ class Engine:
         # into the measured run's summary.
         obs.gauge("serve.prefill.kernel_active").set(
             1.0 if self.prefill_kernel_active else 0.0)
+        obs.gauge("serve.sampling.topk_candidates").set(
+            self.topk_candidates)
         # Tokens the compiled chunks will actually push through the
         # target model: bucket pads included, a prefix hit's cached
         # span excluded (and a cold fallback's full re-plan included).

@@ -54,6 +54,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
+
+# ``[B, V]``: the vocabulary major, the rows minor (on the lanes).
+_ROWS_ON_LANES = Layout(major_to_minor=(1, 0))
 
 
 def finite_rows(logits) -> jax.Array:
@@ -65,6 +69,63 @@ def finite_rows(logits) -> jax.Array:
     requests with ``FinishReason.ERROR`` instead of decoding garbage
     forever or killing the batch."""
     return jnp.isfinite(logits).all(axis=-1)
+
+
+def _tile_width(v: int, k: int) -> int:
+    """The tile width :func:`top_k_values` cuts a ``v``-wide row into for
+    ``k`` values, or 0 where it ranks the row whole: the widest power of
+    two from 8 that leaves more than ``32 * k`` tiles. Its first ranking
+    then takes over ``32 * k`` tile maxima and its last ``k * w``
+    candidates, a sixteenth of the row or less. Timed alone on a v5e at
+    the six serving cells' rows (``experiments/topk_alone.py``), wider
+    tiles made the compiler lay the tiles out again, tile-minor, before
+    the gather, at up to a millisecond a call; narrower ones made the
+    first ranking the cost."""
+    w = 8
+    if v // w <= 32 * k:
+        return 0
+    while v // (2 * w) > 32 * k:
+        w *= 2
+    return w
+
+
+def top_k_values(x, k: int, divisor) -> jax.Array:
+    """``lax.top_k(x / divisor, k)[0]`` for ``[B, V]`` rows and a
+    positive ``[B, 1]`` divisor (the temperature), ranking no row whole
+    where the row is wide (:func:`_tile_width`).
+
+    The row is cut into tiles of ``w`` entries, each tile's maximum taken,
+    and the ``k`` tiles with the largest maxima chosen; the ``k`` largest
+    of the chosen tiles' entries (with the ``V % w`` left over past the
+    last whole tile) are the row's, value for value and in order. Let
+    ``t`` be the least chosen maximum: the chosen tiles hold ``k``
+    entries of at least ``t`` (their maxima), so the row's ``k`` largest
+    are all ``>= t``, and an entry outside them is at most its tile's
+    maximum, ``<= t``: it can only tie with a value the candidates hold
+    as often as the row does. The tiles are chosen on ``x`` itself (a
+    positive divisor keeps every order the maxima have) and only the
+    candidates are divided, so neither stage writes a divided copy of
+    the row."""
+    b, v = x.shape
+    w = _tile_width(v, k)
+    if not w:
+        return lax.top_k(x / divisor, k)[0]
+    t = v // w
+    tiles = x[:, :t * w].reshape(b, t, w)
+    chosen = lax.top_k(tiles.max(axis=-1), k)[1]                # [B, k]
+    candidates = jnp.take_along_axis(tiles, chosen[:, :, None], axis=1)
+    candidates = jnp.concatenate(
+        [candidates.reshape(b, k * w), x[:, t * w:]], axis=1)
+    return lax.top_k(candidates / divisor, k)[0]
+
+
+def topk_candidates(v: int, k: int) -> int:
+    """The entries the last ranking of :func:`top_k_values` takes in a
+    ``v``-wide row: the ``k`` chosen tiles' and the remainder past the
+    last whole tile, or the whole row (the engine's gauge
+    ``serve.sampling.topk_candidates``)."""
+    w = _tile_width(v, k)
+    return k * w + v % w if w else v
 
 
 def _sorted_nucleus_threshold(scaled, top_p) -> jax.Array:
@@ -88,8 +149,8 @@ def filter_logits_and_flag(logits, temperature, top_k, top_p,
     the full-vocabulary sort (the step program returns it; the engine
     counts ``serve.sampling.full_sort_steps_total``).
 
-    The ``k_max`` largest scaled logits ``lax.top_k`` returns (the
-    "head", in descending order) are, once the row's top-k rule is
+    The ``k_max`` largest scaled logits :func:`top_k_values` returns
+    (the "head", in descending order) are, once the row's top-k rule is
     applied to them, the first ``k_max`` entries of the sorted
     survivors, so the nucleus threshold is read off their exclusive
     cumulative mass; the normaliser is one ``exp``-sum over ALL the
@@ -102,11 +163,19 @@ def filter_logits_and_flag(logits, temperature, top_k, top_p,
     b, v = logits.shape
     if not 1 <= k_max <= v:
         raise ValueError(f"k_max must be in [1, {v}], got {k_max}")
-    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+    divisor = jnp.maximum(temperature, 1e-6)[:, None]
+    scaled = logits / divisor
+    if b % 128 == 0:
+        # Rows that fill whole lanes stay on them, where the native TopK
+        # kept them: a tile of the vocabulary is then a block of
+        # sublanes and the tiles' view a bitcast. Left to itself the
+        # compiler laid GPT-2's filter out vocabulary-minor and copied
+        # the 256 x 50,257 row across (51 MB more live in a step).
+        scaled = with_layout_constraint(scaled, _ROWS_ON_LANES)
 
     # Per-row top-k under the static cap: the k_max'th-largest values are
     # computed once; each row thresholds at its own (clamped) k-th value.
-    head = lax.top_k(scaled, k_max)[0]                        # [B, k_max]
+    head = top_k_values(logits, k_max, divisor)               # [B, k_max]
     k_eff = jnp.clip(top_k, 1, k_max)
     kth = jnp.take_along_axis(head, (k_eff - 1)[:, None], axis=1)
     apply_k = (top_k > 0)[:, None]

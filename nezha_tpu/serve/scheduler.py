@@ -288,6 +288,9 @@ def register_serve_instruments() -> None:
     # nucleus wider than the k_max head; serve/sampling.py). 0 for
     # top-k traffic and for peaked top-p traffic.
     obs.counter("serve.sampling.full_sort_steps_total")
+    # The entries the head's last top-k ranking takes a row (gauge set
+    # by the engine; the vocabulary where the row is ranked whole).
+    obs.gauge("serve.sampling.topk_candidates")
     # Cross-replica migration (disaggregated prefill/decode tiers,
     # serve/migrate.py): committed installs and their wire bytes —
     # migration GB/s is bytes / the router.migrate span durations.

@@ -17,8 +17,11 @@ import pytest
 from jax import lax
 
 from nezha_tpu.serve import Engine, Request, Scheduler, ServeConfig
+from nezha_tpu.serve import sampling
 from nezha_tpu.serve.sampling import (filter_logits, filter_logits_and_flag,
-                                      split_and_sample)
+                                      finite_rows, sample_tokens_and_flag,
+                                      split_and_sample, top_k_values,
+                                      topk_candidates)
 
 V, K_MAX = 512, 16
 
@@ -230,6 +233,115 @@ def test_split_and_sample_gives_the_references_tokens(top_k):
         np.testing.assert_array_equal(np.asarray(new_keys),
                                       np.asarray(ref_keys))
         keys = new_keys
+
+
+# --------------------------------------------- the head in two stages
+# (rows, vocabulary) of the six serving cells' step programs (GPT-2,
+# Kimi-Linear, K-EXAONE, Falcon-H1, Mistral-Small-4, Xing4.0), then a
+# small vocabulary that keeps the one ranking and one as wide as k_max.
+HEAD_SHAPES = [(256, 50257), (256, 40960), (128, 19200), (256, 32640),
+               (128, 32768), (32, 131072), (8, V), (4, 64)]
+HEAD_K = 64
+_one_ranking = jax.jit(lambda x, d: lax.top_k(x / d, HEAD_K)[0])
+_two_stages = jax.jit(lambda x, d: top_k_values(x, HEAD_K, d))
+
+
+def _head_rows(kind, b, v, seed):
+    """``[b, v]`` float32 rows of one kind."""
+    rng = np.random.default_rng(seed)
+    if kind == "random":
+        rows = rng.standard_normal((b, v)) * 3
+    elif kind == "tied":                # ~40 distinct values a row
+        rows = np.round(rng.standard_normal((b, v)) * 2) / 2
+    elif kind == "tail":
+        # the largest values past the last whole tile, and a run of ties
+        # at the k-th value across tile edges
+        rows = rng.standard_normal((b, v))
+        rows[:, -(v % 32 or 8):] = 9.0 + rng.standard_normal(v % 32 or 8)
+        rows[:, 100:228] = 5.0
+    elif kind == "mostly-inf":          # 0..79 finite entries a row
+        rows = np.full((b, v), -np.inf)
+        for r in range(b):
+            n = min(r % 80, v)
+            rows[r, rng.choice(v, n, replace=False)] = rng.standard_normal(n)
+    else:                               # all equal
+        rows = np.full((b, v), 0.75)
+    return jnp.asarray(rows.astype(np.float32))
+
+
+@pytest.mark.parametrize("b,v", HEAD_SHAPES,
+                         ids=[f"{b}x{v}" for b, v in HEAD_SHAPES])
+def test_two_stage_head_is_the_one_ranking_bit_for_bit(b, v):
+    """The k_max head of the six cells' rows, at temperatures 0.8 and 1.5
+    a row, is ``lax.top_k``'s of the divided row, bit for bit, on random
+    rows, rows of many ties, rows whose largest values lie past the last
+    whole tile, rows all ``-inf`` but a few entries (fewer than k_max in
+    most) and rows all equal; the small vocabularies rank the row whole.
+    A row holding NaN or inf is still flagged by ``finite_rows`` and
+    leaves its neighbours' heads alone."""
+    wide = (b, v) in HEAD_SHAPES[:6]
+    assert (sampling._tile_width(v, HEAD_K) > 0) is wide
+    assert (topk_candidates(v, HEAD_K) <= v // 16) is wide
+    d = jnp.asarray(np.resize(np.float32([0.8, 1.5]), (b, 1)))
+    for seed, kind in enumerate(["random", "tied", "tail", "mostly-inf",
+                                 "equal"]):
+        x = _head_rows(kind, b, v, seed)
+        np.testing.assert_array_equal(
+            np.asarray(_two_stages(x, d)).view(np.uint32),
+            np.asarray(_one_ranking(x, d)).view(np.uint32), err_msg=kind)
+    x = np.array(_head_rows("random", b, v, 7))
+    bad = [0, b // 2, b - 1]
+    x[bad[0], 3] = np.nan
+    x[bad[1], v - 1] = np.inf
+    x[bad[2], :] = np.nan
+    x = jnp.asarray(x)
+    np.testing.assert_array_equal(
+        np.flatnonzero(~np.asarray(finite_rows(x))), bad)
+    ok = np.setdiff1d(np.arange(b), bad)
+    np.testing.assert_array_equal(
+        np.asarray(_two_stages(x, d))[ok].view(np.uint32),
+        np.asarray(_one_ranking(x, d))[ok].view(np.uint32))
+
+
+@pytest.mark.parametrize("b,v", HEAD_SHAPES,
+                         ids=[f"{b}x{v}" for b, v in HEAD_SHAPES])
+def test_tokens_and_sort_flag_are_the_one_ranking_filters(b, v,
+                                                          monkeypatch):
+    """For fixed keys, ``sample_tokens_and_flag`` draws the tokens and
+    raises the sort flag that the same filter around ONE ``lax.top_k``
+    does, row params mixed per row (greedy rows, top-k off, 1, 40, the
+    cap and over it, top_p 1, 0.95, 0.5): peaked rows that never sort,
+    and flat rows whose top-k-off nuclei take the sort branch."""
+    k_max = min(HEAD_K, v)
+    rng = np.random.default_rng(b + v)
+    # row 0 samples with top-k off under a nucleus: a flat row's nucleus
+    # is wider than the head wherever the row is wider than it
+    temperature = rng.choice([0.0, 0.8, 1.5], b).astype(np.float32)
+    top_k = rng.choice([0, 1, 40, k_max, 100], b).astype(np.int32)
+    top_p = rng.choice([1.0, 0.95, 0.5], b).astype(np.float32)
+    temperature[0], top_k[0], top_p[0] = 0.8, 0, 0.95
+    params = tuple(map(jnp.asarray, (temperature, top_k, top_p)))
+    keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(b, dtype=jnp.uint32))
+    flat = rng.standard_normal((b, v)).astype(np.float32)
+    peaked = flat.copy()                # one logit far above the rest
+    peaked[np.arange(b), rng.integers(0, v, b)] = 40.0
+    rows = [jnp.asarray(peaked), jnp.asarray(flat * 0.05)]
+
+    def draws():
+        fn = jax.jit(lambda x: sample_tokens_and_flag(x, keys, *params,
+                                                      k_max))
+        return [tuple(map(np.asarray, fn(x))) for x in rows]
+
+    got = draws()
+    with monkeypatch.context() as mp:
+        mp.setattr(sampling, "top_k_values",
+                   lambda x, k, d: lax.top_k(x / d, k)[0])
+        want = draws()
+    for (got_tok, got_sorted), (want_tok, want_sorted) in zip(got, want):
+        np.testing.assert_array_equal(got_tok, want_tok)
+        assert bool(got_sorted) is bool(want_sorted)
+    assert not want[0][1]                       # peaked rows: no sort
+    assert bool(want[1][1]) is (v > k_max)      # flat rows: the sort branch
 
 
 # ------------------------------------------- the counter, through the engine

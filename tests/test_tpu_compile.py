@@ -471,10 +471,11 @@ def test_mistral4_step_decodes_in_the_paged_kernel_and_gathers_no_view(
 
 
 # ---- Sampling sorts the vocabulary only inside a conditional (PR 29): the
-# nucleus threshold comes from the k_max head the native ``TopK`` returns,
-# and the sort is the second branch of one ``lax.cond`` on "some row's
-# nucleus is wider than the head". Before, ``sort f32[256,50257]`` ran every
-# decode step: 18.5 ms of a 51 ms step in the GPT-2 cell.
+# nucleus threshold comes from the k_max head, and the sort is the second
+# branch of one ``lax.cond`` on "some row's nucleus is wider than the
+# head". Before, ``sort f32[256,50257]`` ran every decode step: 18.5 ms of
+# a 51 ms step in the GPT-2 cell. The head itself is ranked in two stages
+# over the row's tiles, so no ranking of a step spans the vocabulary.
 def _sorts_outside_conditional_branches(text):
     """The ``sort`` instructions of an HLO module that run whenever the
     program does: those not in a conditional's branch computation, nor in
@@ -500,23 +501,51 @@ def _sorts_outside_conditional_branches(text):
             for line in body.splitlines() if re.search(r" sort\(", line)]
 
 
+def _head_rankings(text, slots, vocab):
+    """The widths of the ``[slots, n]`` float32 rows the step program
+    ranks whenever it runs: the operands of its ``TopK`` custom calls and
+    of its ``sort`` instructions outside a conditional's branches (where
+    the vocabulary-wide nucleus sort must be). Sampling's head takes its
+    ``k_max`` = 64 values in two stages over the tiles of the row
+    (``serve/sampling.py::top_k_values``), so no ranking is
+    vocabulary-wide: the last takes ``topk_candidates(vocab, 64)``
+    entries, the first the tiles' maxima. Ranks of other rows (the
+    dropless experts sort their token-expert pairs) are left out."""
+    from nezha_tpu.serve.sampling import topk_candidates
+
+    shapes = dict(re.findall(r"%([\w.\-]+) = f32\[(\d+,\d+)\]", text))
+    widths = [shapes[name] for name in re.findall(
+        r'custom-call\(%([\w.\-]+)\)[^\n]*custom_call_target="TopK"', text)]
+    widths += re.findall(r"^\(?f32\[(\d+,\d+)\]",
+                         "\n".join(line.split(" = ", 1)[-1] for line in
+                                   _sorts_outside_conditional_branches(text)),
+                         re.M)
+    widths = sorted(int(w.split(",")[1]) for w in widths
+                    if int(w.split(",")[0]) == slots)
+    assert vocab not in widths, widths
+    assert topk_candidates(vocab, 64) in widths, widths
+    return widths
+
+
 @pytest.mark.parametrize("served", ["gpt2", "mistral4"])
 def test_serve_step_program_sorts_the_vocabulary_only_under_a_conditional(
         request, served):
     """At the cells' deployments (GPT-2: 256 slots, vocabulary 50,257;
-    Mistral-Small-4: 128 slots, 32,768 held): the native ``TopK`` is in
-    the step program and no vocabulary-wide ``sort`` outside the branch
-    computations of a ``conditional``. GPT-2's step has no other sort
-    at all; Mistral's dropless experts sort their token-expert pairs."""
+    Mistral-Small-4: 128 slots, 32,768 held): no ``TopK`` and no ``sort``
+    that runs whenever the step does takes a ``[slots, vocabulary]``
+    row, the head's last ranking takes its chosen tiles' entries, and
+    the vocabulary-wide ``sort`` is a branch of a ``conditional``.
+    GPT-2's step ranks nothing else; Mistral's dropless experts sort
+    their token-expert pairs."""
     if served == "gpt2":
         text = request.getfixturevalue("gpt2_programs")("bf16")[
             "step"].as_text()
-        vocab = f"[{CELL_BATCH},50257]"
+        slots, vocab = CELL_BATCH, 50257
     else:
         text = request.getfixturevalue("mistral4_programs")["step"]
-        vocab = f"[{M4_SLOTS},32768]"
-    assert re.search(r'custom_call_target="TopK"', text)
+        slots, vocab = M4_SLOTS, 32768
+    widths = _head_rankings(text, slots, vocab)
     always = _sorts_outside_conditional_branches(text)
-    assert not [line for line in always if vocab in line]
+    assert not [line for line in always if f"[{slots},{vocab}]" in line]
     if served == "gpt2":
-        assert always == []
+        assert len(always) == len(widths) == 2, always

@@ -20,6 +20,7 @@ import pytest
 
 from test_tpu_compile import (  # noqa: F401  (fixtures, by name)
     _serve_programs,
+    _head_rankings,
     _sorts_outside_conditional_branches,
     v5e,
     v5e_devices,
@@ -124,10 +125,12 @@ def test_falcon_prefill_runs_no_kernel_and_scans_eight_chunks(falcon_programs):
 
 def test_falcon_step_program_sorts_the_vocabulary_only_under_a_conditional(
         falcon_programs):
-    """As the other served models: no vocabulary-wide ``sort`` runs outside
-    the branches of a ``conditional``, at 256 slots and the 32,640 rows of
-    the vocabulary held."""
+    """As the other served models: no ``TopK`` or ``sort`` that runs
+    whenever the step does takes a vocabulary-wide row
+    (``_head_rankings``), and the nucleus's ``sort`` runs only in a
+    branch of a ``conditional``, at 256 slots and the 32,640 rows of the
+    vocabulary held."""
     text = falcon_programs["step"].as_text()
-    assert re.search(r'custom_call_target="TopK"', text)
+    _head_rankings(text, FH_SLOTS, 32640)
     always = _sorts_outside_conditional_branches(text)
     assert not [line for line in always if f"[{FH_SLOTS},32640]" in line]

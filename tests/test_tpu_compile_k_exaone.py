@@ -22,6 +22,7 @@ import pytest
 from test_tpu_compile import (  # noqa: F401  (fixtures, by name)
     _serve_programs,
     moe_expert_calls,
+    _head_rankings,
     _sorts_outside_conditional_branches,
     v5e,
     v5e_devices,
@@ -104,11 +105,12 @@ def test_k_exaone_step_decodes_through_one_kernel_body_twice_named(
 def test_k_exaone_step_program_sorts_the_vocabulary_only_under_a_conditional(
         k_exaone_programs):
     """As the other two served models (``test_tpu_compile.py``), at 128
-    slots and the 19,200 rows of the vocabulary held: the native ``TopK``
-    is in the step program and no vocabulary-wide ``sort`` runs outside
-    the branches of a ``conditional`` (the dropless experts sort their
+    slots and the 19,200 rows of the vocabulary held: no ``TopK`` or
+    ``sort`` that runs whenever the step does takes a vocabulary-wide
+    row (``_head_rankings``), and the nucleus's ``sort`` runs only in a
+    branch of a ``conditional`` (the dropless experts sort their
     token-expert pairs)."""
     text = k_exaone_programs["step"].as_text()
-    assert re.search(r'custom_call_target="TopK"', text)
+    _head_rankings(text, KX_SLOTS, 19200)
     always = _sorts_outside_conditional_branches(text)
     assert not [line for line in always if f"[{KX_SLOTS},19200]" in line]

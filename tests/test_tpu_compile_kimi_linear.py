@@ -19,6 +19,7 @@ import pytest
 from test_tpu_compile import (  # noqa: F401  (fixtures, by name)
     _serve_programs,
     moe_expert_calls,
+    _head_rankings,
     _sorts_outside_conditional_branches,
     v5e,
     v5e_devices,
@@ -126,11 +127,12 @@ def test_kimi_prefill_runs_no_kernel_and_scans_sixteen_chunks(kimi_programs):
 
 def test_kimi_step_program_sorts_the_vocabulary_only_under_a_conditional(
         kimi_programs):
-    """As the other served models: the native ``TopK`` is in the step
-    program and no vocabulary-wide ``sort`` runs outside the branches of a
-    ``conditional``, at 256 slots and the 40,960 rows of the vocabulary
-    held."""
+    """As the other served models: no ``TopK`` or ``sort`` that runs
+    whenever the step does takes a vocabulary-wide row
+    (``_head_rankings``), and the nucleus's ``sort`` runs only in a
+    branch of a ``conditional``, at 256 slots and the 40,960 rows of the
+    vocabulary held."""
     text = kimi_programs["step"].as_text()
-    assert re.search(r'custom_call_target="TopK"', text)
+    _head_rankings(text, KL_SLOTS, 40960)
     always = _sorts_outside_conditional_branches(text)
     assert not [line for line in always if f"[{KL_SLOTS},40960]" in line]

@@ -18,6 +18,7 @@ import pytest
 from test_tpu_compile import (  # noqa: F401  (fixtures, by name)
     _serve_programs,
     moe_expert_calls,
+    _head_rankings,
     _sorts_outside_conditional_branches,
     v5e,
     v5e_devices,
@@ -119,10 +120,12 @@ def test_xing4_prefill_folds_the_long_table_and_names_both_mhc_kernels(
 
 def test_xing4_step_program_sorts_the_vocabulary_only_under_a_conditional(
         xing4_programs):
-    """As the other served models: the native ``TopK`` is in the step
-    program and no vocabulary-wide ``sort`` runs outside the branches of a
-    ``conditional``, at 32 slots and the whole vocabulary of 131,072."""
+    """As the other served models: no ``TopK`` or ``sort`` that runs
+    whenever the step does takes a vocabulary-wide row
+    (``_head_rankings``), and the nucleus's ``sort`` runs only in a
+    branch of a ``conditional``, at 32 slots and the whole vocabulary of
+    131,072."""
     text = xing4_programs["step"].as_text()
-    assert re.search(r'custom_call_target="TopK"', text)
+    _head_rankings(text, X4_SLOTS, 131072)
     always = _sorts_outside_conditional_branches(text)
     assert not [line for line in always if f"[{X4_SLOTS},131072]" in line]
