@@ -85,24 +85,26 @@ def scratch_init(m_scr, l_scr, acc_scr):
     acc_scr[:] = jnp.zeros_like(acc_scr)
 
 
-def softmax_block_update(s, v, m_scr, l_scr, acc_scr):
+def softmax_block_update(s, v, m_scr, l_scr, acc_scr, rows=slice(None)):
     """Fold one masked score block ``s [rows, bk]`` and its value tile
     ``v [bk, d]`` into the running ``(max, sum, acc)`` scratch — THE
     online-softmax step every kernel shares. ``s`` arrives fully masked
     (causal / length / start-offset masking is the caller's business);
     softmax statistics and the accumulator stay fp32, P·V dots in the
-    value tile's native dtype."""
-    m_prev = m_scr[:, :1]                                # [rows, 1]
-    l_prev = l_scr[:, :1]
+    value tile's native dtype. ``rows`` (a ``pl.ds``) folds into that row
+    slice of the scratch alone (a causal flash block's row strip)."""
+    m_prev = m_scr[rows, :1]                             # [rows, 1]
+    l_prev = l_scr[rows, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)                               # [rows, bk]
     corr = jnp.exp(m_prev - m_new)
     l_new = corr * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[:] = acc_scr[:] * corr + lax.dot_general(
+    acc_scr[rows] = acc_scr[rows] * corr + lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+    lanes = (m_new.shape[0], m_scr.shape[1])
+    m_scr[rows] = jnp.broadcast_to(m_new, lanes)
+    l_scr[rows] = jnp.broadcast_to(l_new, lanes)
 
 
 def softmax_finalize(o_ref, m_scr, l_scr, acc_scr, lse_ref=None):

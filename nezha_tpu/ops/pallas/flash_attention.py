@@ -2,11 +2,26 @@
 
 Forward: blockwise attention with online softmax — grid = (B, H, Q-blocks,
 K-blocks) with the K dimension sequential ("arbitrary" semantics), VMEM
-scratch carrying the running max/denominator/accumulator across K blocks,
-and causal blocks skipped entirely above the diagonal. Q·Kᵀ and P·V hit the
-MXU in fp32 accumulation; memory per program is O(block_q · block_k), never
-the full S×S score matrix. The training path additionally emits the
-per-row logsumexp residual (lane-broadcast to 128, the TPU-native layout).
+scratch carrying the running max/denominator/accumulator across K blocks.
+Q·Kᵀ and P·V hit the MXU in fp32 accumulation; memory per program is
+O(block_q · block_k), never the full S×S score matrix. The training path
+additionally emits the per-row logsumexp residual (lane-broadcast to 128,
+the TPU-native layout).
+
+Causal skipping happens at two grains. Between grid blocks, a block wholly
+above the diagonal is skipped. Inside a q block several key blocks tall
+(the whole-row block at 512 < S <= 1024), a key block's row strips above
+the square the diagonal crosses are skipped: the square is masked and the
+strips below it are computed whole (``_sub_tile``, ``_strips``), three of
+the four 512-row strips at S = 1024. Where the square is the q block's
+first strip there is nothing above it to skip and the block keeps its
+whole body. Finer sub-tiles lost on a v5e (PERF.md §6): each fold
+of a sub-tile pays a fixed latency that a 128- or 256-wide square walked
+one a loop iteration does not amortise. The strips are walked by a
+``lax.fori_loop`` whose trip count is a traced value, not unrolled in
+Python, so the lowered body is one size whatever their number
+(``tests/test_tpu_compile.py`` holds it to that); and the calls are jitted,
+so a model's layers share one trace and one lowering of each kernel.
 
 Backward (FlashAttention-2 style): two kernels that recompute P blockwise
 from (q, k, lse) instead of materializing S×S —
@@ -45,6 +60,8 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
+from nezha_tpu import obs
+
 # The online-softmax scratch math and the package scalar helpers live
 # in ops/pallas/common.py (shared with the decode and prefill kernels).
 from nezha_tpu.ops.pallas.common import (
@@ -63,9 +80,79 @@ def _auto_blocks(s_q: int, s_k: int):
     single whole-row q block wins (grid overhead dominates; 12.8ms vs 14.2ms
     XLA at S=1024); at S>=2048 square 512 blocks win (17.9ms vs 23.9ms XLA
     at S=2048) — the causal block-skip starts paying once there are enough
-    q rows to skip."""
+    q rows to skip. A whole-row block skips nothing between grid blocks: it
+    skips the row strips above the diagonal inside it (``_sub_tile``)."""
     bq = s_q if s_q <= 1024 else 512
     return bq, 512
+
+
+def _sub_tile(block_q: int, block_k: int) -> Optional[int]:
+    """Side of the square sub-tiles a causal ``block_q x block_k`` block is
+    walked in, a row strip of it at a time: the key block's width, so one
+    strip is the square the diagonal crosses and the others lie wholly
+    above it (skipped) or below it. None where the block keeps its whole
+    body: a q block of one strip, or not of whole strips. (Timed alone on a
+    v5e at [16, 12, 1024, 64], the three kernels walking square sub-tiles
+    of 128 or 256 a loop iteration ran 1.2-2.0x slower than the whole
+    blocks they replaced, and strips of 512 faster; PERF.md §6.)"""
+    if block_q % block_k or block_q == block_k:
+        return None
+    return block_k
+
+
+def _causal_tile_share(s: int, block_q: int, block_k: int) -> float:
+    """Share of the S x S score matrix a causal call computes: the grid
+    blocks it runs, and inside a block the diagonal crosses only the strips
+    from its diagonal square down (what the
+    ``kernel.flash.causal_tile_share`` gauge reads)."""
+    tiled = _sub_tile(block_q, block_k) is not None
+    done = 0
+    for q0 in range(0, s, block_q):
+        for k0 in range(0, s, block_k):
+            if k0 > q0 + block_q - 1:
+                continue
+            if tiled and k0 + block_k - 1 > q0:
+                done += (q0 + block_q - k0) * block_k
+            else:
+                done += block_q * block_k
+    return done / (s * s)
+
+
+def _causal_blocks(q0, k0, block_q, block_k, whole, tiled):
+    """Run one causal grid block whose first row is ``q0`` and first column
+    ``k0``: ``tiled`` where the diagonal crosses the block below its first
+    strip (so there are strips above it to skip), ``whole`` where it lies
+    wholly on or below the diagonal or the diagonal crosses its first strip,
+    nothing where it lies wholly above."""
+    runs = k0 <= q0 + block_q - 1
+    if _sub_tile(block_q, block_k) is None:
+        pl.when(runs)(whole)
+    else:
+        pl.when(k0 <= q0)(whole)
+        pl.when(jnp.logical_and(runs, k0 > q0))(tiled)
+
+
+def _strips(sq, block_q, block_k, strip, state):
+    """``strip(i, rows, state)`` for the row strips ``i`` of a q block from
+    its diagonal square (strip ``sq``) to its last, by a loop whose trip
+    count is a traced value: one body whatever their number."""
+    def body(i, st):
+        rows = pl.ds(pl.multiple_of(i * block_k, block_k), block_k)
+        return strip(i, rows, st)
+
+    return lax.fori_loop(sq, block_q // block_k, body, state)
+
+
+def _strip_scores(q, k, scale, i, sq, ki, block_k, kv_len):
+    """Scaled scores of strip ``i`` of a q block against its key block
+    ``ki``, whose diagonal square is strip ``sq``: causally masked (a strip
+    below the square keeps all of it), and past ``kv_len``."""
+    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+    s = _causal_mask(s, i, sq, block_k, block_k)
+    if kv_len is not None:
+        s = _length_mask(s, ki, block_k, kv_len)
+    return s
 
 
 def _causal_mask(s, qi, ki, block_q, block_k):
@@ -100,10 +187,6 @@ def _fwd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     def _init():
         _scratch_init(m_scr, l_scr, acc_scr)
 
-    # Causal: skip blocks strictly above the diagonal.
-    run = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
-
-    @pl.when(run)
     def _block():
         # Dots take the inputs' native dtype (bf16 on the training path —
         # double MXU rate vs fp32) and accumulate fp32; softmax stats and
@@ -119,14 +202,34 @@ def _fwd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             s = _length_mask(s, ki, block_k, kv_len)
         _softmax_block_update(s, v, m_scr, l_scr, acc_scr)
 
+    def _tiled():
+        # The online softmax on each strip's rows of the scratch.
+        sq = ki - qi * (block_q // block_k)
+
+        def strip(i, rows, carry):
+            s = _strip_scores(q_ref[0, 0, rows, :], k_ref[0, 0], scale, i,
+                              sq, ki, block_k, kv_len)
+            _softmax_block_update(s, v_ref[0, 0], m_scr, l_scr, acc_scr,
+                                  rows)
+            return carry
+
+        _strips(sq, block_q, block_k, strip, 0)
+
+    if causal:
+        _causal_blocks(qi * block_q, ki * block_k, block_q, block_k,
+                       _block, _tiled)
+    else:
+        pl.when(True)(_block)
+
     @pl.when(ki == pl.num_programs(3) - 1)
     def _finalize():
         _softmax_finalize(o_ref, m_scr, l_scr, acc_scr, lse_ref=lse_ref)
 
 
-def _flash_call(q, k, v, causal, scale, block_q, block_k, interpret,
-                return_lse: bool = False, kv_lengths=None):
-    b, h, s_q, d = q.shape
+def _plan(q, k, causal, scale, block_q, block_k, interpret):
+    """(scale, block_q, block_k, interpret) of a call. A causal call sets
+    the ``kernel.flash.causal_tile_share`` gauge as it is traced."""
+    s_q, d = q.shape[2:]
     s_k = k.shape[2]
     if causal and s_q != s_k:
         # _causal_mask has no (s_k - s_q) diagonal offset, so rectangular
@@ -137,7 +240,24 @@ def _flash_call(q, k, v, causal, scale, block_q, block_k, interpret,
     auto_q, auto_k = _auto_blocks(s_q, s_k)
     bq = _pick_block(s_q, block_q or auto_q)
     bk = _pick_block(s_k, block_k or auto_k)
-    interpret = resolve_interpret(interpret)
+    if causal:
+        obs.gauge("kernel.flash.causal_tile_share").set(
+            _causal_tile_share(s_q, bq, bk))
+    return scale, bq, bk, resolve_interpret(interpret)
+
+
+# The two calls are jitted on their static arguments, so a model's layers
+# share one trace and one lowering of each kernel: a kernel body is
+# otherwise traced and lowered again at every call, a layer at a time, on
+# every process start and before the compile cache is consulted.
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret", "return_lse"))
+def _flash_call(q, k, v, causal, scale, block_q, block_k, interpret,
+                return_lse: bool = False, kv_lengths=None):
+    b, h, s_q, d = q.shape
+    s_k = k.shape[2]
+    scale, bq, bk, interpret = _plan(q, k, causal, scale, block_q, block_k,
+                                     interpret)
 
     has_len = kv_lengths is not None
     full = functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -227,9 +347,6 @@ def _bwd_dq_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         delta = jnp.sum(do * o, axis=-1, keepdims=True)      # [bq, 1]
         delta_scr[:] = jnp.broadcast_to(delta, delta_scr.shape)
 
-    run = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
-
-    @pl.when(run)
     def _block():
         p = _recompute_p(q_ref, k_ref, lse_ref, qi, ki, scale, causal,
                          block_q, block_k, kv_len)
@@ -242,6 +359,32 @@ def _bwd_dq_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dq_scr[:] += lax.dot_general(ds.astype(k.dtype), k,
                                      (((1,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32)
+
+    def _tiled():
+        # Each strip's dq, added to its rows of dq_scr.
+        sq = ki - qi * (block_q // block_k)
+
+        def strip(i, rows, carry):
+            k = k_ref[0, 0]
+            p = jnp.exp(_strip_scores(q_ref[0, 0, rows, :], k, scale, i, sq,
+                                      ki, block_k, kv_len)
+                        - lse_ref[0, 0, rows, :][:, :1])
+            dp = lax.dot_general(do_ref[0, 0, rows, :], v_ref[0, 0],
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            ds = p * (dp - delta_scr[rows, :][:, :1]) * scale
+            dq_scr[rows, :] += lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return carry
+
+        _strips(sq, block_q, block_k, strip, 0)
+
+    if causal:
+        _causal_blocks(qi * block_q, ki * block_k, block_q, block_k,
+                       _block, _tiled)
+    else:
+        pl.when(True)(_block)
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _finalize():
@@ -260,10 +403,6 @@ def _bwd_dkv_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    # Causal: q blocks entirely above the diagonal contribute nothing.
-    run = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
-
-    @pl.when(run)
     def _block():
         p = _recompute_p(q_ref, k_ref, lse_ref, qi, ki, scale, causal,
                          block_q, block_k, kv_len)
@@ -279,24 +418,51 @@ def _bwd_dkv_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                                      (((0,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32)
 
+    def _tiled():
+        # dk and dv summed over the strips in values the loop carries,
+        # added to the scratch once.
+        sq = ki - qi * (block_q // block_k)
+
+        def strip(i, rows, acc):
+            q, do = q_ref[0, 0, rows, :], do_ref[0, 0, rows, :]
+            v = v_ref[0, 0]
+            p = jnp.exp(_strip_scores(q, k_ref[0, 0], scale, i, sq, ki,
+                                      block_k, kv_len)
+                        - lse_ref[0, 0, rows, :][:, :1])
+            dv = acc[1] + lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = _ds_block(p, do, o_ref[0, 0, rows, :], v, scale)
+            dk = acc[0] + lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return dk, dv
+
+        zero = jnp.zeros(dk_scr.shape, jnp.float32)
+        dk, dv = _strips(sq, block_q, block_k, strip, (zero, zero))
+        dk_scr[:] += dk
+        dv_scr[:] += dv
+
+    if causal:
+        _causal_blocks(qi * block_q, ki * block_k, block_q, block_k,
+                       _block, _tiled)
+    else:
+        pl.when(True)(_block)
+
     @pl.when(qi == pl.num_programs(3) - 1)
     def _finalize():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret"))
 def _flash_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
                     interpret, kv_lengths=None):
     b, h, s_q, d = q.shape
     s_k = k.shape[2]
-    if causal and s_q != s_k:
-        raise ValueError(
-            f"causal flash_attention requires s_q == s_k, got {s_q} != {s_k}")
-    scale = scale if scale is not None else 1.0 / (d ** 0.5)
-    auto_q, auto_k = _auto_blocks(s_q, s_k)
-    bq = _pick_block(s_q, block_q or auto_q)
-    bk = _pick_block(s_k, block_k or auto_k)
-    interpret = resolve_interpret(interpret)
+    scale, bq, bk, interpret = _plan(q, k, causal, scale, block_q, block_k,
+                                     interpret)
 
     def spec(rows, outer, minor=d):
         """``rows``-tall sequence block indexed by the grid's OUTER

@@ -263,3 +263,95 @@ def test_flash_varlen_zero_length_clamps_to_one():
     only_pos0 = jnp.broadcast_to(v[0, :, :1, :], q[0].shape)
     np.testing.assert_allclose(np.asarray(out0[0]), np.asarray(only_pos0),
                                rtol=5e-4, atol=5e-5)
+
+
+def _causal_reference(q, k, v, lens=None):
+    s = q.shape[2]
+    mask = ops.causal_mask(s, s)
+    if lens is not None:
+        kpos = jnp.arange(s)[None, None, None, :]
+        mask = jnp.where(kpos < lens[:, None, None, None], mask, -jnp.inf)
+    return ops.dot_product_attention(q, k, v, mask=mask)
+
+
+@pytest.mark.parametrize("lengths", [None, (77, 200)],
+                         ids=["dense", "kv_lengths"])
+@pytest.mark.parametrize("s,block_q,block_k", [
+    (128, None, None), (256, None, 128), (512, None, 128), (512, 256, 128),
+    (1024, None, None)])
+def test_flash_causal_sub_tiles_match_reference(s, block_q, block_k,
+                                                lengths):
+    """A causal q block several key blocks tall walks its row strips from
+    the diagonal square down and skips those above: two strips at
+    S = 1024's default blocks, four of 128 at S = 512, two at S = 256, and
+    two q blocks of two strips each at S = 512 with 256-row q blocks;
+    S = 128 is one strip, which keeps the whole-block body. Forward and all
+    three gradients match composed causal attention, with lengths that end
+    inside a strip."""
+    from nezha_tpu.ops.pallas.flash_attention import _auto_blocks, _sub_tile
+    auto_q, auto_k = (min(s, b) for b in _auto_blocks(s, s))
+    assert (_sub_tile(block_q or auto_q, block_k or auto_k) is None) == (
+        s == 128)
+    q, k, v = _qkv(b=2, h=1, s=s, d=64, seed=s)
+    lens = None if lengths is None else jnp.asarray(lengths, jnp.int32)
+    w = jax.random.normal(jax.random.PRNGKey(1), q.shape)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, True, block_q=block_q,
+                               block_k=block_k, kv_lengths=lens)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(_causal_reference(q, k, v, lens)),
+                               rtol=2e-4, atol=2e-5)
+    g1 = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(loss(lambda q, k, v: _causal_reference(q, k, v, lens)),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(g1, g2, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-4, atol=5e-5, err_msg=name)
+
+
+def test_flash_block_fwd_bwd_match_reference():
+    """The ring's raw building blocks at a whole-row causal shape with two
+    row strips: the forward's output and row logsumexp, and the backward's
+    three gradients given the global lse and output."""
+    from nezha_tpu.ops.pallas.flash_attention import (flash_block_bwd,
+                                                      flash_block_fwd)
+    q, k, v = _qkv(b=1, h=2, s=1024, d=64, seed=7)
+    do = jax.random.normal(jax.random.PRNGKey(8), q.shape)
+    out, lse = flash_block_fwd(q, k, v, True)
+    ref, vjp = jax.vjp(_causal_reference, q, k, v)
+    scores = (jnp.einsum("bhqd,bhkd->bhqk", q, k) / 8.0
+              + ops.causal_mask(1024, 1024))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(jax.nn.logsumexp(scores, axis=-1)),
+        rtol=2e-5, atol=2e-5)
+    got = flash_block_bwd(q, k, v, out, lse, do, True)
+    for a, b, name in zip(got, vjp(do), ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-4, atol=5e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("s,share", [(128, 1.0), (256, 1.0), (512, 1.0),
+                                     (1024, 0.75), (2048, 0.625)])
+def test_flash_causal_tile_share_gauge(s, share):
+    """Tracing a causal call sets ``kernel.flash.causal_tile_share`` to the
+    share of the S x S scores it computes: three of four 512-row strips at
+    S = 1024, 1.0 where the block is one strip, the skipped grid blocks
+    beyond 1024."""
+    from nezha_tpu import obs
+    x = jax.ShapeDtypeStruct((1, 1, s, 64), jnp.float32)
+    jax.clear_caches()      # the call is jitted: trace it anew
+    obs.enable()
+    try:
+        obs.gauge("kernel.flash.causal_tile_share").set(0.0)
+        jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, True),
+                       x, x, x)
+        assert obs.gauge("kernel.flash.causal_tile_share").value == share
+    finally:
+        obs.disable()

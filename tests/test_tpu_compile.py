@@ -69,12 +69,13 @@ def _grad_sum(fn):
                     argnums=(0, 1, 2))
 
 
-def _flash(causal, seq, with_lengths=False):
+def _flash(causal, seq, with_lengths=False, block_k=None):
     args = [((BATCH, H, seq, D), BF16)] * 3
     if with_lengths:
         args.append(((BATCH,), jnp.int32))
     return _grad_sum(lambda q, k, v, lens=None: flash_attention(
-        q, k, v, causal=causal, interpret=False, kv_lengths=lens)), args
+        q, k, v, causal=causal, block_k=block_k, interpret=False,
+        kv_lengths=lens)), args
 
 
 # gpt2-124m.batch-gen's deployment (chipbench/configs/gpt2-124m.json): 256
@@ -108,6 +109,10 @@ def _prefill(pool_dtype, chunk):
 CASES = {
     # training: the trainer's default batch 8 x seq 1024, fwd + bwd
     "flash-causal-fwd-bwd-s1024": lambda: _flash(True, 1024),
+    # half the length in key blocks of 128: four row strips where S=1024
+    # has two
+    "flash-causal-fwd-bwd-s512-k128": lambda: _flash(True, 512,
+                                                     block_k=128),
     # BERT: non-causal S=512, without and with right-padding lengths
     "flash-noncausal-fwd-bwd-s512": lambda: _flash(False, 512),
     "flash-noncausal-kvlen-fwd-bwd-s512": lambda: _flash(
@@ -143,6 +148,7 @@ CASES = {
 FLASH_NAMES = ("nezha_flash_fwd", "nezha_flash_bwd_dq", "nezha_flash_bwd_dkv")
 KERNEL_NAMES = {
     "flash-causal-fwd-bwd-s1024": FLASH_NAMES,
+    "flash-causal-fwd-bwd-s512-k128": FLASH_NAMES,
     "flash-noncausal-fwd-bwd-s512": FLASH_NAMES,
     "flash-noncausal-kvlen-fwd-bwd-s512": FLASH_NAMES,
     "decode-dense": ("nezha_decode_attention_dense",),
@@ -195,6 +201,34 @@ def test_kernel_keeps_its_name_in_the_v5e_hlo(hlo_of, case):
     kernels = {re.sub(r"^(transpose_)?(jvp_)?_*|_*(\.\d+)?$", "", n)
                for n in own_names}
     assert kernels == set(KERNEL_NAMES[case]), own_names
+
+
+def _kernel_bodies(text):
+    """Kernel name -> size of its serialized body (the ``body`` of the
+    custom call's ``backend_config``) in a compiled module."""
+    sizes = {}
+    for line in text.splitlines():
+        if "tpu_custom_call" in line and " = " in line:
+            own = line.split(" = ", 1)[0].split()[-1].lstrip("%")
+            name = re.sub(r"^(transpose_)?(jvp_)?_*|_*(\.\d+)?$", "", own)
+            body = re.search(r'"body":"([^"]*)"', line)
+            sizes[name] = len(body.group(1))
+    return sizes
+
+
+def test_causal_flash_bodies_do_not_grow_with_their_strips(hlo_of):
+    """A causal whole-row block walks its row strips from the diagonal down
+    by a loop: four strips of 128 at S=512 with 128-wide key blocks, two of
+    512 at S=1024, and each kernel's body is the same size at both. A body
+    unrolled in Python grows with the count, and is traced and lowered on
+    every process start, before the compile cache is consulted (set-up
+    time)."""
+    short = _kernel_bodies(hlo_of("flash-causal-fwd-bwd-s512-k128"))
+    long = _kernel_bodies(hlo_of("flash-causal-fwd-bwd-s1024"))
+    assert set(short) == set(long) == set(FLASH_NAMES)
+    for name in FLASH_NAMES:
+        assert abs(long[name] - short[name]) <= 0.1 * short[name], (
+            name, short[name], long[name])
 
 
 @pytest.mark.parametrize("case", ["decode-paged-bf16-256slots",
