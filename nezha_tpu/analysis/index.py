@@ -22,7 +22,7 @@ from __future__ import annotations
 import ast
 import dataclasses
 import os
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 # What `nezha-lint` covers by default: the package, the checker shims,
 # and the benchmark drivers. tests/ is deliberately NOT indexed (rules
@@ -41,14 +41,23 @@ class Module:
     text: str
     tree: ast.Module
     parents: Dict[ast.AST, ast.AST]   # child node -> parent node
+    nodes: List[ast.AST]              # every node, in ast.walk order
+    # What rules derive from ``tree`` and share, computed once a module
+    # by its first reader (``traced.traced_functions``: three rules).
+    memo: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
 
-def _attach_parents(tree: ast.Module) -> Dict[ast.AST, ast.AST]:
+def _walk_with_parents(tree: ast.Module
+                       ) -> Tuple[List[ast.AST], Dict[ast.AST, ast.AST]]:
+    """Every node in ``ast.walk`` order, and each child's parent: one
+    walk."""
+    nodes: List[ast.AST] = [tree]
     parents: Dict[ast.AST, ast.AST] = {}
-    for node in ast.walk(tree):
+    for node in nodes:          # grows as it goes: breadth first
         for child in ast.iter_child_nodes(node):
             parents[child] = node
-    return parents
+            nodes.append(child)
+    return nodes, parents
 
 
 def dotted_name(node: ast.AST) -> Optional[str]:
@@ -116,9 +125,10 @@ class SourceIndex:
             except (OSError, SyntaxError, ValueError) as e:
                 self.parse_errors.append((rel, f"{type(e).__name__}: {e}"))
                 continue
+            nodes, parents = _walk_with_parents(tree)
             self.modules[rel] = Module(
                 rel=rel, path=path, text=text, tree=tree,
-                parents=_attach_parents(tree))
+                parents=parents, nodes=nodes)
 
     def __iter__(self) -> Iterator[Module]:
         for rel in sorted(self.modules):

@@ -35,9 +35,7 @@ from typing import Dict, List
 
 from nezha_tpu.analysis.core import Finding, rule
 from nezha_tpu.analysis.index import SourceIndex
-from nezha_tpu.analysis.traced import traced_functions
-
-_FuncDef = (ast.FunctionDef, ast.AsyncFunctionDef)
+from nezha_tpu.analysis.traced import close_over_calls, traced_functions
 
 # The host-side pool bookkeeping state (PagedSlotPool and its sharded
 # subclass). Renaming one of these fields means updating this set — the
@@ -60,29 +58,11 @@ def shard_map_bodies(mod) -> Dict[ast.AST, str]:
     ``shard_map(...)`` call, plus the transitive in-module closure of
     functions their bodies reference — the same closure rule the
     host-sync scope uses, rooted narrower."""
-    traced = traced_functions(mod)
     bodies: Dict[ast.AST, str] = {
-        fn: reason for fn, reason in traced.items()
+        fn: reason for fn, reason in traced_functions(mod).items()
         if "shard_map" in reason}
-    by_name: Dict[str, List[ast.AST]] = {}
-    for node in ast.walk(mod.tree):
-        if isinstance(node, _FuncDef):
-            by_name.setdefault(node.name, []).append(node)
-    changed = True
-    while changed:
-        changed = False
-        for fn in list(bodies):
-            for sub in ast.walk(fn):
-                if (isinstance(sub, ast.Name)
-                        and isinstance(sub.ctx, ast.Load)
-                        and sub.id in by_name):
-                    for callee in by_name[sub.id]:
-                        if callee not in bodies and callee is not fn:
-                            bodies[callee] = (
-                                f"called from shard_map-lowered "
-                                f"{getattr(fn, 'name', '?')}()")
-                            changed = True
-    return bodies
+    return close_over_calls(mod, bodies,
+                            "called from shard_map-lowered {}()")
 
 
 def _touched_state(node: ast.AST):

@@ -71,14 +71,67 @@ def _decorator_traces(dec: ast.AST) -> bool:
     return name.rsplit(".", 1)[-1] in TRACING_CALLS
 
 
+def _function_facts(mod: Module):
+    """``(by_name, refs, collective)``: defs by name, the names each
+    def's body loads (in its walk order), and the first mesh collective
+    each issues (None if none). One pass over the module's nodes; kept
+    on it."""
+    if "function_facts" not in mod.memo:
+        by_name: Dict[str, List[ast.AST]] = {}
+        refs: Dict[ast.AST, Dict[str, None]] = {}
+        collective: Dict[ast.AST, Optional[str]] = {}
+
+        def enclosing_defs(node):
+            cur = mod.parents.get(node)
+            while cur is not None:
+                if isinstance(cur, _FuncDef):
+                    yield cur
+                cur = mod.parents.get(cur)
+
+        for node in mod.nodes:
+            if isinstance(node, _FuncDef):
+                by_name.setdefault(node.name, []).append(node)
+                refs[node], collective[node] = {}, None
+            elif isinstance(node, ast.Name):
+                if isinstance(node.ctx, ast.Load):
+                    for fn in enclosing_defs(node):
+                        refs[fn].setdefault(node.id)
+            elif isinstance(node, ast.Call):
+                last = (dotted_name(node.func) or "").rsplit(".", 1)[-1]
+                if last in COLLECTIVE_CALLS:
+                    for fn in enclosing_defs(node):
+                        if collective[fn] is None:
+                            collective[fn] = last
+        mod.memo["function_facts"] = (by_name, refs, collective)
+    return mod.memo["function_facts"]
+
+
+def close_over_calls(mod: Module, found: Dict[ast.AST, str],
+                     reason: str) -> Dict[ast.AST, str]:
+    """Add to ``found`` every def of ``mod`` that a def in it references
+    by name, transitively (``reason`` formatted with the referrer's
+    name), in breadth-first order: the first referrer names the
+    reason."""
+    by_name, refs, _ = _function_facts(mod)
+    queue = list(found)
+    for fn in queue:
+        for name in refs[fn]:
+            for callee in by_name.get(name, ()):
+                if callee not in found:
+                    found[callee] = reason.format(getattr(fn, "name", "?"))
+                    queue.append(callee)
+    return found
+
+
 def traced_functions(mod: Module) -> Dict[ast.AST, str]:
-    """-> {FunctionDef node: one-line reason it is considered traced}."""
-    by_name: Dict[str, List[ast.AST]] = {}
+    """-> {FunctionDef node: one-line reason it is considered traced}.
+    Computed once a module and kept on it (three rules read it)."""
+    if "traced" in mod.memo:
+        return mod.memo["traced"]
+    by_name, _, collective = _function_facts(mod)
     assigns: Dict[str, List[ast.AST]] = {}
-    for node in ast.walk(mod.tree):
-        if isinstance(node, _FuncDef):
-            by_name.setdefault(node.name, []).append(node)
-        elif isinstance(node, ast.Assign):
+    for node in mod.nodes:
+        if isinstance(node, ast.Assign):
             for t in node.targets:
                 if isinstance(t, ast.Name):
                     assigns.setdefault(t.id, []).append(node.value)
@@ -89,19 +142,14 @@ def traced_functions(mod: Module) -> Dict[ast.AST, str]:
         if fn not in traced:
             traced[fn] = reason
 
-    for node in ast.walk(mod.tree):
+    for node in mod.nodes:
         if isinstance(node, _FuncDef):
             for dec in node.decorator_list:
                 if _decorator_traces(dec):
                     mark(node, "decorated with a tracing transform")
-            for sub in ast.walk(node):
-                if isinstance(sub, ast.Call):
-                    last = (dotted_name(sub.func) or "").rsplit(
-                        ".", 1)[-1]
-                    if last in COLLECTIVE_CALLS:
-                        mark(node, f"issues mesh collective {last}() "
-                                   f"(shard_map-lowered body)")
-                        break
+            if collective[node] is not None:
+                mark(node, f"issues mesh collective {collective[node]}() "
+                           f"(shard_map-lowered body)")
         if isinstance(node, ast.Call):
             cn = dotted_name(node.func) or ""
             if cn.rsplit(".", 1)[-1] in TRACING_CALLS:
@@ -154,20 +202,9 @@ def traced_functions(mod: Module) -> Dict[ast.AST, str]:
                                  f"program built by {node.name}()")
 
     # Transitive closure: helpers called from traced bodies trace too.
-    changed = True
-    while changed:
-        changed = False
-        for fn, reason in list(traced.items()):
-            for sub in ast.walk(fn):
-                if (isinstance(sub, ast.Name)
-                        and isinstance(sub.ctx, ast.Load)
-                        and sub.id in by_name):
-                    for callee in by_name[sub.id]:
-                        if callee not in traced and callee is not fn:
-                            traced[callee] = (f"called from traced "
-                                              f"{getattr(fn, 'name', '?')}()")
-                            changed = True
-    return traced
+    mod.memo["traced"] = close_over_calls(mod, traced,
+                                          "called from traced {}()")
+    return mod.memo["traced"]
 
 
 # Attributes that are STATIC on a traced array — reading them yields

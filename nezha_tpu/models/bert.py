@@ -85,19 +85,30 @@ class EncoderLayer(Module):
         qkv = run_child(self.qkv, "qkv", variables, states, x, training=training)
         qkv = qkv.reshape(b, s, 3, cfg.num_heads, d).transpose(2, 0, 3, 1, 4)
         impl = cfg.attn_impl
+        from nezha_tpu.ops.pallas.common import resolve_interpret, use_kernels
+        from nezha_tpu.parallel.gspmd import auto_partitioner_mesh
+        # the enclosing gspmd trace's mesh, where its ``tp`` axis divides
+        # the heads (the nested shard_map flash path's condition)
+        mesh = auto_partitioner_mesh()
+        if mesh is not None and ("tp" not in mesh.axis_names
+                                 or cfg.num_heads % mesh.shape["tp"]):
+            mesh = None
         if impl == "auto":
-            from nezha_tpu.models.gpt2 import _resolve_auto_impl
-            impl = _resolve_auto_impl(cfg) if mask is None else "xla"
+            # GPT2Config.attn_impl's rule: flash where the kernels run,
+            # else the nested shard_map flash under such a mesh on a
+            # backend that compiles them, else composed
+            impl = ("xla" if mask is not None
+                    else "flash" if use_kernels("auto")
+                    else "flash_shmap"
+                    if mesh is not None and not resolve_interpret(None)
+                    else "xla")
         if impl == "flash_shmap":
             if mask is not None:
                 raise ValueError("attn_impl='flash_shmap' cannot apply an "
                                  "arbitrary padding mask; use right-padded "
                                  "batches with kv_lengths, or 'xla'")
             from nezha_tpu.models.gpt2 import _tp_sharded_flash
-            from nezha_tpu.parallel.gspmd import auto_partitioner_mesh
-            mesh = auto_partitioner_mesh()
-            if mesh is None or "tp" not in mesh.axis_names \
-                    or cfg.num_heads % mesh.shape["tp"]:
+            if mesh is None:
                 raise ValueError(
                     f"attn_impl='flash_shmap' needs an enclosing gspmd "
                     f"trace carrying a mesh with a 'tp' axis dividing "

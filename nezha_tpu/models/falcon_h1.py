@@ -3,7 +3,7 @@ attention and a Mamba-2 mixer side by side on the same normed input, then
 a SwiGLU MLP; µP multipliers on every branch.
 
 Per layer, with ``x`` a token's hidden state (the residual stream is
-float32, as in ``mistral4.py``; ``x_0 = E[t] * embedding_multiplier``)::
+float32, as in ``decoder.py``; ``x_0 = E[t] * embedding_multiplier``)::
 
     n   = RMSNorm(x)                                       input_layernorm
     att = Attn(n * attention_in_multiplier) * attention_out_multiplier
@@ -13,7 +13,7 @@ float32, as in ``mistral4.py``; ``x_0 = E[t] * embedding_multiplier``)::
     x   = x + W_down(SiLU((m W_gate) * g) * (m W_up)) * d   (g, d: mlp_multipliers)
     logits = (RMSNorm(x_L) W_head) * lm_head_multiplier
 
-**Attention**: ``exaone_moe.GQAttention``'s paged decode and blocked
+**Attention**: ``attention.GQAttention``'s paged decode and blocked
 prefill, with this model's projection (:class:`Attention`): no QK-norm,
 ``k`` times ``key_multiplier``, ``q`` and ``k`` rotated in the half-split
 form at ``rope_theta``; query head ``h`` reads K/V head ``h // (H /
@@ -58,19 +58,21 @@ import jax.numpy as jnp
 from jax import lax
 
 from nezha_tpu import nn
-from nezha_tpu.models.exaone_moe import GQAttention
-from nezha_tpu.models.kimi_linear import _conv_taps
-from nezha_tpu.models.mistral4 import _linear, _project_f32
+from nezha_tpu.models.attention import GQAttention
+from nezha_tpu.models.decoder import (DecoderConfig, DecoderLM,
+                                      build_preset, conv_taps, linear,
+                                      project_f32)
 from nezha_tpu.nn.module import Module, Variables, run_child
 from nezha_tpu.ops import rotary
 from nezha_tpu.ops.pallas import (kda_conv_step, kda_conv_step_reference,
                                   ssd_chunked, ssm_decode,
                                   ssm_decode_reference)
+from nezha_tpu.ops.pallas.common import use_kernels
 from nezha_tpu.tensor.policy import DEFAULT_POLICY, Policy
 
 
 @dataclasses.dataclass(frozen=True)
-class FalconH1Config:
+class FalconH1Config(DecoderConfig):
     # The published keys (tiiuae/Falcon-H1-34B-Instruct config.json).
     vocab_size: int = 261120
     hidden_size: int = 5120
@@ -109,23 +111,15 @@ class FalconH1Config:
     # elsewhere; "kernel" / "xla" force one (ServeConfig.decode_impl).
     decode_impl: str = "auto"
 
-    # What serve.Engine and the pools read of any model's config.
-    @property
-    def num_layers(self) -> int:
-        return self.num_hidden_layers
-
-    @property
-    def num_heads(self) -> int:
-        return self.num_attention_heads
-
-    @property
-    def max_positions(self) -> int:
-        return self.max_position_embeddings
-
     @property
     def conv_dim(self) -> int:
         """The convolution's channels: ``xs``, ``B`` and ``C``."""
         return self.mamba_d_ssm + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def sparse_layers(self) -> Tuple[int, ...]:
+        """No expert layer."""
+        return ()
 
 
 # One chip's share of the stated deployment: the first four layers (all of
@@ -141,11 +135,6 @@ TINY_KW = dict(
     rope_theta=1e4, max_position_embeddings=4096)
 
 
-def _uses_kernels(impl: str) -> bool:
-    return impl == "kernel" or (impl == "auto"
-                                and jax.default_backend() == "tpu")
-
-
 class Attention(GQAttention):
     """``GQAttention`` (its cache-less, decode and prefill paths) over
     this model's projection: no QK-norm; the keys times
@@ -154,10 +143,10 @@ class Attention(GQAttention):
     def __init__(self, cfg: FalconH1Config, policy: Policy):
         self.cfg, self.window, self.policy = cfg, None, policy
         h, d = cfg.hidden_size, cfg.head_dim
-        self.q = _linear(h, cfg.num_attention_heads * d, policy)
-        self.k = _linear(h, cfg.num_key_value_heads * d, policy)
-        self.v = _linear(h, cfg.num_key_value_heads * d, policy)
-        self.o = _linear(cfg.num_attention_heads * d, h, policy)
+        self.q = linear(h, cfg.num_attention_heads * d, policy)
+        self.k = linear(h, cfg.num_key_value_heads * d, policy)
+        self.v = linear(h, cfg.num_key_value_heads * d, policy)
+        self.o = linear(cfg.num_attention_heads * d, h, policy)
 
     def project(self, variables: Variables, x, positions):
         c = self.cfg
@@ -183,11 +172,11 @@ class SSMixer(Module):
     def __init__(self, cfg: FalconH1Config, policy: Policy):
         self.cfg, self.policy = cfg, policy
         c = cfg
-        self.in_proj = _linear(
+        self.in_proj = linear(
             c.hidden_size,
             2 * c.mamba_d_ssm + 2 * c.mamba_n_groups * c.mamba_d_state
             + c.mamba_n_heads, policy)
-        self.out_proj = _linear(c.mamba_d_ssm, c.hidden_size, policy)
+        self.out_proj = linear(c.mamba_d_ssm, c.hidden_size, policy)
 
     def init(self, rng: jax.Array) -> Variables:
         """The two projections, and the leaves that are no child's:
@@ -225,7 +214,7 @@ class SSMixer(Module):
         after softplus)."""
         c = self.cfg
         p = variables["params"]
-        zxbcdt = _project_f32(self.in_proj, variables, "in_proj", u)
+        zxbcdt = project_f32(self.in_proj, variables, "in_proj", u)
         sizes = (c.mamba_d_ssm, c.mamba_d_ssm,
                  c.mamba_n_groups * c.mamba_d_state,
                  c.mamba_n_groups * c.mamba_d_state, c.mamba_n_heads)
@@ -261,7 +250,7 @@ class SSMixer(Module):
         g = g * lax.rsqrt(jnp.mean(g * g, -1, keepdims=True)
                           + c.rms_norm_eps)
         y = g.reshape(y.shape) * p["norm"].astype(jnp.float32)
-        return _project_f32(self.out_proj, variables, "out_proj", y)
+        return project_f32(self.out_proj, variables, "out_proj", y)
 
     def _scan(self, variables, rows, dt, s0, valid):
         """One sequence: ``rows`` ``[d_conv - 1 + S, conv_dim]`` (the
@@ -271,7 +260,7 @@ class SSMixer(Module):
         c = self.cfg
         p = variables["params"]
         s = dt.shape[0]
-        xs, b, cc = self._split(_conv_taps(rows, p["conv"])
+        xs, b, cc = self._split(conv_taps(rows, p["conv"])
                                 + p["conv_bias"].astype(jnp.float32))
         dt = jnp.where((jnp.arange(s) < valid)[:, None], dt, 0.0)
         pad = -s % c.mamba_chunk_size       # the cache-less forward at any length
@@ -311,7 +300,7 @@ class SSMixer(Module):
             ent = tab[:, 0] if active is None else jnp.where(
                 active, tab[:, 0], 0)
             conv_step, update = (
-                (kda_conv_step, ssm_decode) if _uses_kernels(c.decode_impl)
+                (kda_conv_step, ssm_decode) if use_kernels(c.decode_impl)
                 else (kda_conv_step_reference, ssm_decode_reference))
             y, conv_pool = conv_step(conv_pool, ent, xbc[:, 0], p["conv"])
             xs, bb, cc = self._split(y + p["conv_bias"].astype(jnp.float32))
@@ -347,20 +336,21 @@ class MLP(Module):
 
     def __init__(self, cfg: FalconH1Config, policy: Policy):
         self.cfg = cfg
-        self.gate = _linear(cfg.hidden_size, cfg.intermediate_size, policy)
-        self.up = _linear(cfg.hidden_size, cfg.intermediate_size, policy)
-        self.down = _linear(cfg.intermediate_size, cfg.hidden_size, policy)
+        self.gate = linear(cfg.hidden_size, cfg.intermediate_size, policy)
+        self.up = linear(cfg.hidden_size, cfg.intermediate_size, policy)
+        self.down = linear(cfg.intermediate_size, cfg.hidden_size, policy)
 
     def apply(self, variables: Variables, x, training: bool = False, rng=None):
         g_mult, d_mult = self.cfg.mlp_multipliers
-        g = _project_f32(self.gate, variables, "gate", x)
-        u = _project_f32(self.up, variables, "up", x)
-        return _project_f32(self.down, variables, "down",
-                            jax.nn.silu(g * g_mult) * u) * d_mult, {}
+        g = project_f32(self.gate, variables, "gate", x)
+        u = project_f32(self.up, variables, "up", x)
+        return project_f32(self.down, variables, "down",
+                           jax.nn.silu(g * g_mult) * u) * d_mult, {}
 
 
 class Block(Module):
-    def __init__(self, cfg: FalconH1Config, policy: Policy):
+    def __init__(self, cfg: FalconH1Config, layer: int, policy: Policy):
+        del layer       # every layer is alike
         self.cfg = cfg
         h = cfg.hidden_size
         self.input_layernorm = nn.RMSNorm(h, cfg.rms_norm_eps, policy)
@@ -397,14 +387,14 @@ class Block(Module):
         return x + run_child(self.mlp, "mlp", variables, states, m), states
 
 
-class FalconH1(Module):
-    """Returns logits [B, S, vocab_held] (float32); untied head."""
+class FalconH1(DecoderLM):
+    """The embedding enters times ``embedding_multiplier``, the logits
+    leave times ``lm_head_multiplier``."""
+
+    Block = Block
 
     def __init__(self, cfg: FalconH1Config = FalconH1Config(),
                  policy: Policy = DEFAULT_POLICY):
-        if not 1 <= cfg.vocab_held <= cfg.vocab_size:
-            raise ValueError(f"vocab_held {cfg.vocab_held} outside the "
-                             f"vocabulary of {cfg.vocab_size}")
         if cfg.num_attention_heads % cfg.num_key_value_heads:
             raise ValueError(
                 f"{cfg.num_attention_heads} query heads are not whole "
@@ -419,29 +409,14 @@ class FalconH1(Module):
             raise ValueError(
                 f"mamba_chunk_size {cfg.mamba_chunk_size}: the prefill "
                 f"buckets are scanned in power-of-two chunks")
-        self.cfg = cfg
-        self.policy = policy
-        self.embed = nn.Embedding(cfg.vocab_held, cfg.hidden_size,
-                                  policy=policy)
-        self.h = [Block(cfg, policy) for _ in range(cfg.num_hidden_layers)]
-        self.norm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps, policy)
-        self.lm_head = _linear(cfg.hidden_size, cfg.vocab_held, policy)
+        super().__init__(cfg, policy)
 
-    def apply(self, variables: Variables, batch, training: bool = False,
-              rng=None, cache=None, pos=None, prefill: bool = False,
-              active=None):
-        c = self.cfg
-        tokens = batch["tokens"][:, :-1] if isinstance(batch, dict) else batch
-        states: dict = {}
-        x = run_child(self.embed, "embed", variables, states, tokens)
-        x = x.astype(jnp.float32) * c.embedding_multiplier
-        for i, block in enumerate(self.h):
-            x = run_child(block, f"h{i}", variables, states, x,
-                          cache=None if cache is None else cache[i],
-                          pos=pos, prefill=prefill, active=active)
-        x = run_child(self.norm, "norm", variables, states, x)
-        return (_project_f32(self.lm_head, variables, "lm_head", x)
-                * c.lm_head_multiplier), states
+    def enter(self, x):
+        return x.astype(jnp.float32) * self.cfg.embedding_multiplier
+
+    def read_out(self, variables: Variables, states: dict, x):
+        return (super().read_out(variables, states, x)
+                * self.cfg.lm_head_multiplier)
 
     # ------------------------------------- what serve.Engine asks a model
     @property
@@ -484,12 +459,6 @@ class FalconH1(Module):
                 "state_tables": ssm["tables"]})
         return out
 
-    def expert_load(self, states: dict):
-        return None
-
-    def paged_prefill_uses_kernel(self) -> bool:
-        return False
-
     def prefill_scan_chunks(self, width: int) -> int:
         """Chunks a state layer's scan walks in a prefill bucket of
         ``width`` tokens (the ``serve.engine.prefill`` span's
@@ -499,15 +468,6 @@ class FalconH1(Module):
 
 def falcon_h1(preset: str = "full", policy: Optional[Policy] = None,
               **overrides) -> FalconH1:
-    """``full``: one chip's share at the published widths (``FULL_KW``),
-    bf16 parameters and compute. ``tiny``: float32, for CPU tests."""
-    if preset == "full":
-        kw = dict(FULL_KW)
-        policy = policy or Policy(jnp.bfloat16, jnp.bfloat16)
-    elif preset == "tiny":
-        kw = dict(TINY_KW)
-        policy = policy or DEFAULT_POLICY
-    else:
-        raise ValueError(f"unknown preset {preset!r}")
-    kw.update(overrides)
-    return FalconH1(FalconH1Config(**kw), policy=policy)
+    """``models.decoder.build_preset``'s ``full`` / ``tiny``."""
+    return build_preset(FalconH1, FalconH1Config, FULL_KW, TINY_KW, preset,
+                        policy, overrides)

@@ -139,13 +139,12 @@ def _tp_flash_mesh(num_heads: int):
     usable for ``num_heads`` (TPU backend, a ``tp`` axis that divides the
     heads); None otherwise. (Mosaic inside shard_map compiled and ran on
     a four-chip v5e host in PR 21 — ``chip_smoke.py --chips 4``.)"""
-    import jax
-
+    from nezha_tpu.ops.pallas.common import resolve_interpret
     from nezha_tpu.parallel.gspmd import auto_partitioner_mesh
     mesh = auto_partitioner_mesh()
     if (mesh is not None and "tp" in mesh.axis_names
             and num_heads % mesh.shape["tp"] == 0
-            and jax.default_backend() == "tpu"):
+            and not resolve_interpret(None)):   # the kernels compile here
         return mesh
     return None
 
@@ -251,14 +250,10 @@ def _prefill_flash_shmap_mesh(cfg):
 
 
 def _flash_auto_ok() -> bool:
-    """ONE backend policy for every attn_impl='auto' site (train, prefill,
-    BERT): compiled flash on TPU, and never under the GSPMD
-    auto-partitioner (jit-with-shardings cannot partition a Mosaic custom
-    call; shard_map paths see per-device blocks and are fine)."""
-    import jax
-
-    from nezha_tpu.parallel.gspmd import under_auto_partitioner
-    return jax.default_backend() == "tpu" and not under_auto_partitioner()
+    """Whether attn_impl='auto' takes the compiled kernels here: the
+    package-wide rule (``ops.pallas.common.use_kernels``)."""
+    from nezha_tpu.ops.pallas.common import use_kernels
+    return use_kernels("auto")
 
 
 def _pool_rows(x):
@@ -1056,6 +1051,11 @@ class GPT2(Module):
 
 
     # ------------------------------------- what serve.Engine asks a model
+    # No recurrent-state layers, no hyper-connection maps
+    # (``models.decoder.DecoderLM`` has both counts).
+    ssm_layers = 0
+    mhc_sublayers = 0
+
     def cache_leaves(self, block_size: int, dtype, quantized: bool = False
                      ) -> list:
         """One entry a layer, ``(group, window, leaves)``: the cache

@@ -4,7 +4,7 @@ layers without positions (NoPE MLA), 3 to 1; a leading dense layer and
 then sigmoid-routed dropless experts with a shared expert.
 
 Per layer ``l``, with ``x`` a token's hidden state (the residual stream is
-float32, as in ``mistral4.py``)::
+float32, as in ``decoder.py``)::
 
     h = x + Mix_l(RMSNorm(x))              # KDA or MLA by linear_attn_config
     y = h + MLP(RMSNorm(h))                          # l < first_k_dense_replace
@@ -35,7 +35,7 @@ admission) and writes back the state and the tail after the chunk's last
 REAL token: a pad has ``beta = 0`` and ``g = 0`` and does not enter the
 tail (the engine says how many tokens are real: ``cache["valid"]``).
 
-**MLA layer**: ``mistral4.MLAttention`` with a direct query projection
+**MLA layer**: ``attention.MLAttention`` with a direct query projection
 (``q_lora_rank=None``) and no rotation (``mla_use_nope``): nothing in this
 model reads a position. Its latent rows live in the growing table;
 decode runs the paged kernel's latent form, a prefill chunk folds the
@@ -58,12 +58,15 @@ import jax.numpy as jnp
 from jax import lax
 
 from nezha_tpu import nn
-from nezha_tpu.models.mistral4 import (GatedMLP, MLAttention, _linear,
-                                       _project_f32)
+from nezha_tpu.models.attention import MLAttention
+from nezha_tpu.models.decoder import (DecoderConfig, DecoderLM, GatedMLP,
+                                      build_preset, conv_taps, linear,
+                                      project_f32)
 from nezha_tpu.nn.module import Module, Variables, run_child
 from nezha_tpu.ops.pallas import (kda_chunked, kda_conv_step,
                                   kda_conv_step_reference, kda_decode,
                                   kda_decode_reference)
+from nezha_tpu.ops.pallas.common import use_kernels
 from nezha_tpu.parallel.expert import DroplessMoE, DroplessMoEConfig
 from nezha_tpu.tensor.policy import DEFAULT_POLICY, Policy
 
@@ -74,7 +77,7 @@ _KDA = tuple(l for l in range(1, 28) if l not in _FULL)
 
 
 @dataclasses.dataclass(frozen=True)
-class KimiLinearConfig:
+class KimiLinearConfig(DecoderConfig):
     # The published keys (moonshotai/Kimi-Linear-48B-A3B-Instruct
     # config.json; ``linear_attn_config`` flattened to ``kda_*`` /
     # ``full_attn_layers`` / ``short_conv_kernel_size``).
@@ -111,20 +114,11 @@ class KimiLinearConfig:
     # The chip's share (the defaults are the whole model).
     experts_held: Tuple[int, int] = (0, 256)
     vocab_held: int = 163840
-    # How a decode step reads both caches (mistral4.Mistral4Config,
-    # ServeConfig.decode_impl): "auto" is the kernels on a TPU (the paged
+    # How a decode step reads both caches (ServeConfig.decode_impl,
+    # ops.pallas.common.use_kernels): "auto" is the kernels on a TPU (the paged
     # kernel's latent form, the KDA update and convolution step) and their
     # ``jax.numpy`` twins elsewhere; "kernel" / "xla" force one.
     decode_impl: str = "auto"
-
-    # What serve.Engine and the pools read of any model's config.
-    @property
-    def num_layers(self) -> int:
-        return self.num_hidden_layers
-
-    @property
-    def num_heads(self) -> int:
-        return self.num_attention_heads
 
     @property
     def max_positions(self) -> int:
@@ -134,7 +128,7 @@ class KimiLinearConfig:
     def num_experts_per_tok(self) -> int:
         return self.num_experts_per_token
 
-    # What mistral4.MLAttention reads beside the published keys.
+    # What attention.MLAttention reads beside the published keys.
     @property
     def latent_width(self) -> int:
         return self.kv_lora_rank + self.qk_rope_head_dim
@@ -172,16 +166,6 @@ TINY_KW = dict(
     moe_intermediate_size=32, model_max_length=4096)
 
 
-def _conv_taps(rows, w):
-    """``y_t = sum_i w_i x_{t-K+1+i}`` over ``rows`` ``[..., K - 1 + T,
-    C]`` (the tail, then the chunk) -> ``[..., T, C]`` float32: a plain
-    sum of ``K`` shifted slices."""
-    k = w.shape[0]
-    t = rows.shape[-2] - k + 1
-    rows, w = rows.astype(jnp.float32), w.astype(jnp.float32)
-    return sum(rows[..., i:i + t, :] * w[i] for i in range(k))
-
-
 class KDAttention(Module):
     """One KDA layer (the module docstring has the equations)."""
 
@@ -189,16 +173,16 @@ class KDAttention(Module):
         self.cfg, self.policy = cfg, policy
         h = cfg.hidden_size
         inner = cfg.kda_num_heads * cfg.kda_head_dim
-        self.q = _linear(h, inner, policy)
-        self.k = _linear(h, inner, policy)
-        self.v = _linear(h, inner, policy)
-        self.f_a = _linear(h, cfg.kda_gate_rank, policy)
-        self.f_b = _linear(cfg.kda_gate_rank, inner, policy)
-        self.g_a = _linear(h, cfg.kda_gate_rank, policy)
-        self.g_b = _linear(cfg.kda_gate_rank, inner, policy)
-        self.b = _linear(h, cfg.kda_num_heads, policy)
+        self.q = linear(h, inner, policy)
+        self.k = linear(h, inner, policy)
+        self.v = linear(h, inner, policy)
+        self.f_a = linear(h, cfg.kda_gate_rank, policy)
+        self.f_b = linear(cfg.kda_gate_rank, inner, policy)
+        self.g_a = linear(h, cfg.kda_gate_rank, policy)
+        self.g_b = linear(cfg.kda_gate_rank, inner, policy)
+        self.b = linear(h, cfg.kda_num_heads, policy)
         self.o_norm = nn.RMSNorm(cfg.kda_head_dim, cfg.rms_norm_eps, policy)
-        self.o = _linear(inner, h, policy)
+        self.o = linear(inner, h, policy)
 
     def init(self, rng: jax.Array) -> Variables:
         """The linear children, and the leaves that are no child's:
@@ -236,14 +220,14 @@ class KDAttention(Module):
              for m, n in ((self.q, "q"), (self.k, "k"), (self.v, "v"))],
             axis=-1)
         p = variables["params"]
-        f = _project_f32(self.f_b, variables, "f_b",
-                         _project_f32(self.f_a, variables, "f_a", x))
+        f = project_f32(self.f_b, variables, "f_b",
+                        project_f32(self.f_a, variables, "f_a", x))
         g = -jnp.exp(p["a_log"])[:, None] * jax.nn.softplus(
             f + p["dt_bias"]).reshape(b, s, heads, d)
-        beta = jax.nn.sigmoid(_project_f32(self.b, variables, "b", x))
-        gate = jax.nn.sigmoid(_project_f32(
+        beta = jax.nn.sigmoid(project_f32(self.b, variables, "b", x))
+        gate = jax.nn.sigmoid(project_f32(
             self.g_b, variables, "g_b",
-            _project_f32(self.g_a, variables, "g_a", x)))
+            project_f32(self.g_a, variables, "g_a", x)))
         return qkv, g, beta, gate.reshape(b, s, heads, d)
 
     def _heads(self, conv_out):
@@ -264,7 +248,7 @@ class KDAttention(Module):
         """``o`` [B, S, H, d] float32 -> the layer's output [B, S, h]."""
         o = run_child(self.o_norm, "o_norm", variables, {}, o)
         o = (o.astype(jnp.float32) * gate).reshape(*o.shape[:2], -1)
-        return _project_f32(self.o, variables, "o", o)
+        return project_f32(self.o, variables, "o", o)
 
     def _scan(self, conv_w, qkv_rows, g, beta, s0, valid):
         """One sequence: ``qkv_rows`` ``[K - 1 + S, 3*H*d]`` (the tail,
@@ -273,7 +257,7 @@ class KDAttention(Module):
         state after the last real token)."""
         c = self.cfg
         s = g.shape[0]
-        q, k, v = self._heads(_conv_taps(qkv_rows, conv_w))
+        q, k, v = self._heads(conv_taps(qkv_rows, conv_w))
         real = jnp.arange(s) < valid
         g = jnp.where(real[:, None, None], g, 0.0)
         beta = jnp.where(real[:, None], beta, 0.0)
@@ -318,10 +302,8 @@ class KDAttention(Module):
             # updates the scratch entry (entry 0) instead of its own.
             ent = tab[:, 0] if active is None else jnp.where(
                 active, tab[:, 0], 0)
-            impl = c.decode_impl
             conv_step, update = (
-                (kda_conv_step, kda_decode) if impl == "kernel" or (
-                    impl == "auto" and jax.default_backend() == "tpu")
+                (kda_conv_step, kda_decode) if use_kernels(c.decode_impl)
                 else (kda_conv_step_reference, kda_decode_reference))
             y, conv_pool = conv_step(conv_pool, ent, qkv[:, 0], conv_w)
             q, k, v = self._heads(y)
@@ -380,7 +362,7 @@ class Block(Module):
                       run_child(self.attn_norm, "attn_norm", variables,
                                 states, x),
                       cache=cache, pos=pos, prefill=prefill, active=active)
-        x = x.astype(jnp.float32) + a       # float32 residual (mistral4.py)
+        x = x.astype(jnp.float32) + a       # float32 residual (decoder.py)
         y = run_child(self.mlp_norm, "mlp_norm", variables, states, x)
         if not self.sparse:
             return x + run_child(self.mlp, "mlp", variables, states, y), states
@@ -391,38 +373,15 @@ class Block(Module):
         return x + shared + routed.reshape(b, s, h), states
 
 
-class KimiLinear(Module):
-    """Returns logits [B, S, vocab_held] (float32); untied head."""
+class KimiLinear(DecoderLM):
+    Block = Block
 
     def __init__(self, cfg: KimiLinearConfig = KimiLinearConfig(),
                  policy: Policy = DEFAULT_POLICY):
-        if not 1 <= cfg.vocab_held <= cfg.vocab_size:
-            raise ValueError(f"vocab_held {cfg.vocab_held} outside the "
-                             f"vocabulary of {cfg.vocab_size}")
         if cfg.kda_chunk & (cfg.kda_chunk - 1):
             raise ValueError(f"kda_chunk {cfg.kda_chunk}: the prefill "
                              f"buckets are scanned in power-of-two chunks")
-        self.cfg = cfg
-        self.policy = policy
-        self.embed = nn.Embedding(cfg.vocab_held, cfg.hidden_size,
-                                  policy=policy)
-        self.h = [Block(cfg, i, policy)
-                  for i in range(cfg.num_hidden_layers)]
-        self.norm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps, policy)
-        self.lm_head = _linear(cfg.hidden_size, cfg.vocab_held, policy)
-
-    def apply(self, variables: Variables, batch, training: bool = False,
-              rng=None, cache=None, pos=None, prefill: bool = False,
-              active=None):
-        tokens = batch["tokens"][:, :-1] if isinstance(batch, dict) else batch
-        states: dict = {}
-        x = run_child(self.embed, "embed", variables, states, tokens)
-        for i, block in enumerate(self.h):
-            x = run_child(block, f"h{i}", variables, states, x,
-                          cache=None if cache is None else cache[i],
-                          pos=pos, prefill=prefill, active=active)
-        x = run_child(self.norm, "norm", variables, states, x)
-        return _project_f32(self.lm_head, variables, "lm_head", x), states
+        super().__init__(cfg, policy)
 
     # ------------------------------------- what serve.Engine asks a model
     def cache_leaves(self, block_size: int, dtype, quantized: bool = False
@@ -452,26 +411,6 @@ class KimiLinear(Module):
                 else ("global", None, latent)
                 for i in range(c.num_hidden_layers)]
 
-    def caches_from_states(self, states: dict, prev: list) -> list:
-        return [states.get(f"h{i}", {}).get("attn", {}).get("cache", prev[i])
-                for i in range(self.cfg.num_hidden_layers)]
-
-    def expert_load(self, states: dict):
-        """[sparse layers, experts held] int32: pairs computed per held
-        expert in this forward pass."""
-        return jnp.stack([states[f"h{i}"]["moe"]["load"]
-                          for i in self.cfg.sparse_layers])
-
-    def expert_visits(self, states: dict):
-        """[sparse layers, 2] int32: the (row tile, expert) visits the
-        experts' kernel made in this forward pass and the held experts
-        it touched (``ops/pallas/moe_experts.py::visit_plan``)."""
-        return jnp.stack([states[f"h{i}"]["moe"]["visits"]
-                          for i in self.cfg.sparse_layers])
-
-    def paged_prefill_uses_kernel(self) -> bool:
-        return False
-
     def prefill_scan_chunks(self, width: int) -> int:
         """Chunks a state layer's scan walks in a prefill bucket of
         ``width`` tokens (asked of a model with state layers only: the
@@ -481,15 +420,6 @@ class KimiLinear(Module):
 
 def kimi_linear(preset: str = "full", policy: Optional[Policy] = None,
                 **overrides) -> KimiLinear:
-    """``full``: one chip's share at the published widths (``FULL_KW``),
-    bf16 parameters and compute. ``tiny``: float32, for CPU tests."""
-    if preset == "full":
-        kw = dict(FULL_KW)
-        policy = policy or Policy(jnp.bfloat16, jnp.bfloat16)
-    elif preset == "tiny":
-        kw = dict(TINY_KW)
-        policy = policy or DEFAULT_POLICY
-    else:
-        raise ValueError(f"unknown preset {preset!r}")
-    kw.update(overrides)
-    return KimiLinear(KimiLinearConfig(**kw), policy=policy)
+    """``models.decoder.build_preset``'s ``full`` / ``tiny``."""
+    return build_preset(KimiLinear, KimiLinearConfig, FULL_KW, TINY_KW,
+                        preset, policy, overrides)

@@ -15,7 +15,7 @@ A token carries ``X`` in ``R^{n x C}`` (float32, stored flat ``[n*C]``).
     MLP = Shared(v) + sum_{e in top-k, held} w_e E_e(v)             otherwise
 
 and after the last layer ``logits = RMSNorm(sum_i X_i) W_head`` (untied).
-``Attn`` is ``mistral4.MLAttention`` as Mistral-Small-4 runs it (absorbed at
+``Attn`` is ``attention.MLAttention`` as Mistral-Small-4 runs it (absorbed at
 decode through the paged kernel's latent form, yarn's ``m^2`` in the softmax
 scale, interleaved rotary pairs, no position-dependent query scaling); the
 router is ``parallel.expert.route_top_k`` with sigmoid scores, one group.
@@ -39,17 +39,18 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 
 from nezha_tpu import nn
-from nezha_tpu.models.mistral4 import (GatedMLP, MLAttention, _linear,
-                                       _project_f32)
+from nezha_tpu.models.attention import MLAttention
+from nezha_tpu.models.decoder import (DecoderConfig, DecoderLM, GatedMLP,
+                                      build_preset)
 from nezha_tpu.nn.hyper_connections import HyperConnection, sinkhorn_residual
 from nezha_tpu.nn.module import Module, Variables, child_vars, run_child
 from nezha_tpu.ops import rotary
 from nezha_tpu.parallel.expert import DroplessMoE, DroplessMoEConfig
-from nezha_tpu.tensor.policy import DEFAULT_POLICY, Policy
+from nezha_tpu.tensor.policy import Policy
 
 
 @dataclasses.dataclass(frozen=True)
-class Xing4Config:
+class Xing4Config(DecoderConfig):
     # The published keys (XingChen-AGI/Xing4.0-29B-A4B config.json;
     # ``rope_scaling`` flattened to ``rope_*``).
     vocab_size: int = 131072
@@ -92,20 +93,7 @@ class Xing4Config:
     # elsewhere. "kernel" / "xla" force one (ServeConfig.decode_impl).
     decode_impl: str = "auto"
 
-    # What serve.Engine and the pools read of any model's config.
-    @property
-    def num_layers(self) -> int:
-        return self.num_hidden_layers
-
-    @property
-    def num_heads(self) -> int:
-        return self.num_attention_heads
-
-    @property
-    def max_positions(self) -> int:
-        return self.max_position_embeddings
-
-    # What mistral4.MLAttention reads beside the published keys.
+    # What attention.MLAttention reads beside the published keys.
     llama_4_scaling_beta = 0.0      # the query is not scaled by position
 
     @property
@@ -199,40 +187,19 @@ class Block(Module):
         return self.hc_mlp.post(x, f, maps_m), states
 
 
-class Xing4(Module):
-    """Returns logits [B, S, vocab_held] (float32); untied head. The
-    streams between the blocks are float32."""
+class Xing4(DecoderLM):
+    """The streams between the blocks are float32 ``[B, S, n*C]``: the
+    embedding enters every stream, and the read-out is their sum."""
 
-    def __init__(self, cfg: Xing4Config = Xing4Config(),
-                 policy: Policy = DEFAULT_POLICY):
-        if not 1 <= cfg.vocab_held <= cfg.vocab_size:
-            raise ValueError(f"vocab_held {cfg.vocab_held} outside the "
-                             f"vocabulary of {cfg.vocab_size}")
-        self.cfg = cfg
-        self.policy = policy
-        self.embed = nn.Embedding(cfg.vocab_held, cfg.hidden_size,
-                                  policy=policy)
-        self.h = [Block(cfg, i, policy)
-                  for i in range(cfg.num_hidden_layers)]
-        self.norm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps, policy)
-        self.lm_head = _linear(cfg.hidden_size, cfg.vocab_held, policy)
+    Block = Block
 
-    def apply(self, variables: Variables, batch, training: bool = False,
-              rng=None, cache=None, pos=None, prefill: bool = False,
-              active=None):
-        tokens = batch["tokens"][:, :-1] if isinstance(batch, dict) else batch
-        states: dict = {}
-        n = self.cfg.hc_mult
-        e = run_child(self.embed, "embed", variables, states,
-                      tokens).astype(jnp.float32)
-        x = jnp.concatenate([e] * n, axis=-1)       # every stream starts as e
-        for i, block in enumerate(self.h):
-            x = run_child(block, f"h{i}", variables, states, x,
-                          cache=None if cache is None else cache[i],
-                          pos=pos, prefill=prefill, active=active)
-        x = x.reshape(x.shape[:-1] + (n, -1)).sum(axis=-2)  # the read-out
-        x = run_child(self.norm, "norm", variables, states, x)
-        return _project_f32(self.lm_head, variables, "lm_head", x), states
+    def enter(self, x):
+        e = x.astype(jnp.float32)
+        return jnp.concatenate([e] * self.cfg.hc_mult, axis=-1)
+
+    def read_out(self, variables: Variables, states: dict, x):
+        x = x.reshape(x.shape[:-1] + (self.cfg.hc_mult, -1)).sum(axis=-2)
+        return super().read_out(variables, states, x)
 
     # ------------------------------------- what serve.Engine asks a model
     def cache_leaves(self, block_size: int, dtype, quantized: bool = False
@@ -247,22 +214,6 @@ class Xing4(Module):
         leaves = {"latent": ((block_size, self.cfg.latent_row_width), dtype)}
         return [("global", None, leaves)] * self.cfg.num_hidden_layers
 
-    def caches_from_states(self, states: dict, prev: list) -> list:
-        return [states.get(f"h{i}", {}).get("attn", {}).get("cache", prev[i])
-                for i in range(self.cfg.num_hidden_layers)]
-
-    def expert_load(self, states: dict):
-        """[sparse layers, experts held] int32: pairs computed per held
-        expert in this forward pass."""
-        return jnp.stack([states[f"h{i}"]["moe"]["load"]
-                          for i in self.cfg.sparse_layers])
-
-    def expert_visits(self, states: dict):
-        """[sparse layers, 2] int32: the experts' kernel's (row tile,
-        expert) visits and the held experts it touched."""
-        return jnp.stack([states[f"h{i}"]["moe"]["visits"]
-                          for i in self.cfg.sparse_layers])
-
     def mhc_residual(self, states: dict):
         """The largest ``|row sum - 1|`` or ``|column sum - 1|`` of any
         ``H_res`` of this forward pass (every token, both sublayers of
@@ -275,22 +226,10 @@ class Xing4(Module):
         """Maps computed a token a forward pass."""
         return 2 * self.cfg.num_hidden_layers
 
-    def paged_prefill_uses_kernel(self) -> bool:
-        return False
-
 
 def xing4(preset: str = "full", policy: Optional[Policy] = None,
           **overrides) -> Xing4:
-    """``full``: the first pipeline stage at the published widths
-    (``FULL_KW``), bf16 parameters and compute. ``tiny``: float32, for CPU
-    tests."""
-    if preset == "full":
-        kw = dict(FULL_KW)
-        policy = policy or Policy(jnp.bfloat16, jnp.bfloat16)
-    elif preset == "tiny":
-        kw = dict(TINY_KW)
-        policy = policy or DEFAULT_POLICY
-    else:
-        raise ValueError(f"unknown preset {preset!r}")
-    kw.update(overrides)
-    return Xing4(Xing4Config(**kw), policy=policy)
+    """``models.decoder.build_preset``'s ``full`` (here the first pipeline
+    stage) / ``tiny``."""
+    return build_preset(Xing4, Xing4Config, FULL_KW, TINY_KW, preset, policy,
+                        overrides)

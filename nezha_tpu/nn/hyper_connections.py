@@ -28,8 +28,9 @@ phi_post | phi_res]``), ``b`` ``[n*n + 2n]`` in the same order, ``alpha``
 ``[3]`` (pre, post, res); all float32 whatever the policy, as the maps and
 the streams are. :class:`HyperConnection` has two forms of :meth:`pre` and
 :meth:`post`: the Pallas kernels of ``ops/pallas/mhc.py`` (``impl``
-``"kernel"``, or ``"auto"`` on a TPU) and the composed ``jax.numpy`` form
-here, which is the path off the TPU and the kernels' test oracle.
+``"kernel"``, or ``"auto"`` as ``ops.pallas.common.use_kernels`` rules)
+and the composed ``jax.numpy`` form here, which is the path off the TPU
+and the kernels' test oracle.
 """
 
 from __future__ import annotations
@@ -114,8 +115,9 @@ class HyperConnection(Module):
             "b": init_lib.normal(0.02)(r_b, (self.num_maps,), jnp.float32)})
 
     def _use_kernel(self) -> bool:
-        return self.impl == "kernel" or (
-            self.impl == "auto" and jax.default_backend() == "tpu")
+        # imported here: ops/pallas/mhc.py imports this module
+        from nezha_tpu.ops.pallas.common import use_kernels
+        return use_kernels(self.impl)
 
     def static_args(self) -> dict:
         return dict(n=self.streams, iters=self.sinkhorn_iters, eps=self.eps,

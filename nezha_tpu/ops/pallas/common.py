@@ -42,6 +42,25 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     return not on_tpu if interpret is None else interpret
 
 
+def use_kernels(impl: str) -> bool:
+    """THE kernel-or-XLA choice of every model's ``*_impl`` knob:
+    ``"kernel"`` forces the kernels (interpreted off a TPU), ``"xla"``
+    their composed forms, and ``"auto"`` takes the kernels on a TPU
+    backend outside the GSPMD auto-partitioner (jit-with-shardings
+    cannot partition a Mosaic custom call; shard_map paths see
+    per-device blocks and decide for themselves)."""
+    if impl == "kernel":
+        return True
+    if impl == "xla":
+        return False
+    if impl != "auto":
+        raise ValueError(f"impl {impl!r}: expected 'auto', 'kernel' or "
+                         f"'xla'")
+    # imported here: ops/ loads nothing of parallel/ when it is imported
+    from nezha_tpu.parallel.gspmd import under_auto_partitioner
+    return jax.default_backend() == "tpu" and not under_auto_partitioner()
+
+
 def pick_block(size: int, target: int) -> int:
     """Largest divisor of ``size`` that is <= target (block shapes must
     tile the sequence exactly)."""

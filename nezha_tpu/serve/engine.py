@@ -693,12 +693,9 @@ class Engine:
         # it computes a token (``mhc_sublayers``) and how far a pass's
         # worst H_res lay from doubly stochastic (``mhc_residual``): the
         # serve programs return that scalar last and this is its running
-        # maximum (0.0 for any other model).
-        self._mhc_sublayers = _mhc_sublayers(model)
-        # A model with state-space layers declares how many
-        # (``ssm_layers``): the ``serve.ssm.*`` counters' multiplier, 0
-        # for any other model.
-        self._ssm_layers = int(getattr(model, "ssm_layers", 0))
+        # maximum (0.0 for any other model). A model with state-space
+        # layers declares how many (``ssm_layers``): the ``serve.ssm.*``
+        # counters' multiplier. Both are 0 for any other model.
         self._mhc_pending: List[Any] = []
         self.mhc_residual_max = 0.0
         # Tokens the most recent prefill's compiled chunks pushed
@@ -1023,9 +1020,9 @@ class Engine:
             state_chunks = sum(
                 self.model.prefill_scan_chunks(w) for _, _, w in chunks)
             ann.set(state_chunks=state_chunks)
-            if self._ssm_layers:
+            if self.model.ssm_layers:
                 obs.counter("serve.ssm.scan_chunks_total").inc(
-                    state_chunks * self._ssm_layers)
+                    state_chunks * self.model.ssm_layers)
             if start == 0:
                 obs.counter("serve.state.resets_total").inc()
         qerrs: List[Any] = []
@@ -1079,12 +1076,12 @@ class Engine:
                     # completion.
                     out, err = out[:-1], out[-1]
                     qerrs.append(err)
-                if self._mhc_sublayers:
+                if self.model.mhc_sublayers:
                     # the chunk's worst H_res, read at the next step
                     out, res = out[:-1], out[-1]
                     self._mhc_pending.append(res)
                     obs.counter("serve.mhc.maps_total").inc(
-                        width * self._mhc_sublayers)
+                        width * self.model.mhc_sublayers)
                 (self.pool.caches, self.last_logits, self.positions,
                  self.keys, self.temps, self.top_ks, self.top_ps,
                  self.eos_ids, self.budgets) = out
@@ -1315,7 +1312,7 @@ class Engine:
             # in the scheduler): the fetches then find bytes already in
             # flight instead of paying the full sync serially.
             _start_host_copies(tok, emitted, ok, full_sorts, *load)
-            mhc = load.pop() if self._mhc_sublayers else None
+            mhc = load.pop() if self.model.mhc_sublayers else None
         self.pool.caches = caches
         if faults.enabled():
             last = faults.corrupt(
@@ -1354,10 +1351,10 @@ class Engine:
                 if block.mhc is not None:
                     self._record_mhc(block.rows, block.mhc)
         obs.counter("serve.sampling.full_sort_steps_total").inc(full_sorts_h)
-        if self._ssm_layers:
+        if self.model.ssm_layers:
             # a row's state advances once a token it emitted, a layer
             obs.counter("serve.ssm.decode_rows_total").inc(
-                int(emitted_h.sum()) * self._ssm_layers)
+                int(emitted_h.sum()) * self.model.ssm_layers)
         if block.load:
             self._record_expert_load(block.rows, visits_h)
         # Positions advance and budgets decay on device exactly once an
@@ -1378,7 +1375,8 @@ class Engine:
         pending, self._mhc_pending = self._mhc_pending, []
         worst = max(float(np.asarray(r)) for r in (residual, *pending))
         self.mhc_residual_max = max(self.mhc_residual_max, worst)
-        obs.counter("serve.mhc.maps_total").inc(tokens * self._mhc_sublayers)
+        obs.counter("serve.mhc.maps_total").inc(
+            tokens * self.model.mhc_sublayers)
         obs.gauge("serve.mhc.sinkhorn_residual_max").set(
             self.mhc_residual_max)
 
@@ -1560,15 +1558,6 @@ def _pool_leaves(new_rows, caches):
     return [{kk: r[kk] for kk in pool} for r, pool in zip(new_rows, caches)]
 
 
-def _mhc_sublayers(model) -> int:
-    """Maps a token passes in a model whose residual path is mixed by
-    hyper-connections (its ``mhc_sublayers``; such a model also has
-    ``mhc_residual(states)``), 0 for any other: the ONE attribute that
-    decides whether the serve programs return the residual and whether
-    the engine reads it."""
-    return int(getattr(model, "mhc_sublayers", 0))
-
-
 def _build_prefill(model, width: int, quantized: bool = False,
                    groups=None):
     def prefill(variables, caches, tables, tokens, length, slot, pos,
@@ -1624,7 +1613,7 @@ def _build_prefill(model, width: int, quantized: bool = False,
                set_row(top_ps, top_p),
                set_row(eos_ids, eos_id),
                set_row(budgets, budget))
-        if _mhc_sublayers(model):       # last: Engine.prefill pops it
+        if model.mhc_sublayers:         # last: Engine.prefill pops it
             out += (model.mhc_residual(states),)
         return out + (qerr,) if quantized else out
 
@@ -1688,7 +1677,7 @@ def _build_step(model, k_max: int, pad_id: int, horizon: int, groups=None):
             load = (load, model.expert_visits(states))
         # ... and, of a model whose residual path is mixed by maps, how
         # far this pass's worst H_res lay from doubly stochastic
-        mhc = model.mhc_residual(states) if _mhc_sublayers(model) else None
+        mhc = model.mhc_residual(states) if model.mhc_sublayers else None
         new_caches = _pool_leaves(new_rows, caches)
         row_logits = logits[:, -1, :]
         ok = jnp.where(emit, ok & finite_rows(row_logits), ok)

@@ -120,6 +120,34 @@ def test_decode_impl_resolution():
     assert not _decode_flash_ok(GPT2Config(decode_impl="auto"))
 
 
+@pytest.mark.parametrize("impl, backend, partitioned, want", [
+    ("kernel", "cpu", False, True),     # forced: interpreted off a TPU
+    ("xla", "tpu", False, False),       # forced: composed on a TPU
+    ("auto", "cpu", False, False),
+    ("auto", "tpu", False, True),
+    ("auto", "tpu", True, False),       # never under the auto-partitioner
+    ("flash", "tpu", False, ValueError),
+])
+def test_use_kernels_is_the_one_kernel_policy(monkeypatch, impl, backend,
+                                              partitioned, want):
+    """``use_kernels`` is every model's ``*_impl`` rule: ``auto`` means
+    a TPU backend outside the GSPMD auto-partitioner, as GPT-2's
+    ``attn_impl="auto"`` always has; a name it does not know raises."""
+    import contextlib
+
+    from nezha_tpu.ops.pallas.common import use_kernels
+    from nezha_tpu.parallel.gspmd import auto_partitioner_scope
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with (auto_partitioner_scope() if partitioned
+          else contextlib.nullcontext()):
+        if want is ValueError:
+            with pytest.raises(ValueError, match="flash"):
+                use_kernels(impl)
+        else:
+            assert use_kernels(impl) is want
+
+
 # -------------------------------------------------------- benchmark CLI
 def test_decode_attention_benchmark_cli(tmp_path):
     """benchmarks/decode_attention.py runs at tier-1 shapes (interpret

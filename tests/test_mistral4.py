@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from chipbench.reference import mistral4 as ref
-from nezha_tpu.models import mistral4
+from nezha_tpu.models import attention
 from nezha_tpu.models.gpt2 import GPT2, GPT2Config
 from nezha_tpu.models.mistral4 import (TINY_KW, Mistral4, Mistral4Config,
                                        mistral_small4)
@@ -88,8 +88,8 @@ def test_engine_prefill_and_decode_match_reference(tiny, n_prompt, paths,
     model, variables = tiny
     kw = {}
     if paths == "kernel-folded":
-        monkeypatch.setattr(mistral4, "GATHERED_KEYS_MAX", 0)
-        monkeypatch.setattr(mistral4, "PREFILL_KEY_BLOCK", 16)
+        monkeypatch.setattr(attention, "GATHERED_KEYS_MAX", 0)
+        monkeypatch.setattr(attention, "PREFILL_KEY_BLOCK", 16)
         kw["decode_impl"] = "kernel"
     eng = _engine(model, variables, **kw)
     assert eng.model.cfg.decode_impl == kw.get("decode_impl", "auto")

@@ -21,9 +21,9 @@ import jax
 import jax.numpy as jnp
 
 from chipbench.reference import kimi_linear as ref
-from nezha_tpu.models import mistral4
+from nezha_tpu.models import attention
+from nezha_tpu.models.attention import MLAttention
 from nezha_tpu.models.kimi_linear import KimiLinearConfig, kimi_linear
-from nezha_tpu.models.mistral4 import MLAttention
 from nezha_tpu.parallel.expert import DroplessMoE, DroplessMoEConfig
 from nezha_tpu.serve import (Engine, Request, Scheduler, ServeConfig,
                              SpeculativeConfig)
@@ -64,8 +64,8 @@ def folded_tables(monkeypatch):
     time, as the deployment's 16,384-key table is; the engines here have
     tables of 96 keys, so the limit comes down to 0 and the key block to
     16, and every prefill below takes the deployment's path."""
-    monkeypatch.setattr(mistral4, "GATHERED_KEYS_MAX", 0)
-    monkeypatch.setattr(mistral4, "PREFILL_KEY_BLOCK", 16)
+    monkeypatch.setattr(attention, "GATHERED_KEYS_MAX", 0)
+    monkeypatch.setattr(attention, "PREFILL_KEY_BLOCK", 16)
 
 
 def _engine(model, variables, **kw):
@@ -80,6 +80,18 @@ def _ref_row(variables, c, seq):
     """The reference's logits for the last token of ``seq``."""
     return ref.logits_at(variables["params"], jnp.asarray([seq], jnp.int32),
                          jnp.asarray([[len(seq) - 1]]), ref_cfg(c))[0, 0]
+
+
+def _ref_rows(variables, c, seq, first: int):
+    """The reference's logits for the tokens of ``seq`` from index
+    ``first`` on. One padded length for every call (the reference is
+    causal, so what follows a token does not reach it): one compiled
+    program for every row."""
+    padded = np.zeros((1, 96), np.int32)
+    padded[0, :len(seq)] = seq
+    return np.asarray(ref.logits_at(
+        variables["params"], jnp.asarray(padded),
+        jnp.arange(first, len(seq))[None], ref_cfg(c)))[0]
 
 
 def test_the_tiny_preset_has_every_kind_of_layer(tiny):
@@ -125,15 +137,18 @@ def test_engine_prefill_and_decode_match_reference(tiny, impl):
     for seq in seqs:
         eng.prefill(eng.pool.alloc(), seq, max_new_tokens=40)
     active = np.ones(3, bool)
-    worst = 0.0
+    got = [[] for _ in seqs]            # each row's logits, step by step
     for _ in range(12):
-        for r, seq in enumerate(seqs):
-            worst = max(worst, float(jnp.abs(
-                eng.last_logits[r] - _ref_row(variables, model.cfg, seq)).max()))
+        for r, rows in enumerate(got):
+            rows.append(np.asarray(eng.last_logits[r]))
         tok, emitted = eng.step(active)
         assert emitted.tolist() == [1, 1, 1]
         for r, seq in enumerate(seqs):
             seq.append(int(tok[r, 0]))
+    # one reference call a row, over the tokens its compared rows saw
+    worst = max(float(np.abs(np.stack(rows) - _ref_rows(
+        variables, model.cfg, seq[:-1], len(seq) - 1 - len(rows))).max())
+        for rows, seq in zip(got, seqs))
     assert worst < F32_TOL
     assert eng.last_expert_load.shape == (4, 4)     # sparse layers x held
     # one state entry a slot; the growing group holds what was written

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from chipbench.reference import xing4 as ref
 from test_hyper_connections import bf16_maps
 from nezha_tpu.cli import common, serve as cli
-from nezha_tpu.models import mistral4
+from nezha_tpu.models import attention
 from nezha_tpu.models.xing4 import Xing4, Xing4Config, xing4
 from nezha_tpu.nn import hyper_connections
 from nezha_tpu.serve import Engine, Request, ServeConfig
@@ -62,8 +62,8 @@ def folded_tables(monkeypatch):
     time, as the deployment's 16,384-key table is; the engines here have
     tables of 96 keys, so the limit comes down to 0 and the key block to
     16, and every prefill below takes the deployment's path."""
-    monkeypatch.setattr(mistral4, "GATHERED_KEYS_MAX", 0)
-    monkeypatch.setattr(mistral4, "PREFILL_KEY_BLOCK", 16)
+    monkeypatch.setattr(attention, "GATHERED_KEYS_MAX", 0)
+    monkeypatch.setattr(attention, "PREFILL_KEY_BLOCK", 16)
 
 
 def _engine(model, variables, **kw):
