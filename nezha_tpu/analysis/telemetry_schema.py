@@ -105,6 +105,12 @@ _SERVE_COUNTERS = {"serve.admitted_total", "serve.rejected_total",
                    # model without such layers reports 0s.
                    "serve.ssm.decode_rows_total",
                    "serve.ssm.scan_chunks_total",
+                   # Block-sparse layers: blocks the decode queries read
+                   # and blocks their rows held, x K/V heads x layers.
+                   # Model-invariant: a model without such layers
+                   # reports 0s.
+                   "serve.sparse.blocks_read_total",
+                   "serve.sparse.blocks_resident_total",
                    # Cross-replica KV migration (PR 11, disaggregated
                    # prefill/decode tiers): committed installs and
                    # their int8-wire bytes. Topology-invariant: a

@@ -199,6 +199,10 @@ RANDOM_INIT_MODELS = {
     "falcon_h1": ("falcon_h1", "falcon_h1",
                   "Falcon-H1: Mamba-2 heads beside grouped-query attention "
                   "in every layer, a float32 state a slot"),
+    "minicpm_sala": ("minicpm_sala", "minicpm_sala",
+                     "MiniCPM-SALA: block-sparse attention over the paged "
+                     "table with a compressed-key cache beside lightning "
+                     "linear-attention layers"),
 }
 SERVED_MODELS = ("gpt2", *RANDOM_INIT_MODELS)
 

@@ -19,6 +19,8 @@ _LAZY = {
     "Xing4": "xing4", "Xing4Config": "xing4", "xing4": "xing4",
     "FalconH1": "falcon_h1", "FalconH1Config": "falcon_h1",
     "falcon_h1": "falcon_h1",
+    "MiniCPMSALA": "minicpm_sala", "MiniCPMSALAConfig": "minicpm_sala",
+    "minicpm_sala": "minicpm_sala",
     "generate": "generate", "init_cache": "generate",
     "gpt2_from_hf": "convert", "bert_from_hf": "convert",
     "gpt2_params_from_hf": "convert", "gpt2_params_to_hf": "convert",

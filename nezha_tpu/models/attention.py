@@ -9,6 +9,9 @@ paged pool.
   layer (the growing group) or a sliding one (a ring of blocks)
   (K-EXAONE; Falcon-H1 subclasses its projection). Per-head K/V rows,
   lane-dense: ``[N, bs, KVH*D]``.
+- :class:`SparseAttention`: MiniCPM4's block-sparse layer (InfLLM v2,
+  MiniCPM-SALA): the same K/V rows and a third leaf of compressed keys; a
+  decode query reads only the blocks it selects.
 
 Whether a decode step runs the paged kernels or their composed
 ``jax.numpy`` forms is the config's ``decode_impl``, resolved by
@@ -30,6 +33,7 @@ from nezha_tpu.ops import rotary
 from nezha_tpu.ops.pallas import (flash_decode_attention,
                                   latent_decode_attention,
                                   paged_attention_composed)
+from nezha_tpu.ops.pallas import sparse_attention as sparse_ops
 from nezha_tpu.ops.pallas.common import NEG_BIG, pick_block, use_kernels
 from nezha_tpu.tensor.policy import Policy
 
@@ -558,3 +562,254 @@ class GQAttention(Module):
             states["cache"] = {"k": kp, "v": vp, "tables": cache["tables"]}
         return project_f32(self.o, variables, "o", out), states
 
+
+
+class SparseAttention(Module):
+    """MiniCPM4's sparse layer (InfLLM v2): grouped-query attention with
+    no position encoding and per-head RMSNorm on ``q`` and ``k``, whose
+    decode query reads only the blocks it selects
+    (``ops/pallas/sparse_attention.py`` has the rule), and a sigmoid
+    output gate: ``W_o(o * sigmoid(x W_g))``. Its paged part holds three
+    leaves in the growing group: ``k``, ``v`` ``[bs, KVH*D]`` and ``ck``
+    ``[r*KVH, D]`` float32 (the compressed keys, ``r = bs / stride`` a
+    block). The pool's block is the selection's block.
+
+    Decode: ``sparse_score`` (every compressed key of the row, and the
+    window its own token completes, scored), the selection in
+    ``jax.numpy``, ``sparse_decode_paged`` over the selected list; the
+    completed window's mean is then written into ``ck``. A prefill chunk
+    (``jax.numpy``) writes its K/V rows and the windows it completes, then
+    folds the table ``PREFILL_KEY_BLOCK`` keys at a time under each
+    query's own selection, scored ``SCORE_QUERY_BLOCK`` queries at a time
+    (a chunk that lies wholly at or below ``dense_len`` scores nothing)."""
+
+    SCORE_QUERY_BLOCK = 256
+
+    def __init__(self, cfg, policy: Policy):
+        self.cfg, self.policy = cfg, policy
+        h, d = cfg.hidden_size, cfg.head_dim
+        heads, kvh = cfg.num_attention_heads, cfg.num_key_value_heads
+        self.sizes = cfg.sparse_sizes
+        self.q = linear(h, heads * d, policy)
+        self.k = linear(h, kvh * d, policy)
+        self.v = linear(h, kvh * d, policy)
+        self.q_norm = nn.RMSNorm(d, cfg.rms_norm_eps, policy)
+        self.k_norm = nn.RMSNorm(d, cfg.rms_norm_eps, policy)
+        self.o_gate = linear(h, heads * d, policy)
+        self.o = linear(heads * d, h, policy)
+
+    def project(self, variables: Variables, x):
+        """-> q [B,S,H,D], k [B,S,KVH,D] (both normed), v [B,S,KVH,D]."""
+        c = self.cfg
+        b, s, _ = x.shape
+        st: dict = {}
+        q = run_child(self.q, "q", variables, st, x).reshape(
+            b, s, c.num_attention_heads, c.head_dim)
+        k = run_child(self.k, "k", variables, st, x).reshape(
+            b, s, c.num_key_value_heads, c.head_dim)
+        v = run_child(self.v, "v", variables, st, x).reshape(
+            b, s, c.num_key_value_heads, c.head_dim)
+        q = run_child(self.q_norm, "q_norm", variables, st, q)
+        k = run_child(self.k_norm, "k_norm", variables, st, k)
+        return q, k, v
+
+    def _scale(self):
+        return self.cfg.head_dim ** -0.5
+
+    def _query_masks(self, q, ckeys, lengths):
+        """``q`` [Q, H, D] (one row's queries), ``ckeys`` [NC, KVH, D]
+        float32 (every window of the row's table; a window's score is
+        read only where it is complete at the query's ``lengths``) ->
+        the selected blocks [KVH, Q, M]."""
+        c, z = self.cfg, self.sizes
+        kvh = c.num_key_value_heads
+        qg = q.reshape(q.shape[0], kvh, -1, c.head_dim).astype(jnp.float32)
+        logits = jnp.einsum("qghd,jgd->gqhj", qg * self._scale(), ckeys,
+                            precision=lax.Precision.HIGHEST)
+        n_c = sparse_ops.n_complete(lengths, z)              # [Q]
+        valid = jnp.arange(ckeys.shape[0]) < n_c[:, None, None]
+        m = jnp.max(jnp.where(valid, logits, -jnp.inf), -1, keepdims=True)
+        p = jnp.where(valid, jnp.exp(logits - m), 0.0)
+        p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
+        scores = sparse_ops.block_scores(p.sum(2), n_c[None, :], z)
+        return sparse_ops.select_mask(scores, lengths[None, :], z)
+
+    def _chunk_masks(self, q, ckeys, lengths):
+        """:meth:`_query_masks` ``SCORE_QUERY_BLOCK`` queries at a time
+        (the scores of one block are ``[H, block, NC]`` float32)."""
+        s = q.shape[0]
+        qb = pick_block(s, self.SCORE_QUERY_BLOCK)
+        masks = lax.map(
+            lambda a: self._query_masks(a[0], ckeys, a[1]),
+            (q.reshape(s // qb, qb, *q.shape[1:]), lengths.reshape(-1, qb)))
+        return masks.transpose(1, 0, 2, 3).reshape(
+            masks.shape[1], s, masks.shape[-1])
+
+    def _attend_masked(self, q, keys, vals, visible):
+        """``q`` [Q,H,D], ``keys``/``vals`` [T,KVH,D], ``visible``
+        [KVH,Q,T] -> [Q,H*D]."""
+        c = self.cfg
+        kvh = c.num_key_value_heads
+        qg = q.reshape(q.shape[0], kvh, -1, c.head_dim)
+        sc = jnp.einsum("qghd,tgd->gqht", qg, keys.astype(q.dtype),
+                        preferred_element_type=jnp.float32) * self._scale()
+        sc = jnp.where(visible[:, :, None], sc, -jnp.inf)
+        p = jax.nn.softmax(sc, axis=-1).astype(vals.dtype)
+        o = jnp.einsum("gqht,tgd->qghd", p, vals)
+        return o.reshape(q.shape[0], -1)
+
+    def _dense(self, q, k, v):
+        """The cache-less forward: each query's own selection over the
+        sequence's complete windows."""
+        z = self.sizes
+        b, s, kvh, d = k.shape
+        m = -(-s // z.block)
+        lengths = jnp.arange(1, s + 1)
+
+        taps = (z.stride * jnp.arange(m * z.per_block)[:, None]
+                + jnp.arange(z.kernel))
+
+        def one(qr, kr, vr):
+            kpad = jnp.pad(kr.astype(jnp.float32),
+                           ((0, m * z.block + z.kernel - s), (0, 0), (0, 0)))
+            ckeys = kpad[taps].mean(1)                         # [NC, KVH, D]
+            masks = self._query_masks(qr, ckeys, lengths)      # [KVH,S,M]
+            t = jnp.arange(s)
+            visible = (jnp.repeat(masks, z.block, axis=-1)[..., :s]
+                       & (t[None, None, :] <= t[None, :, None]))
+            return self._attend_masked(qr, kr, vr, visible)
+
+        return jax.vmap(one)(q, k, v)
+
+    def _write_windows(self, kp, ck, tab, first, count, last_len):
+        """Write the windows ``first .. first + count - 1`` (a static
+        count; those at or past ``n_c(last_len)`` land on the scratch
+        block) of ONE row, each the mean of its keys read back from the
+        pool, into ``ck`` at their block's slot."""
+        z = self.sizes
+        bs, m = kp.shape[1], tab.shape[1]
+        kvh, d = self.cfg.num_key_value_heads, self.cfg.head_dim
+        j = first + jnp.arange(count)
+        keep = j < sparse_ops.n_complete(last_len, z)
+        p = jnp.clip(z.stride * j[:, None] + jnp.arange(z.kernel), 0,
+                     m * bs - 1)
+        rows = kp[tab[0, p // bs], p % bs].astype(jnp.float32)
+        means = rows.mean(1).reshape(count, kvh, d)
+        blk = jnp.where(keep, tab[0, jnp.clip(j // z.per_block, 0, m - 1)], 0)
+        row = ((j % z.per_block)[:, None] * kvh + jnp.arange(kvh))
+        return ck.at[blk[:, None], row].set(means)
+
+    def _prefill(self, q, k, v, cache, pos):
+        """A chunk of ``S`` tokens of one row at the traced offset
+        ``pos``; only the first ``cache["valid"]`` are real."""
+        z = self.sizes
+        kp, vp, ck, tab = cache["k"], cache["v"], cache["ck"], cache["tables"]
+        b, s = q.shape[:2]
+        bs, m = kp.shape[1], tab.shape[1]
+        valid = cache.get("valid", s)
+        local = jnp.arange(s)
+        p_abs = pos + local
+        keep = (local < valid) & (p_abs < m * bs)
+        blk = jnp.where(keep[None, :],
+                        tab[:, jnp.clip(p_abs // bs, 0, m - 1)], 0)
+        off = jnp.where(keep, p_abs % bs, 0)[None, :]
+        kp = kp.at[blk, off, :].set(k.reshape(b, s, -1).astype(kp.dtype))
+        vp = vp.at[blk, off, :].set(v.reshape(b, s, -1).astype(vp.dtype))
+        # the windows this chunk completes
+        ck = self._write_windows(kp, ck, tab, sparse_ops.n_complete(pos, z),
+                                 s // z.stride + 1, pos + valid)
+        lengths = p_abs + 1
+        kvh, d = k.shape[2], k.shape[3]
+        ckeys = ck[tab[0]].reshape(m * z.per_block, kvh, d)
+        masks = lax.cond(
+            pos + valid > z.dense_len,
+            lambda: self._chunk_masks(q[0], ckeys, lengths),
+            lambda: jnp.broadcast_to(
+                jnp.arange(m)[None, None, :] * bs < lengths[None, :, None],
+                (kvh, s, m)))
+        e = pick_block(m, max(1, PREFILL_KEY_BLOCK // bs))
+        span = e * bs
+        qg = q.reshape(b, s, kvh, -1, d).transpose(0, 2, 3, 1, 4)
+
+        def fold(i, carry):
+            ent = lax.dynamic_slice_in_dim(tab, i * e, e, axis=1)
+            kk = kp[ent].reshape(b, span, kvh, d).astype(q.dtype)
+            vv = vp[ent].reshape(b, span, kvh, d).astype(q.dtype)
+            sc = jnp.einsum("bkgsd,blkd->bkgsl", qg, kk,
+                            preferred_element_type=jnp.float32) * self._scale()
+            kpos = i * span + jnp.arange(span)
+            sel = jnp.repeat(lax.dynamic_slice_in_dim(masks, i * e, e, axis=2),
+                             bs, axis=2)                     # [KVH, S, span]
+            visible = (kpos[None, :] <= p_abs[:, None])[None] & sel
+            return _fold(carry, sc, visible[None, :, None], vv)
+
+        shape = qg.shape[:-1] + (1,)
+        init = (jnp.full(shape, NEG_BIG, jnp.float32),
+                jnp.zeros(shape, jnp.float32),
+                jnp.zeros(qg.shape, jnp.float32))
+        blocks = jnp.minimum((pos + s + span - 1) // span, m // e)
+        _, l, acc = lax.fori_loop(0, blocks, fold, init)
+        out = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
+        out = out.transpose(0, 3, 1, 2, 4).reshape(b, s, -1)
+        return out, {"k": kp, "v": vp, "ck": ck}
+
+    def _decode(self, q, k, v, cache, pos, active):
+        """One token a row at its own depth: write its K/V rows, score,
+        select, attend the selected list, write the window its token
+        completed."""
+        c, z = self.cfg, self.sizes
+        kp, vp, ck, tab = cache["k"], cache["v"], cache["ck"], cache["tables"]
+        b = q.shape[0]
+        bs, m = kp.shape[1], tab.shape[1]
+        kvh, d = c.num_key_value_heads, c.head_dim
+        pos_w = jnp.minimum(pos, m * bs - 1)
+        blk = jnp.take_along_axis(tab, (pos_w // bs)[:, None], axis=1)[:, 0]
+        off = pos_w % bs
+        lengths = pos + 1
+        if active is not None:
+            blk, off = jnp.where(active, blk, 0), jnp.where(active, off, 0)
+            lengths = jnp.where(active, lengths, 0)
+        kp = kp.at[blk, off, :].set(k.reshape(b, -1).astype(kp.dtype))
+        vp = vp.at[blk, off, :].set(v.reshape(b, -1).astype(vp.dtype))
+        kernels = use_kernels(c.decode_impl)
+        score = (sparse_ops.sparse_score if kernels
+                 else sparse_ops.sparse_score_reference)
+        s, fresh = score(q[:, 0], ck, kp, tab, lengths, self._scale(), z)
+        n_c = sparse_ops.n_complete(lengths, z)
+        scores = sparse_ops.block_scores(s.transpose(0, 2, 1), n_c[:, None], z)
+        mask = sparse_ops.select_mask(scores, lengths[:, None], z)
+        ids, keys = sparse_ops.select_list(mask, lengths, z, z.list_len(m))
+        attend = (sparse_ops.sparse_decode_paged if kernels
+                  else sparse_ops.sparse_decode_reference)
+        out = attend(q[:, 0], kp, vp, tab, ids, keys, self._scale())
+        # the window this token completed, into its block's slot
+        due = (lengths >= z.kernel) & ((lengths - z.kernel) % z.stride == 0)
+        j = jnp.maximum(n_c - 1, 0)
+        wblk = jnp.take_along_axis(
+            tab, jnp.clip(j // z.per_block, 0, m - 1)[:, None], axis=1)[:, 0]
+        wblk = jnp.where(due, wblk, 0)
+        row = (j % z.per_block)[:, None] * kvh + jnp.arange(kvh)
+        ck = ck.at[wblk[:, None], row].set(fresh.reshape(b, kvh, d))
+        return out.reshape(b, 1, -1), {"k": kp, "v": vp, "ck": ck}
+
+    def apply(self, variables: Variables, x, training: bool = False, rng=None,
+              cache=None, pos=None, prefill: bool = False, active=None):
+        del training, rng, prefill
+        s = x.shape[1]
+        q, k, v = self.project(variables, x)
+        states: dict = {}
+        if cache is None:
+            out = self._dense(q, k, v)
+        else:
+            if getattr(pos, "ndim", 0) == 1:
+                if s > 1:
+                    raise ValueError(
+                        "multi-token steps at per-row positions "
+                        "(speculative verify) are not implemented")
+                out, leaves = self._decode(q, k, v, cache, pos, active)
+            else:
+                out, leaves = self._prefill(q, k, v, cache, pos)
+            states["cache"] = {**leaves, "tables": cache["tables"]}
+        gate = jax.nn.sigmoid(project_f32(self.o_gate, variables, "o_gate", x))
+        out = (out.astype(jnp.float32) * gate).astype(out.dtype)
+        return project_f32(self.o, variables, "o", out), states

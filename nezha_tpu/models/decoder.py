@@ -2,7 +2,7 @@
 their layers from.
 
 A served decoder (Mistral-Small-4, K-EXAONE, Kimi-Linear, Xing4.0,
-Falcon-H1) is an embedding of the ``vocab_held`` rows it holds, one
+Falcon-H1, MiniCPM-SALA) is an embedding of the ``vocab_held`` rows it holds, one
 ``Block(cfg, layer, policy)`` a layer, a final RMSNorm and an untied head:
 :class:`DecoderLM`. A model file supplies its config, its presets, its
 ``Block`` and mixers, and ``cache_leaves``; the shell keeps the rest of
@@ -96,9 +96,12 @@ class DecoderLM(Module):
     # What serve.Engine reads of a model beside its methods: layers
     # whose recurrent state a decode step updates (``serve.ssm.*``), and
     # hyper-connection maps a token passes (``serve.mhc.*``; such a
-    # model also has ``mhc_residual(states)``).
+    # model also has ``mhc_residual(states)``), and layers whose decode
+    # query selects blocks (``serve.sparse.*``; such a model also has
+    # ``sparse_blocks(lengths)``).
     ssm_layers = 0
     mhc_sublayers = 0
+    sparse_attention_layers = 0
 
     def __init__(self, cfg, policy: Policy = DEFAULT_POLICY):
         if not 1 <= cfg.vocab_held <= cfg.vocab_size:

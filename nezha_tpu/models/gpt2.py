@@ -1051,10 +1051,11 @@ class GPT2(Module):
 
 
     # ------------------------------------- what serve.Engine asks a model
-    # No recurrent-state layers, no hyper-connection maps
-    # (``models.decoder.DecoderLM`` has both counts).
+    # No recurrent-state layers, no hyper-connection maps, no block
+    # selection (``models.decoder.DecoderLM`` has the three counts).
     ssm_layers = 0
     mhc_sublayers = 0
+    sparse_attention_layers = 0
 
     def cache_leaves(self, block_size: int, dtype, quantized: bool = False
                      ) -> list:

@@ -17,6 +17,10 @@ are Mosaic/Pallas kernels tiled for MXU/VPU and VMEM:
   attention layer (`kda.py`, with its chunked and recurrent forms).
 - `ssm_decode`: the one-pass state update of a Mamba-2 (SSD) layer
   (`ssm.py`, `nezha_ssm_decode`, with its chunked and recurrent forms).
+- `sparse_score` / `sparse_decode_paged`: InfLLM-v2 block-sparse decode
+  attention (`sparse_attention.py`, `nezha_sparse_score`,
+  `nezha_sparse_decode_paged`): compressed keys scored, then a list of
+  selected blocks of the paged table attended.
 - `moe_experts`: the routed experts of a dropless layer as one grouped
   matmul (`nezha_moe_experts`: gate, up, SiLU and down over the pair rows
   sorted by held expert; a touched expert's weights cross HBM once a call,
@@ -57,6 +61,13 @@ from nezha_tpu.ops.pallas.moe_experts import (
     moe_experts,
     moe_experts_reference,
 )
+from nezha_tpu.ops.pallas.sparse_attention import (
+    SparseSizes,
+    sparse_decode_paged,
+    sparse_decode_reference,
+    sparse_score,
+    sparse_score_reference,
+)
 from nezha_tpu.ops.pallas.ssm import (
     ssd_chunked,
     ssd_recurrent,
@@ -76,5 +87,7 @@ __all__ = ["flash_attention", "flash_decode_attention",
            "kda_recurrent", "latent_attention_composed",
            "latent_decode_attention", "mhc_post", "mhc_pre", "moe_experts",
            "moe_experts_reference", "paged_attention_composed",
-           "ring_entries", "ssd_chunked", "ssd_recurrent", "ssm_decode",
+           "ring_entries", "SparseSizes", "sparse_decode_paged",
+           "sparse_decode_reference", "sparse_score",
+           "sparse_score_reference", "ssd_chunked", "ssd_recurrent", "ssm_decode",
            "ssm_decode_reference"]

@@ -69,21 +69,24 @@ def _ssm_decode_kernel(ent_ref, rows_ref, cols_ref, s_ref, y_ref, s_out_ref,
                        *, per_group: int):
     """One grid step = one row, all heads. ``rows_ref`` ``[1, H, 2, P]``
     holds a head's ``dt * x`` and its decay ``exp(dt * a)`` (the scalar
-    repeated over the lanes) as two ROW vectors; ``cols_ref`` ``[1, G, N,
-    2]`` a group's ``B`` and ``C`` as two COLUMN vectors (d_state on
-    sublanes): so the write ``B (dt x)^T`` is a column times a row and
-    ``y = S^T C`` a sum over sublanes, and no vector is turned in the
-    kernel."""
+    repeated over the lanes) as two ROW vectors; ``cols_ref`` ``[1, G, 2,
+    N]`` a group's ``B`` and ``C`` as two rows, turned once a group into
+    two COLUMN vectors (d_state on sublanes): so the write ``B (dt x)^T``
+    is a column times a row and ``y = S^T C`` a sum over sublanes. The
+    rows lie dense in HBM whatever ``G``: a ``[.., N, 2]`` operand would
+    take a 128-lane tile a pair (64 times its bytes: 0.4 GB a call at 32
+    groups of 128 and 192 rows)."""
     del ent_ref     # read by the index maps
     heads = s_ref.shape[1]
-    for h in range(heads):
-        g = h // per_group
-        dx = rows_ref[0, h, 0:1, :]                           # [1, P]
-        decay = rows_ref[0, h, 1:2, :]                        # [1, P]
-        st = s_ref[0, h] * decay + cols_ref[0, g, :, 0:1] * dx
-        s_out_ref[0, h] = st
-        y_ref[0, h:h + 1, :] = jnp.sum(st * cols_ref[0, g, :, 1:2], axis=0,
-                                       keepdims=True)
+    for g in range(heads // per_group):
+        bc = cols_ref[0, g].T                                 # [N, 2]
+        for h in range(g * per_group, (g + 1) * per_group):
+            dx = rows_ref[0, h, 0:1, :]                       # [1, P]
+            decay = rows_ref[0, h, 1:2, :]                    # [1, P]
+            st = s_ref[0, h] * decay + bc[:, 0:1] * dx
+            s_out_ref[0, h] = st
+            y_ref[0, h:h + 1, :] = jnp.sum(st * bc[:, 1:2], axis=0,
+                                           keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -96,14 +99,14 @@ def _ssm_decode_call(s_pool, entries, x, dt, a, b, c, interpret):
     rows = jnp.stack([dt[..., None] * x.astype(f32),
                       jnp.broadcast_to(decay[..., None], x.shape)],
                      axis=2)                                  # [B, H, 2, P]
-    cols = jnp.stack([b.astype(f32), c.astype(f32)], axis=-1)  # [B, G, N, 2]
+    cols = jnp.stack([b.astype(f32), c.astype(f32)], axis=2)   # [B, G, 2, N]
     row = lambda b_, ent: (b_, 0, 0, 0)                       # noqa: E731
     state = pl.BlockSpec((1, heads, n, p),
                          lambda b_, ent: (ent[b_], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=(bsz,),
         in_specs=[pl.BlockSpec((1, heads, 2, p), row),
-                  pl.BlockSpec((1, groups, n, 2), row), state],
+                  pl.BlockSpec((1, groups, 2, n), row), state],
         out_specs=[pl.BlockSpec((1, heads, p), lambda b_, ent: (b_, 0, 0)),
                    state])
     return pl.pallas_call(

@@ -1322,6 +1322,8 @@ class Engine:
         self.budgets = budgets
         bound = np.where(active, np.clip(self.host_budgets, 0,
                                          self.cfg.decode_horizon), 0)
+        if self.model.sparse_attention_layers:
+            self._count_sparse_blocks(bound)
         self.host_positions += bound
         self.host_budgets -= bound
         return _Block(self.step_calls, int(np.count_nonzero(active)),
@@ -1365,6 +1367,20 @@ class Engine:
         self.host_positions -= short
         self.host_budgets += short
         return tok_h, emitted_h
+
+    def _count_sparse_blocks(self, bound: np.ndarray) -> None:
+        """The blocks the sparse layers' decode queries of a launched
+        block read and the blocks their rows hold, by the model's
+        selection rule at each token's visible length (the host mirrors
+        before the launch, no device read): ``serve.sparse.
+        blocks_read_total`` and ``serve.sparse.blocks_resident_total``."""
+        read = held = 0
+        for i in range(int(bound.max(initial=0))):
+            r, h = self.model.sparse_blocks(
+                np.where(bound > i, self.host_positions + 1 + i, 0))
+            read, held = read + r, held + h
+        obs.counter("serve.sparse.blocks_read_total").inc(read)
+        obs.counter("serve.sparse.blocks_resident_total").inc(held)
 
     def _record_mhc(self, tokens: int, residual) -> None:
         """The residual path's maps of one step (``tokens`` rows) and of

@@ -311,6 +311,12 @@ def register_serve_instruments() -> None:
     # and the chunks a prefill program's scan walked x layers.
     obs.counter("serve.ssm.decode_rows_total")
     obs.counter("serve.ssm.scan_chunks_total")
+    # Block-sparse layers (a model with ``sparse_attention_layers`` only;
+    # the engine counts them from the rows' positions and the selection
+    # rule): blocks the decode queries read and blocks their rows held,
+    # x K/V heads x layers; read over resident is the share a step reads.
+    obs.counter("serve.sparse.blocks_read_total")
+    obs.counter("serve.sparse.blocks_resident_total")
     # Tiered KV host spill (PR 15): trie blocks demoted to host RAM on
     # eviction instead of discarded, and blocks promoted back on a
     # returning prefix hit; occupancy gauges for the host-side LRU.

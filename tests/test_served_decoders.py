@@ -1,4 +1,4 @@
-"""What a served decoder is made of. The five served decoders share one
+"""What a served decoder is made of. The six served decoders share one
 shell (``models/decoder.py``) and one set of attention mixers
 (``models/attention.py``) and import no other model module: an edit to
 one model's file cannot break another's."""
@@ -10,7 +10,8 @@ import pytest
 
 _MODELS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "nezha_tpu", "models")
-SERVED = ("mistral4", "exaone_moe", "kimi_linear", "xing4", "falcon_h1")
+SERVED = ("mistral4", "exaone_moe", "kimi_linear", "xing4", "falcon_h1",
+          "minicpm_sala")
 SHARED = {"decoder", "attention"}
 
 

@@ -318,8 +318,10 @@ def test_nested_shard_map_kernel_compiles_for_v5e_mesh4(v5e_devices, case):
 # Before PR 27 (``[N, H, bs, D]``, a 64-wide minor dimension) every program
 # copied each pool three times: 86.7% of the cell's device time.
 def _serve_programs(model, quantized, v5e, *, slots, table, block, chunk,
-                    logits):
-    """{"step" | "prefill": compiled program} of ``model`` for one v5e."""
+                    logits, growing_blocks=None):
+    """{"step" | "prefill": compiled program} of ``model`` for one v5e.
+    ``growing_blocks``: the growing group's blocks (``--kv-num-blocks``;
+    default every slot's whole table)."""
     from nezha_tpu.ops.pallas import ring_entries
     from nezha_tpu.serve.engine import _build_prefill, _build_step
 
@@ -338,7 +340,12 @@ def _serve_programs(model, quantized, v5e, *, slots, table, block, chunk,
     def entries(group, w):      # table entries a slot, by kind of group
         return 1 if group == "state" else ring.get(w, table)
 
-    caches = [{name: spec((1 + slots * entries(g, w),) + tuple(shape), dt)
+    def blocks(group, w):
+        if group == "global" and growing_blocks:
+            return growing_blocks
+        return 1 + slots * entries(group, w)
+
+    caches = [{name: spec((blocks(g, w),) + tuple(shape), dt)
                for g, w, leaves in layer
                for name, (shape, dt) in leaves.items()}
               for layer in layers]
